@@ -89,7 +89,8 @@ def main(argv: list[str] | None = None) -> int:
         return 0
 
     from .framework.scheduler import Scheduler
-    from .runtime import snapshot
+    from .runtime import compile_cache, snapshot
+    compile_cache.enable()
     if not args.snapshot:
         parser.error(f"{args.command} requires --snapshot")
     cluster = snapshot.load(args.snapshot)

@@ -65,7 +65,7 @@ import numpy as np
 
 from ..intake import apply as intake_apply
 from ..intake.router import IntakeConfig, IntakeRouter
-from ..runtime import compile_watch, wire_ledger
+from ..runtime import compile_cache, compile_watch, wire_ledger
 from ..runtime.cluster import Cluster
 from ..runtime.snapshot import dump_cluster, load_cluster
 from . import metrics
@@ -726,6 +726,7 @@ class SchedulerServer:
             self.intake.coalesce(self.cluster)
 
     def start(self) -> "SchedulerServer":
+        compile_cache.enable()
         self._thread = threading.Thread(
             target=self._httpd.serve_forever, daemon=True)
         self._thread.start()

@@ -46,6 +46,7 @@ from jax import lax
 from ..apis.types import UNLIMITED
 from ..runtime import compile_watch
 from ..state.cluster_state import ClusterState
+from ..utils.numerics import einsum_exact
 from . import ordering
 from .predicates import feasible_nodes, feasible_nodes_dual, node_portion
 from .scoring import (BIG_NEG, W_NOMINATED, W_TOPOLOGY, PlacementConfig,
@@ -574,7 +575,7 @@ def _attempt_gang_in_domain(
         # folded into the QUEUE accel ledger in-cycle so MIG-heavy
         # queues hit quota/over-share gates the same cycle they place;
         # node pools keep tracking the extended scalars themselves
-        ext_gq = task_ext @ g.ext_accel      # [T]
+        ext_gq = einsum_exact("te,e->t", task_ext, g.ext_accel)  # [T]
     if ext_free is None:
         ext_free = n.extended_free
     if extra_extended_releasing is None:
@@ -965,8 +966,13 @@ def _attempt_gang_in_domain_uniform(
     already_count = jnp.sum(already.astype(jnp.int32))
     unplaced = tcount - already_count
     goal = jnp.minimum(quota, unplaced)
-    prior_on_node = jnp.zeros((N,), jnp.int32).at[
-        jnp.maximum(prior_nodes, 0)].add(already.astype(jnp.int32)) > 0
+    # [T, N] compare-and-any, not a scatter-add into zeros: the flag is
+    # all the kernel reads, T is small, and the TPU compiler's scatter
+    # emitter rejects the scatter once it fuses with this predicate
+    # (unplaced slots hold -1 and match no node)
+    prior_on_node = jnp.any(
+        prior_nodes[:, None] == jnp.arange(N, dtype=jnp.int32)[None, :],
+        axis=0)
 
     # ---- queue capacity gate: max replicas within every ancestor cap ----
     limit_eff = jnp.where(state.queues.limit <= UNLIMITED + 0.5,
@@ -1382,7 +1388,8 @@ def allocate(
             # the snapshot rollups and the placement queue delta do
             gang_req_all = gang_req_all.at[:, 0].add(jnp.sum(jnp.where(
                 g.task_valid & remaining0[:, None],
-                g.task_extended @ g.ext_accel, 0.0), axis=1))
+                einsum_exact("gte,e->gt", g.task_extended,
+                             g.ext_accel), 0.0), axis=1))
         # exclusive per-queue cumulative request along the static job
         # order, O(G·R): queue-major sort, one cumsum, subtract each
         # queue's segment-start prefix (a [G, Q, R] one-hot cumsum
@@ -1786,13 +1793,13 @@ def allocate(
                             mode="drop")
             free = free - upd[:n.n]
         else:
-            free = free - jnp.einsum("b,bnr->nr", w, d_free)
-        qa = qa + jnp.einsum("b,bqr->qr", w, d_qa)
-        qan = qan + jnp.einsum("b,bqr->qr", w, d_qan)
+            free = free - einsum_exact("b,bnr->nr", w, d_free)
+        qa = qa + einsum_exact("b,bqr->qr", w, d_qa)
+        qan = qan + einsum_exact("b,bqr->qr", w, d_qan)
         if config.track_devices:
-            dev = dev - jnp.einsum("b,bnd->nd", w, d_dev)
+            dev = dev - einsum_exact("b,bnd->nd", w, d_dev)
         if config.extended:
-            ext = ext - jnp.einsum("b,bne->ne", w, d_ext)
+            ext = ext - einsum_exact("b,bne->ne", w, d_ext)
 
         nodes_b = jnp.where(take[:, None], nodes_b, -1)
         devt_b = jnp.where(take[:, None], devt_b, -1)

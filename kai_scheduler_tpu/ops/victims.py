@@ -61,7 +61,7 @@ from jax import lax
 
 from ..apis.types import UNLIMITED
 from ..runtime import compile_watch
-from ..utils.numerics import cumsum_ds
+from ..utils.numerics import cumsum_ds, einsum_exact
 from ..state.cluster_state import ClusterState
 from . import ordering
 from .allocate import (AllocateConfig, AllocationResult, _ancestor_gate,
@@ -176,8 +176,8 @@ def freed_by_mask(state: ClusterState, mask: jax.Array, chain: jax.Array):
         jnp.where(mask & ~r.preemptible, jnp.maximum(r.queue, 0), q.q),
         num_segments=q.q + 1)[:q.q]
     chain_f = chain.astype(leaf.dtype)
-    freed_q = jnp.einsum("qa,qr->ar", chain_f, leaf)
-    freed_q_np = jnp.einsum("qa,qr->ar", chain_f, leaf_np)
+    freed_q = einsum_exact("qa,qr->ar", chain_f, leaf)
+    freed_q_np = einsum_exact("qa,qr->ar", chain_f, leaf_np)
     # extended (MIG) scalars held by the victims return to their node's
     # pool — the credit-back that lets a preemptor reclaim a MIG slice
     freed_ext = jax.ops.segment_sum(
@@ -794,8 +794,8 @@ def _freed_by_lane(state: ClusterState, lane: jax.Array, B: int,
         req_m, seg_q, num_segments=(B + 1) * (Q + 1))
     leaf_own = per_q.reshape(B + 1, Q + 1, -1)[:B, :Q]         # [B, Q, R]
     leaf_cum = jnp.cumsum(leaf_own, axis=0) if compose else leaf_own
-    freed_q = jnp.einsum("qa,bqr->bar", chain.astype(req_m.dtype),
-                         leaf_cum)
+    freed_q = einsum_exact("qa,bqr->bar", chain.astype(req_m.dtype),
+                           leaf_cum)
     freed_e = None
     if extended:
         per_e = jax.ops.segment_sum(
@@ -1123,7 +1123,7 @@ def _run_victim_action_chunked(
                 # the full cumulative.
                 seg_incl = (same_q_b & (lanes[None, :] <= lanes[:, None])
                             & cand_valid[None, :])               # [B, B]
-                cum_req_q = jnp.einsum(
+                cum_req_q = einsum_exact(
                     "bc,cr->br", seg_incl.astype(lane_req.dtype), lane_req)
                 targets = cum_req_q - cluster_free[None, :] - EPS
             need_b = cand_valid & jnp.any(targets > 0, axis=-1)
@@ -1213,7 +1213,8 @@ def _run_victim_action_chunked(
                 # already-consumed rollup S_cons) stays above fair share
                 # — or above deserved quota when the reclaimer is under
                 # its own quota.
-                S_cons = jnp.einsum("va,vr->ar", chain_f, Cv_at_c)  # [Q, R]
+                S_cons = einsum_exact("va,vr->ar", chain_f,
+                                      Cv_at_c)               # [Q, R]
                 thr_fs = (qa - fair_share - EPS + S_cons).reshape(-1)
                 bnd_fs = jnp.max(jax.vmap(
                     jnp.searchsorted, in_axes=(1, 0))(
@@ -1577,21 +1578,21 @@ def _run_victim_action_chunked(
                     jnp.where(take_e[:, None], req_b[lane_e], 0.0),
                     mode="drop")
                 new_free = free - upd[:n.n]
-                new_extra = extra + jnp.einsum("b,bnr->nr", w, freed_n_b)
-                new_qa = (qa - jnp.einsum("b,bqr->qr", w, freed_q_b)
-                          + jnp.einsum("b,bqr->qr", w, d_qa))
+                new_extra = extra + einsum_exact("b,bnr->nr", w, freed_n_b)
+                new_qa = (qa - einsum_exact("b,bqr->qr", w, freed_q_b)
+                          + einsum_exact("b,bqr->qr", w, d_qa))
             else:
-                new_free = free - jnp.einsum("b,bnr->nr", w, d_free)
+                new_free = free - einsum_exact("b,bnr->nr", w, d_free)
                 new_extra = sel(extra_b, extra)
                 new_qa = (sel(qa_eff_b, qa)
-                          + jnp.einsum("b,bqr->qr", w, d_qa))
+                          + einsum_exact("b,bqr->qr", w, d_qa))
             res = res.replace(
                 free=new_free,
-                device_free=(dev - jnp.einsum(
+                device_free=(dev - einsum_exact(
                     "b,bnd->nd", w,
                     jnp.where(okm, dev[None] - dev2_b, 0.0))
                     if (not sparse) and pcfg.track_devices else dev),
-                extended_free=(ext - jnp.einsum(
+                extended_free=(ext - einsum_exact(
                     "b,bne->ne", w,
                     jnp.where(okm, ext[None] - ext2_b, 0.0))
                     if (not sparse) and pcfg.extended else ext),
@@ -1602,7 +1603,7 @@ def _run_victim_action_chunked(
                                           if track_ext else ext_extra),
                 queue_allocated=new_qa,
                 queue_allocated_nonpreemptible=(
-                    qan + jnp.einsum("b,bqr->qr", w, d_qan)),
+                    qan + einsum_exact("b,bqr->qr", w, d_qan)),
                 placements=res.placements.at[cand_g].set(
                     jnp.where(take[:, None], nodes_b,
                               res.placements[cand_g])),
@@ -1886,8 +1887,8 @@ def run_victim_action(
         cand_leaf = jax.ops.segment_sum(
             jnp.where(base[:, None], r.req, 0.0), rq,
             num_segments=q.q + 1)[:q.q]                        # [Q, R]
-        freeable = jnp.einsum("qa,qr->ar", chain.astype(cand_leaf.dtype),
-                              cand_leaf)
+        freeable = einsum_exact(
+            "qa,qr->ar", chain.astype(cand_leaf.dtype), cand_leaf)
         qa_lower = jnp.maximum(result.queue_allocated - freeable, 0.0)
         viable = viable & jax.vmap(
             lambda qi, tr: _ancestor_gate(

@@ -15,9 +15,8 @@ decode into whichever step first blocks (historically all of it landed
 in ``commit_seconds``).  The cycle driver therefore records explicit
 **device-sync markers** (``device_sync=True`` spans) around the first
 blocking transfer, splitting the old commit wall into
-``device_wait`` / ``host_decode`` / ``commit`` — the attribution
-ROADMAP item 1 (breaking the ~109 ms host↔device link floor) needs
-before any of that floor can be attacked.
+``device_wait`` / ``host_decode`` / ``commit`` — the attribution any
+attack on host↔device transfer cost needs first.
 
 Concurrency model: span recording is **thread-local** — each thread
 owns the trace of the cycle it is running (an open trace is reachable

@@ -15,8 +15,21 @@ scan — the TPU-native answer to "compute it in float64".
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+
+#: ``jnp.einsum`` for the mask-times-amount contractions that update the
+#: free pool and the queue ledgers (accepted-lane deltas, ancestor-chain
+#: rollups).  At default precision the TPU multiplies f32 operands in
+#: ONE bf16 pass, which rounds the amounts themselves (13.7 GiB becomes
+#: 13.6875) — every accepted placement would then leak into ``free`` and
+#: ``queue_allocated``.  HIGHEST splits each f32 into three bf16 pieces,
+#: so a 0/1 mask times an amount is exact; the products are tiny next
+#: to the rest of a chunk step.
+einsum_exact = functools.partial(jnp.einsum,
+                                 precision=jax.lax.Precision.HIGHEST)
 
 
 def _two_sum(a: jax.Array, b: jax.Array):

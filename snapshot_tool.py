@@ -127,6 +127,8 @@ def _record(out: str, opts: dict) -> int:
 
 
 def main(argv: list[str]) -> int:
+    from kai_scheduler_tpu.runtime import compile_cache
+    compile_cache.enable()
     args = argv[1:]
     if not args or args[0] not in ("dump", "replay", "record"):
         print(__doc__, file=sys.stderr)

@@ -1,0 +1,159 @@
+"""The main path's device programs compile for a described v5e chip.
+
+The TPU compiler is installed in the sandbox and compiles for a chip
+that is described, not attached (on-chip-measurement guide, section 2).
+Before PR 23 it aborted the process — a fatal check in its scatter
+emitter, not a Python exception — on every victim action of the default
+cycle, at any size; these four compiles guard that at the saturated
+64-node shape where the abort reproduced.  Nothing runs: a compile that
+passes says what the compiler accepts, never what the chip does.
+
+Only one process at a time may load the TPU library, and it keeps it
+until it exits: the topology is described inside a module-scoped
+fixture (never at import, never in ``conftest.py``), the compiles run in
+the test's own process, and every such test lives in THIS file so that
+xdist hands them all to one worker.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe skips
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # conftest compiles the suite at -O0; that would skip the very
+    # passes under test.  And a described-chip executable is written to
+    # the persistent cache but cannot be read back without a chip.
+    saved = (jax.config.read("jax_disable_most_optimizations"),
+             jax.config.jax_enable_compilation_cache)
+    jax.config.update("jax_disable_most_optimizations", False)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_disable_most_optimizations", saved[0])
+    jax.config.update("jax_enable_compilation_cache", saved[1])
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def saturated():
+    """64 nodes x 4 accelerators filled exactly by 32 running gangs of
+    8; 8 pending gangs sit in under-served queues, so every victim
+    action has work.  The session's auto-tuned config is the one
+    production would compile."""
+    from kai_scheduler_tpu.framework.session import Session
+    from kai_scheduler_tpu.state import make_cluster
+    return Session.open(*make_cluster(
+        num_nodes=64, node_accel=4.0, num_gangs=40, tasks_per_gang=8,
+        running_fraction=0.8, queue_accel_quota=6.4,
+        partition_queues_by_running=True, seed=0))
+
+
+def _shapes(tree, sharding):
+    """ShapeDtypeStructs of ``tree`` placed by ``sharding`` — one
+    sharding for every leaf, or a matching pytree of them."""
+    if not isinstance(sharding, jax.sharding.Sharding):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            tree, sharding)
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _pipeline_kwargs(cfg):
+    from kai_scheduler_tpu.framework.scheduler import SchedulerConfig
+    return dict(actions=SchedulerConfig().actions,
+                num_levels=cfg.num_levels, acfg=cfg.allocate,
+                vcfg=cfg.victims, grace_s=cfg.stale_grace_s)
+
+
+def _fits_one_chip(compiled):
+    m = compiled.memory_analysis()
+    total = (m.temp_size_in_bytes + m.argument_size_in_bytes
+             + m.output_size_in_bytes + m.generated_code_size_in_bytes)
+    assert 0 < total < 16e9
+
+
+def test_fused_five_actions_compile(topo, saturated):
+    """The classic entry: ``_fused_pipeline`` as ``run_once`` calls it."""
+    from kai_scheduler_tpu.framework import scheduler as S
+    one = SingleDeviceSharding(topo.devices[0])
+    st = _shapes(saturated.state, one)
+    compiled = S._fused_pipeline.__kai_jit__.lower(
+        st, st.queues.fair_share,
+        **_pipeline_kwargs(saturated.config)).compile()
+    _fits_one_chip(compiled)
+
+
+def test_resident_cycle_compiles_with_donation(topo, saturated):
+    """The fused resident entry exactly as ``_resident_jit`` builds it
+    off-CPU: state donated (it never is on the CPU backend)."""
+    from kai_scheduler_tpu.framework import scheduler as S
+    from kai_scheduler_tpu.ops import resident as resident_ops
+    one = SingleDeviceSharding(topo.devices[0])
+    state, cfg = saturated.state, saturated.config
+    tmpl = resident_ops.empty_delta(state)
+    seg = resident_ops.MIN_BUCKET
+    delta = {
+        "idx": {k: jax.ShapeDtypeStruct((seg,), jnp.int32, sharding=one)
+                for k in tmpl["idx"]},
+        "val": {k: jax.ShapeDtypeStruct((seg,), v.dtype, sharding=one)
+                for k, v in tmpl["val"].items()}}
+    fn = jax.jit(S.resident_cycle, donate_argnums=(0,),
+                 static_argnames=S.RESIDENT_STATIC_ARGNAMES)
+    compiled = fn.lower(
+        _shapes(state, one), delta,
+        jax.ShapeDtypeStruct((state.gangs.g,), jnp.float32, sharding=one),
+        jax.ShapeDtypeStruct((), jnp.float32, sharding=one),
+        track_devices=saturated.index.needs_device_table,
+        analytics_cfg=cfg.analytics, **_pipeline_kwargs(cfg)).compile()
+    _fits_one_chip(compiled)
+    # donation took: the returned state aliases the donated buffers
+    assert compiled.memory_analysis().alias_size_in_bytes > 0
+
+
+def test_headline_allocate_compiles(topo):
+    """``bench.py``'s headline step — fair share + allocate on an empty
+    cluster under the session's tuned config."""
+    from kai_scheduler_tpu.framework.session import Session
+    from kai_scheduler_tpu.ops import drf
+    from kai_scheduler_tpu.ops.allocate import allocate
+    from kai_scheduler_tpu.state import make_cluster
+    ses = Session.open(*make_cluster(
+        num_nodes=64, node_accel=8.0, num_gangs=40, tasks_per_gang=8))
+    cfg = ses.config
+
+    def cycle(state):
+        fair_share = drf.set_fair_share(state, num_levels=cfg.num_levels)
+        state = state.replace(
+            queues=state.queues.replace(fair_share=fair_share))
+        res = allocate(state, fair_share, num_levels=cfg.num_levels,
+                       config=cfg.allocate)
+        return res.placements, res.allocated, res.free
+
+    one = SingleDeviceSharding(topo.devices[0])
+    _fits_one_chip(jax.jit(cycle).lower(_shapes(ses.state, one)).compile())
+
+
+def test_fused_five_actions_compile_node_sharded(topo, saturated):
+    """The one multi-device program (``__graft_entry__.sharded_cycle``,
+    what ``chip_smoke.py --chips 4`` runs): the full cycle with the node
+    axis sharded over the four described chips."""
+    import __graft_entry__ as ge
+    from kai_scheduler_tpu.parallel import make_mesh, state_shardings
+    mesh = make_mesh(list(topo.devices))
+    shardings = state_shardings(saturated.state, mesh)
+    compiled = jax.jit(
+        ge._full_cycle_fn(saturated.config),
+        in_shardings=(shardings,)).lower(
+            _shapes(saturated.state, shardings)).compile()
+    _fits_one_chip(compiled)

@@ -1,6 +1,7 @@
 """Config layering + CLI — ref ``conf_util/scheduler_conf_util.go`` merge
 semantics and ``cmd/scheduler/app/options``."""
 import json
+import os
 import subprocess
 import sys
 
@@ -97,9 +98,28 @@ def test_cli_print_config_and_cycle(tmp_path):
     cluster = Cluster.from_objects(nodes, queues, groups, pods, topo)
     snap_path = tmp_path / "cluster.json.gz"
     snapshot.save(cluster, str(snap_path))
+    # the compile cache is placed from outside: with the variable set
+    # the entry point writes there (runtime/compile_cache.py)
+    cache_dir = tmp_path / "xla-cache"
     out = subprocess.run(
         [sys.executable, "-m", "kai_scheduler_tpu", "cycle",
          "--snapshot", str(snap_path)],
-        capture_output=True, text=True, check=True)
+        capture_output=True, text=True, check=True,
+        env={**os.environ, "JAX_COMPILATION_CACHE_DIR": str(cache_dir)})
     result = json.loads(out.stdout.strip().splitlines()[-1])
     assert result["bind_requests"] == 4
+    assert any(cache_dir.iterdir())
+
+
+def test_compile_cache_defaults_to_the_checkout():
+    """Unset, the cache is ``<checkout>/.jax_cache`` — a normalised,
+    fixed path (the path is part of the cache key)."""
+    import jax
+
+    from kai_scheduler_tpu.runtime import compile_cache
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.DEFAULT_DIR == os.path.join(root, ".jax_cache")
+    # conftest enabled it by the same rule every entry point uses
+    assert jax.config.jax_compilation_cache_dir == (
+        os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        or compile_cache.DEFAULT_DIR)

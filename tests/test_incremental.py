@@ -10,6 +10,7 @@ drive churn through it and then check the patch path actually engaged
 """
 import dataclasses
 
+import jax
 import numpy as np
 import pytest
 
@@ -293,6 +294,36 @@ class TestFallbacks:
         refresh(snap, cluster)
         assert snap.stats.patched == 0
         assert "dirty-threshold" in snap.stats.fallbacks
+
+    def test_fallback_rebuild_keeps_compiled_shapes(self):
+        """A churn-driven fallback rebuilds in full but must not also
+        move a padded axis: shapes are jit cache keys, and the fused
+        pipeline takes minutes to compile at 10k nodes.  Capacity only
+        grows — a shrunken cluster keeps the axes it compiled for, an
+        overflowing one re-pads."""
+        cluster = build(num_gangs=8)
+        snap = IncrementalSnapshotter(verify=False, dirty_threshold=0.1)
+        state, _ = refresh(snap, cluster)
+        cap0 = snap._capacity
+        shapes0 = [leaf.shape for leaf in jax.tree.leaves(state)]
+        # shrink: half the pods bind (pending tasks and gangs drop)
+        for p in list(cluster.pods.values())[:len(cluster.pods) // 2]:
+            cluster.bind_pod(p.name, "node-0")
+        state, _ = refresh(snap, cluster)
+        assert snap.stats.last["mode"] == "full"
+        assert snap.stats.last["fallback_reason"] == "dirty-threshold"
+        assert snap._capacity == cap0
+        assert [leaf.shape for leaf in jax.tree.leaves(state)] == shapes0
+        # overflow: more gangs than the pinned axis holds re-pads it
+        for i in range(cap0.gangs):
+            cluster.submit(
+                apis.PodGroup(f"grow-{i}", queue="queue-0-0",
+                              min_member=1),
+                [apis.Pod(f"grow-{i}-p", f"grow-{i}",
+                          apis.ResourceVec(1.0, 1.0, 1.0))])
+        refresh(snap, cluster)
+        assert snap._capacity.gangs > cap0.gangs
+        assert snap._capacity.nodes == cap0.nodes
 
     def test_topology_swap_falls_back(self):
         cluster = build(topology_levels=(2, 2))

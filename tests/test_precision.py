@@ -13,7 +13,6 @@ property tests pin the divergence:
   request.
 """
 import jax
-import jax.experimental
 import jax.numpy as jnp
 import numpy as np
 
@@ -53,7 +52,7 @@ def test_drf_f32_tracks_f64_at_contended_scale():
         quota=jnp.asarray(messy_quota, jnp.float32)))
     fs32 = np.asarray(drf.set_fair_share(state32, num_levels=2))
 
-    with jax.experimental.enable_x64(True):
+    with jax.enable_x64(True):
         state64 = ses.state.replace(
             queues=_to64(q).replace(
                 request=jnp.asarray(messy_req, jnp.float64),

@@ -249,3 +249,73 @@ def test_auto_tune_clamps_lane_width_to_pending_spread():
     assert pending == 16
     assert bsp == 16                         # pow4ceil(16)
     assert ses.config.victims.sparse_unit_k >= 8
+
+
+def _saturated_session(seed):
+    """chip_smoke.py's saturated shape at test size: running gangs fill
+    every accelerator, pending gangs sit in under-served queues."""
+    return Session.open(*make_cluster(
+        num_nodes=32, node_accel=4.0, num_gangs=20, tasks_per_gang=8,
+        running_fraction=0.8, queue_accel_quota=3.2,
+        partition_queues_by_running=True, seed=seed))
+
+
+@pytest.mark.parametrize("case", ["reclaim", "preempt", "consolidate",
+                                  "allocate-repush"])
+def test_uniform_kernel_prior_node_flag_matches_sequential(case):
+    """The uniform whole-gang kernel's per-node "holds a replica from a
+    prior attempt" flag (ops/allocate.py) is a [T, N] compare-and-any —
+    the scatter-add it replaced made the TPU compiler abort on every
+    victim action.  Each action that reaches the kernel must still
+    agree with the ``batch_size=1`` sequential scan: the victim solver
+    (where the flag is constant-false) on a seeded saturated cluster,
+    and allocate's elastic re-push (where it is live: a one-per-node
+    gang's remainder must avoid the nodes its quorum took)."""
+    if case == "allocate-repush":
+        from kai_scheduler_tpu.ops.allocate import allocate_jit
+        nodes, queues, groups, pods, topo = make_cluster(
+            num_nodes=12, node_accel=4.0, num_gangs=6, tasks_per_gang=6,
+            seed=3)
+        for i, g in enumerate(groups):
+            g.min_member = 2          # quorum first, remainder re-pushed
+            for p in pods:
+                if p.group == g.name:
+                    p.host_ports = [8000 + i]   # one replica per node
+        ses = Session.open(nodes, queues, groups, pods, topo)
+        assert ses.config.allocate.uniform_tasks
+        assert (np.asarray(ses.state.gangs.anti_self_level)[:6] >= 0).all()
+        outs = []
+        for b in (ses.config.allocate.batch_size, 1):
+            res = allocate_jit(
+                ses.state, ses.state.queues.fair_share, num_levels=2,
+                config=dataclasses.replace(ses.config.allocate,
+                                           batch_size=b))
+            outs.append((np.asarray(res.allocated),
+                         np.asarray(res.placements)))
+        # lanes break score ties apart, so the wavefront's node choice
+        # drifts from the scan's by design; what the flag decides is
+        # that every gang is whole and no node holds two of its replicas
+        np.testing.assert_array_equal(outs[0][0], outs[1][0])
+        for allocated, placements in outs:
+            assert allocated[:6].all()
+            for row in placements[:6]:
+                placed = row[row >= 0]
+                assert len(placed) == 6
+                assert len(set(placed.tolist())) == 6, row
+        return
+    # preempt is intra-queue: the partitioned shape gives it nothing
+    ses = (_many_queue_session(0) if case == "preempt"
+           else _saturated_session(seed=0))
+    assert ses.config.victims.placement.uniform_tasks
+    tuned = _outs(_run(ses, case, ses.config.victims))
+    seq = _outs(_run(ses, case, dataclasses.replace(
+        ses.config.victims, batch_size=1, batch_size_preempt=1,
+        placement=dataclasses.replace(ses.config.victims.placement,
+                                      batch_size=1))))
+    if case != "consolidate":   # nothing to defragment on this shape
+        assert seq[0].any() and seq[1].any(), "must exercise the action"
+    np.testing.assert_array_equal(tuned[0], seq[0], err_msg="allocated")
+    np.testing.assert_array_equal(tuned[1], seq[1], err_msg="victim")
+    np.testing.assert_array_equal((tuned[2] >= 0).sum(-1),
+                                  (seq[2] >= 0).sum(-1),
+                                  err_msg="placement counts")
