@@ -1,0 +1,5 @@
+from lib.phases import mean_phase_ms
+
+
+def read(run):
+    return mean_phase_ms(run, "snapshot")

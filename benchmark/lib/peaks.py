@@ -1,0 +1,15 @@
+"""The table of peaks, keyed by ``device_kind``.  A device that is not in
+the table is an error, never a default."""
+from __future__ import annotations
+
+import json
+import os
+
+
+def peaks_for(device_kind: str) -> dict:
+    with open(os.path.join(os.path.dirname(__file__), "peaks.json")) as fh:
+        table = json.load(fh)
+    if device_kind not in table:
+        raise KeyError(f"no peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(table)}")
+    return table[device_kind]
