@@ -26,24 +26,11 @@ open_session_latency = registry.histogram(
 action_latency = registry.histogram(
     "kai_action_scheduling_latency_seconds",
     "Per-action latency", label_names=("action",))
-plugin_latency = registry.histogram(
-    "kai_plugin_scheduling_latency_seconds",
-    "Per-plugin latency", label_names=("plugin", "extension"))
-pod_scheduling = registry.histogram(
-    "kai_pod_scheduling_latency_seconds", "Per-pod scheduling latency")
 podgroups_scheduled = registry.counter(
     "kai_podgroups_scheduled_total", "Pod groups scheduled by action",
     label_names=("action",))
 podgroups_considered = registry.counter(
     "kai_podgroups_considered_total", "Pod groups considered per cycle")
-scenarios_simulated = registry.counter(
-    "kai_scenarios_simulated_total",
-    "Victim scenarios simulated", label_names=("action",))
-scenarios_filtered = registry.counter(
-    "kai_scenarios_filtered_total",
-    "Victim scenarios pruned before simulation", label_names=("action",))
-preemption_attempts = registry.counter(
-    "kai_preemption_attempts_total", "Preemption attempts")
 queue_fair_share = registry.gauge(
     "kai_queue_fair_share", "Per-queue fair share",
     label_names=("queue", "resource"))

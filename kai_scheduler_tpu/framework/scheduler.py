@@ -73,8 +73,11 @@ def run_actions(state, fair_share, *, actions, num_levels, acfg, vcfg,
     production compiles."""
     res = init_result(state)
     for name in actions:
-        res = _PURE_ACTIONS[name](state, fair_share, res, num_levels,
-                                  acfg, vcfg, grace_s)
+        # a scope per action: every device operation's op_name in the
+        # profiler's trace says which action it belongs to
+        with jax.named_scope(name):
+            res = _PURE_ACTIONS[name](state, fair_share, res, num_levels,
+                                      acfg, vcfg, grace_s)
     return res
 
 
@@ -113,9 +116,11 @@ def resident_cycle(state, delta, ages, k_value, *, actions, num_levels,
     commit-set tensors, and the i16 commit array ``gather_host`` syncs.
     ``analytics_cfg=None`` is an analytics-skipped cadence cycle.
     """
-    state = resident_ops.apply_delta(state, delta)
-    fair_share = drf.set_fair_share(state, num_levels=num_levels,
-                                    k_value=k_value)
+    with jax.named_scope("apply_delta"):
+        state = resident_ops.apply_delta(state, delta)
+    with jax.named_scope("fair_share"):
+        fair_share = drf.set_fair_share(state, num_levels=num_levels,
+                                        k_value=k_value)
     solved = state.replace(
         queues=state.queues.replace(fair_share=fair_share))
     res = run_actions(solved, fair_share, actions=actions,
@@ -123,11 +128,13 @@ def resident_cycle(state, delta, ages, k_value, *, actions, num_levels,
                       grace_s=grace_s)
     bundle = None
     if analytics_cfg is not None:
-        bundle = cluster_analytics(solved, res, ages,
-                                   config=analytics_cfg)
-    packed = _PACK_COMMIT_FN(res, solved, track_devices=track_devices,
-                             track_analytics=analytics_cfg is not None,
-                             analytics=bundle)
+        with jax.named_scope("analytics"):
+            bundle = cluster_analytics(solved, res, ages,
+                                       config=analytics_cfg)
+    with jax.named_scope("pack_commit"):
+        packed = _PACK_COMMIT_FN(
+            res, solved, track_devices=track_devices,
+            track_analytics=analytics_cfg is not None, analytics=bundle)
     # the resident state returns WITHOUT the fair-share replacement:
     # fair share is derived per cycle, and the device state must stay
     # leaf-identical to the snapshotter's host mirror (verify compares)
@@ -248,6 +255,10 @@ class CycleResult:
     #: pairs by construction (twin/replay.py digests them)
     cycle_index: int = 0
     cycle_seed: int = 0
+    #: the cycle's span tree (``runtime.tracing.CycleTrace``), complete
+    #: once ``run_once`` has returned; None for a follower's empty result
+    trace: object | None = dataclasses.field(
+        default=None, repr=False, compare=False)
 
 
 def cycle_seed_for(seed: int, cycle_index: int) -> int:
@@ -613,6 +624,7 @@ class Scheduler:
                 evictions=len(result.evictions),
                 cycle_index=result.cycle_index,
                 cycle_seed=result.cycle_seed)
+        result.trace = trace
         return result
 
     def _run_traced(self, cluster: Cluster, trace, t0: float) -> CycleResult:
@@ -671,23 +683,27 @@ class Scheduler:
                     rr = self._snapshotter.refresh_resident(
                         cluster, now=cluster.now,
                         queue_usage=queue_usage)
-                    if rr.mode == "resident":
-                        resident_mode = True
-                        staged_delta = rr.delta
-                        session = Session.resident(
-                            rr.index, config=self.config.session,
-                            host_state=rr.host)
-                    else:
-                        session = Session.from_state(
-                            rr.state, rr.index,
-                            config=self.config.session)
-                        session.host_state = rr.host
+                    # snapshot.session: the Session over the refreshed
+                    # state (classic path: the fair-share dispatch)
+                    with self.tracer.span("snapshot.session"):
+                        if rr.mode == "resident":
+                            resident_mode = True
+                            staged_delta = rr.delta
+                            session = Session.resident(
+                                rr.index, config=self.config.session,
+                                host_state=rr.host)
+                        else:
+                            session = Session.from_state(
+                                rr.state, rr.index,
+                                config=self.config.session)
+                            session.host_state = rr.host
                 else:
                     state, index = self._snapshotter.refresh(
                         cluster, now=cluster.now,
                         queue_usage=queue_usage)
-                    session = Session.from_state(
-                        state, index, config=self.config.session)
+                    with self.tracer.span("snapshot.session"):
+                        session = Session.from_state(
+                            state, index, config=self.config.session)
                 # journal-delta stats of THIS refresh onto the span:
                 # mode (patched/full/resident), fallback reason, dirty
                 # rows, changed leaves and bytes actually uploaded
@@ -714,11 +730,13 @@ class Scheduler:
         result.cycle_index = self._cycle_index
         result.cycle_seed = cycle_seed_for(self.config.seed,
                                            self._cycle_index)
-        if not resident_mode:
-            result.tensors = init_result(session.state)
         result.open_seconds = open_s
         packed = None
         with self.tracer.span("solve_dispatch"):
+            if not resident_mode:
+                # a dozen small dispatches: inside the span, as the
+                # phase's checkpoints already count them
+                result.tensors = init_result(session.state)
             every = self.config.analytics_every
             run_analytics = every > 0 and self._cycle_index % every == 0
             self._cycle_index += 1
@@ -902,54 +920,59 @@ class Scheduler:
                         pending=self.status_updater.pending,
                         applied=self.status_updater.applied,
                         errors=self.status_updater.errors)
-            events, dropped, counts = session.decision_events(
-                result.tensors, host=host, evictions=result.evictions,
-                limit=self.decisions.max_events_per_cycle,
-                repack_for=repack_target)
-            # kai-pulse starvation: advance the per-gang pending-age
-            # counters and fire `starved` events for gangs crossing the
-            # alarm threshold this cycle (crossings counted EXACTLY;
-            # only event construction is bounded)
-            starved, crossings = self._advance_starvation(
-                cluster, session, host)
-            if crossings:
-                counts[gang_events.OUTCOME_STARVED] = crossings
-                room = max(0, self.decisions.max_events_per_cycle
-                           - len(events))
-                events = events + starved[:room]
-            self.decisions.record_cycle(trace.cycle_id, events,
-                                        dropped=dropped, counts=counts)
-            self._record_metrics(session, result, host)
-            if host.get("analytics") is not None:
-                result.analytics = session.analytics_doc(
-                    host,
-                    alarm_cycles=self.config.starvation_alarm_cycles)
-                self._record_analytics(session, host)
-                # atomic swap: published doc is never mutated, so
-                # /debug/cluster reads it without the server state lock
-                self._last_analytics = result.analytics
-                # kai-repack trigger streak: consecutive analytics
-                # cycles with the fragmentation gauge above threshold
-                score = float(host["analytics"]["frag_score"])
-                self._frag_streak = (
-                    self._frag_streak + 1
-                    if score > self.config.repack_frag_threshold else 0)
-            # kai-repack unblock accounting: a gang a firing migrated
-            # for that places within the observation window counts as
-            # unblocked (the kai_repack_gangs_unblocked_total payoff
-            # metric).  The dict is empty on every non-repack cycle.
-            if self._repack_watch:
-                self._watch_repack_unblocks(session, host)
-            # kai-wire: close this cycle's transfer window.  The
-            # summary rides the result (healthz/bench) and the trace as
-            # Chrome counter lanes — bytes-on-wire and live-bytes step
-            # charts aligned with the phase spans above.
-            result.wire = _wire.LEDGER.roll_cycle(trace.cycle_id)
-            trace.counters.append(("wire bytes/cycle", {
-                "uploaded": result.wire["bytes"],
-                "redundant": result.wire["redundant_bytes"]}))
-            trace.counters.append(("device resident bytes", {
-                "live": result.wire["resident_bytes"]}))
+            with self.tracer.span("commit.decisions"):
+                events, dropped, counts = session.decision_events(
+                    result.tensors, host=host, evictions=result.evictions,
+                    limit=self.decisions.max_events_per_cycle,
+                    repack_for=repack_target)
+                # kai-pulse starvation: advance the per-gang pending-age
+                # counters and fire `starved` events for gangs crossing
+                # the alarm threshold this cycle (crossings counted
+                # EXACTLY; only event construction is bounded)
+                starved, crossings = self._advance_starvation(
+                    cluster, session, host)
+                if crossings:
+                    counts[gang_events.OUTCOME_STARVED] = crossings
+                    room = max(0, self.decisions.max_events_per_cycle
+                               - len(events))
+                    events = events + starved[:room]
+                self.decisions.record_cycle(trace.cycle_id, events,
+                                            dropped=dropped, counts=counts)
+            with self.tracer.span("commit.metrics"):
+                self._record_metrics(session, result, host)
+                if host.get("analytics") is not None:
+                    result.analytics = session.analytics_doc(
+                        host,
+                        alarm_cycles=self.config.starvation_alarm_cycles)
+                    self._record_analytics(session, host)
+                    # atomic swap: published doc is never mutated, so
+                    # /debug/cluster reads it without the server state
+                    # lock
+                    self._last_analytics = result.analytics
+                    # kai-repack trigger streak: consecutive analytics
+                    # cycles with the fragmentation gauge above threshold
+                    score = float(host["analytics"]["frag_score"])
+                    self._frag_streak = (
+                        self._frag_streak + 1
+                        if score > self.config.repack_frag_threshold
+                        else 0)
+                # kai-repack unblock accounting: a gang a firing
+                # migrated for that places within the observation window
+                # counts as unblocked (the
+                # kai_repack_gangs_unblocked_total payoff metric).  The
+                # dict is empty on every non-repack cycle.
+                if self._repack_watch:
+                    self._watch_repack_unblocks(session, host)
+                # kai-wire: close this cycle's transfer window.  The
+                # summary rides the result (healthz/bench) and the trace
+                # as Chrome counter lanes — bytes-on-wire and live-bytes
+                # step charts aligned with the phase spans above.
+                result.wire = _wire.LEDGER.roll_cycle(trace.cycle_id)
+                trace.counters.append(("wire bytes/cycle", {
+                    "uploaded": result.wire["bytes"],
+                    "redundant": result.wire["redundant_bytes"]}))
+                trace.counters.append(("device resident bytes", {
+                    "live": result.wire["resident_bytes"]}))
         t_end = time.perf_counter()
         result.phase_seconds = {
             "snapshot": max(0.0, open_s - upload_s),

@@ -50,6 +50,9 @@ Reference surfaces collapse into one stdlib HTTP server:
 The server is deliberately dependency-free (http.server); a production
 deployment would front it with gRPC — the payloads are already the
 stable JSON documents of ``runtime/snapshot.py``.
+
+``docs/TRACING.md`` lists every span, device scope, counter and
+``/healthz`` field by name, and how to get them out of a running server.
 """
 from __future__ import annotations
 
@@ -58,6 +61,7 @@ import cProfile
 import json
 import pstats
 import threading
+import time
 import urllib.parse
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
@@ -68,6 +72,7 @@ from ..intake.router import IntakeConfig, IntakeRouter
 from ..runtime import compile_cache, compile_watch, wire_ledger
 from ..runtime.cluster import Cluster
 from ..runtime.snapshot import dump_cluster, load_cluster
+from ..runtime.tracing import GcWatch
 from . import metrics
 from .scheduler import Scheduler
 from .session import Session
@@ -263,6 +268,9 @@ class SchedulerServer:
         #: threads swap in a fresh dict under _state_lock, readers take
         #: the current binding without it
         self._cycle_stats: dict | None = None  # kai-race: guarded-by=atomic-swap
+        #: times the process's garbage collections while the server
+        #: runs (start() installs the hook, stop() removes it)
+        self._gc_watch = GcWatch()
         # kai-twin stream recorder: attached to the stored cluster so
         # the shared intake applier (intake/apply.py choke point)
         # mirrors every applied mutation; /cycle/stored appends cycle
@@ -514,13 +522,7 @@ class SchedulerServer:
                                 codec.apply_delta_msg(outer.cluster, delta)
                             self._send_pb(pb.CommitSet())
                         elif self.path == "/cycle/stored":
-                            with outer._state_lock:
-                                outer.intake.coalesce(outer.cluster)
-                                result = outer.scheduler.run_once(
-                                    outer.cluster)
-                                outer._record_cycle(result)
-                                if outer.recorder is not None:
-                                    outer.recorder.record_cycle()
+                            result = outer._run_stored_cycle()
                             self._send_pb(codec.commit_to_msg(result))
                         else:
                             self.send_error(404)
@@ -578,13 +580,7 @@ class SchedulerServer:
                         # point: staged lane events merge into the hub
                         # journal (global seq order) before the cycle
                         # snapshots it.
-                        with outer._state_lock:
-                            outer.intake.coalesce(outer.cluster)
-                            result = outer.scheduler.run_once(
-                                outer.cluster)
-                            outer._record_cycle(result)
-                            if outer.recorder is not None:
-                                outer.recorder.record_cycle()
+                        result = outer._run_stored_cycle()
                         self._send(_commit_doc(result))
                     elif self.path == "/twin/record":
                         # kai-twin recorder control: start re-anchors
@@ -667,7 +663,27 @@ class SchedulerServer:
                 twin["last_replay"]["divergences"])
         return out
 
-    def _record_cycle(self, result) -> None:
+    def _run_stored_cycle(self):
+        """``POST /cycle/stored``, either framing: take the commit lock,
+        merge what the intake lanes staged into the hub journal (global
+        seq order: the cycle boundary is the kai-intake coalesce
+        point), run the cycle, publish its stats.  The wait for the
+        lock and the coalesce come before the cycle's root span opens,
+        so they are timed here and served as ``entry_seconds``."""
+        t0 = time.perf_counter()
+        with self._state_lock:
+            t1 = time.perf_counter()
+            self.intake.coalesce(self.cluster)
+            t2 = time.perf_counter()
+            result = self.scheduler.run_once(self.cluster)
+            self._record_cycle(result, lock_wait_s=t1 - t0,
+                               coalesce_s=t2 - t1)
+            if self.recorder is not None:
+                self.recorder.record_cycle()
+        return result
+
+    def _record_cycle(self, result, lock_wait_s: float = 0.0,
+                      coalesce_s: float = 0.0) -> None:
         """Swap in a fresh immutable per-cycle stats document (served
         by ``GET /healthz``).  Called under ``_state_lock``; readers
         take the current binding without it (atomic-swap discipline —
@@ -686,6 +702,27 @@ class SchedulerServer:
                 # kai-wire summary of the cycle: bytes on the wire by
                 # reason, redundant re-uploads, device residency
                 wire=dict(result.wire))
+            trace = result.trace
+            if trace is not None:
+                # the cycle from the inside (docs/TRACING.md): self time
+                # of every span, what the snapshotter did, the
+                # collector's pauses, what the entry spent before the
+                # cycle opened.  Start-up is the first cycle's phases,
+                # frozen, and the compile stages, which count on: a
+                # recompile in steady state shows
+                first = (prev["startup"]["phase_seconds"]
+                         if prev and "startup" in prev
+                         else dict(result.phase_seconds))
+                stats.update(
+                    span_self_seconds=trace.self_seconds(),
+                    snapshot=next(
+                        (dict(sp.attrs) for sp in trace.root.children
+                         if sp.name == "snapshot"), {}),
+                    gc=trace.gc,
+                    entry_seconds={"lock_wait": lock_wait_s,
+                                   "coalesce": coalesce_s},
+                    startup={"phase_seconds": first,
+                             **compile_watch.WATCHER.stage_seconds()})
             # kai-pulse slice: the headline cluster-health gauges of
             # the latest analytics cycle (this one, or — on cycles the
             # cadence skipped — the last one that ran)
@@ -727,6 +764,8 @@ class SchedulerServer:
 
     def start(self) -> "SchedulerServer":
         compile_cache.enable()
+        compile_watch.WATCHER.listen()
+        self.scheduler.tracer.gc_watch = self._gc_watch.install()
         self._thread = threading.Thread(
             target=self._httpd.serve_forever, daemon=True)
         self._thread.start()
@@ -739,6 +778,7 @@ class SchedulerServer:
         if self.profiler is not None:
             self.profiler.stop()
         self.intake.stop()
+        self._gc_watch.uninstall()
         self._httpd.shutdown()
         if self._thread is not None:
             self._thread.join(timeout=5)

@@ -1881,7 +1881,8 @@ def allocate(
               jnp.zeros((G,), bool), jnp.asarray(G * (T + 1), jnp.int32))
     if hoist_topo:
         carry0 = carry0 + topo_tables_build(init.free)
-    out = lax.while_loop(cond, chunk, carry0)
+    with jax.named_scope("placement_loop"):
+        out = lax.while_loop(cond, chunk, carry0)
     return out[0]
 
 

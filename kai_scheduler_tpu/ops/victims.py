@@ -494,8 +494,9 @@ def solve_for_preemptor(
             lambda vq: _leveled_queue(chain, q.depth, vq, queue))(
                 leaf_safe)                                     # [U]
         contrib = chain[leaf_safe] & (unit_leaf >= 0)[:, None]  # [U, Q]
-        inc = contrib[:, :, None] * unit_req[:, None, :]       # [U, Q, R]
-        csum_excl = cumsum_ds(inc, axis=0) - inc
+        with jax.named_scope("unit_tables"):
+            inc = contrib[:, :, None] * unit_req[:, None, :]   # [U, Q, R]
+            csum_excl = cumsum_ds(inc, axis=0) - inc
         lq_safe = jnp.maximum(lq_u, 0)
         freed_excl = csum_excl[jnp.arange(M), lq_safe]         # [U, R]
         remaining_u = qa[lq_safe] - freed_excl
@@ -1665,10 +1666,12 @@ def _run_victim_action_chunked(
                 res0 = res0.replace(
                     wavefront_stats=res0.wavefront_stats
                     .at[ROW, 3].add(1))
-            res, _, _, _, fuel_left = lax.while_loop(
-                lambda cr: jnp.any(cr[1]) & (cr[4] > 0), chunk,
-                (res0, remaining0, jnp.full((Q,), -1, jnp.int32),
-                 jnp.zeros((Q,), jnp.int32), jnp.asarray(G, jnp.int32)))
+            with jax.named_scope("wavefront"):
+                res, _, _, _, fuel_left = lax.while_loop(
+                    lambda cr: jnp.any(cr[1]) & (cr[4] > 0), chunk,
+                    (res0, remaining0, jnp.full((Q,), -1, jnp.int32),
+                     jnp.zeros((Q,), jnp.int32),
+                     jnp.asarray(G, jnp.int32)))
             if _DEBUG_CHUNKS:
                 # stash the chunk count in the last fit_reason slot
                 # (scratch diagnostics only — that slot is snapshot
@@ -1679,18 +1682,27 @@ def _run_victim_action_chunked(
 
         return run
 
+    def tabled_run(sparse: bool, fell_back: bool):
+        # make_run's own work is the table build (the loop runs when
+        # the closure is called): a scope of its own, since the dense
+        # [U, Q, R] cumulatives are where a many-tenant cluster's
+        # device time goes
+        with jax.named_scope("unit_tables"):
+            return make_run(sparse, fell_back)
+
     if not sparse_able:
-        return make_run(False, False)(result)
+        return tabled_run(False, False)(result)
     if KU >= M:
         # no queue can ever expose more units than running pods exist:
         # the dense fallback is statically unreachable, so skip the
         # cond (small tier-1 shapes trace ONE loop, not two)
-        return make_run(True, False)(result)
+        return tabled_run(True, False)(result)
     cnt_units_q = jax.ops.segment_sum(
         has_leaf.astype(jnp.int32), jnp.where(has_leaf, leaf_safe, Q),
         num_segments=Q + 1)[:Q]
     return lax.cond(jnp.any(cnt_units_q > KU),
-                    make_run(False, True), make_run(True, False), result)
+                    tabled_run(False, True), tabled_run(True, False),
+                    result)
 
 
 #: scratch diagnostics flag (set True to expose chunk counts)
@@ -1916,10 +1928,11 @@ def run_victim_action(
             statics=statics, job_rank=job_rank0,
             lq_tab=lq_tab if mode == "reclaim" else None,
             cnt_q=cnt_q, task_req_g=task_req_g)
-    res, _, _, _ = lax.while_loop(
-        lambda c: jnp.any(c[1]) & (c[3] > 0), step,
-        (result, remaining0, jnp.zeros((q.q,), jnp.int32),
-         jnp.asarray(G, jnp.int32)))
+    with jax.named_scope("wavefront"):
+        res, _, _, _ = lax.while_loop(
+            lambda c: jnp.any(c[1]) & (c[3] > 0), step,
+            (result, remaining0, jnp.zeros((q.q,), jnp.int32),
+             jnp.asarray(G, jnp.int32)))
     return res
 
 

@@ -32,6 +32,13 @@ The wrapper is HOST-side and adds ~tens of microseconds per call
 (one ``tree_flatten`` + tuple build) — never traced, zero new
 primitives in any jit region (the jaxpr probe baseline is unchanged).
 
+A miss inside an open cycle is also a ``compile:<entry>`` span of that
+cycle's trace (``runtime/tracing.py``), so a recompiling cycle shows
+where.  ``listen()`` adds JAX's own account of a compile, by stage
+(trace, lower, backend compile, cache load), from ``jax.monitoring``'s
+duration events: ``stage_seconds()``, served under ``/healthz``
+``last_cycle.startup``.
+
 Surfaces: ``kai_compile_*`` registry metrics, the ``compile`` section
 of ``GET /debug/wire``, and per-event docs in a bounded ring.
 Concurrency: all watcher state is accessed under ``_lock`` (declared
@@ -45,8 +52,21 @@ import time
 import zlib
 
 import jax
+import jax.monitoring
+
+from . import tracing
 
 __all__ = ["CompileWatcher", "WATCHER", "watch"]
+
+#: ``jax.monitoring`` duration event -> key of ``stage_seconds()``.
+#: JAX books the load of a cached executable under the backend-compile
+#: event too, so ``cache_load_s`` is a part of ``backend_compile_s``.
+_STAGE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_compile_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load_s",
+}
 
 
 def _signature(args, kwargs) -> tuple:
@@ -96,6 +116,9 @@ class CompileWatcher:
         #: entry -> recent miss monotonic stamps (storm detection)
         self._miss_times: dict[str, list] = {}
         self._alarms = 0
+        #: seconds by compile stage since ``listen()``
+        self._stage_s = dict.fromkeys(_STAGE_EVENTS.values(), 0.0)
+        self._listening = False
         #: bounds — immutable after construction
         self._retain = max(1, int(retain_events))
         self.storm_threshold = max(2, int(storm_threshold))
@@ -123,7 +146,11 @@ class CompileWatcher:
                 return fn(*args, **kwargs)
             t0 = time.perf_counter()
             out = fn(*args, **kwargs)
-            self._observe_miss(entry, sig, time.perf_counter() - t0)
+            t1 = time.perf_counter()
+            self._observe_miss(entry, sig, t1 - t0)
+            tracing.add_span_to_open_cycle(
+                f"compile:{entry}", t0, t1,
+                signature=_render_signature(sig))
             return out
 
         # the raw python function, one hop past the jit object (jax's
@@ -184,6 +211,30 @@ class CompileWatcher:
         metrics.compile_seconds.inc(entry, by=float(seconds))
         if storm:
             metrics.compile_storm_alarms.inc(entry)
+
+    # -- JAX's own stage clock ---------------------------------------------
+
+    def listen(self) -> None:
+        """Start summing JAX's compile-stage durations.  Once per
+        watcher: ``jax.monitoring`` keeps a listener for the life of the
+        process."""
+        with self._lock:
+            if self._listening:
+                return
+            self._listening = True
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_duration)
+
+    def _on_duration(self, name: str, secs: float, **_) -> None:
+        key = _STAGE_EVENTS.get(name)
+        if key is not None:
+            with self._lock:
+                self._stage_s[key] += secs
+
+    def stage_seconds(self) -> dict[str, float]:
+        """Cumulative seconds by compile stage since ``listen()``."""
+        with self._lock:
+            return dict(self._stage_s)
 
     # -- reading -----------------------------------------------------------
 

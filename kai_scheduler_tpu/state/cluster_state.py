@@ -33,6 +33,7 @@ from flax import struct
 
 from ..apis import types as apis
 from ..runtime import wire_ledger as _wire
+from ..runtime.tracing import SpanSections, span_of
 from . import node_filters
 
 UNLIMITED = apis.UNLIMITED
@@ -645,15 +646,73 @@ def build_snapshot(
     storage_classes: dict[str, apis.StorageClass] | None = None,
     capacity: SnapshotCapacity | None = None,
     _return_host: bool = False,
+    tracer=None,
 ) -> tuple[ClusterState, SnapshotIndex]:
     """Flatten API objects into a ClusterState (+ index for the commit path).
 
     This is the TPU-native analogue of the reference's snapshot step
     (``cache/cluster_info/cluster_info.go:229`` snapshotNodes,
     ``:346`` snapshotPodGroups).
+
+    ``tracer`` (a ``runtime.tracing.CycleTracer``; ``None`` records
+    nothing) gets the two halves as spans of the open cycle:
+    ``snapshot.encode``, the host work, with one child per group of
+    sections, and ``snapshot.transfer``, the one ``device_put``.
     """
+    sections = SpanSections(tracer)
+    with span_of(tracer, "snapshot.encode"):
+        try:
+            host_state, index = _encode_snapshot(
+                nodes, queues, pod_groups, pods, topology, sections,
+                max_tasks_per_gang=max_tasks_per_gang, pad=pad,
+                dtype=dtype, now=now, queue_usage=queue_usage,
+                resource_claims=resource_claims,
+                device_classes=device_classes,
+                volume_claims=volume_claims,
+                storage_classes=storage_classes, capacity=capacity)
+        finally:
+            sections.close()
+    # through the kai-wire TransferLedger (the package's device_put
+    # choke point, KAI071): the full snapshot supersedes the previous
+    # one's buffers, so the upload replaces the ledger's resident set
+    with span_of(tracer, "snapshot.transfer") as transfer_sp:
+        state = _wire.LEDGER.device_put(
+            host_state, reason=_wire.REASON_FULL_BUILD, replace_site=True)
+        held = _wire.LEDGER.residency()
+        transfer_sp.attrs.update(bytes=held["bytes"],
+                                 leaves=held["buffers"])
+    if _return_host:
+        # the incremental snapshotter caches the pre-device_put numpy
+        # leaves so later cycles can patch rows and ship only changes
+        return state, index, host_state
+    return state, index
+
+
+def _encode_snapshot(
+    nodes: list[apis.Node],
+    queues: list[apis.Queue],
+    pod_groups: list[apis.PodGroup],
+    pods: list[apis.Pod],
+    topology: apis.Topology | None,
+    sections: SpanSections,
+    *,
+    max_tasks_per_gang: int | None,
+    pad: int,
+    dtype,
+    now: float | None,
+    queue_usage: dict[str, "np.ndarray"] | None,
+    resource_claims: dict[str, apis.ResourceClaim] | None,
+    device_classes: dict[str, apis.DeviceClass] | None,
+    volume_claims: dict[str, apis.PersistentVolumeClaim] | None,
+    storage_classes: dict[str, apis.StorageClass] | None,
+    capacity: SnapshotCapacity | None,
+) -> tuple[ClusterState, SnapshotIndex]:
+    """The host half of :func:`build_snapshot`: the snapshot as numpy
+    leaves and its index.  ``sections(name)`` marks where each group of
+    sections starts (a span each under a tracer)."""
     cap = capacity or SnapshotCapacity()
     # --- vocabularies -----------------------------------------------------
+    sections("encode.vocab")
     selector_keys: list[str] = []
     for pod in pods:
         for k in pod.node_selector:
@@ -713,6 +772,7 @@ def build_snapshot(
             g_of_ext[_col] = float(_m.group(1))
 
     # --- nodes ------------------------------------------------------------
+    sections("encode.nodes")
     live_nodes = [n for n in nodes if not n.unschedulable]
     N = _round_up(max(len(live_nodes), cap.nodes), pad)
     node_alloc = np.zeros((N, R), np.float32)
@@ -767,6 +827,7 @@ def build_snapshot(
             off += len(t.levels)
 
     # --- queues (parents before children) --------------------------------
+    sections("encode.queues")
     Q = _round_up(max(len(queues), cap.queues), pad)
     qt = build_queue_tables(queues, Q)
     queue_names, q_index = qt["queue_names"], qt["q_index"]
@@ -777,6 +838,7 @@ def build_snapshot(
     q_preempt_eff, q_reclaim_eff = qt["q_preempt_eff"], qt["q_reclaim_eff"]
 
     # --- pod groups + tasks ----------------------------------------------
+    sections("encode.gangs")
     group_names = [g.name for g in pod_groups]
     g_index = {name: i for i, name in enumerate(group_names)}
     pending_by_group: dict[str, list[apis.Pod]] = {g.name: [] for g in pod_groups}
@@ -1364,6 +1426,7 @@ def build_snapshot(
                     attract_static[row, ni] = True
 
     # --- running pods -----------------------------------------------------
+    sections("encode.running")
     # Pods whose node is missing from the snapshot (cordoned/deleted) keep
     # valid=True with node=-1: they still count toward queue allocation so
     # DRF fairness stays honest, but victim kernels skip node<0 rows.
@@ -1580,6 +1643,7 @@ def build_snapshot(
         gk["subgroup_min_member"] - sub_running, 0)
 
     # --- task-type table + scheduling signatures --------------------------
+    sections("encode.rollups")
     Y = _round_up(max(len(task_type_index), 1, cap.types), 4)
     gk["type_req"] = np.zeros((Y, R), np.float32)
     gk["type_selector"] = np.full((Y, K), -1, np.int32)
@@ -1725,12 +1789,6 @@ def build_snapshot(
                         attract_static=attract_static),
         running=RunningState(**rk),
     )
-    host_state = state
-    # through the kai-wire TransferLedger (the package's device_put
-    # choke point, KAI071): the full snapshot supersedes the previous
-    # one's buffers, so the upload replaces the ledger's resident set
-    state = _wire.LEDGER.device_put(
-        state, reason=_wire.REASON_FULL_BUILD, replace_site=True)
     index = SnapshotIndex(
         node_names=node_names,
         queue_names=queue_names,
@@ -1780,8 +1838,4 @@ def build_snapshot(
             and bool((gk["anti_self_level"] < 0).all())
             and bool((gk["subgroup_required_level"] < 0).all())),
     )
-    if _return_host:
-        # the incremental snapshotter caches the pre-device_put numpy
-        # leaves so later cycles can patch rows and ship only changes
-        return state, index, host_state
     return state, index

@@ -71,6 +71,7 @@ import numpy as np
 from ..apis import types as apis
 from ..ops import resident as _resident
 from ..runtime import wire_ledger as _wire
+from ..runtime.tracing import span_of
 from . import cluster_state as _cs
 from .cluster_state import (
     SnapshotCapacity,
@@ -319,6 +320,15 @@ def _is_plain_pod(pod: apis.Pod) -> bool:
         or pod.dra_accel_count > 0)
 
 
+#: ``SnapshotterStats.last`` of a rebuilt cycle, but for its reason
+_FULL_STATS = {
+    "mode": "full", "fallback_reason": "",
+    "dirty_pods": 0, "dirty_gangs": 0,
+    "leaves_shipped": 0, "bytes_shipped": 0,
+    "ship_seconds": 0.0, "ship_dispatches": 0,
+}
+
+
 @dataclasses.dataclass
 class SnapshotterStats:
     full_builds: int = 0
@@ -397,6 +407,11 @@ class IncrementalSnapshotter:
             self._tracer.add_span(name, start, time.perf_counter(),
                                   **attrs)
 
+    def _span(self, name: str, **attrs):
+        """A child span of whatever is open (``with``): nothing
+        recorded without a tracer or outside a cycle."""
+        return span_of(self._tracer, name, **attrs)
+
     # -- public -----------------------------------------------------------
 
     def _bind_cluster(self, cluster) -> None:
@@ -415,15 +430,15 @@ class IncrementalSnapshotter:
              else None)
         reason = self._patch_blockers(cluster, j)
         if reason is None:
-            t_patch = time.perf_counter()
-            try:
-                host_new, index = self._patch(cluster, j, now,
-                                              queue_usage)
-            except _Fallback as exc:
-                reason = exc.reason
-                self._add_span("snapshot.patch_abandoned", t_patch,
-                               fallback_reason=reason)
-            else:
+            with self._span("snapshot.patch") as patch_sp:
+                try:
+                    host_new, index = self._patch(cluster, j, now,
+                                                  queue_usage)
+                except _Fallback as exc:
+                    reason = exc.reason
+                    patch_sp.name = "snapshot.patch_abandoned"
+                    patch_sp.attrs["fallback_reason"] = reason
+            if reason is None:
                 state = self._ship(host_new)
                 self._index = index
                 self.stats.patched += 1
@@ -435,25 +450,17 @@ class IncrementalSnapshotter:
                     "leaves_shipped": ship[0], "bytes_shipped": ship[1],
                     "ship_seconds": ship[2], "ship_dispatches": ship[3],
                 }
-                self._add_span("snapshot.patch", t_patch,
-                               **self.stats.last)
+                patch_sp.attrs.update(self.stats.last)
                 if self.verify:
                     self._verify(cluster, now, queue_usage)
                 return state, index
         self.stats.fallback(reason)
-        t_full = time.perf_counter()
-        out = self._full(cluster, now, queue_usage)
-        # the full builder's device transfer happens inside
-        # build_snapshot, so upload is not separable here — the whole
-        # rebuild is one section
-        self.stats.last = {
-            "mode": "full", "fallback_reason": reason,
-            "dirty_pods": 0, "dirty_gangs": 0,
-            "leaves_shipped": 0, "bytes_shipped": 0,
-            "ship_seconds": 0.0, "ship_dispatches": 0,
-        }
-        self._add_span("snapshot.full_build", t_full,
-                       fallback_reason=reason)
+        # the transfer happens inside the rebuild (its own span,
+        # snapshot.transfer) and the wire ledger books it under
+        # "fallback", so this cycle's upload phase reads 0
+        with self._span("snapshot.full_build", fallback_reason=reason):
+            out = self._full(cluster, now, queue_usage)
+        self.stats.last = dict(_FULL_STATS, fallback_reason=reason)
         return out
 
     # -- kai-resident ------------------------------------------------------
@@ -488,22 +495,23 @@ class IncrementalSnapshotter:
         if reason is None:
             reason = self._patch_blockers(cluster, j)
         if reason is None:
-            t_patch = time.perf_counter()
-            try:
-                host_new, index = self._patch(cluster, j, now,
-                                              queue_usage)
-                delta, merged, dstats = _resident.pack_delta(
-                    self._host, host_new,
-                    min_buckets=self._delta_buckets)
-            except _Fallback as exc:
-                reason = exc.reason
-                self._add_span("snapshot.patch_abandoned", t_patch,
-                               fallback_reason=reason)
-            except _resident.DeltaShapeError as exc:
-                reason = f"delta-shape:{exc}"
-                self._add_span("snapshot.patch_abandoned", t_patch,
-                               fallback_reason="delta-shape")
-            else:
+            with self._span("snapshot.patch") as patch_sp:
+                try:
+                    host_new, index = self._patch(cluster, j, now,
+                                                  queue_usage)
+                    with self._span("patch.pack_delta"):
+                        delta, merged, dstats = _resident.pack_delta(
+                            self._host, host_new,
+                            min_buckets=self._delta_buckets)
+                except _Fallback as exc:
+                    reason = exc.reason
+                    patch_sp.name = "snapshot.patch_abandoned"
+                    patch_sp.attrs["fallback_reason"] = reason
+                except _resident.DeltaShapeError as exc:
+                    reason = f"delta-shape:{exc}"
+                    patch_sp.name = "snapshot.patch_abandoned"
+                    patch_sp.attrs["fallback_reason"] = "delta-shape"
+            if reason is None:
                 t_ship = time.perf_counter()
                 # ONE transient device_put: the delta is consumed by
                 # the donated scatter-apply dispatch and never joins
@@ -528,8 +536,7 @@ class IncrementalSnapshotter:
                     "delta_elements": dstats["elements"],
                     "ship_seconds": ship_s, "ship_dispatches": 1,
                 }
-                self._add_span("snapshot.patch", t_patch,
-                               **self.stats.last)
+                patch_sp.attrs.update(self.stats.last)
                 self._add_span("upload", t_ship,
                                leaves=dstats["leaves"],
                                bytes=dstats["bytes"], dispatches=1)
@@ -539,16 +546,9 @@ class IncrementalSnapshotter:
                     mode="resident", index=index, state=None,
                     delta=delta_dev, host=self._host)
         self.stats.fallback(reason)
-        t_full = time.perf_counter()
-        state, index = self._full(cluster, now, queue_usage)
-        self.stats.last = {
-            "mode": "full", "fallback_reason": reason,
-            "dirty_pods": 0, "dirty_gangs": 0,
-            "leaves_shipped": 0, "bytes_shipped": 0,
-            "ship_seconds": 0.0, "ship_dispatches": 0,
-        }
-        self._add_span("snapshot.full_build", t_full,
-                       fallback_reason=reason)
+        with self._span("snapshot.full_build", fallback_reason=reason):
+            state, index = self._full(cluster, now, queue_usage)
+        self.stats.last = dict(_FULL_STATS, fallback_reason=reason)
         return ResidentRefresh(mode="full", index=index, state=state,
                                delta=None, host=self._host)
 
@@ -633,19 +633,21 @@ class IncrementalSnapshotter:
         # the caller), the next refresh must not patch over a cache that
         # no longer matches the already-consumed journal
         self._host = None
-        lists = cluster.snapshot_lists()
-        nodes, queues, groups, pods, topology = lists
-        live_nodes = [n for n in nodes if not n.unschedulable]
-        pend_per_group: dict[str, int] = {g.name: 0 for g in groups}
-        n_running = 0
-        for p in pods:
-            if p.status == apis.PodStatus.PENDING:
-                if p.group in pend_per_group:
-                    pend_per_group[p.group] += 1
-            elif p.status in (apis.PodStatus.BOUND, apis.PodStatus.RUNNING,
-                              apis.PodStatus.RELEASING):
-                n_running += 1
-        max_pending = max(pend_per_group.values(), default=0)
+        with self._span("snapshot.lists"):
+            lists = cluster.snapshot_lists()
+            nodes, queues, groups, pods, topology = lists
+            live_nodes = [n for n in nodes if not n.unschedulable]
+            pend_per_group: dict[str, int] = {g.name: 0 for g in groups}
+            n_running = 0
+            for p in pods:
+                if p.status == apis.PodStatus.PENDING:
+                    if p.group in pend_per_group:
+                        pend_per_group[p.group] += 1
+                elif p.status in (apis.PodStatus.BOUND,
+                                  apis.PodStatus.RUNNING,
+                                  apis.PodStatus.RELEASING):
+                    n_running += 1
+            max_pending = max(pend_per_group.values(), default=0)
         old = self._capacity
 
         def keep(floor: int, count: int) -> int:
@@ -673,7 +675,7 @@ class IncrementalSnapshotter:
                 device_classes=cluster.device_classes,
                 volume_claims=cluster.volume_claims,
                 storage_classes=cluster.storage_classes,
-                capacity=cap, _return_host=True)
+                capacity=cap, _return_host=True, tracer=self._tracer)
         # the per-entity ledger only pays off if a later cycle can
         # actually patch — skip it (stay cold) while a persistent
         # environment condition forces full rebuilds regardless, e.g. a
@@ -702,7 +704,8 @@ class IncrementalSnapshotter:
             # settled signature stays warm across the fallback.
             self._delta_buckets.clear()
         self._host, self._dev, self._index = host, state, index
-        self._rebuild_ledgers(cluster, lists, host, index)
+        with self._span("snapshot.ledgers"):
+            self._rebuild_ledgers(cluster, lists, host, index)
         return state, index
 
     def _rebuild_ledgers(self, cluster, lists, host, index) -> None:
@@ -1185,8 +1188,10 @@ class IncrementalSnapshotter:
             raise _Fallback("topology-drift")
 
     def _patch(self, cluster, j, now, queue_usage):
-        dirty_rows, dirty_gangs = self._apply_journal(cluster, j)
-        self._sweep(cluster, dirty_rows, dirty_gangs)
+        with self._span("patch.journal"):
+            dirty_rows, dirty_gangs = self._apply_journal(cluster, j)
+        with self._span("patch.sweep"):
+            self._sweep(cluster, dirty_rows, dirty_gangs)
         self._last_dirty = (len(dirty_rows), len(dirty_gangs))
         if self._nonplain > 0:
             raise _Fallback("nonplain-pods")
@@ -1209,8 +1214,9 @@ class IncrementalSnapshotter:
         if now is None:
             order = self._order
             now = float(self.p_crea[order].max()) if len(order) else 0.0
-        return self._assemble(
-            cluster, dirty_gangs, now, queue_usage, host_old)
+        with self._span("patch.assemble"):
+            return self._assemble(
+                cluster, dirty_gangs, now, queue_usage, host_old)
 
     # -- assembly ----------------------------------------------------------
 

@@ -286,3 +286,74 @@ def test_profiler_second_start_after_failed_join_raises():
     assert prof._thread is not None and prof._thread.is_alive()
     prof.stop()
     assert prof._thread is None
+
+
+# ---------------------------------------------------------------------------
+# the cycle from the inside, on /healthz (ISSUE 26; docs/TRACING.md)
+# ---------------------------------------------------------------------------
+
+
+def _post_cycle(base, framing="json"):
+    headers = {"Content-Type": "application/json" if framing == "json"
+               else "application/x-protobuf"}
+    req = urllib.request.Request(
+        f"{base}/cycle/stored", data=b"{}" if framing == "json" else b"",
+        headers=headers)
+    urllib.request.urlopen(req, timeout=120).read()
+    return json.load(urllib.request.urlopen(f"{base}/healthz"))[
+        "last_cycle"]
+
+
+def test_healthz_carries_the_cycle_from_the_inside():
+    server = SchedulerServer(_cluster()).start()
+    base = f"http://127.0.0.1:{server.port}"
+    try:
+        first = _post_cycle(base)
+        second = _post_cycle(base, framing="proto")
+    finally:
+        server.stop()
+    for stats in (first, second):
+        assert {"span_self_seconds", "snapshot", "gc", "entry_seconds",
+                "startup"} <= set(stats)
+        selfs = stats["span_self_seconds"]
+        assert "cycle/snapshot" in selfs and "cycle/device_wait" in selfs
+        # the spans partition the cycle, and the cycle is the session
+        assert abs(sum(selfs.values()) - stats["total_seconds"]) < 1e-3
+        assert stats["snapshot"]["mode"] in ("full", "patched")
+        assert "fallback_reason" in stats["snapshot"]
+        assert len(stats["gc"]["collections"]) == 3
+        assert len(stats["gc"]["pause_seconds"]) == 3
+        assert set(stats["entry_seconds"]) == {"lock_wait", "coalesce"}
+        assert all(v >= 0.0 for v in stats["entry_seconds"].values())
+    # start-up is the first cycle's, frozen; the compile stages count on
+    assert first["startup"]["phase_seconds"] == first["phase_seconds"]
+    assert second["startup"]["phase_seconds"] == first["phase_seconds"]
+    assert second["phase_seconds"] != first["phase_seconds"]
+    stages = ("trace_s", "lower_s", "backend_compile_s", "cache_load_s")
+    assert set(first["startup"]) == {"phase_seconds", *stages}
+    assert all(second["startup"][k] >= first["startup"][k] >= 0.0
+               for k in stages)
+
+
+def test_server_installs_and_removes_the_gc_hook():
+    import gc
+    before = len(gc.callbacks)
+    server = SchedulerServer(_cluster()).start()
+    try:
+        assert len(gc.callbacks) == before + 1
+        assert server.scheduler.tracer.gc_watch is not None
+    finally:
+        server.stop()
+    assert len(gc.callbacks) == before
+
+
+def test_metrics_offer_no_series_that_nothing_feeds():
+    from kai_scheduler_tpu.framework import metrics
+    text = metrics.registry.render()
+    for dead in ("kai_plugin_scheduling_latency_seconds",
+                 "kai_pod_scheduling_latency_seconds",
+                 "kai_scenarios_simulated_total",
+                 "kai_scenarios_filtered_total",
+                 "kai_preemption_attempts_total"):
+        assert dead not in text
+    assert "kai_e2e_scheduling_latency_seconds" in text

@@ -16,6 +16,8 @@ Covers the acceptance properties directly:
 import json
 import urllib.request
 
+import pytest
+
 from kai_scheduler_tpu.apis import types as apis
 from kai_scheduler_tpu.framework.scheduler import Scheduler, SchedulerConfig
 from kai_scheduler_tpu.framework.server import SchedulerServer
@@ -395,3 +397,237 @@ def test_debug_endpoints_hammer_no_torn_documents():
         assert all(s == 200 for s in statuses)
     finally:
         server.stop()
+
+
+# ---------------------------------------------------------------------------
+# spans inside the snapshot, self times, one clock, GC (ISSUE 26)
+# ---------------------------------------------------------------------------
+
+
+def _find(span, name):
+    """First span called ``name`` in the tree under ``span``, or None."""
+    for child in span.children:
+        if child.name == name:
+            return child
+        hit = _find(child, name)
+        if hit is not None:
+            return hit
+    return None
+
+
+def _full_then_patched():
+    """(trace of a rebuilt cycle, trace of a patched cycle)."""
+    cluster = _small_cluster()
+    sched = Scheduler()
+    sched.run_once(cluster)           # cold: rebuilds
+    full = sched.tracer.last(1)[0]
+    for _ in range(3):                # a time advance alone patches
+        cluster.tick()
+        sched.run_once(cluster)
+        patched = sched.tracer.last(1)[0]
+        if _find(patched.root, "snapshot.patch") is not None:
+            break
+    return full, patched
+
+
+@pytest.mark.parametrize("which, span", [
+    (0, "snapshot.full_build"), (1, "snapshot.patch")])
+def test_self_seconds_partition_the_cycle(which, span):
+    trace = _full_then_patched()[which]
+    assert _find(trace.root, span) is not None
+    selfs = trace.self_seconds()
+    assert abs(sum(selfs.values()) - trace.root.seconds) < 1e-6
+    assert all(v >= 0.0 for v in selfs.values())
+    assert "cycle" in selfs and "cycle/snapshot" in selfs
+    # every path starts at the root and names a span of the tree
+    for path in selfs:
+        node = trace.root
+        for part in path.split("/")[1:]:
+            node = next(c for c in node.children if c.name == part)
+
+
+def test_full_build_spans_nested_in_order():
+    full, _ = _full_then_patched()
+    build = _find(full.root, "snapshot.full_build")
+    assert build.attrs["fallback_reason"] == "cold"
+    assert [c.name for c in build.children] == [
+        "snapshot.lists", "snapshot.encode", "snapshot.transfer",
+        "snapshot.ledgers"]
+    sections = [c.name for c in _find(build, "snapshot.encode").children]
+    assert sections == ["encode.vocab", "encode.nodes", "encode.queues",
+                        "encode.gangs", "encode.running", "encode.rollups"]
+    transfer = _find(build, "snapshot.transfer")
+    assert transfer.attrs["bytes"] > 0 and transfer.attrs["leaves"] > 0
+    # the rest of the snapshot phase and of commit have spans too
+    assert _find(full.root, "snapshot.session") is not None
+    commit = next(c for c in full.root.children if c.name == "commit")
+    assert [c.name for c in commit.children] == [
+        "writes", "status_updates", "commit.decisions", "commit.metrics"]
+
+
+def test_patched_cycle_spans_nested_in_order():
+    _, patched = _full_then_patched()
+    patch = _find(patched.root, "snapshot.patch")
+    assert patch is not None and patch.attrs["mode"] == "patched"
+    assert [c.name for c in patch.children] == [
+        "patch.journal", "patch.sweep", "patch.assemble"]
+    snap = next(c for c in patched.root.children if c.name == "snapshot")
+    # the upload stays the snapshot phase's own child, after the patch
+    assert [c.name for c in snap.children][:2] == ["snapshot.patch",
+                                                   "upload"]
+    assert _find(patched.root, "snapshot.full_build") is None
+
+
+def test_build_snapshot_without_a_tracer_records_nothing():
+    from kai_scheduler_tpu.state import build_snapshot
+    tr = CycleTracer()
+    cluster = _small_cluster()
+    with tr.cycle() as trace:
+        build_snapshot(*cluster.snapshot_lists())
+        assert trace.root.children == []
+        build_snapshot(*cluster.snapshot_lists(), tracer=tr)
+    assert [c.name for c in trace.root.children] == [
+        "snapshot.encode", "snapshot.transfer"]
+
+
+def test_each_action_has_a_device_scope():
+    """Metadata only: the lowered five-action program names every
+    action in its operations' ``op_name`` locations, and the scopes one
+    level down where the device time is."""
+    from kai_scheduler_tpu.framework.scheduler import _fused_pipeline
+    from kai_scheduler_tpu.framework.session import Session
+    cfg = SchedulerConfig()
+    session = Session.open(*_preempt_cluster().snapshot_lists(),
+                           config=cfg.session, now=100.0)
+    sc = session.config
+    text = _fused_pipeline.__kai_jit__.lower(
+        session.state, session.state.queues.fair_share,
+        actions=tuple(cfg.actions), num_levels=sc.num_levels,
+        acfg=sc.allocate, vcfg=sc.victims,
+        grace_s=sc.stale_grace_s).as_text(debug_info=True)
+    locs = [ln for ln in text.splitlines() if ln.startswith("#loc")]
+    for scope in ("allocate", "consolidation", "reclaim", "preempt",
+                  "stalegangeviction", "placement_loop", "unit_tables",
+                  "wavefront"):
+        assert any(f"/{scope}/" in ln or f"/{scope}\"" in ln
+                   for ln in locs), scope
+
+
+def test_cycle_and_spans_enter_profiler_annotations(monkeypatch):
+    """One clock with the device: with ``TraceAnnotation`` replaced by a
+    recorder (no profiler session: one a process, seconds to start), a
+    cycle enters ``kai:cycle`` and its phases in order, and nothing
+    under a name the benchmark harness filters by."""
+    import jax.profiler
+
+    entered = []
+
+    class Recorder:
+        def __init__(self, name, **_):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    cluster = _small_cluster()
+    sched = Scheduler()
+    sched.run_once(cluster)           # compile outside the recording
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    cluster.tick()
+    sched.run_once(cluster)
+    assert all(n.startswith("kai:") for n in entered)
+    phases = [n for n in entered if n in (
+        "kai:cycle", "kai:snapshot", "kai:solve_dispatch",
+        "kai:device_wait", "kai:host_decode", "kai:commit")]
+    assert phases == ["kai:cycle", "kai:snapshot", "kai:solve_dispatch",
+                      "kai:device_wait", "kai:host_decode", "kai:commit"]
+    assert not {"churn_post", "cycle_post"} & set(entered)
+    trace = sched.tracer.last(1)[0]
+    assert abs(trace.wall_start_ns / 1e9 - trace.wall_start) < 1e-3
+
+
+def test_forced_full_collection_is_one_gc_pause_span():
+    import gc
+
+    from kai_scheduler_tpu.runtime.tracing import GcWatch
+    watch = GcWatch().install()
+    tr = CycleTracer(gc_watch=watch)
+    gc.disable()                      # only the forced collection
+    try:
+        with tr.cycle() as trace:
+            with tr.span("snapshot"):
+                with tr.span("snapshot.encode"):
+                    gc.collect()
+    finally:
+        gc.enable()
+        watch.uninstall()
+    assert trace.gc["collections"] == [0, 0, 1]
+    assert trace.gc["pause_seconds"][2] > 0.0
+    encode = _find(trace.root, "snapshot.encode")
+    pauses = [c for c in encode.children if c.name == "gc.pause"]
+    assert len(pauses) == 1 and "collected" in pauses[0].attrs
+    assert abs(pauses[0].seconds - trace.gc["pause_seconds"][2]) < 1e-6
+    assert sum(1 for p in trace.self_seconds() if "gc.pause" in p) == 1
+    assert watch._on_gc not in gc.callbacks
+    # without a watch a cycle books zeros
+    with CycleTracer().cycle() as bare:
+        pass
+    assert bare.gc == {"collections": [0, 0, 0],
+                       "pause_seconds": [0.0, 0.0, 0.0]}
+
+
+def test_gc_pause_across_a_span_boundary_is_cut_there():
+    """A full collection another thread set off can straddle two spans
+    of the cycle: each part goes to the span it lies in, and self times
+    still partition the cycle."""
+    from kai_scheduler_tpu.runtime.tracing import GcWatch
+    watch = GcWatch()
+    tr = CycleTracer(gc_watch=watch)
+    with tr.cycle() as trace:
+        with tr.span("a") as a:
+            pass
+        with tr.span("b") as b:
+            pass
+        # as the hook would have booked it, from inside a to inside b
+        mid_a = (a.start + a.end) / 2
+        mid_b = (b.start + b.end) / 2
+        watch.collections[2] += 1
+        watch.seconds[2] += mid_b - mid_a
+        watch.recent_full[0] = (mid_a, mid_b, 7)
+    pieces = {path: secs for path, secs in trace.self_seconds().items()
+              if path.endswith("gc.pause")}
+    assert set(pieces) == {"cycle/a/gc.pause", "cycle/gc.pause",
+                           "cycle/b/gc.pause"}
+    assert abs(sum(pieces.values()) - (mid_b - mid_a)) < 1e-9
+    assert abs(sum(trace.self_seconds().values())
+               - trace.root.seconds) < 1e-9
+    _assert_strictly_nested(tr.export_chrome())
+
+
+def test_jit_miss_inside_a_cycle_is_a_compile_span():
+    import jax
+    import jax.numpy as jnp
+
+    from kai_scheduler_tpu.runtime import compile_watch
+    watcher = compile_watch.CompileWatcher()
+    toy = watcher.wrap("toy", jax.jit(lambda x: x * 2 + 1))
+    compile_watch.WATCHER.listen()
+    before = compile_watch.WATCHER.stage_seconds()
+    assert set(before) == {"trace_s", "lower_s", "backend_compile_s",
+                           "cache_load_s"}
+    tr = CycleTracer()
+    with tr.cycle() as trace:
+        with tr.span("solve_dispatch"):
+            toy(jnp.arange(7))        # miss: traced, lowered, compiled
+            toy(jnp.arange(7))        # hit: no span
+    dispatch = trace.root.children[0]
+    assert [c.name for c in dispatch.children] == ["compile:toy"]
+    assert "signature" in dispatch.children[0].attrs
+    after = compile_watch.WATCHER.stage_seconds()
+    assert after["trace_s"] > before["trace_s"]
+    assert all(after[k] >= before[k] for k in before)
+    toy(jnp.arange(9))                # a miss outside any cycle: no-op
