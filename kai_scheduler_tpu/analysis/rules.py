@@ -858,8 +858,8 @@ _JOURNAL_CHOKE_PREFIX = "kai_scheduler_tpu/intake/"
 #: any of these on a journal object IS a hub-journal write
 _JOURNAL_MARK_METHODS = frozenset({
     "mark_pod", "mark_pod_added", "mark_pod_removed", "mark_gang",
-    "mark_gang_added", "mark_node", "mark_structural", "mark_time",
-    "merge",
+    "mark_gang_added", "mark_gang_removed", "mark_node",
+    "mark_structural", "mark_time", "merge",
 })
 
 

@@ -18,7 +18,10 @@ mark through these helpers.  One choke point buys two things:
 The helpers are deliberately thin pass-throughs: the journal's locking
 and cursor fan-out live with the journal (``state/incremental.py``);
 the gate owns only the *semantic mapping* from object mutations to mark
-kinds.  Dependency-free by design so ``runtime/cluster.py`` (which
+kinds: pods, pod groups and bind requests map to per-key marks the
+snapshotter patches (added, touched, removed); a new or deleted node or
+queue, and any write to the DRA/volume stores, is ``structural`` and
+rebuilds.  Dependency-free by design so ``runtime/cluster.py`` (which
 everything imports) can route through it without cycles.
 """
 from __future__ import annotations
@@ -57,6 +60,10 @@ def gang_touched(journal: "MutationJournal", name: str) -> None:
 
 def gang_added(journal: "MutationJournal", name: str) -> None:
     journal.mark_gang_added(name)
+
+
+def gang_removed(journal: "MutationJournal", name: str) -> None:
+    journal.mark_gang_removed(name)
 
 
 def node_touched(journal: "MutationJournal", name: str) -> None:
@@ -112,7 +119,15 @@ def delete_marks(coll: str, name: str, existed: bool, out: list) -> None:
         return
     if coll == "pods":
         out.append(("pod_removed", name))
+    elif coll == "pod_groups":
+        # a cluster deletes a group with its owner, so this is every
+        # completion and every eviction: the snapshotter closes up the
+        # gang's ledger row instead of rebuilding.  The delta applies
+        # this collection before ``pods`` (COLLECTIONS), so the group's
+        # pods may outlive the mark; the consumer orders the batch
+        out.append(("gang_removed", name))
     elif coll == "bind_requests":
         out.append(("pod", name))
     else:
+        # node/queue rows anchor vocabularies and dense id spaces
         out.append(("structural", f"{coll}-delete"))

@@ -18,9 +18,9 @@ Three pieces:
 
 - :class:`MutationJournal` — the cluster hub's change feed.  Every
   mutation (``submit``/``bind_pod``/``evict_pod``/``tick``, binder
-  commits, wire-delta upserts/deletes) records dirty node/queue/gang/pod
-  keys under a generation counter.  Multiple consumers each get their
-  own :class:`JournalCursor`.
+  commits, wire-delta upserts/deletes) records dirty, added and removed
+  node/gang/pod keys under a generation counter.  Multiple consumers
+  each get their own :class:`JournalCursor`.
 
 - :class:`IncrementalSnapshotter` — retains the previous cycle's host
   arrays + ``SnapshotIndex`` and re-derives only dirty rows through the
@@ -34,9 +34,14 @@ Three pieces:
 - Automatic **fallback to the full rebuild** whenever a patch cannot be
   proven bit-identical to a fresh ``build_snapshot``:
 
-  * structural change — node/queue/pod-group set or order changed,
-    topology swapped, padded-dim overflow (entity counts outgrew the
-    pinned :class:`~.cluster_state.SnapshotCapacity`);
+  * structural change — node/queue set or order changed, topology
+    swapped, padded-dim overflow (entity counts outgrew the pinned
+    :class:`~.cluster_state.SnapshotCapacity`).  The pod-group set is
+    NOT structural: a new group appends a ledger row, a deleted one
+    has its row closed up (``_remove_gangs`` — a cluster deletes a
+    group with its owner, so every completion and eviction does
+    this); only a name deleted and created again inside one window
+    escalates, since it moved to the end of the store;
   * vocabulary growth — selector keys, extended (MIG) keys, or filter
     classes beyond the empty spec would renumber dense id spaces;
   * feature pods — fractional/memory-share requests, DRA claims,
@@ -61,6 +66,7 @@ without ever reading the device state back on non-verify runs.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 import weakref
@@ -108,8 +114,8 @@ class _Fallback(Exception):
 
 
 _CURSOR_FIELDS = ("pods_dirty", "pods_added", "pods_removed",
-                  "gangs_dirty", "gangs_added", "nodes_dirty",
-                  "structural", "time_dirty")
+                  "gangs_dirty", "gangs_added", "gangs_removed",
+                  "nodes_dirty", "structural", "time_dirty")
 
 
 class JournalBatch:
@@ -124,6 +130,7 @@ class JournalBatch:
         self.pods_removed: set[str] = set()
         self.gangs_dirty: set[str] = set()
         self.gangs_added: list[str] = []
+        self.gangs_removed: set[str] = set()
         self.nodes_dirty: set[str] = set()
         self.structural: list[str] = []
         self.time_dirty = False
@@ -151,6 +158,7 @@ class JournalCursor:
         self.pods_removed: set[str] = set()
         self.gangs_dirty: set[str] = set()
         self.gangs_added: list[str] = []
+        self.gangs_removed: set[str] = set()
         self.nodes_dirty: set[str] = set()
         self.structural: list[str] = []
         self.time_dirty = False
@@ -235,7 +243,15 @@ class MutationJournal:
             elif kind == "gang":
                 c.gangs_dirty.add(name)
             elif kind == "gang_added":
-                c.gangs_added.append(name)
+                if name not in c.gangs_removed:
+                    c.gangs_added.append(name)
+                else:
+                    # removed-then-readded inside one window: the name
+                    # moved to the end of the store, every row between
+                    # shifted — same escalation as pod-readded
+                    c.structural.append("gang-readded")
+            elif kind == "gang_removed":
+                c.gangs_removed.add(name)
             elif kind == "node":
                 c.nodes_dirty.add(name)
             elif kind == "structural":
@@ -264,6 +280,10 @@ class MutationJournal:
     def mark_gang_added(self, name: str) -> None:
         with self._lock:
             self._apply_mark("gang_added", name)
+
+    def mark_gang_removed(self, name: str) -> None:
+        with self._lock:
+            self._apply_mark("gang_removed", name)
 
     def mark_node(self, name: str) -> None:
         with self._lock:
@@ -320,10 +340,46 @@ def _is_plain_pod(pod: apis.Pod) -> bool:
         or pod.dra_accel_count > 0)
 
 
+#: the gang ledger's per-row arrays as (attribute, dtype, fill): built
+#: by ``_rebuild_ledgers``, grown by ``_grow_gangs``, closed up by
+#: ``_remove_gangs`` (the ``g_objs``/``g_names``/``g_tc`` lists beside
+#: them hold exactly one entry per row)
+_GANG_COLUMNS = (
+    ("g_queue", np.int32, 0), ("g_minm", np.int32, 0),
+    ("g_prio", np.int32, 0), ("g_preempt", bool, False),
+    ("g_unsched", bool, False), ("g_start", np.float64, -1.0),
+    ("g_stale", np.float64, np.nan), ("g_reqlvl", np.int32, -1),
+    ("g_preflvl", np.int32, -1),
+)
+
+#: likewise the pod ledger's, as (attribute, dtype, fill, row shape);
+#: ``p_objs`` and ``p_sweep`` are the lists beside them
+_POD_COLUMNS = (
+    ("p_names", object, None, ()), ("p_live", bool, False, ()),
+    ("p_req", np.float32, 0.0, (R,)), ("p_prio", np.int64, 0, ()),
+    ("p_crea", np.float64, 0.0, ()), ("p_group", np.int32, -1, ()),
+    ("p_leader", bool, False, ()), ("p_plain", bool, False, ()),
+    ("p_devmask", np.int32, 0, ()), ("p_held", np.float32, 0.0, ()),
+    ("p_hasdev", bool, False, ()), ("p_eff_status", np.int8, -1, ()),
+    ("p_eff_node", np.int32, -1, ()), ("p_iid", np.int32, -1, ()),
+    ("p_ti", np.int32, -1, ()),
+)
+
+
+def _gather_rows(table: np.ndarray, src: np.ndarray, fill) -> np.ndarray:
+    """``table`` with rows ``src`` moved up to the front, in order, and
+    every row behind them padding — a fresh array (the old one may be
+    the previous cycle's leaf or index view)."""
+    out = np.full_like(table, fill)
+    out[:len(src)] = table[src]
+    return out
+
+
 #: ``SnapshotterStats.last`` of a rebuilt cycle, but for its reason
 _FULL_STATS = {
     "mode": "full", "fallback_reason": "",
     "dirty_pods": 0, "dirty_gangs": 0,
+    "pods_removed": 0, "gangs_removed": 0,
     "leaves_shipped": 0, "bytes_shipped": 0,
     "ship_seconds": 0.0, "ship_dispatches": 0,
 }
@@ -447,6 +503,8 @@ class IncrementalSnapshotter:
                     "mode": "patched", "fallback_reason": "",
                     "dirty_pods": self._last_dirty[0],
                     "dirty_gangs": self._last_dirty[1],
+                    "pods_removed": self._last_removed[0],
+                    "gangs_removed": self._last_removed[1],
                     "leaves_shipped": ship[0], "bytes_shipped": ship[1],
                     "ship_seconds": ship[2], "ship_dispatches": ship[3],
                 }
@@ -531,6 +589,8 @@ class IncrementalSnapshotter:
                     "mode": "resident", "fallback_reason": "",
                     "dirty_pods": self._last_dirty[0],
                     "dirty_gangs": self._last_dirty[1],
+                    "pods_removed": self._last_removed[0],
+                    "gangs_removed": self._last_removed[1],
                     "leaves_shipped": dstats["leaves"],
                     "bytes_shipped": dstats["bytes"],
                     "delta_elements": dstats["elements"],
@@ -618,9 +678,6 @@ class IncrementalSnapshotter:
             return "nonplain-gangs"
         if self._present_twice > 0:
             return "inflight-move"
-        live = int(self.p_live.sum())
-        if len(self.p_objs) > 2 * max(live, 64):
-            return "ledger-compaction"
         return None
 
     # ------------------------------------------------------------------
@@ -757,15 +814,8 @@ class IncrementalSnapshotter:
         self.g_objs: list = [None] * NG
         self.g_names: list[str] = [g.name for g in groups]
         self._gang_index = {g.name: i for i, g in enumerate(groups)}
-        self.g_queue = np.zeros((NG,), np.int32)
-        self.g_minm = np.zeros((NG,), np.int32)
-        self.g_prio = np.zeros((NG,), np.int32)
-        self.g_preempt = np.zeros((NG,), bool)
-        self.g_unsched = np.zeros((NG,), bool)
-        self.g_start = np.full((NG,), -1.0, np.float64)
-        self.g_stale = np.full((NG,), np.nan, np.float64)
-        self.g_reqlvl = np.full((NG,), -1, np.int32)
-        self.g_preflvl = np.full((NG,), -1, np.int32)
+        for name, dtype, fill in _GANG_COLUMNS:
+            setattr(self, name, np.full((NG,), fill, dtype))
         self.g_tc: list = [None] * NG
         self._q_index = {n: i for i, n in enumerate(self._queue_names)}
         self._nonplain_gangs = 0
@@ -774,24 +824,11 @@ class IncrementalSnapshotter:
         # --- pod ledger ---------------------------------------------------
         U = len(pods)
         self.p_objs: list = [None] * U
-        self.p_names = np.empty((U,), object)
         #: per-row (obj, raw status, raw node) — ONE list index per pod
         #: in the sweep's hot loop
         self.p_sweep: list = [None] * U
-        self.p_live = np.zeros((U,), bool)
-        self.p_req = np.zeros((U, R), np.float32)
-        self.p_prio = np.zeros((U,), np.int64)
-        self.p_crea = np.zeros((U,), np.float64)
-        self.p_group = np.full((U,), -1, np.int32)
-        self.p_leader = np.zeros((U,), bool)
-        self.p_plain = np.zeros((U,), bool)
-        self.p_devmask = np.zeros((U,), np.int32)
-        self.p_held = np.zeros((U,), np.float32)
-        self.p_hasdev = np.zeros((U,), bool)
-        self.p_eff_status = np.full((U,), -1, np.int8)
-        self.p_eff_node = np.full((U,), -1, np.int32)
-        self.p_iid = np.full((U,), -1, np.int32)
-        self.p_ti = np.full((U,), -1, np.int32)
+        for name, dtype, fill, tail in _POD_COLUMNS:
+            setattr(self, name, np.full((U,) + tail, fill, dtype))
         self._intern: dict[tuple, int] = {}
         self._intern_req = np.zeros((0, R), np.float32)
         self._nonplain = 0
@@ -958,64 +995,75 @@ class IncrementalSnapshotter:
         slack so appends stay amortized O(1))."""
         U = len(self.p_live)
         n = max(extra, U // 2, 64)
-        self.p_names = np.concatenate(
-            [self.p_names, np.empty((n,), object)])
-        for name in ("p_live", "p_leader", "p_plain", "p_hasdev"):
+        for name, dtype, fill, tail in _POD_COLUMNS:
             setattr(self, name, np.concatenate(
-                [getattr(self, name), np.zeros((n,), bool)]))
-        self.p_req = np.concatenate(
-            [self.p_req, np.zeros((n, R), np.float32)])
-        self.p_prio = np.concatenate(
-            [self.p_prio, np.zeros((n,), np.int64)])
-        self.p_crea = np.concatenate(
-            [self.p_crea, np.zeros((n,), np.float64)])
-        self.p_group = np.concatenate(
-            [self.p_group, np.full((n,), -1, np.int32)])
-        self.p_devmask = np.concatenate(
-            [self.p_devmask, np.zeros((n,), np.int32)])
-        self.p_held = np.concatenate(
-            [self.p_held, np.zeros((n,), np.float32)])
-        self.p_eff_status = np.concatenate(
-            [self.p_eff_status, np.full((n,), -1, np.int8)])
-        self.p_eff_node = np.concatenate(
-            [self.p_eff_node, np.full((n,), -1, np.int32)])
-        self.p_iid = np.concatenate(
-            [self.p_iid, np.full((n,), -1, np.int32)])
-        self.p_ti = np.concatenate(
-            [self.p_ti, np.full((n,), -1, np.int32)])
+                [getattr(self, name), np.full((n,) + tail, fill, dtype)]))
+
+    def _compact_pods(self) -> None:
+        """Close up the pod ledger over its released rows (appends only
+        ever take new rows, so churn leaves dead ones behind).  Live
+        rows keep their order, which is the store's; nothing they hold
+        changes, so nothing becomes dirty."""
+        keep = self.p_live[:len(self.p_objs)]
+        src = np.flatnonzero(keep)
+        n = len(src)
+        remap = np.cumsum(keep) - 1
+        kept = keep.tolist()
+        self.p_objs = list(itertools.compress(self.p_objs, kept))
+        self.p_sweep = list(itertools.compress(self.p_sweep, kept))
+        for name, _dtype, fill, _tail in _POD_COLUMNS:
+            col = getattr(self, name)
+            col[:n] = col[src]
+            col[n:] = fill
+        self._pod_row = {name: row for row, name
+                         in enumerate(self.p_names[:n].tolist())}
+        self._order = remap[self._order]
+        self._order_list = self._order.tolist()
 
     def _grow_gangs(self, extra: int) -> None:
         """Array-capacity growth; the g_* lists append exactly."""
         n = max(extra, 8)
-        self.g_queue = np.concatenate(
-            [self.g_queue, np.zeros((n,), np.int32)])
-        self.g_minm = np.concatenate(
-            [self.g_minm, np.zeros((n,), np.int32)])
-        self.g_prio = np.concatenate(
-            [self.g_prio, np.zeros((n,), np.int32)])
-        self.g_preempt = np.concatenate(
-            [self.g_preempt, np.zeros((n,), bool)])
-        self.g_unsched = np.concatenate(
-            [self.g_unsched, np.zeros((n,), bool)])
-        self.g_start = np.concatenate(
-            [self.g_start, np.full((n,), -1.0, np.float64)])
-        self.g_stale = np.concatenate(
-            [self.g_stale, np.full((n,), np.nan, np.float64)])
-        self.g_reqlvl = np.concatenate(
-            [self.g_reqlvl, np.full((n,), -1, np.int32)])
-        self.g_preflvl = np.concatenate(
-            [self.g_preflvl, np.full((n,), -1, np.int32)])
+        for name, dtype, fill in _GANG_COLUMNS:
+            setattr(self, name, np.concatenate(
+                [getattr(self, name), np.full((n,), fill, dtype)]))
 
-    def _apply_journal(self, cluster, j) -> tuple[set, set]:
+    def _apply_journal(self, cluster, j
+                       ) -> tuple[set, set, np.ndarray | None]:
         """Membership + dirty-field updates → (dirty pod rows, dirty
-        gang rows).  Raises _Fallback on anything unpatchable."""
+        gang rows, old row of each gang row).  The last is None unless
+        gang rows went (``_remove_gangs``).  Raises _Fallback on
+        anything unpatchable."""
         dirty_gangs: set[int] = set()
         dirty_rows: set[int] = set()
-        membership = bool(j.pods_added or j.pods_removed)
-        # gang appends first so new pods resolve their group row
+        membership = False
+        pods_gone = 0
+        # removals first, pods ahead of gangs: a delta deletes a group
+        # before its pods (gate.COLLECTIONS) but the batch is applied
+        # after the fact, and a pod that went in the same window is not
+        # an orphan of its group
+        for name in j.pods_removed:
+            row = self._pod_row.get(name)
+            if row is None or not self.p_live[row]:
+                continue
+            gi = int(self.p_group[row])
+            if gi >= 0:
+                dirty_gangs.add(gi)
+            self._release_pod(row)
+            del self._pod_row[name]
+            membership = True
+            pods_gone += 1
+        gang_src = None
+        gangs_gone = 0
+        if j.gangs_removed:
+            gang_src, gangs_gone = self._remove_gangs(
+                cluster, j.gangs_removed, dirty_rows, dirty_gangs)
+        self._last_removed = (pods_gone, gangs_gone)
+        # gang appends before pod appends so new pods resolve their row
         if j.gangs_added:
             for name in j.gangs_added:
                 g = cluster.pod_groups.get(name)
+                if g is None and name in j.gangs_removed:
+                    continue  # added then removed within the window
                 if g is None or name in self._gang_index:
                     raise _Fallback("gang-add-drift")
                 i = len(self._gang_index)
@@ -1039,22 +1087,12 @@ class IncrementalSnapshotter:
         for name in j.gangs_dirty:
             i = self._gang_index.get(name)
             if i is None:
-                continue  # deleted since; structural would have fired
+                continue  # touched, then removed within the window
             g = cluster.pod_groups.get(name)
             if g is None:
                 raise _Fallback("gang-removed-unjournaled")
             self._encode_gang(i, g)
             dirty_gangs.add(i)
-        for name in j.pods_removed:
-            row = self._pod_row.get(name)
-            if row is None or not self.p_live[row]:
-                continue
-            gi = int(self.p_group[row])
-            if gi >= 0:
-                dirty_gangs.add(gi)
-            self._release_pod(row)
-            del self._pod_row[name]
-            membership = True
         added_rows: list[int] = []
         for name in j.pods_added:
             pod = cluster.pods.get(name)
@@ -1095,7 +1133,52 @@ class IncrementalSnapshotter:
                     [order, np.asarray(added_rows, np.int64)])
             self._order = order
             self._order_list = order.tolist()
-        return dirty_rows, dirty_gangs
+        return dirty_rows, dirty_gangs, gang_src
+
+    def _remove_gangs(self, cluster, names, dirty_rows: set,
+                      dirty_gangs: set) -> tuple[np.ndarray | None, int]:
+        """Close up the ledger over the removed gangs' rows, in one
+        pass whatever their number → (src, rows removed).
+
+        A gang's row is its position in ``cluster.pod_groups``, so a
+        removal moves every later row up.  ``src[i]`` is the old row
+        of new row ``i``: ``_assemble`` sends the retained ``[G, T]``
+        task rows through it; everything else it derives from the
+        ledger.  A row that only moved is NOT dirty — its task slots
+        stand, it is not re-sorted and it does not count toward
+        ``dirty_threshold``; ``dirty_gangs`` comes out in new rows.  A
+        live pod of a removed gang is an orphan, encoded as a rebuild
+        would encode it (``p_group`` -1)."""
+        # a name not in the ledger was added in this same window and
+        # never got a row
+        gone = [i for i in map(self._gang_index.get, names)
+                if i is not None]
+        if not gone:
+            return None, 0
+        keep = np.ones((len(self.g_objs),), bool)
+        keep[gone] = False
+        src = np.flatnonzero(keep)
+        remap = np.where(keep, np.cumsum(keep) - 1, -1).astype(np.int32)
+        self._nonplain_gangs -= sum(
+            bool(self.g_objs[i].sub_groups) for i in gone)
+        kept = keep.tolist()
+        self.g_objs = list(itertools.compress(self.g_objs, kept))
+        self.g_names = list(itertools.compress(self.g_names, kept))
+        self.g_tc = list(itertools.compress(self.g_tc, kept))
+        self._gang_index = {n: i for i, n in enumerate(self.g_names)}
+        for name, _dtype, _fill in _GANG_COLUMNS:
+            col = getattr(self, name)
+            col[:len(src)] = col[src]
+        had = self.p_group >= 0
+        self.p_group[had] = remap[self.p_group[had]]
+        orphans = np.flatnonzero(self.p_live & had & (self.p_group < 0))
+        for row in orphans.tolist():
+            self._encode_pod(row, self.p_objs[row], cluster)
+            dirty_rows.add(row)
+        moved = [int(remap[i]) for i in dirty_gangs if keep[i]]
+        dirty_gangs.clear()
+        dirty_gangs.update(moved)
+        return src, len(gone)
 
     def _sweep(self, cluster, dirty_rows: set, dirty_gangs: set) -> None:
         """Detect un-journaled drift: object replacement, status/node
@@ -1189,7 +1272,10 @@ class IncrementalSnapshotter:
 
     def _patch(self, cluster, j, now, queue_usage):
         with self._span("patch.journal"):
-            dirty_rows, dirty_gangs = self._apply_journal(cluster, j)
+            if len(self.p_objs) > 2 * max(int(self.p_live.sum()), 64):
+                self._compact_pods()
+            dirty_rows, dirty_gangs, gang_src = self._apply_journal(
+                cluster, j)
         with self._span("patch.sweep"):
             self._sweep(cluster, dirty_rows, dirty_gangs)
         self._last_dirty = (len(dirty_rows), len(dirty_gangs))
@@ -1216,11 +1302,13 @@ class IncrementalSnapshotter:
             now = float(self.p_crea[order].max()) if len(order) else 0.0
         with self._span("patch.assemble"):
             return self._assemble(
-                cluster, dirty_gangs, now, queue_usage, host_old)
+                cluster, dirty_gangs, gang_src, now, queue_usage,
+                host_old)
 
     # -- assembly ----------------------------------------------------------
 
-    def _assemble(self, cluster, dirty_gangs, now, queue_usage, old):
+    def _assemble(self, cluster, dirty_gangs, gang_src, now, queue_usage,
+                  old):
         cap = self._capacity
         G, T = cap.gangs, cap.tasks
         N, Q, M = cap.nodes, cap.queues, cap.running
@@ -1264,11 +1352,18 @@ class IncrementalSnapshotter:
         task_req = np.asarray(og.task_req)
         task_type_old = np.asarray(og.task_type)
         tnames = self._task_names_obj
+        if gang_src is not None:
+            # gang rows were closed up: the retained rows move with
+            # their gangs, the rows vacated at the tail become padding
+            task_valid = _gather_rows(task_valid, gang_src, False)
+            task_req = _gather_rows(task_req, gang_src, 0.0)
+            tnames = _gather_rows(tnames, gang_src, None)
         if dirty_gangs:
             dg = np.asarray(sorted(dirty_gangs), np.int64)
-            task_valid = task_valid.copy()
-            task_req = task_req.copy()
-            tnames = tnames.copy()
+            if gang_src is None:
+                task_valid = task_valid.copy()
+                task_req = task_req.copy()
+                tnames = tnames.copy()
             task_valid[dg] = False
             task_req[dg] = 0.0
             tnames[dg] = None
@@ -1293,7 +1388,7 @@ class IncrementalSnapshotter:
                 task_valid[g_of, ti] = True
                 task_req[g_of, ti] = self._intern_req[self.p_iid[rows_s]]
                 tnames[g_of, ti] = self.p_names[rows_s]
-            self._task_names_obj = tnames
+        self._task_names_obj = tnames
         # task_type renumbers globally (dense first-encounter ids)
         task_type = np.zeros((G, T), np.int32)
         if len(intake):

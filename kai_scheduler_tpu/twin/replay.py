@@ -32,8 +32,8 @@ from . import stream as stream_mod
 #: the journal cursor fields the oracle compares (state/incremental.py
 #: ``JournalBatch`` — sets/lists of dirty keys plus the time flag)
 CURSOR_FIELDS = ("pods_dirty", "pods_added", "pods_removed",
-                 "gangs_dirty", "gangs_added", "nodes_dirty",
-                 "structural", "time_dirty")
+                 "gangs_dirty", "gangs_added", "gangs_removed",
+                 "nodes_dirty", "structural", "time_dirty")
 
 #: DecisionLog event fields digested per cycle (runtime/events.py)
 _DECISION_FIELDS = ("gang", "queue", "outcome", "detail")

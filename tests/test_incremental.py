@@ -17,6 +17,8 @@ import pytest
 from kai_scheduler_tpu.apis import types as apis
 from kai_scheduler_tpu.binder import Binder
 from kai_scheduler_tpu.framework.scheduler import Scheduler, SchedulerConfig
+from kai_scheduler_tpu.intake import apply as intake_apply
+from kai_scheduler_tpu.intake import gate
 from kai_scheduler_tpu.runtime.cluster import Cluster
 from kai_scheduler_tpu.state import make_cluster
 from kai_scheduler_tpu.state.incremental import (
@@ -36,6 +38,32 @@ def build(num_nodes=8, num_gangs=6, tasks_per_gang=2, **kw) -> Cluster:
 
 def refresh(snap, cluster):
     return snap.refresh(cluster, now=cluster.now)
+
+
+def delete_groups(cluster, names, pods=True) -> None:
+    """What a shim posts when gangs finish or are evicted whole: the
+    groups and — unless ``pods`` is False — their pods and the bind
+    requests that placed them, in one delta document."""
+    names = list(names)
+    delta = {"pod_groups_delete": names}
+    if pods:
+        gone = [p.name for p in cluster.pods.values() if p.group in names]
+        delta["pods_delete"] = gone
+        delta["bind_requests_delete"] = [
+            n for n in gone if n in cluster.bind_requests]
+    intake_apply.apply_cluster_delta(cluster, delta)
+
+
+def submit_groups(cluster, names, tasks=2) -> None:
+    """New gangs with pod groups of their own, as one delta document."""
+    intake_apply.apply_cluster_delta(cluster, {
+        "pod_groups_upsert": [
+            {"name": n, "queue": "queue-0-0", "min_member": tasks}
+            for n in names],
+        "pods_upsert": [
+            {"name": f"{n}-p{t}", "group": n,
+             "resources": {"accel": 1.0, "cpu": 1.0, "memory": 4.0}}
+            for n in names for t in range(tasks)]})
 
 
 # ---------------------------------------------------------------------------
@@ -91,6 +119,37 @@ class TestJournal:
         assert got.gangs_added == ["new-gang"]
         assert got.pods_added == ["new-pod"]
 
+    def test_gang_removed_is_a_mark_of_its_own(self):
+        j = MutationJournal()
+        cur = j.register()
+        gate.gang_removed(j, "g0")
+        j.mark_gang_added("g1")
+        got = cur.consume()
+        assert got.gangs_removed == {"g0"} and not got.structural
+        assert got.gangs_added == ["g1"]
+        # removed, then added again inside one window: the name moved
+        # to the end of the store — as pod-readded, too subtle to patch
+        j.mark_gang_removed("g2")
+        j.mark_gang_added("g2")
+        got = cur.consume()
+        assert got.structural == ["gang-readded"]
+        assert got.gangs_removed == {"g2"} and not got.gangs_added
+
+    @pytest.mark.parametrize("coll,mark", [
+        ("pods", ("pod_removed", "x")),
+        ("pod_groups", ("gang_removed", "x")),
+        ("bind_requests", ("pod", "x")),
+        ("nodes", ("structural", "nodes-delete")),
+        ("queues", ("structural", "queues-delete")),
+        ("resource_claims", ("structural", "resource_claims-delete")),
+    ])
+    def test_delete_marks_by_collection(self, coll, mark):
+        out: list = []
+        gate.delete_marks(coll, "x", True, out)
+        assert out == [mark]
+        gate.delete_marks(coll, "x", False, out)  # nothing was stored
+        assert out == [mark]
+
 
 # ---------------------------------------------------------------------------
 # Patch equivalence (verify=True asserts bit-identity internally)
@@ -145,7 +204,7 @@ class TestPatchEquivalence:
         for cycle in range(12):
             for _ in range(int(rng.integers(1, 4))):
                 op = rng.choice(["bind", "evict", "submit", "tick",
-                                 "mutate"])
+                                 "mutate", "delete"])
                 pods = list(cluster.pods.values())
                 if op == "bind":
                     pend = [p for p in pods
@@ -174,6 +233,12 @@ class TestPatchEquivalence:
                         for i in range(int(rng.integers(1, 3)))])
                 elif op == "tick":
                     cluster.tick()
+                elif op == "delete":
+                    names = list(cluster.pod_groups)
+                    k = min(len(names) - 1, int(rng.integers(1, 3)))
+                    delete_groups(cluster, [
+                        names[i] for i in rng.choice(
+                            len(names), size=k, replace=False)])
                 else:
                     run = [p for p in pods if p.status
                            == apis.PodStatus.RUNNING]
@@ -232,6 +297,180 @@ class TestPatchEquivalence:
         assert state1.nodes.allocatable is state0.nodes.allocatable
         # the running table did change
         assert state1.running.valid is not state0.running.valid
+
+
+# ---------------------------------------------------------------------------
+# Gang removal: the ledger closes up, the cycle patches (ISSUE 27)
+# ---------------------------------------------------------------------------
+
+
+def only_cold(snap) -> bool:
+    return snap.stats.fallbacks == {"cold": 1}
+
+
+class TestGangRemoval:
+    """A pod-group delete is a journal mark the patch path consumes.
+    ``verify=True`` holds every patched state to a fresh rebuild, leaf
+    for leaf and name for name; the tests then check that the patch
+    path engaged, for a fallback would pass that vacuously."""
+
+    def warm(self, **kw):
+        cluster = build(num_nodes=8, num_gangs=8, tasks_per_gang=2,
+                        running_fraction=0.5, **kw)
+        snap = IncrementalSnapshotter(verify=True, dirty_threshold=1.0)
+        refresh(snap, cluster)
+        return cluster, snap
+
+    @pytest.mark.parametrize("rows", [(0,), (7,), (1, 4, 5), (0, 7),
+                                      (0, 1, 2, 3, 4, 5, 6)])
+    def test_rows_close_up(self, rows):
+        cluster, snap = self.warm()
+        names = list(cluster.pod_groups)
+        delete_groups(cluster, [names[i] for i in rows])
+        _, index = refresh(snap, cluster)
+        assert snap.stats.patched == 1 and only_cold(snap), snap.stats
+        assert snap.stats.last["gangs_removed"] == len(rows)
+        assert snap.stats.last["pods_removed"] == 2 * len(rows)
+        # rows that only moved are not dirty
+        assert snap.stats.last["dirty_gangs"] == 0
+        kept = list(cluster.pod_groups)
+        assert index.gang_names[:len(kept)] == kept
+        # and a second window over the closed-up ledger patches too
+        cluster.tick()
+        refresh(snap, cluster)
+        assert snap.stats.patched == 2 and only_cold(snap)
+
+    def test_removal_and_arrival_in_one_window(self):
+        cluster, snap = self.warm()
+        names = list(cluster.pod_groups)
+        delete_groups(cluster, [names[2], names[6]])
+        submit_groups(cluster, ["late-a", "late-b", "late-c"])
+        _, index = refresh(snap, cluster)
+        assert snap.stats.patched == 1 and only_cold(snap), snap.stats
+        assert snap.stats.last["gangs_removed"] == 2
+        assert snap.stats.last["dirty_gangs"] == 3  # the arrivals only
+        assert index.gang_names[:9] == list(cluster.pod_groups)
+
+    def test_orphans_stay_until_their_pods_go(self):
+        """The group goes a cycle ahead of its pods: they are encoded
+        as a rebuild encodes a pod of no known group."""
+        cluster, snap = self.warm()
+        name = list(cluster.pod_groups)[3]
+        delete_groups(cluster, [name], pods=False)
+        refresh(snap, cluster)
+        assert snap.stats.patched == 1 and only_cold(snap), snap.stats
+        assert snap.stats.last["gangs_removed"] == 1
+        assert snap.stats.last["pods_removed"] == 0
+        assert snap.stats.last["dirty_pods"] == 2  # the orphans
+        orphans = [p.name for p in cluster.pods.values()
+                   if p.group == name]
+        intake_apply.apply_cluster_delta(
+            cluster, {"pods_delete": orphans})
+        refresh(snap, cluster)
+        assert snap.stats.patched == 2 and only_cold(snap), snap.stats
+        assert snap.stats.last["pods_removed"] == 2
+
+    def test_orphans_resolve_when_their_group_returns_later(self):
+        cluster, snap = self.warm()
+        name = list(cluster.pod_groups)[0]
+        queue = cluster.pod_groups[name].queue
+        delete_groups(cluster, [name], pods=False)
+        refresh(snap, cluster)
+        intake_apply.apply_cluster_delta(cluster, {"pod_groups_upsert": [
+            {"name": name, "queue": queue, "min_member": 2}]})
+        _, index = refresh(snap, cluster)
+        assert snap.stats.patched == 2 and only_cold(snap), snap.stats
+        assert index.gang_names[7] == name  # back, at the store's end
+
+    def test_added_then_removed_in_one_window(self):
+        cluster, snap = self.warm()
+        submit_groups(cluster, ["blink", "stays"])
+        delete_groups(cluster, ["blink"])
+        _, index = refresh(snap, cluster)
+        assert snap.stats.patched == 1 and only_cold(snap), snap.stats
+        # it never had a row, so none was closed up
+        assert snap.stats.last["gangs_removed"] == 0
+        assert "blink" not in index.gang_names
+        assert "stays" in index.gang_names
+
+    def test_removed_then_readded_escalates(self):
+        cluster, snap = self.warm()
+        name = list(cluster.pod_groups)[1]
+        delete_groups(cluster, [name])
+        submit_groups(cluster, [name])
+        refresh(snap, cluster)
+        assert snap.stats.patched == 0
+        assert snap.stats.last["fallback_reason"] \
+            == "structural:gang-readded"
+        # the rebuild re-anchors the ledger: the next removal patches
+        delete_groups(cluster, [name])
+        refresh(snap, cluster)
+        assert snap.stats.patched == 1
+
+    def test_touched_then_removed_in_one_window(self):
+        cluster, snap = self.warm()
+        name = list(cluster.pod_groups)[5]
+        gate.gang_touched(cluster.journal, name)
+        delete_groups(cluster, [name])
+        refresh(snap, cluster)
+        assert snap.stats.patched == 1 and only_cold(snap), snap.stats
+
+    def test_removal_does_not_trip_dirty_threshold(self):
+        """Seven of eight rows move when the first goes; none is dirty,
+        so even a threshold of nothing lets the patch through."""
+        cluster = build(num_nodes=8, num_gangs=8, tasks_per_gang=2,
+                        running_fraction=0.5)
+        snap = IncrementalSnapshotter(verify=True, dirty_threshold=0.0)
+        refresh(snap, cluster)
+        delete_groups(cluster, [list(cluster.pod_groups)[0]])
+        refresh(snap, cluster)
+        assert snap.stats.patched == 1 and only_cold(snap), snap.stats
+        assert snap.stats.last["dirty_pods"] == 0
+        assert snap.stats.last["dirty_gangs"] == 0
+
+    def test_shapes_hold_over_turnover(self):
+        """8 gangs out and 8 in, 20 cycles: the gang axis never fills
+        (a ledger that only appended would overflow it within the run)
+        and no padded shape moves."""
+        cluster = build(num_nodes=16, num_gangs=32, tasks_per_gang=2,
+                        running_fraction=0.5)
+        snap = IncrementalSnapshotter(verify=True, dirty_threshold=1.0)
+        state, _ = refresh(snap, cluster)
+        shapes = [leaf.shape for leaf in jax.tree.leaves(state)]
+        cap = snap._capacity
+        assert cap.gangs < 32 + 20 * 8
+        rng = np.random.default_rng(7)
+        for cyc in range(20):
+            names = list(cluster.pod_groups)
+            delete_groups(cluster, [names[i] for i in rng.choice(
+                len(names), size=8, replace=False)])
+            submit_groups(cluster, [f"t{cyc}-{i}" for i in range(8)])
+            state, index = refresh(snap, cluster)
+            assert [leaf.shape for leaf in jax.tree.leaves(state)] \
+                == shapes
+            assert index.gang_names[:32] == list(cluster.pod_groups)
+        assert snap.stats.patched == 20 and only_cold(snap), snap.stats
+        assert snap._capacity == cap
+
+    def test_pod_ledger_compacts_in_place(self):
+        """Appends only ever take new pod rows; once the dead ones
+        outnumber the live the ledger closes up over them — it used to
+        rebuild (``ledger-compaction``)."""
+        cluster = build(num_nodes=16, num_gangs=40, tasks_per_gang=2,
+                        running_fraction=0.5)
+        snap = IncrementalSnapshotter(verify=True, dirty_threshold=1.0)
+        refresh(snap, cluster)
+        live = len(cluster.pods)
+        longest = 0
+        for cyc in range(24):
+            delete_groups(cluster, list(cluster.pod_groups)[:4])
+            submit_groups(cluster, [f"c{cyc}-{i}" for i in range(4)])
+            refresh(snap, cluster)
+            longest = max(longest, len(snap.p_objs))
+            assert len(snap.p_objs) <= 2 * live + 8
+        assert longest > 2 * live - 8  # the threshold was reached
+        assert len(snap.p_objs) < longest  # and the ledger closed up
+        assert snap.stats.patched == 24 and only_cold(snap), snap.stats
 
 
 # ---------------------------------------------------------------------------

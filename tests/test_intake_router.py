@@ -33,8 +33,8 @@ from kai_scheduler_tpu.state.incremental import MutationJournal
 pytestmark = pytest.mark.core
 
 CURSOR_FIELDS = ("pods_dirty", "pods_added", "pods_removed",
-                 "gangs_dirty", "gangs_added", "nodes_dirty",
-                 "structural", "time_dirty")
+                 "gangs_dirty", "gangs_added", "gangs_removed",
+                 "nodes_dirty", "structural", "time_dirty")
 
 
 def _cluster():
@@ -52,10 +52,11 @@ def _assert_cursor_equal(batch_a, batch_b):
 def _storm_deltas(rng: random.Random, n: int) -> list[dict]:
     """Interleaved creates / partial updates / deletes / clock ticks
     over a small key space, so same-key races (update-after-delete,
-    delete-then-recreate) occur by construction."""
+    delete-then-recreate, a group deleted under its pods and created
+    again) occur by construction."""
     deltas = []
     for i in range(n):
-        kind = rng.randrange(5)
+        kind = rng.randrange(6)
         pid = rng.randrange(12)
         pod = f"storm-p{pid}"
         gang = f"storm-g{pid % 5}"
@@ -74,6 +75,11 @@ def _storm_deltas(rng: random.Random, n: int) -> list[dict]:
             deltas.append({"pods_delete": [pod]})
         elif kind == 3:  # clock advance
             deltas.append({"now": float(i)})
+        elif kind == 5:  # a gang finishes: group, and often its pod
+            doc = {"pod_groups_delete": [gang]}
+            if rng.randrange(3):
+                doc["pods_delete"] = [pod]
+            deltas.append(doc)
         else:  # mixed multi-collection document
             deltas.append({
                 "pods_upsert": [{"name": pod, "group": gang}],
@@ -90,13 +96,17 @@ def _storm_deltas(rng: random.Random, n: int) -> list[dict]:
 def test_journal_merge_identical_to_sequential_marks():
     """MutationJournal.merge replays (kind, name) batches with the
     exact per-mark semantics — including the order-sensitive
-    pod-readded structural escalation — under one lock acquisition."""
+    pod-readded and gang-readded structural escalations — under one
+    lock acquisition."""
     j_seq, j_merge = MutationJournal(), MutationJournal()
     cur_seq, cur_merge = j_seq.register(), j_merge.register()
     ops = [("pod", "a"), ("pod_added", "b"), ("pod_removed", "c"),
            ("pod_added", "c"),           # removed-then-readded
            ("gang", "g"), ("gang_added", "h"), ("node", "n"),
-           ("structural", "why"), ("time", ""), ("pod_added", "a")]
+           ("structural", "why"), ("time", ""), ("pod_added", "a"),
+           ("gang_removed", "k"), ("gang_removed", "h"),
+           ("gang_added", "h"),          # removed-then-readded
+           ("gang_added", "m"), ("gang_removed", "m")]
     j_seq.mark_pod("a")
     j_seq.mark_pod_added("b")
     j_seq.mark_pod_removed("c")
@@ -107,9 +117,19 @@ def test_journal_merge_identical_to_sequential_marks():
     j_seq.mark_structural("why")
     j_seq.mark_time()
     j_seq.mark_pod_added("a")
+    j_seq.mark_gang_removed("k")
+    j_seq.mark_gang_removed("h")
+    j_seq.mark_gang_added("h")
+    j_seq.mark_gang_added("m")
+    j_seq.mark_gang_removed("m")
     j_merge.merge(ops)
     assert j_seq.generation == j_merge.generation == len(ops)
-    _assert_cursor_equal(cur_seq.consume(), cur_merge.consume())
+    got_seq, got_merge = cur_seq.consume(), cur_merge.consume()
+    _assert_cursor_equal(got_seq, got_merge)
+    assert got_merge.gangs_removed == {"k", "h", "m"}
+    assert got_merge.gangs_added == ["h", "m"]
+    assert got_merge.structural == ["pod-readded", "why", "pod-readded",
+                                    "gang-readded"]
 
     with pytest.raises(ValueError, match="unknown journal mark"):
         j_merge.merge([("bogus", "x")])
@@ -193,6 +213,68 @@ def test_storm_vs_sequential_bit_identical():
                       for e in evs if e["cycle"] == last)
 
     assert last_events(s_classic) == last_events(s_intake)
+
+
+def test_group_delete_storm_patches_identically_on_both_paths():
+    """Gang turnover (groups deleted with their pods, new gangs with
+    groups of their own) between two cycles: through the lanes and
+    through the classic path the journals agree mark for mark, both
+    snapshotters PATCH over the closed-up ledger (``verify_incremental``
+    holds each to a fresh rebuild), and the cycles' results agree."""
+    c_classic = _cluster()
+    c_intake = copy.deepcopy(c_classic)
+    cfg = SchedulerConfig(verify_incremental=True,
+                          incremental_dirty_threshold=1.0)
+    s_classic, s_intake = Scheduler(cfg), Scheduler(cfg)
+    assert s_classic.run_once(c_classic).bind_requests \
+        == s_intake.run_once(c_intake).bind_requests
+    cur_classic = c_classic.journal.register()
+    cur_intake = c_intake.journal.register()
+
+    rng = random.Random(99)
+    names = list(c_classic.pod_groups)
+    rng.shuffle(names)
+    deltas = []
+    for k, gang in enumerate(names[:3]):
+        pods = [p.name for p in c_classic.pods.values() if p.group == gang]
+        deltas.append({"pod_groups_delete": [gang], "pods_delete": pods,
+                       "bind_requests_delete": pods})
+        deltas.append({
+            "pod_groups_upsert": [{"name": f"turn-{k}",
+                                   "queue": "queue-0-0", "min_member": 2}],
+            "pods_upsert": [{"name": f"turn-{k}-{t}", "group": f"turn-{k}",
+                             "resources": {"accel": 1.0, "cpu": 1.0,
+                                           "memory": 1.0}}
+                            for t in range(2)]})
+        deltas.append({"now": float(k + 1)})
+
+    for d in deltas:
+        intake_apply.apply_cluster_delta(c_classic, d)
+    router = IntakeRouter(IntakeConfig(lanes=4, lane_capacity=1000,
+                                       batch=4)).start()
+    try:
+        for d in deltas:
+            assert router.submit_delta(d)["shed"] == 0
+        assert router.drain_inline(timeout=30)
+        router.coalesce(c_intake)
+    finally:
+        router.stop()
+
+    got_classic, got_intake = cur_classic.consume(), cur_intake.consume()
+    _assert_cursor_equal(got_classic, got_intake)
+    assert got_intake.gangs_removed == set(names[:3])
+    assert not got_intake.structural
+    assert dump_cluster(c_classic) == dump_cluster(c_intake)
+
+    r_classic = s_classic.run_once(c_classic)
+    r_intake = s_intake.run_once(c_intake)
+    assert r_classic.bind_requests == r_intake.bind_requests
+    assert len(r_intake.bind_requests) == 6
+    assert r_classic.evictions == r_intake.evictions
+    for sched in (s_classic, s_intake):
+        last = sched._snapshotter.stats.last
+        assert last["mode"] == "patched", last
+        assert last["gangs_removed"] == 3 and last["pods_removed"] == 6
 
 
 def test_concurrent_producers_storm_converges():

@@ -97,3 +97,32 @@ def test_stored_cluster_and_delta_through_proto():
         assert "g1-0" in binds and "g0-0" in binds
     finally:
         server.stop()
+
+
+def test_proto_delta_group_delete_is_a_patchable_mark():
+    """The proto delta applier marks through the same gate as the JSON
+    one: a pod-group delete is ``gang_removed``, not structural, and the
+    snapshot of the cycle after it patches."""
+    from kai_scheduler_tpu.state.incremental import IncrementalSnapshotter
+    plain = _cluster()
+    cluster = Cluster.from_objects(
+        list(plain.nodes.values()), list(plain.queues.values()),
+        [apis.PodGroup(name=g, queue="q0", min_member=2)
+         for g in ("g0", "g1")],
+        [apis.Pod(name=f"{g}-{i}", group=g,
+                  resources=apis.ResourceVec(1.0, 1.0, 1.0))
+         for g in ("g0", "g1") for i in range(2)], None)
+    snap = IncrementalSnapshotter(verify=True, dirty_threshold=1.0)
+    snap.refresh(cluster, now=cluster.now)
+    cursor = cluster.journal.register()
+    delta = pb.ClusterDelta()
+    delta.pod_groups_delete.append("g0")
+    delta.pods_delete.extend(["g0-0", "g0-1"])
+    codec.apply_delta_msg(cluster, delta)
+    batch = cursor.consume()
+    assert batch.gangs_removed == {"g0"} and not batch.structural
+    assert batch.pods_removed == {"g0-0", "g0-1"}
+    _, index = snap.refresh(cluster, now=cluster.now)
+    assert snap.stats.last["mode"] == "patched"
+    assert snap.stats.last["gangs_removed"] == 1
+    assert index.gang_names[0] == "g1"

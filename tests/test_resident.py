@@ -28,6 +28,7 @@ import pytest
 from kai_scheduler_tpu.apis import types as apis
 from kai_scheduler_tpu.framework.scheduler import (Scheduler,
                                                    SchedulerConfig)
+from kai_scheduler_tpu.intake import apply as intake_apply
 from kai_scheduler_tpu.ops import resident as resident_ops
 from kai_scheduler_tpu.runtime.cluster import Cluster
 from kai_scheduler_tpu.runtime.compile_watch import WATCHER
@@ -367,6 +368,43 @@ def test_resident_verify_mode_passes_and_catches_divergence():
     snap._host.nodes.free.reshape(-1)[0] += 1.0
     with pytest.raises(IncrementalVerifyError, match="resident leaf"):
         snap.verify_device_residency()
+
+
+def test_group_delete_rides_the_packed_delta():
+    """Finished gangs' groups go with their pods: the mirror closes up
+    the gang rows and the cycle stays RESIDENT — every gang row behind
+    a removed one moves, and all of that rides the one packed delta
+    (no fallback, no re-upload).  Device state equal to the mirror,
+    decisions equal to a full-rebuild twin's."""
+    c_res = _steady_cluster(num_nodes=8, num_gangs=12)
+    c_twin = copy.deepcopy(c_res)
+    s_res = Scheduler(SchedulerConfig(resident=True,
+                                      verify_incremental=True,
+                                      incremental_dirty_threshold=1.0))
+    s_twin = Scheduler(SchedulerConfig(incremental=False))
+    assert s_res.run_once(c_res).bind_requests \
+        == s_twin.run_once(c_twin).bind_requests
+    for cyc in range(3):
+        running = {p.group for p in c_res.pods.values()
+                   if p.status == apis.PodStatus.RUNNING}
+        gone = [g for g in c_res.pod_groups if g in running][:2]
+        assert len(gone) == 2
+        pods = [p.name for p in c_res.pods.values() if p.group in gone]
+        for cl in (c_res, c_twin):
+            intake_apply.apply_cluster_delta(cl, {
+                "pod_groups_delete": gone, "pods_delete": pods,
+                "now": cl.now + 1.0})
+            _submit_extra_gang(cl, cyc)
+        r1, r2 = s_res.run_once(c_res), s_twin.run_once(c_twin)
+        assert r1.bind_requests == r2.bind_requests, cyc
+        assert r1.evictions == r2.evictions, cyc
+        last = s_res._snapshotter.stats.last
+        assert last["mode"] == "resident", (cyc, last)
+        assert last["gangs_removed"] == 2
+        assert last["pods_removed"] == len(pods)
+    snap = s_res._snapshotter
+    assert set(snap.stats.fallbacks) == {"cold"}
+    snap.verify_device_residency()  # device == the closed-up mirror
 
 
 def test_desync_guard_forces_full_rebuild():

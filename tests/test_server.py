@@ -357,3 +357,82 @@ def test_metrics_offer_no_series_that_nothing_feeds():
                  "kai_preemption_attempts_total"):
         assert dead not in text
     assert "kai_e2e_scheduling_latency_seconds" in text
+
+
+# ---------------------------------------------------------------------------
+# gang turnover, served: group deletes patch (ISSUE 27)
+# ---------------------------------------------------------------------------
+
+
+def _post_json(base, path, doc):
+    req = urllib.request.Request(
+        base + path, data=json.dumps(doc).encode(),
+        headers={"Content-Type": "application/json"})
+    return json.load(urllib.request.urlopen(req, timeout=300))
+
+
+def test_served_gang_turnover_patches_over_deleted_groups():
+    """What a shim sends between cycles of a busy cluster, at 256 nodes
+    half full: finished gangs' pods, bind requests and pod GROUPS
+    deleted through ``/cluster/delta``, new gangs with groups of their
+    own through ``/intake``.  Every cycle after the first patches its
+    snapshot (``verify_incremental`` holds each to a fresh rebuild) and
+    says on ``/healthz`` how many gang rows it closed up."""
+    from kai_scheduler_tpu.framework.scheduler import (Scheduler,
+                                                       SchedulerConfig)
+    nodes, queues, groups, pods, topo = make_cluster(
+        num_nodes=256, node_accel=8.0, num_gangs=128, tasks_per_gang=8,
+        running_fraction=1.0)
+    cluster = Cluster.from_objects(nodes, queues, groups, pods, topo)
+    placed = {g.name: [p.name for p in pods if p.group == g.name]
+              for g in groups}
+    bound_by_commit: set = set()
+    server = SchedulerServer(
+        cluster, Scheduler(SchedulerConfig(verify_incremental=True))
+    ).start()
+    base = f"http://127.0.0.1:{server.port}"
+    snaps = []
+    try:
+        for cyc in range(5):
+            # three of the first hour and the newest: from the second
+            # cycle on that is a gang the last commit bound
+            finished = list(placed)[cyc::17][:3] + [list(placed)[-1]]
+            gone = [p for g in finished for p in placed.pop(g)]
+            _post_json(base, "/cluster/delta", {
+                "now": float(cyc + 1), "pods_delete": gone,
+                "pod_groups_delete": finished,
+                "bind_requests_delete": [
+                    p for p in gone if p in bound_by_commit]})
+            names = [f"job-{cyc}-{i}" for i in range(4)]
+            _post_json(base, "/intake", {
+                "pod_groups_upsert": [
+                    {"name": n, "queue": "queue-0-0", "min_member": 8}
+                    for n in names],
+                "pods_upsert": [
+                    {"name": f"{n}-{t}", "group": n,
+                     "resources": {"accel": 1.0, "cpu": 1.0,
+                                   "memory": 4.0}}
+                    for n in names for t in range(8)]})
+            commit = _post_json(base, "/cycle/stored", {})
+            assert len(commit["bind_requests"]) == 32, cyc
+            assert commit["evictions"] == []
+            for br in commit["bind_requests"]:
+                placed.setdefault(br["pod"].rsplit("-", 1)[0],
+                                  []).append(br["pod"])
+                bound_by_commit.add(br["pod"])
+            snaps.append(json.load(urllib.request.urlopen(
+                f"{base}/healthz"))["last_cycle"]["snapshot"])
+        stored = json.load(urllib.request.urlopen(f"{base}/snapshot"))
+    finally:
+        server.stop()
+    assert snaps[0]["mode"] == "full"  # the cold build
+    for cyc, snap in enumerate(snaps[1:], start=1):
+        assert snap["mode"] == "patched", (cyc, snap)
+        assert snap["gangs_removed"] == 4 and snap["pods_removed"] == 32
+        # the four arrivals and the three gangs of the last commit's
+        # four that are still there: of the rows that only moved, none
+        assert snap["dirty_gangs"] == 7
+    # later cycles finished gangs that earlier commits had bound
+    assert "job-0-3" not in placed and bound_by_commit
+    assert {g["name"] for g in stored["pod_groups"]} == set(placed)
+    assert len(stored["pod_groups"]) == 128
