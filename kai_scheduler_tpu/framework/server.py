@@ -673,17 +673,19 @@ class SchedulerServer:
         t0 = time.perf_counter()
         with self._state_lock:
             t1 = time.perf_counter()
-            self.intake.coalesce(self.cluster)
+            merged = self.intake.coalesce(self.cluster)
             t2 = time.perf_counter()
             result = self.scheduler.run_once(self.cluster)
             self._record_cycle(result, lock_wait_s=t1 - t0,
-                               coalesce_s=t2 - t1)
+                               coalesce_s=t2 - t1,
+                               parsed_pods=merged["parsed_pods"])
             if self.recorder is not None:
                 self.recorder.record_cycle()
         return result
 
     def _record_cycle(self, result, lock_wait_s: float = 0.0,
-                      coalesce_s: float = 0.0) -> None:
+                      coalesce_s: float = 0.0,
+                      parsed_pods: int = 0) -> None:
         """Swap in a fresh immutable per-cycle stats document (served
         by ``GET /healthz``).  Called under ``_state_lock``; readers
         take the current binding without it (atomic-swap discipline —
@@ -721,6 +723,7 @@ class SchedulerServer:
                     gc=trace.gc,
                     entry_seconds={"lock_wait": lock_wait_s,
                                    "coalesce": coalesce_s},
+                    intake_parsed_pods=parsed_pods,
                     startup={"phase_seconds": first,
                              **compile_watch.WATCHER.stage_seconds()})
             # kai-pulse slice: the headline cluster-health gauges of

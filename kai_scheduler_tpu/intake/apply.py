@@ -113,6 +113,12 @@ def _pod_fast_tables() -> tuple[dict, list, frozenset]:
 
 _POD_SHARED, _POD_FRESH, _POD_KNOWN_KEYS = None, None, None
 
+#: pod upserts that took the generic parser since the process started
+#: (a new pod the fast path refused, or an update of a stored one).  It
+#: only counts on; whoever holds the commit lock over an apply reads it
+#: before and after (``IntakeRouter.coalesce``)
+PARSED_PODS = [0]
+
 
 def _fast_new_pod(doc: dict):
     """A brand-new pod from a delta doc, bypassing the default-doc
@@ -196,6 +202,8 @@ def apply_event(cluster, op: str, coll: str, payload,
         if coll == "pods" and key0 not in store:
             obj = _fast_new_pod(doc)
         if obj is None:
+            if coll == "pods":
+                PARSED_PODS[0] += 1
             if key0 in store:
                 full = snap._to_jsonable(store[key0])
             else:

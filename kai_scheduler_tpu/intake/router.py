@@ -504,6 +504,7 @@ class IntakeRouter:
                 staged.extend(taken[:cut])
         staged.sort(key=attrgetter("seq"))
         apply_errors: list = []
+        parsed0 = _apply.PARSED_PODS[0]
         n = _apply.apply_events(cluster, staged, errors=apply_errors)
         applied = n - len(apply_errors)
         dt = time.perf_counter() - t0
@@ -525,6 +526,7 @@ class IntakeRouter:
                 str(snap["lane"]),
                 value=float(snap["queued"] + snap["staged"]))
         return {"events": applied, "seconds": dt,
+                "parsed_pods": _apply.PARSED_PODS[0] - parsed0,
                 "apply_errors": apply_errors[:8]}
 
     # -- observability ----------------------------------------------------------

@@ -771,7 +771,8 @@ def _attempt_gang_in_domain(
         # confined to a small domain (an absolute-index rotation would
         # collapse every lane onto the same first feasible node there,
         # serializing the chunk to one accepted gang)
-        rank_feas = jnp.cumsum(fit_pipe.astype(jnp.int32)) - 1
+        with jax.named_scope("feasibility"):
+            rank_feas = jnp.cumsum(fit_pipe.astype(jnp.int32)) - 1
         tie_jitter = (-1e-4 / N) * jnp.mod(rank_feas - lane, N).astype(
             jnp.float32)                                               # [N]
         # soft filter bands (PreferNoSchedule / preferred pod-affinity)
@@ -1118,7 +1119,8 @@ def _attempt_gang_in_domain_uniform(
         # when selectors/filters/domains confine feasibility to a sliver
         # of the index space (an absolute rotation would collapse every
         # lane onto the same first feasible node there)
-        rank_feas = jnp.cumsum(fit_pipe.astype(jnp.int32)) - 1
+        with jax.named_scope("feasibility"):
+            rank_feas = jnp.cumsum(fit_pipe.astype(jnp.int32)) - 1
         tie_jitter = (-1e-4 / N) * jnp.mod(rank_feas - lane, N).astype(
             jnp.float32)                                # [N]
 

@@ -157,11 +157,15 @@ def feasible_nodes(
     fit = resource_fit_mask(avail, req)
     accel = accel_fit_mask(nodes, task_req, task_portion, task_accel_mem,
                            df, include_releasing)
-    sel = selector_mask(nodes.labels, task_selector)
-    out = fit & accel & sel & nodes.valid
-    if task_class is not None:
-        # taints/affinity/pod-affinity, host-evaluated per filter class
-        out = out & nodes.filter_masks[task_class]
+    with jax.named_scope("feasibility"):
+        # (the operations and their order are the parent's: the scope is
+        # metadata, and the compiled program stays the one the cells had)
+        sel = selector_mask(nodes.labels, task_selector)
+        out = fit & accel & sel & nodes.valid
+        if task_class is not None:
+            # taints/affinity/pod-affinity, host-evaluated per filter
+            # class
+            out = out & nodes.filter_masks[task_class]
     return out
 
 
@@ -190,9 +194,10 @@ def feasible_nodes_dual(
     portion = jnp.asarray(task_portion)
     is_frac = (portion > 0) | (mem > 0)
     req = jnp.asarray(task_req)
-    sel = selector_mask(nodes.labels, task_selector) & nodes.valid     # [N]
-    if task_class is not None:
-        sel = sel & nodes.filter_masks[task_class]
+    with jax.named_scope("feasibility"):
+        sel = selector_mask(nodes.labels, task_selector) & nodes.valid  # [N]
+        if task_class is not None:
+            sel = sel & nodes.filter_masks[task_class]
 
     if not devices:
         fit_idle = jnp.all(free + EPS >= req[None, :], axis=-1) & sel

@@ -1704,6 +1704,7 @@ def _encode_snapshot(
     q_request, q_usage = roll["q_request"], roll["q_usage"]
 
     # --- evaluate filter classes against nodes (host, once per spec) ------
+    sections("encode.filters")
     running_views = [
         node_filters._RunningPodView(
             labels=pod.labels,
@@ -1719,6 +1720,7 @@ def _encode_snapshot(
         running_views, N, incycle_pos_terms=frozenset(incycle_pos_terms))
 
     # --- kernel-config hints derived from the snapshot shape --------------
+    sections("encode.rollups")
     has_fracs = bool(gk["task_portion"].any() or gk["task_accel_mem"].any()
                      or (rk["device"] >= 0).any())
     tvm = gk["task_valid"][:, :, None]

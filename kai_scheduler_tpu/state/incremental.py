@@ -384,6 +384,11 @@ _FULL_STATS = {
     "ship_seconds": 0.0, "ship_dispatches": 0,
 }
 
+#: what a patched cycle's cluster holds of the irregular vocabularies:
+#: nothing, or ``_patch_blockers`` would have rebuilt.  A rebuilt cycle
+#: reports what its build found (``_full``)
+_PLAIN_VOCAB = {"nonplain_pods": 0, "filter_classes": 1, "selector_keys": 0}
+
 
 @dataclasses.dataclass
 class SnapshotterStats:
@@ -449,6 +454,8 @@ class IncrementalSnapshotter:
         self._dev = None         # device ClusterState (previous cycle)
         self._index = None
         self._capacity = SnapshotCapacity()
+        #: the last rebuild's irregular vocabularies (``stats.last``)
+        self._built_vocab: dict = {}
         #: kai-resident desync guard: True between a staged delta
         #: (refresh_resident) and its adoption (adopt_device_state)
         self._delta_outstanding = False
@@ -507,6 +514,7 @@ class IncrementalSnapshotter:
                     "gangs_removed": self._last_removed[1],
                     "leaves_shipped": ship[0], "bytes_shipped": ship[1],
                     "ship_seconds": ship[2], "ship_dispatches": ship[3],
+                    **_PLAIN_VOCAB,
                 }
                 patch_sp.attrs.update(self.stats.last)
                 if self.verify:
@@ -518,7 +526,8 @@ class IncrementalSnapshotter:
         # "fallback", so this cycle's upload phase reads 0
         with self._span("snapshot.full_build", fallback_reason=reason):
             out = self._full(cluster, now, queue_usage)
-        self.stats.last = dict(_FULL_STATS, fallback_reason=reason)
+        self.stats.last = dict(_FULL_STATS, fallback_reason=reason,
+                               **self._built_vocab)
         return out
 
     # -- kai-resident ------------------------------------------------------
@@ -595,6 +604,7 @@ class IncrementalSnapshotter:
                     "bytes_shipped": dstats["bytes"],
                     "delta_elements": dstats["elements"],
                     "ship_seconds": ship_s, "ship_dispatches": 1,
+                    **_PLAIN_VOCAB,
                 }
                 patch_sp.attrs.update(self.stats.last)
                 self._add_span("upload", t_ship,
@@ -608,7 +618,8 @@ class IncrementalSnapshotter:
         self.stats.fallback(reason)
         with self._span("snapshot.full_build", fallback_reason=reason):
             state, index = self._full(cluster, now, queue_usage)
-        self.stats.last = dict(_FULL_STATS, fallback_reason=reason)
+        self.stats.last = dict(_FULL_STATS, fallback_reason=reason,
+                               **self._built_vocab)
         return ResidentRefresh(mode="full", index=index, state=state,
                                delta=None, host=self._host)
 
@@ -670,12 +681,14 @@ class IncrementalSnapshotter:
             return "node-dirty"
         if cluster.topology is not self._topology:
             return "topology-changed"
-        if not self._clean:
-            return "vocab-residue"
+        # the pods and gangs that are here before the vocabulary they
+        # leave behind: a reason names its cause
         if self._nonplain > 0:
             return "nonplain-pods"
         if self._nonplain_gangs > 0:
             return "nonplain-gangs"
+        if not self._clean:
+            return "vocab-residue"
         if self._present_twice > 0:
             return "inflight-move"
         return None
@@ -733,6 +746,9 @@ class IncrementalSnapshotter:
                 volume_claims=cluster.volume_claims,
                 storage_classes=cluster.storage_classes,
                 capacity=cap, _return_host=True, tracer=self._tracer)
+        self._built_vocab = {
+            "filter_classes": int(host.nodes.filter_masks.shape[0]),
+            "selector_keys": len(index.selector_keys)}
         # the per-entity ledger only pays off if a later cycle can
         # actually patch — skip it (stay cold) while a persistent
         # environment condition forces full rebuilds regardless, e.g. a
@@ -763,6 +779,7 @@ class IncrementalSnapshotter:
         self._host, self._dev, self._index = host, state, index
         with self._span("snapshot.ledgers"):
             self._rebuild_ledgers(cluster, lists, host, index)
+        self._built_vocab["nonplain_pods"] = self._nonplain
         return state, index
 
     def _rebuild_ledgers(self, cluster, lists, host, index) -> None:

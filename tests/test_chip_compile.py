@@ -4,9 +4,12 @@ The TPU compiler is installed in the sandbox and compiles for a chip
 that is described, not attached (on-chip-measurement guide, section 2).
 Before PR 23 it aborted the process — a fatal check in its scatter
 emitter, not a Python exception — on every victim action of the default
-cycle, at any size; these four compiles guard that at the saturated
-64-node shape where the abort reproduced.  Nothing runs: a compile that
-passes says what the compiler accepts, never what the chip does.
+cycle, at any size; these compiles guard that at the saturated 64-node
+shape where the abort reproduced, the fifth on the non-dense placement
+path (selectors, a filter class, feasible-rank tie-break) that a cluster
+of tainted pools takes and the CPU alone had run before PR 28.  Nothing
+runs: a compile that passes says what the compiler accepts, never what
+the chip does.
 
 Only one process at a time may load the TPU library, and it keeps it
 until it exits: the topology is described inside a module-scoped
@@ -43,6 +46,14 @@ def topo():
     compilation_cache.reset_cache()
 
 
+def _saturated_objects():
+    from kai_scheduler_tpu.state import make_cluster
+    return make_cluster(
+        num_nodes=64, node_accel=4.0, num_gangs=40, tasks_per_gang=8,
+        running_fraction=0.8, queue_accel_quota=6.4,
+        partition_queues_by_running=True, seed=0)
+
+
 @pytest.fixture(scope="module")
 def saturated():
     """64 nodes x 4 accelerators filled exactly by 32 running gangs of
@@ -50,11 +61,28 @@ def saturated():
     action has work.  The session's auto-tuned config is the one
     production would compile."""
     from kai_scheduler_tpu.framework.session import Session
-    from kai_scheduler_tpu.state import make_cluster
-    return Session.open(*make_cluster(
-        num_nodes=64, node_accel=4.0, num_gangs=40, tasks_per_gang=8,
-        running_fraction=0.8, queue_accel_quota=6.4,
-        partition_queues_by_running=True, seed=0))
+    return Session.open(*_saturated_objects())
+
+
+@pytest.fixture(scope="module")
+def saturated_pools():
+    """``saturated`` as accelerator pools are deployed: every node
+    tainted and of one of two GPU types, every pod tolerating, the
+    pending gangs selecting one type, the other, or none."""
+    from kai_scheduler_tpu.apis import types as apis
+    from kai_scheduler_tpu.framework.session import Session
+    nodes, queues, groups, pods, topology = _saturated_objects()
+    for i, node in enumerate(nodes):
+        node.labels["gpu.type"] = "volta" if i % 4 else "pascal"
+        node.taints = [apis.Taint("nvidia.com/gpu", "present")]
+    selects = {g.name: ("volta", "pascal", None)[i % 3]
+               for i, g in enumerate(groups)}
+    for pod in pods:
+        pod.tolerations = [apis.Toleration(
+            "nvidia.com/gpu", "Exists", effect="NoSchedule")]
+        if pod.node is None and selects[pod.group]:
+            pod.node_selector = {"gpu.type": selects[pod.group]}
+    return Session.open(nodes, queues, groups, pods, topology)
 
 
 def _shapes(tree, sharding):
@@ -91,6 +119,24 @@ def test_fused_five_actions_compile(topo, saturated):
     compiled = S._fused_pipeline.__kai_jit__.lower(
         st, st.queues.fair_share,
         **_pipeline_kwargs(saturated.config)).compile()
+    _fits_one_chip(compiled)
+
+
+def test_fused_five_actions_compile_non_dense(topo, saturated_pools):
+    """The same entry over tainted pools: two selector values, the
+    toleration's filter class, ``dense_feasibility`` false in allocate
+    and in the victim actions' placements."""
+    from kai_scheduler_tpu.framework import scheduler as S
+    ses = saturated_pools
+    assert ses.index.selector_keys == ["gpu.type"]
+    assert ses.state.nodes.filter_masks.shape[0] == 2
+    assert not ses.config.allocate.dense_feasibility
+    assert not ses.config.victims.placement.dense_feasibility
+    assert ses.config.allocate.uniform_tasks
+    one = SingleDeviceSharding(topo.devices[0])
+    st = _shapes(ses.state, one)
+    compiled = S._fused_pipeline.__kai_jit__.lower(
+        st, st.queues.fair_share, **_pipeline_kwargs(ses.config)).compile()
     _fits_one_chip(compiled)
 
 
