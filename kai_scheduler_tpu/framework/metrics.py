@@ -62,6 +62,11 @@ victim_wavefront_leftover_demotions = registry.gauge(
     "its claims consumed; the same lane re-demoted in a later chunk "
     "counts again — the gauge measures serialization pressure, not "
     "distinct lanes)", label_names=("action",))
+victim_action_skipped = registry.gauge(
+    "kai_victim_action_skipped",
+    "1 when the victim action found no viable preemptor last cycle and "
+    "built no order or table (its gate stayed closed)",
+    label_names=("action",))
 # kai-trace phase attribution (runtime/tracing.py): the cycle timeline
 # partitioned into contiguous phases — snapshot (host build/patch),
 # upload (changed-leaves transfer DISPATCH; device_put is async, so the

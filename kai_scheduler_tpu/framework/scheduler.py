@@ -31,7 +31,8 @@ from ..ops.allocate import (AllocationResult, allocate, allocate_jit,
 from ..ops.analytics import cluster_analytics, cluster_analytics_jit
 from ..ops.repack import RepackConfig, plan_repack_jit
 from ..ops.stale import stale_gang_eviction
-from ..ops.victims import run_victim_action, run_victim_action_jit
+from ..ops.victims import (VICTIM_ACTIONS, run_victim_action,
+                           run_victim_action_jit)
 from ..runtime import compile_watch
 from ..runtime import wire_ledger as _wire
 from ..runtime.cluster import Cluster
@@ -248,6 +249,11 @@ class CycleResult:
     repack: dict = dataclasses.field(default_factory=dict)
     #: host-side dispatch cost of the repack solve (0.0 when not fired)
     repack_seconds: float = 0.0
+    #: victim action -> 1 when its gate stayed closed this cycle (no
+    #: viable preemptor, so no order frozen and no table built;
+    #: ``AllocationResult.victim_skipped``), else 0
+    victim_actions_skipped: dict[str, int] = dataclasses.field(
+        default_factory=dict)
     #: kai-twin determinism anchors: the cycle's logical index and the
     #: per-cycle seed derived from ``SchedulerConfig.seed`` — pure
     #: functions of (config seed, cycle index), never of wall clock or
@@ -1015,6 +1021,14 @@ class Scheduler:
                         action, value=float(fb))
                     metrics.victim_wavefront_leftover_demotions.set(
                         action, value=float(demo))
+        # ... and so does one flag per victim action whose gate stayed
+        # closed: nobody was a viable preemptor, nothing was built
+        skipped = host.get("victim_skipped")
+        if skipped is not None:
+            result.victim_actions_skipped = {
+                action: int(n) for action, n in zip(VICTIM_ACTIONS, skipped)}
+            for action, n in result.victim_actions_skipped.items():
+                metrics.victim_action_skipped.set(action, value=float(n))
         # arrays come from the cycle's single batched transfer; change
         # detection is VECTORIZED against the previous cycle's tables so
         # the Python loop touches only cells that moved — O(changed)

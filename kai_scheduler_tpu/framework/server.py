@@ -724,6 +724,8 @@ class SchedulerServer:
                     entry_seconds={"lock_wait": lock_wait_s,
                                    "coalesce": coalesce_s},
                     intake_parsed_pods=parsed_pods,
+                    victim_actions_skipped=dict(
+                        result.victim_actions_skipped),
                     startup={"phase_seconds": first,
                              **compile_watch.WATCHER.stage_seconds()})
             # kai-pulse slice: the headline cluster-health gauges of

@@ -30,7 +30,7 @@ from ..runtime import compile_watch
 from ..runtime import events as gang_events
 from ..runtime import wire_ledger as _wire
 from ..ops.allocate import AllocateConfig, AllocationResult
-from ..ops.victims import VictimConfig
+from ..ops.victims import VICTIM_ACTIONS, VictimConfig
 from ..state.cluster_state import (ClusterState, SnapshotIndex,
                                    _pow2_ceil, build_snapshot)
 
@@ -89,6 +89,8 @@ def _pack_commit(result: AllocationResult, state: ClusterState,
         jax.lax.bitcast_convert_type(q.fair_share, jnp.int16).ravel(),
         jax.lax.bitcast_convert_type(
             result.wavefront_stats, jnp.int16).ravel(),
+        jax.lax.bitcast_convert_type(
+            result.victim_skipped, jnp.int16).ravel(),
     ]
     if track_devices:
         parts.append(
@@ -418,6 +420,8 @@ class Session:
             take(Q * R_ * 2).tobytes(), np.float32).reshape(Q, R_)
         out["wavefront_stats"] = np.frombuffer(
             take(2 * 5 * 2).tobytes(), np.int32).reshape(2, 5)
+        out["victim_skipped"] = np.frombuffer(
+            take(len(VICTIM_ACTIONS) * 2).tobytes(), np.int32)
         if devices:
             out["placement_device"] = (take(G * T).astype(np.int32) - 1
                                        ).reshape(G, T)

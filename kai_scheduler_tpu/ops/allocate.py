@@ -118,6 +118,11 @@ class AllocationResult(struct.PyTreeNode):
     #: feeds the ``kai_victim_wavefront_*`` gauges
     #: (``framework/metrics.py``).
     wavefront_stats: jax.Array
+    #: victim actions of this cycle that found no viable preemptor and
+    #: built nothing — i32 [3]: reclaim, preempt, consolidation
+    #: (``ops/victims.py`` ``VICTIM_ACTIONS``).  Rides the packed commit
+    #: beside ``wavefront_stats``; feeds ``kai_victim_action_skipped``.
+    victim_skipped: jax.Array
 
 
 def init_result(state: ClusterState) -> AllocationResult:
@@ -129,6 +134,7 @@ def init_result(state: ClusterState) -> AllocationResult:
     return AllocationResult(
         anti_used=jnp.zeros((TA + 1, AD + 1), bool),
         wavefront_stats=jnp.zeros((2, 5), jnp.int32),
+        victim_skipped=jnp.zeros((3,), jnp.int32),
         placements=jnp.full((G, T), -1, jnp.int32),
         extended_free=n.extended_free,
         placement_device=jnp.full((G, T), -1, jnp.int32),

@@ -1709,46 +1709,144 @@ def _run_victim_action_chunked(
 _DEBUG_CHUNKS = False
 
 
-def run_victim_action(
+#: the victim actions, in the slot order of
+#: ``AllocationResult.victim_skipped`` (action names as the pipeline and
+#: the ``action`` label of the gauges spell them)
+VICTIM_ACTIONS = ("reclaim", "preempt", "consolidation")
+_SKIP_SLOT = {"reclaim": 0, "preempt": 1, "consolidate": 2}
+
+
+def _viable_preemptors(
     state: ClusterState,
     fair_share: jax.Array,
     result: AllocationResult,
     *,
     num_levels: int,
-    mode: str,                   # "reclaim" | "preempt" | "consolidate"
-    config: VictimConfig = VictimConfig(),
-) -> AllocationResult:
-    """The reclaim / preempt / consolidation action: scan pending
-    unallocated gangs in fairness order, solving victim scenarios for each.
+    mode: str,
+    chain: jax.Array,
+):
+    """The action's vectorized viability prefilter: which pending gangs
+    could preempt at all.  Returns (``remaining0`` bool [G], ``cnt_q``
+    i32 [Q] candidate victims per leaf queue, ``task_req_g`` f32 [G, R]).
 
-    Functional equivalent of ``reclaim.Execute`` / ``preempt.Execute`` /
-    ``consolidation.Execute``.  Successful preemptors are committed as
-    *pipelined* placements (they wait for their victims' pods to
-    terminate — the reference pipelines preemptors onto releasing
-    resources the same way); consolidation victims additionally get a
-    planned re-placement node in ``victim_move``.
+    The per-gang scan is the expensive part (a fairness re-sort per
+    step); gangs that cannot possibly preempt are dropped upfront.
+    Sound because queue allocation only GROWS within the action, so the
+    capacity/fair-share gates (re-checked live per attempt) only get
+    stricter — a gang failing them at action start can never pass later.
+
+    This is ALL an action with nobody waiting computes (the gate of
+    :func:`run_victim_action`): segment sums and per-gang gates only —
+    nothing sorted over [M], nothing of [U, Q, *] size.
     """
-    if mode not in ("reclaim", "preempt", "consolidate"):
-        raise ValueError(f"unknown victim action mode: {mode!r}")
     g, q, r = state.gangs, state.queues, state.running
     G = g.g
+    base = (r.valid & ~r.releasing & (r.node >= 0) & r.preemptible
+            & (r.gang >= 0))
+    rq = jnp.where(base, r.queue, q.q)
+    cnt_q = jax.ops.segment_sum(base.astype(jnp.int32), rq,
+                                num_segments=q.q + 1)[:q.q]       # [Q]
+    total_cnt = jnp.sum(cnt_q)
+    gq = jnp.maximum(g.queue, 0)
+    if mode == "reclaim":
+        has_cand = (total_cnt - cnt_q[gq]) > 0
+    elif mode == "consolidate":
+        own = jax.ops.segment_sum(
+            base.astype(jnp.int32), jnp.where(base, r.gang, G),
+            num_segments=G + 1)[:G]
+        has_cand = (total_cnt - own) > 0
+    else:  # preempt: a lower-priority candidate in the gang's own queue
+        minprio = jax.ops.segment_min(
+            jnp.where(base, r.priority, BIG), rq,
+            num_segments=q.q + 1)[:q.q]
+        has_cand = minprio[gq] < g.priority
+    task_req_g = jnp.sum(
+        jnp.where(g.task_valid[:, :, None], g.task_req, 0.0), axis=1)
+    gate_np = jax.vmap(
+        lambda qi, tr: _ancestor_gate(
+            q.parent, qi, num_levels,
+            result.queue_allocated_nonpreemptible, q.quota, tr)
+    )(gq, task_req_g)
+    viable = has_cand & jnp.where(~g.preemptible, gate_np, True)
+    if mode == "reclaim":
+        # the fair-share gate must use a LOWER bound of future queue
+        # allocation — reclaim evictions SHRINK allocation as the action
+        # proceeds, so gating on the live value would wrongly exclude
+        # reclaimers whose chain drops under fair share once victims
+        # free up.  Lower bound: current allocation minus everything any
+        # candidate could ever free along the chain.
+        cand_leaf = jax.ops.segment_sum(
+            jnp.where(base[:, None], r.req, 0.0), rq,
+            num_segments=q.q + 1)[:q.q]                        # [Q, R]
+        freeable = einsum_exact(
+            "qa,qr->ar", chain.astype(cand_leaf.dtype), cand_leaf)
+        qa_lower = jnp.maximum(result.queue_allocated - freeable, 0.0)
+        viable = viable & jax.vmap(
+            lambda qi, tr: _ancestor_gate(
+                q.parent, qi, num_levels, qa_lower,
+                fair_share, tr))(gq, task_req_g)
+    elif mode == "consolidate":
+        viable = viable & g.preemptible
+        # conservation gate: moving victims frees NOTHING in aggregate —
+        # a consolidation preemptor must fit the cluster's total spare
+        # capacity, or no rearrangement can ever place it.  On a
+        # saturated cluster this empties the action outright.
+        spare = jnp.sum(jnp.where(
+            state.nodes.valid[:, None],
+            result.free + state.nodes.releasing + result.releasing_extra,
+            0.0), axis=0)
+        viable = viable & jnp.all(task_req_g <= spare[None, :] + EPS,
+                                  axis=-1)
+    remaining0 = g.valid & (g.backoff <= 0) & ~result.allocated & viable
+    return remaining0, cnt_q, task_req_g
+
+
+def _victim_search(
+    state: ClusterState,
+    fair_share: jax.Array,
+    result: AllocationResult,
+    *,
+    num_levels: int,
+    mode: str,
+    config: VictimConfig,
+    remaining0: jax.Array,       # bool [G] from _viable_preemptors
+    chain: jax.Array,
+    cnt_q: jax.Array,
+    task_req_g: jax.Array,
+) -> AllocationResult:
+    """The action behind its gate: everything preemptor-independent
+    (victim statics, the frozen job and unit orders, the per-queue
+    tables) and the search loop itself — chunked wavefront or one gang
+    at a time."""
+    g, q = state.gangs, state.queues
+    G = g.g
     total = state.total_capacity
-    chain = _chain_membership(q.parent, num_levels)
+    gq = jnp.maximum(g.queue, 0)
     depth = (config.queue_depth_preempt
              if mode == "preempt" and config.queue_depth_preempt is not None
              else config.queue_depth)
     statics = victim_statics(state)
     job_rank0 = frozen_job_rank(state, result.queue_allocated, fair_share)
+    lq_tab = None
+    if mode == "reclaim":
+        # [victim leaf, reclaimer leaf] leveled-queue table for the live
+        # strategy-viability drop
+        qidx = jnp.arange(q.q)
+        lq_tab = jax.vmap(lambda v: jax.vmap(
+            lambda c: _leveled_queue(chain, q.depth, v, c))(qidx))(qidx)
+
+    if (config.batch_size > 1 and mode in ("reclaim", "preempt")
+            and (mode != "reclaim" or config.chunk_reclaim)):
+        return _run_victim_action_chunked(
+            state, fair_share, result, num_levels=num_levels, mode=mode,
+            config=config, remaining0=remaining0, chain=chain,
+            statics=statics, job_rank=job_rank0, lq_tab=lq_tab,
+            cnt_q=cnt_q, task_req_g=task_req_g)
+
     quota_eff_q = jnp.where(q.quota <= UNLIMITED + 0.5, jnp.inf, q.quota)
     anti = config.placement.anti_groups
     if anti:
         dom_static, _TA = anti_domain_tables(state)
-    if mode == "reclaim":
-        # [victim leaf, reclaimer leaf] leveled-queue table for the live
-        # strategy-viability drop inside `step`
-        qidx = jnp.arange(q.q)
-        lq_tab = jax.vmap(lambda v: jax.vmap(
-            lambda c: _leveled_queue(chain, q.depth, v, c))(qidx))(qidx)
 
     def step(carry):
         res, remaining, q_att, fuel = carry
@@ -1833,8 +1931,6 @@ def run_victim_action(
             # leaf queue with candidates is still evictable for it; once
             # shares exhaust, the loop ends in O(successes) steps instead
             # of attempting every remaining pending gang.
-            # (cnt_q / task_req_g / gq / lq_tab / quota_eff_q are bound
-            # later in the enclosing scope, before the while_loop traces.)
             qa_l = res.queue_allocated
             under_g = jax.vmap(
                 lambda qi, tr: _ancestor_gate(
@@ -1854,86 +1950,58 @@ def run_victim_action(
                 ev_fs_c[gq] | (under_g & ev_qt_c[gq]))
         return res, remaining, q_att, fuel - 1
 
-    remaining0 = g.valid & (g.backoff <= 0) & ~result.allocated
-
-    # ---- vectorized viability prefilter ---------------------------------
-    # The per-gang scan is the expensive part (a fairness re-sort per
-    # step); gangs that cannot possibly preempt are dropped upfront.
-    # Sound because queue allocation only GROWS within the action, so the
-    # capacity/fair-share gates (re-checked live per attempt) only get
-    # stricter — a gang failing them at action start can never pass later.
-    base = (r.valid & ~r.releasing & (r.node >= 0) & r.preemptible
-            & (r.gang >= 0))
-    rq = jnp.where(base, r.queue, q.q)
-    cnt_q = jax.ops.segment_sum(base.astype(jnp.int32), rq,
-                                num_segments=q.q + 1)[:q.q]       # [Q]
-    total_cnt = jnp.sum(cnt_q)
-    gq = jnp.maximum(g.queue, 0)
-    if mode == "reclaim":
-        has_cand = (total_cnt - cnt_q[gq]) > 0
-    elif mode == "consolidate":
-        own = jax.ops.segment_sum(
-            base.astype(jnp.int32), jnp.where(base, r.gang, G),
-            num_segments=G + 1)[:G]
-        has_cand = (total_cnt - own) > 0
-    else:  # preempt: a lower-priority candidate in the gang's own queue
-        minprio = jax.ops.segment_min(
-            jnp.where(base, r.priority, BIG), rq,
-            num_segments=q.q + 1)[:q.q]
-        has_cand = minprio[gq] < g.priority
-    task_req_g = jnp.sum(
-        jnp.where(g.task_valid[:, :, None], g.task_req, 0.0), axis=1)
-    gate_np = jax.vmap(
-        lambda qi, tr: _ancestor_gate(
-            q.parent, qi, num_levels,
-            result.queue_allocated_nonpreemptible, q.quota, tr)
-    )(gq, task_req_g)
-    viable = has_cand & jnp.where(~g.preemptible, gate_np, True)
-    if mode == "reclaim":
-        # the fair-share gate must use a LOWER bound of future queue
-        # allocation — reclaim evictions SHRINK allocation as the action
-        # proceeds, so gating on the live value would wrongly exclude
-        # reclaimers whose chain drops under fair share once victims
-        # free up.  Lower bound: current allocation minus everything any
-        # candidate could ever free along the chain.
-        cand_leaf = jax.ops.segment_sum(
-            jnp.where(base[:, None], r.req, 0.0), rq,
-            num_segments=q.q + 1)[:q.q]                        # [Q, R]
-        freeable = einsum_exact(
-            "qa,qr->ar", chain.astype(cand_leaf.dtype), cand_leaf)
-        qa_lower = jnp.maximum(result.queue_allocated - freeable, 0.0)
-        viable = viable & jax.vmap(
-            lambda qi, tr: _ancestor_gate(
-                q.parent, qi, num_levels, qa_lower,
-                fair_share, tr))(gq, task_req_g)
-    elif mode == "consolidate":
-        viable = viable & g.preemptible
-        # conservation gate: moving victims frees NOTHING in aggregate —
-        # a consolidation preemptor must fit the cluster's total spare
-        # capacity, or no rearrangement can ever place it.  On a
-        # saturated cluster this empties the action outright.
-        spare = jnp.sum(jnp.where(
-            state.nodes.valid[:, None],
-            result.free + state.nodes.releasing + result.releasing_extra,
-            0.0), axis=0)
-        viable = viable & jnp.all(task_req_g <= spare[None, :] + EPS,
-                                  axis=-1)
-    remaining0 = remaining0 & viable
-
-    if (config.batch_size > 1 and mode in ("reclaim", "preempt")
-            and (mode != "reclaim" or config.chunk_reclaim)):
-        return _run_victim_action_chunked(
-            state, fair_share, result, num_levels=num_levels, mode=mode,
-            config=config, remaining0=remaining0, chain=chain,
-            statics=statics, job_rank=job_rank0,
-            lq_tab=lq_tab if mode == "reclaim" else None,
-            cnt_q=cnt_q, task_req_g=task_req_g)
     with jax.named_scope("wavefront"):
         res, _, _, _ = lax.while_loop(
             lambda c: jnp.any(c[1]) & (c[3] > 0), step,
             (result, remaining0, jnp.zeros((q.q,), jnp.int32),
              jnp.asarray(G, jnp.int32)))
     return res
+
+
+def run_victim_action(
+    state: ClusterState,
+    fair_share: jax.Array,
+    result: AllocationResult,
+    *,
+    num_levels: int,
+    mode: str,                   # "reclaim" | "preempt" | "consolidate"
+    config: VictimConfig = VictimConfig(),
+) -> AllocationResult:
+    """The reclaim / preempt / consolidation action: scan pending
+    unallocated gangs in fairness order, solving victim scenarios for each.
+
+    Functional equivalent of ``reclaim.Execute`` / ``preempt.Execute`` /
+    ``consolidation.Execute``.  Successful preemptors are committed as
+    *pipelined* placements (they wait for their victims' pods to
+    terminate — the reference pipelines preemptors onto releasing
+    resources the same way); consolidation victims additionally get a
+    planned re-placement node in ``victim_move``.
+
+    Like the reference, which walks the pending jobs and returns when
+    there are none, the action first asks whether ANY gang is a viable
+    preemptor (:func:`_viable_preemptors`) and only then freezes orders,
+    ranks eviction units and builds the per-queue tables
+    (:func:`_victim_search`, under one ``lax.cond``).  A closed gate
+    returns ``result`` as it came — what a search loop of zero
+    iterations returns — and counts itself in ``victim_skipped``.
+    """
+    if mode not in _SKIP_SLOT:
+        raise ValueError(f"unknown victim action mode: {mode!r}")
+    chain = _chain_membership(state.queues.parent, num_levels)
+    remaining0, cnt_q, task_req_g = _viable_preemptors(
+        state, fair_share, result, num_levels=num_levels, mode=mode,
+        chain=chain)
+    anyone = jnp.any(remaining0)
+    result = result.replace(
+        victim_skipped=result.victim_skipped.at[_SKIP_SLOT[mode]].add(
+            (~anyone).astype(jnp.int32)))
+    return lax.cond(
+        anyone,
+        lambda res: _victim_search(
+            state, fair_share, res, num_levels=num_levels, mode=mode,
+            config=config, remaining0=remaining0, chain=chain,
+            cnt_q=cnt_q, task_req_g=task_req_g),
+        lambda res: res, result)
 
 
 @functools.partial(jax.jit,

@@ -76,8 +76,14 @@ def test_victim_wavefront_gauges_populated():
     assert metrics.victim_wavefront_sparse_fallbacks.value("preempt") == 0
     assert (metrics.victim_wavefront_leftover_demotions.value("preempt")
             >= 0)
+    # preempt's gate opened; reclaim had nobody to serve from one queue
+    assert metrics.victim_action_skipped.value("preempt") == 0
+    assert metrics.victim_action_skipped.value("reclaim") == 1
+    assert res.victim_actions_skipped == {
+        "reclaim": 1, "preempt": 0, "consolidation": 1}
     text = metrics.registry.render()
-    for name in ("kai_victim_wavefront_chunks",
+    for name in ("kai_victim_action_skipped",
+                 "kai_victim_wavefront_chunks",
                  "kai_victim_wavefront_lane_occupancy",
                  "kai_victim_wavefront_sparse_fallbacks",
                  "kai_victim_wavefront_leftover_demotions"):
@@ -175,3 +181,37 @@ def test_render_consistent_under_concurrent_observation():
         stop.set()
         t.join(timeout=10)
     assert not torn, f"torn expositions: {torn[:3]}"
+
+
+def test_benchmark_reads_skipped_victim_actions_from_healthz():
+    """``benchmark/layer_metrics/victim_actions_skipped.py`` over
+    ``last_cycle`` documents as ``/healthz`` serves them: the flags of a
+    cycle summed, then the mean over the window's cycles; a program
+    from before the counter (no such key) gives nothing, not an error."""
+    import importlib.util
+    import os
+    import sys
+    import types
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path.insert(0, bench)
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "victim_actions_skipped", os.path.join(
+                bench, "layer_metrics", "victim_actions_skipped.py"))
+        reader = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(reader)
+    finally:
+        sys.path.remove(bench)
+
+    def run_of(*healths):
+        return types.SimpleNamespace(
+            cycles=[{"health": h} for h in healths])
+
+    closed = {"reclaim": 1, "preempt": 1, "consolidation": 1}
+    opened = {"reclaim": 0, "preempt": 1, "consolidation": 1}
+    assert reader.read(run_of(
+        {"victim_actions_skipped": closed},
+        {"victim_actions_skipped": opened})) == 2.5
+    assert reader.read(run_of({"phase_seconds": {}}, {})) is None
+    assert reader.read(run_of()) is None

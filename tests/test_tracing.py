@@ -336,6 +336,10 @@ def test_debug_trace_and_events_endpoints():
         stats = health["last_cycle"]
         assert set(stats["phase_seconds"]) == PHASES
         assert stats["decisions"]["events"] >= 2
+        # ... and one flag per victim action: was its gate closed
+        assert set(stats["victim_actions_skipped"]) == {
+            "reclaim", "preempt", "consolidation"}
+        assert set(stats["victim_actions_skipped"].values()) <= {0, 1}
     finally:
         server.stop()
 

@@ -363,3 +363,217 @@ class TestEvictionUnitAccounting:
             state, cand2, state.queues.allocated, fair_share,
             jnp.asarray(prior))
         assert int(num_units2) == 1
+
+
+# ---------------------------------------------------------------------------
+# the action-level gate: a victim action with no viable preemptor builds
+# nothing (ref reclaim/preempt/consolidation.Execute return when no job
+# is pending)
+# ---------------------------------------------------------------------------
+
+from kai_scheduler_tpu.ops import victims  # noqa: E402
+
+SLOT = victims._SKIP_SLOT
+MODES = tuple(SLOT)
+
+
+def _leaves(res):
+    import jax
+    return {jax.tree_util.keystr(path): np.asarray(leaf) for path, leaf
+            in jax.tree_util.tree_leaves_with_path(res)}
+
+
+def assert_same_result(got, want, *, but=("victim_skipped",)):
+    """Leaf for leaf, bit for bit; ``but`` names the leaves left out."""
+    got, want = _leaves(got), _leaves(want)
+    assert got.keys() == want.keys()
+    for name in want:
+        if any(b in name for b in but):
+            continue
+        assert got[name].dtype == want[name].dtype, name
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+
+
+def ungated_action(mode, num_levels, config):
+    """The action's prefilter and its search called one after the other,
+    no gate in between: what the program did before it had one."""
+    def action(state, fair_share, res):
+        chain = victims._chain_membership(state.queues.parent, num_levels)
+        remaining0, cnt_q, task_req_g = victims._viable_preemptors(
+            state, fair_share, res, num_levels=num_levels, mode=mode,
+            chain=chain)
+        return victims._victim_search(
+            state, fair_share, res, num_levels=num_levels, mode=mode,
+            config=config, remaining0=remaining0, chain=chain, cnt_q=cnt_q,
+            task_req_g=task_req_g)
+    return action
+
+
+def _gate_session(scenario, seed):
+    """Seeded clusters on which no gang is a viable preemptor."""
+    from kai_scheduler_tpu.framework.session import Session
+    from kai_scheduler_tpu.state import make_cluster
+    if scenario == "nobody-pending":
+        # everything runs: after allocate nobody is left to serve
+        kw = dict(num_nodes=16, node_accel=4.0, num_gangs=12,
+                  tasks_per_gang=4, running_fraction=1.0)
+    elif scenario == "all-placed-by-allocate":
+        # half full: allocate places every pending gang before the
+        # victim actions look (the churn cells' cycle)
+        kw = dict(num_nodes=16, node_accel=4.0, num_gangs=12,
+                  tasks_per_gang=4, running_fraction=0.5)
+    else:
+        # pending gangs that do not fit and nothing running to evict
+        assert scenario == "no-victims"
+        kw = dict(num_nodes=2, node_accel=2.0, num_gangs=6,
+                  tasks_per_gang=4, running_fraction=0.0)
+    return Session.open(*make_cluster(
+        num_departments=2, queues_per_department=3, seed=seed, **kw))
+
+
+class TestActionGate:
+    @pytest.mark.parametrize("mode", MODES)
+    @pytest.mark.parametrize("scenario", ["nobody-pending",
+                                          "all-placed-by-allocate",
+                                          "no-victims"])
+    def test_closed_gate_returns_its_input(self, mode, scenario):
+        """(a) no viable preemptor: the action returns the commit set it
+        was given, leaf for leaf, and raises its skip flag — what a
+        search loop of zero iterations returned before the gate."""
+        from kai_scheduler_tpu.ops.allocate import allocate_jit
+        from kai_scheduler_tpu.ops.victims import run_victim_action_jit
+        ses = _gate_session(scenario, seed=7)
+        fs = ses.state.queues.fair_share
+        before = allocate_jit(ses.state, fs, num_levels=2,
+                              config=ses.config.allocate)
+        pending = np.asarray(ses.state.gangs.valid)
+        placed = np.asarray(before.allocated)[pending]
+        if scenario == "all-placed-by-allocate":
+            assert placed.size and placed.all()
+        elif scenario == "no-victims":
+            assert not placed.all(), "somebody must still be waiting"
+        after = run_victim_action_jit(
+            ses.state, fs, before, num_levels=2, mode=mode,
+            config=ses.config.victims)
+        assert_same_result(after, before)
+        want = np.zeros(3, np.int32)
+        want[SLOT[mode]] = 1
+        np.testing.assert_array_equal(
+            np.asarray(after.victim_skipped), want)
+
+    def test_open_gate_leaves_its_flag_down(self):
+        state, index = two_queue_cluster()
+        res, _ = run_reclaim(state)
+        assert bool(res.allocated[index.gang_names.index("pending-gang")])
+        np.testing.assert_array_equal(
+            np.asarray(res.victim_skipped), [0, 0, 0])
+
+    def test_cycle_with_reclaim_open_and_preempt_closed(self, monkeypatch):
+        """(c) one fused five-action cycle on a saturated, partitioned
+        cluster (reclaim serves the under-quota leaves; nothing of lower
+        priority sits in them for preempt): the gated program commits
+        exactly what the composition of the ungated action bodies does,
+        and the served counters say which gates stayed closed."""
+        import jax
+        from kai_scheduler_tpu.framework import Scheduler, SchedulerConfig
+        from kai_scheduler_tpu.framework import metrics
+        from kai_scheduler_tpu.framework import scheduler as sched_mod
+        from kai_scheduler_tpu.runtime.cluster import Cluster
+        from kai_scheduler_tpu.state import make_cluster
+
+        nodes, queues, groups, pods, topo = make_cluster(
+            num_nodes=32, node_accel=4.0, num_gangs=20, tasks_per_gang=8,
+            running_fraction=0.8, queue_accel_quota=3.2,
+            partition_queues_by_running=True, seed=0)
+        cluster = Cluster.from_objects(nodes, queues, groups, pods,
+                                       topology=topo)
+        # what the cycle hands its one program
+        calls = []
+        fused = sched_mod._fused_pipeline
+        monkeypatch.setattr(
+            sched_mod, "_fused_pipeline",
+            lambda *a, **kw: calls.append((a, kw)) or fused(*a, **kw))
+        sched = Scheduler(SchedulerConfig())
+        result = sched.run_once(cluster)
+        (state, fair_share), kw = calls[0]
+        assert kw["actions"] == ("allocate", "consolidation", "reclaim",
+                                 "preempt", "stalegangeviction")
+        assert result.victim_actions_skipped["reclaim"] == 0
+        assert result.victim_actions_skipped["preempt"] == 1
+        assert metrics.victim_action_skipped.value("reclaim") == 0.0
+        assert metrics.victim_action_skipped.value("preempt") == 1.0
+        assert result.evictions, "reclaim must have worked"
+        assert np.asarray(result.tensors.victim).sum() == len(
+            result.evictions)
+
+        # the same pipeline with every victim action's body called
+        # directly, no gate: what the program was before the gate
+        nl = kw["num_levels"]
+
+        def ungated(mode):
+            action = ungated_action(mode, nl, kw["vcfg"])
+            return lambda st, fs, res, *_: action(st, fs, res)
+
+        plain = dict(sched_mod._PURE_ACTIONS,
+                     consolidation=ungated("consolidate"),
+                     reclaim=ungated("reclaim"), preempt=ungated("preempt"))
+
+        @jax.jit
+        def reference(st, fs):
+            res = init_result(st)
+            for name in kw["actions"]:
+                res = plain[name](st, fs, res, nl, kw["acfg"], kw["vcfg"],
+                                  kw["grace_s"])
+            return res
+
+        assert_same_result(result.tensors, reference(state, fair_share))
+
+    @pytest.mark.parametrize("mode,chunked", [
+        ("reclaim", True), ("reclaim", False), ("preempt", True),
+        ("preempt", False), ("consolidate", False)])
+    def test_nothing_large_is_built_before_the_gate(self, mode, chunked):
+        """(d) structure: in the action's jaxpr no equation outside the
+        one ``cond`` has an output of [M, Q] elements or more — the
+        frozen orders, rankings and per-queue tables all lie under it.
+        An edit that hoists a table back out fails here, not in a
+        benchmark."""
+        import dataclasses
+        import functools
+        import jax
+        from kai_scheduler_tpu.framework.session import Session
+        from kai_scheduler_tpu.ops.victims import run_victim_action
+        from kai_scheduler_tpu.state import make_cluster
+
+        ses = Session.open(*make_cluster(
+            num_nodes=48, node_accel=2.0, num_gangs=64, tasks_per_gang=2,
+            running_fraction=48 / 64, num_departments=2,
+            queues_per_department=8, pending_priority_boost=100, seed=0))
+        cfg = dataclasses.replace(
+            ses.config.victims, chunk_reclaim=chunked,
+            batch_size=ses.config.victims.batch_size if chunked else 1,
+            batch_size_preempt=None,
+            # the dense tables, so that the chunked branch holds [U, Q, R]
+            optimistic_preempt=False)
+        M, Q = ses.state.running.m, ses.state.queues.q
+        jaxpr = jax.make_jaxpr(functools.partial(
+            run_victim_action, num_levels=2, mode=mode, config=cfg))(
+            ses.state, ses.state.queues.fair_share,
+            init_result(ses.state)).jaxpr
+
+        def largest(jpr, into_cond):
+            worst = 0
+            for eqn in jpr.eqns:
+                worst = max([worst] + [int(np.prod(v.aval.shape))
+                                       for v in eqn.outvars])
+                if eqn.primitive.name == "cond" and not into_cond:
+                    continue
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    worst = max(worst, largest(sub, True))
+            return worst
+
+        gates = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+        assert len(gates) == 1
+        assert largest(jaxpr, into_cond=False) < M * Q, (M, Q)
+        if chunked:
+            # and the bound means something: the tables are in there
+            assert largest(jaxpr, into_cond=True) >= M * Q

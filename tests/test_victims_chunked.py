@@ -319,3 +319,72 @@ def test_uniform_kernel_prior_node_flag_matches_sequential(case):
     np.testing.assert_array_equal((tuned[2] >= 0).sum(-1),
                                   (seq[2] >= 0).sum(-1),
                                   err_msg="placement counts")
+
+
+def _fragmented_session(seed):
+    """Consolidation's shape, seeded: 4-accel nodes each half full with
+    one 2-accel runner, and pending gangs that need a whole node — they
+    fit only once a runner has moved."""
+    from kai_scheduler_tpu.apis import types as apis
+    rng = np.random.default_rng(seed)
+    n_nodes = int(rng.integers(6, 10))
+    nodes = [apis.Node(f"node-{i}", apis.ResourceVec(4.0, 64.0, 256.0))
+             for i in range(n_nodes)]
+    queues = [apis.Queue("q0", accel=apis.QueueResource(
+        quota=4.0 * n_nodes))]
+    groups, pods = [], []
+    for i in rng.permutation(n_nodes):
+        groups.append(apis.PodGroup(
+            f"frag{i}", queue="q0", min_member=1,
+            creation_timestamp=float(i), last_start_timestamp=float(i)))
+        pods.append(apis.Pod(
+            f"f{i}", f"frag{i}", resources=apis.ResourceVec(2.0, 1.0, 4.0),
+            status=apis.PodStatus.RUNNING, node=f"node-{i}"))
+    for j in range(2):
+        groups.append(apis.PodGroup(f"big{j}", queue="q0", min_member=1,
+                                    creation_timestamp=100.0 + j))
+        pods.append(apis.Pod(
+            f"big{j}-0", f"big{j}",
+            resources=apis.ResourceVec(4.0, 1.0, 4.0),
+            creation_timestamp=100.0 + j))
+    return Session.open(nodes, queues, groups, pods, None)
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("mode,path", [
+    ("reclaim", "chunked"), ("reclaim", "sequential"),
+    ("preempt", "sparse"), ("preempt", "dense"),
+    ("preempt", "sequential"), ("consolidate", "sequential")])
+def test_gated_action_equals_its_ungated_body(mode, path, seed):
+    """The action-level gate changes nothing when it is open: with
+    viable preemptors, ``run_victim_action`` (prefilter, then
+    ``lax.cond`` over the search) returns what the search body called
+    directly returns, leaf for leaf — on the chunked wavefront (dense
+    tables, and preempt's compact ones) and on the sequential scan."""
+    import jax
+    from tests.test_victims import assert_same_result, ungated_action
+    if mode == "reclaim":
+        ses = Session.open(*make_cluster(
+            num_nodes=48, node_accel=4.0, num_gangs=24, tasks_per_gang=4,
+            running_fraction=0.5, num_departments=2,
+            queues_per_department=4, queue_accel_quota=8.0,
+            partition_queues_by_running=True, seed=seed))
+    elif mode == "preempt":
+        ses = _many_queue_session(seed)
+    else:
+        ses = _fragmented_session(seed)
+    b = 1 if path == "sequential" else 64
+    cfg = dataclasses.replace(
+        ses.config.victims, batch_size=b, batch_size_preempt=b,
+        chunk_reclaim=True,
+        optimistic_preempt=(False if path == "dense" else None))
+    nl = ses.config.num_levels
+    state, fs = ses.state, ses.state.queues.fair_share
+    want = jax.jit(ungated_action(mode, nl, cfg))(
+        state, fs, init_result(state))
+    assert np.asarray(want.allocated).any(), "the family must open the gate"
+    assert np.asarray(want.victim).any()
+    got = run_victim_action_jit(state, fs, init_result(state),
+                                num_levels=nl, mode=mode, config=cfg)
+    assert_same_result(got, want, but=())
+    assert not np.asarray(got.victim_skipped).any()
