@@ -23,11 +23,6 @@ pending pods**, p99 cycle latency against the driver's 50 ms bar
                 racks; a rack-required 256-pod gang is unplaceable
                 until a rack frees — measures analytics overhead and
                 the gauge's predictive drop
-  resident      kai-resident device-resident state @ 10k nodes × 50k
-                pods, 1% churn: per-cycle p99 with ONE fused dispatch
-                + ONE packed-delta upload vs the classic patch-ship
-                twin (delta bytes/cycle, dispatches/cycle, phase
-                shares)
   storm         kai-intake traffic storm: a 1M-event pod create/delete
                 burst (BENCH_STORM_EVENTS overrides) through the async
                 multi-lane router while cycles keep running — sustained
@@ -210,7 +205,6 @@ def bench_headline_full(iters: int) -> dict:
                      ("churn", bench_churn),
                      ("phases", bench_phases),
                      ("frag", bench_frag),
-                     ("resident", bench_resident),
                      # bounded storm in the artifact row; the
                      # standalone BENCH_CONFIG=storm run does the full
                      # 1M-event burst
@@ -567,107 +561,6 @@ def bench_phases(iters: int, *, num_nodes: int = 10_000,
             "extra": extra}
 
 
-def bench_resident(iters: int, *, num_nodes: int = 10_000,
-                   num_gangs: int = 6250, tasks_per_gang: int = 8) -> dict:
-    """kai-resident (ops/resident.py) @ the headline shape with 1%
-    journaled churn: the snapshot stays device-resident across cycles,
-    patched cycles upload ONE packed journal delta and run the whole
-    dispatch chain (delta apply → fair share → pipeline → analytics →
-    packed commit) as ONE fused donated-state dispatch.  Measured
-    against a classic patch-ship twin (same churn stream) so the
-    artifact records the upload + device_wait share collapse ROADMAP
-    item 1 calls for, plus delta bytes/cycle, dispatches/cycle, and
-    the resident reused-vs-uploaded gauge pair."""
-    import numpy as np
-
-    from kai_scheduler_tpu.apis import types as apis
-    from kai_scheduler_tpu.framework.scheduler import (Scheduler,
-                                                       SchedulerConfig)
-    from kai_scheduler_tpu.runtime.cluster import Cluster
-    from kai_scheduler_tpu.state import make_cluster
-
-    def build():
-        nodes, queues, groups, pods, topo = make_cluster(
-            num_nodes=num_nodes, node_accel=8.0, num_gangs=num_gangs,
-            tasks_per_gang=tasks_per_gang, running_fraction=0.5)
-        cursor: dict = {}
-        for p in pods:
-            if p.status == apis.PodStatus.RUNNING:
-                c = cursor.get(p.node, 0)
-                p.accel_devices = [c]
-                cursor[p.node] = c + 1
-        return Cluster.from_objects(nodes, queues, groups, pods, topo)
-
-    def run(resident: bool):
-        cluster = build()
-        sched = Scheduler(SchedulerConfig(resident=resident))
-        rng = np.random.default_rng(0)
-        sched.run_once(cluster)  # cold full build + classic compiles
-        # warm until the steady-state mode engages (the first resident
-        # cycle compiles the fused entry — that must not be timed)
-        want = "resident" if resident else "patched"
-        for _ in range(4):
-            _churn_cluster(cluster, rng, 0.01, num_nodes)
-            sched.run_once(cluster)
-            if sched._snapshotter.stats.last.get("mode") == want:
-                break
-        walls: list[float] = []
-        acc: dict[str, list[float]] = {}
-        deltas: list[int] = []
-        dispatches = 0
-        modes: dict[str, int] = {}
-        reused = uploaded = 0
-        cycles = max(5, iters)
-        for _ in range(cycles):
-            _churn_cluster(cluster, rng, 0.01, num_nodes)
-            t0 = time.perf_counter()
-            res = sched.run_once(cluster)
-            walls.append(time.perf_counter() - t0)
-            for k, v in res.phase_seconds.items():
-                acc.setdefault(k, []).append(v)
-            last = sched._snapshotter.stats.last
-            modes[last["mode"]] = modes.get(last["mode"], 0) + 1
-            deltas.append(int(last.get("bytes_shipped", 0)))
-            dispatches += res.wire["dispatches"]
-            reused = res.wire["resident_reused_bytes"]
-            uploaded = res.wire["resident_uploaded_bytes"]
-        phases = {k: round(float(np.mean(v)) * 1e3, 2)
-                  for k, v in acc.items()}
-        return {"p99_ms": _p99(walls), "phases_ms": phases,
-                "modes": modes,
-                "delta_bytes_per_cycle": round(float(np.mean(deltas))),
-                "dispatches_per_cycle": round(dispatches / cycles, 2),
-                "resident_reused_bytes": reused,
-                "resident_uploaded_bytes": uploaded,
-                "fallbacks": dict(sched._snapshotter.stats.fallbacks)}
-
-    res_on = run(True)
-    res_off = run(False)
-    transfer_share = {
-        "resident_upload_plus_wait_ms": round(
-            res_on["phases_ms"].get("upload", 0.0)
-            + res_on["phases_ms"].get("device_wait", 0.0), 2),
-        "classic_upload_plus_wait_ms": round(
-            res_off["phases_ms"].get("upload", 0.0)
-            + res_off["phases_ms"].get("device_wait", 0.0), 2),
-    }
-    extra = {
-        "resident": res_on,
-        "classic_patch_twin": res_off,
-        "speedup_vs_classic": round(
-            res_off["p99_ms"] / max(res_on["p99_ms"], 1e-9), 2),
-        **transfer_share,
-    }
-    return {"metric": (f"kai-resident cycle p99 @ {num_nodes} nodes x "
-                       f"{num_gangs * tasks_per_gang} pods, 1% churn "
-                       "(one fused dispatch + one packed-delta upload "
-                       "per cycle; vs classic patch-ship twin "
-                       f"{round(res_off['p99_ms'], 1)} ms)"),
-            "value": round(res_on["p99_ms"], 3), "unit": "ms",
-            "vs_baseline": round(50.0 / max(res_on["p99_ms"], 1e-9), 3),
-            "extra": extra}
-
-
 def bench_storm(iters: int, *, num_nodes: int = 2000,
                 num_gangs: int = 500, tasks_per_gang: int = 4,
                 events: int | None = None) -> dict:
@@ -788,8 +681,8 @@ def bench_storm(iters: int, *, num_nodes: int = 2000,
 
     # quiescent boundary overhead: a coalesce with nothing staged is
     # what every cycle pays once the storm is over — it must be noise
-    # (microseconds) next to the cycle itself, or intake would tax the
-    # PR-11 resident steady state
+    # (microseconds) next to the cycle itself, or intake would tax
+    # every quiet cycle
     empty = []
     idle_router = IntakeRouter(IntakeConfig(lanes=4))
     for _ in range(50):
@@ -1179,7 +1072,6 @@ CONFIGS = {
     "churn": bench_churn,
     "phases": bench_phases,
     "frag": bench_frag,
-    "resident": bench_resident,
     "storm": bench_storm,
     "headline": bench_headline,
     "e2e": bench_e2e,

@@ -13,10 +13,6 @@ size (10 000 nodes x 50 000 pods, saturated, all five default actions):
               /cycle/stored``, a seeded ``POST /cluster/delta``, a seeded
               ``POST /intake``, two more cycles, then ``GET /healthz``,
               ``/metrics``, ``/debug/wire``.
-``resident``  the same three cycles on a second server whose scheduler is
-              ``resident=True, verify_incremental=True``: the donated
-              fused entry runs and the device state is compared leaf by
-              leaf with the host mirror.
 ``precision`` small clusters whose requests bf16 cannot hold (1.37 CPU,
               13.7 GiB, portion 0.35): the five actions on the chip and
               on the CPU backend of the same process, same snapshot.
@@ -275,20 +271,10 @@ def drive_server(phase: str, nodes: int, seed: int, config, meter,
     metrics = _http(server.port, "/metrics").decode()
     assert "kai_e2e_scheduling_latency_seconds" in metrics
     server.stop()
-    # warm-up is cycle 1 on the classic path.  The resident entry's
-    # signature carries the packed delta's segment lengths, which only
-    # grow: it compiles at its first dispatch (cycle 2) and again
-    # whenever a cycle's churn outgrows a segment (cycle 3 here), so its
-    # compiles are printed above, not asserted on
-    assert config.resident or misses[-1] == misses[0], (
+    # warm-up is cycle 1: nothing compiles after it
+    assert misses[-1] == misses[0], (
         f"{phase}: a jit entry compiled anew after cycle 1: {misses}")
     return commits
-
-
-def _decisions(commit: dict):
-    return (sorted((b["pod"], b["node"]) for b in commit["bind_requests"]),
-            sorted((e["pod"], e["move_to"] or "")
-                   for e in commit["evictions"]))
 
 
 def phase_precision(seed: int) -> None:
@@ -353,16 +339,8 @@ def phase_precision(seed: int) -> None:
 def run_one_chip(args, device, meter) -> None:
     from kai_scheduler_tpu.framework.scheduler import SchedulerConfig
 
-    classic = drive_server("classic", args.nodes, args.seed,
-                           SchedulerConfig(), meter, device)
-    resident = drive_server(
-        "resident", args.nodes, args.seed,
-        SchedulerConfig(resident=True, verify_incremental=True),
-        meter, device)
-    for cycle, (a, b) in enumerate(zip(classic, resident), start=1):
-        assert _decisions(a) == _decisions(b), (
-            f"classic and resident commit sets differ in cycle {cycle}")
-    say(phase="classic==resident", cycles=len(classic), equal=True)
+    drive_server("classic", args.nodes, args.seed, SchedulerConfig(),
+                 meter, device)
     phase_precision(args.seed)
 
 

@@ -30,10 +30,9 @@ This package machine-checks those invariants in two layers:
   over the same per-entry jaxpr walk the probe uses — def/last-use
   liveness for peak-live-bytes (sub-jaxprs worst-case-resident), a
   per-primitive FLOPs/traffic cost table, the ``KAI201`` broadcast-
-  blowup and ``KAI202`` donation-effectiveness checks, per-entry
-  budgets in ``cost_baseline.json``, and a scaling mode that fits the
-  peak-memory growth exponent over the node axis (the mesh-sharding
-  go/no-go signal).
+  blowup check, per-entry budgets in ``cost_baseline.json``, and a
+  scaling mode that fits the peak-memory growth exponent over the node
+  axis (the mesh-sharding go/no-go signal).
 * **Layer 5 — kai-comms** (``comms``): a static SPMD sharding &
   collective-cost audit over the same shared walk — PartitionSpec
   propagation seeded from ``parallel/mesh.state_shardings``, a ring

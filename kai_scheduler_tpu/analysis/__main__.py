@@ -54,7 +54,7 @@ def main(argv: list[str] | None = None) -> int:
     mode.add_argument("--cost", action="store_true",
                       help="kai-cost jaxpr dataflow audit only "
                            "(KAI2xx: liveness peak-memory, FLOPs, "
-                           "traffic, blowup, donation)")
+                           "traffic, blowup)")
     mode.add_argument("--comms", action="store_true",
                       help="kai-comms sharding audit only (KAI3xx: "
                            "PartitionSpec propagation, collective "
@@ -242,25 +242,10 @@ def main(argv: list[str] | None = None) -> int:
             baseline=cost_base.get("entries", {}))
         findings = costmodel.cost_findings(reports, cost_base)
         if args.update_baseline:
-            # stats (peak/FLOPs/traffic/blowup ratios) are absorbed;
-            # KAI202 donation failures — including an UNVERIFIABLE
-            # donation check — have no legitimate new value, so they
-            # block the rewrite, exactly like probe invariants
-            problems = costmodel.unverifiable_donations(reports)
-            kai202 = [f for f in findings if f.code == "KAI202"]
-            cost_update_ok = not (kai202 or problems)
-            if kai202 or problems:
-                # keep EVERY finding visible (a KAI201 riding along is
-                # neither absorbed nor silently dropped), and hold the
-                # deferred probe write back too — joint or nothing
-                if not args.as_json:
-                    print("cost baseline NOT updated — donation "
-                          "failures first:")
-                    if probe_update_ok:
-                        print("probe baseline NOT updated — cost "
-                              "stage blocked the joint refresh")
-            elif probe_update_ok is False:
-                cost_update_ok = False
+            # stats (peak/FLOPs/traffic/blowup ratios) are absorbed
+            problems = []
+            cost_update_ok = probe_update_ok is not False
+            if not cost_update_ok:
                 if not args.as_json:
                     print("cost baseline NOT updated — probe "
                           "invariant failures blocked the joint "
@@ -296,11 +281,6 @@ def main(argv: list[str] | None = None) -> int:
                 if r.unknown_prims:
                     extra += (f", {sum(r.unknown_prims.values())} "
                               f"bytes-only eqns")
-                if r.donation is not None:
-                    extra += (f", donation "
-                              f"{r.donation['compiled_aliased']}"
-                              f"/{r.donation['donated_leaves']} "
-                              f"aliased")
                 print(f"cost {r.name}: peak "
                       f"{r.peak_live_bytes / 1e6:.2f}MB, "
                       f"{r.flops / 1e6:.2f} MFLOP, traffic "
@@ -343,7 +323,7 @@ def main(argv: list[str] | None = None) -> int:
             # and a failed (or UNVERIFIABLE) lowering cross-validation
             # have no legitimate new value, so they block the rewrite
             # — and hold the deferred probe/cost writes back too,
-            # joint or nothing (KAI202 precedent)
+            # joint or nothing
             kai3 = [f for f in findings if f.code.startswith("KAI3")]
             problems = list(lowering_probs)
             if kai3 or problems:
@@ -356,12 +336,10 @@ def main(argv: list[str] | None = None) -> int:
                     if probe_update_ok:
                         print("probe baseline NOT updated — comms "
                               "stage blocked the joint refresh")
-            elif probe_update_ok is False or cost_update_ok is False:
-                blocker = ("probe invariant" if probe_update_ok is
-                           False else "cost donation")
+            elif probe_update_ok is False:
                 if not args.as_json:
-                    print(f"comm baseline NOT updated — {blocker} "
-                          f"failures blocked the joint refresh")
+                    print("comm baseline NOT updated — probe invariant "
+                          "failures blocked the joint refresh")
             else:
                 comms.update_comm_baseline(reports, comm_path)
                 if not args.as_json:
@@ -414,8 +392,7 @@ def main(argv: list[str] | None = None) -> int:
             for p in problems:
                 print(f"COMMS FAIL: {p}")
         failed |= bool(problems) or bool(findings)
-        if args.update_baseline and (probe_update_ok is False
-                                     or cost_update_ok is False):
+        if args.update_baseline and probe_update_ok is False:
             failed = True
 
     if args.as_json:

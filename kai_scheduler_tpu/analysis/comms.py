@@ -47,8 +47,8 @@ and comm baselines atomically or not at all.  A **lowering
 cross-validation** stage jits the fused entries with the real
 ``in_shardings`` on an 8-virtual-device CPU mesh and asserts the
 collective ops in the compiled HLO are within the model's predicted
-set — UNVERIFIABLE introspection blocks baseline updates, mirroring
-KAI202.  ``--comms --scaling`` fits modeled comm bytes vs device count
+set — UNVERIFIABLE introspection blocks baseline updates.
+``--comms --scaling`` fits modeled comm bytes vs device count
 {2, 4, 8}: the sub-linear-comm go/no-go signal for the sharded solver.
 
 Run via ``python -m kai_scheduler_tpu.analysis --comms``.  Tier-1:
@@ -97,9 +97,9 @@ SUBLINEAR_EXPONENT_BAR = 1.0
 COMM_RULES = {k: v for k, v in PROGRAM_RULES.items()
               if k.startswith("KAI3")}
 
-#: the fused production entries the HLO cross-validation stage lowers
+#: the fused production entry the HLO cross-validation stage lowers
 #: with real in_shardings on the virtual CPU mesh
-LOWERING_ENTRIES = ("fused_pipeline", "resident_cycle")
+LOWERING_ENTRIES = ("fused_pipeline",)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1159,7 +1159,7 @@ _MODEL_KIND_IMPLIES = {
 def _compiled_hlo_text(compiled) -> str | None:
     """Compiled-executable HLO text, ``None`` when the jax build
     exposes no introspection (report UNVERIFIABLE, never silently
-    pass) — same access pattern as the KAI202 donation check."""
+    pass)."""
     try:
         mods = compiled.runtime_executable().hlo_modules()
         return "\n".join(m.to_string() for m in mods)
@@ -1192,7 +1192,7 @@ def lowering_check(names=LOWERING_ENTRIES, *,
     and assert the collective kinds in the HLO fall inside the model's
     predicted set (the model is a conservative upper bound).  A doc
     with ``verified: False`` always fails the gate and blocks
-    ``--update-baseline`` — mirroring KAI202's UNVERIFIABLE rule."""
+    ``--update-baseline``."""
     n = int(num_devices or config.num_devices)
     unknown = set(names) - set(registered_comm_entries())
     if unknown:
@@ -1266,7 +1266,7 @@ def lowering_check(names=LOWERING_ENTRIES, *,
 
 def lowering_problems(docs: list[dict]) -> list[str]:
     """Gate messages for the cross-validation docs ([] = clean) —
-    UNVERIFIABLE always fails, exactly like the KAI202 donation rule."""
+    UNVERIFIABLE always fails."""
     problems = []
     for d in docs:
         if d.get("unexplained"):

@@ -4,12 +4,11 @@ The probe (layer 2, ``trace_probe.py``) counts eqns and const bytes —
 enough to catch program bloat, but silent on the binding constraint of
 the 100k-node mesh target (ROADMAP 2): **peak live device memory per
 entry**.  Nothing before PR 14 could say *before a run* whether a
-sharded config fits in HBM, whether an intermediate silently
+sharded config fits in HBM or whether an intermediate silently
 materializes at N× its inputs (the PR-5 ``[B,N,*]`` lane-prefix cumsum
-class), or whether a declared ``donate_argnums`` actually aliased in
-the compiled executable (the PR-11 XLA:CPU corruption class).  This
-module runs four static analyses off the **shared per-entry jaxpr
-walk** (``trace_probe.EntryTrace`` — one trace feeds probe and cost):
+class).  This module runs three static analyses off the **shared
+per-entry jaxpr walk** (``trace_probe.EntryTrace`` — one trace feeds
+probe and cost):
 
 * **liveness** — a def/last-use linear scan over each entry's eqn
   list.  Level inputs are caller-held for the whole dispatch; internal
@@ -30,15 +29,6 @@ walk** (``trace_probe.EntryTrace`` — one trace feeds probe and cost):
   ``blowup_factor ×`` the entry's largest input (padding-era default
   16×; entries with a checked-in ``max_blowup`` get that ratio plus
   tolerance headroom instead, exactly like the eqn budgets).
-* **donation effectiveness (KAI202)** — for entries that ship with
-  ``donate_argnums`` (the fused ``resident_cycle`` path), lower and
-  compile the *donating* jit and verify through the executable's
-  ``input_output_alias`` metadata that every donated input leaf
-  actually aliased an output.  A donated-but-unaliased buffer is freed
-  instead of reused — statically, this is the bug class PR 11 hit at
-  runtime.  The audit always donates argnum 0, independent of the
-  production CPU carve-out (``_resident_donate_argnums``): it checks
-  the program **as shipped on accelerator backends**.
 
 Findings ride the engine's machinery: :class:`engine.Finding` objects
 under ``file="jaxpr:<entry>"`` filtered through the same count-based
@@ -65,10 +55,7 @@ import dataclasses
 import json
 import math
 import os
-import re
-import warnings
 from collections import Counter
-from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -140,9 +127,7 @@ class CostReport:
     unknown_prims: dict
     #: while-loops charged a single trip (trip count is dynamic)
     unbounded_whiles: int
-    #: donation-effectiveness doc for donating entries, else None
-    donation: dict | None
-    #: KAI201/KAI202 findings (engine.Finding), pre-baseline
+    #: KAI201 findings (engine.Finding), pre-baseline
     findings: list
 
 
@@ -416,116 +401,7 @@ def _report_from_closed(name: str, closed, *, config: CostConfig,
         traffic_bytes=lc.traffic, max_blowup=round(blowup, 2),
         top_intermediates=top, unknown_prims=dict(
             sorted(lc.unknown.items())),
-        unbounded_whiles=lc.whiles, donation=None, findings=findings)
-
-
-# ---------------------------------------------------------------------------
-# donation effectiveness (KAI202)
-
-@dataclasses.dataclass(frozen=True)
-class DonationSpec:
-    """A production entry that ships with ``donate_argnums``."""
-
-    entry: str
-    fn: Callable
-    donate_argnums: tuple
-    static_argnames: tuple
-
-
-def _donation_specs() -> dict[str, DonationSpec]:
-    """Every production entry whose accelerator build donates buffers.
-
-    The audit re-jits with the donation FORCED ON (the production
-    ``_resident_donate_argnums`` carve-out turns it off on CPU — the
-    exact blindness that let PR 11's corruption ship; this check exists
-    to see through it)."""
-    from ..framework.scheduler import (RESIDENT_STATIC_ARGNAMES,
-                                       resident_cycle)
-    return {
-        "resident_cycle": DonationSpec(
-            entry="resident_cycle", fn=resident_cycle,
-            donate_argnums=(0,),
-            static_argnames=RESIDENT_STATIC_ARGNAMES),
-    }
-
-
-def _compiled_aliased_params(compiled) -> int | None:
-    """Distinct parameter numbers the compiled executable aliases to
-    outputs, read from the HloModule header's ``input_output_alias``
-    config — ``None`` when the executable exposes no introspection
-    (report as unverifiable, never as a silent pass)."""
-    text = None
-    try:
-        mods = compiled.runtime_executable().hlo_modules()
-        text = mods[0].to_string()
-    except Exception:  # noqa: BLE001 — jax/jaxlib API drift
-        try:
-            text = compiled.as_text()
-        except Exception:  # noqa: BLE001
-            return None
-    header = text.split("\n", 1)[0]
-    if "input_output_alias" not in header:
-        return 0
-    return len(set(re.findall(
-        r"\((\d+), \{[^}]*\}, (?:may|must)-alias\)", header)))
-
-
-def check_donation(spec: DonationSpec, args: tuple,
-                   kwargs: dict) -> tuple[dict, list[Finding]]:
-    """Lower + compile the donating jit and verify every donated input
-    leaf aliased an output in the executable."""
-    # audit-time jit, built per check on purpose: the production
-    # wrapper may carve donation OUT (CPU backend), and this one must
-    # donate unconditionally; it is lowered+compiled exactly once per
-    # audit and never dispatched, so the KAI032 per-call cache-miss
-    # hazard does not apply
-    jit_fn = jax.jit(  # kai-lint: disable=KAI032
-        spec.fn, donate_argnums=spec.donate_argnums,
-        static_argnames=spec.static_argnames)
-    donated_leaves = sum(
-        len(jax.tree_util.tree_leaves(args[p]))
-        for p in spec.donate_argnums if p < len(args))
-    with warnings.catch_warnings():
-        # "Some donated buffers were not usable" is exactly what we
-        # convert into a KAI202 finding below — don't also print it
-        warnings.simplefilter("ignore")
-        lowered = jit_fn.lower(*args, **kwargs)
-        marked = len(re.findall(r"tf\.aliasing_output",
-                                lowered.as_text()))
-        compiled = lowered.compile()
-    aliased = _compiled_aliased_params(compiled)
-    if (aliased == 0 and donated_leaves > 0
-            and marked == donated_leaves):
-        # lowering marked EVERY donated leaf (tf.aliasing_output) yet
-        # the compiled header parsed to zero aliases — far more likely
-        # input_output_alias moved off the header line (jaxlib format
-        # drift) than XLA dropping every alias.  Classify UNVERIFIABLE
-        # so the failure diagnoses the parser, not a phantom
-        # production donation bug
-        aliased = None
-    doc = {
-        "entry": spec.entry,
-        "donate_argnums": list(spec.donate_argnums),
-        "donated_leaves": donated_leaves,
-        "lowered_aliased": marked,
-        "compiled_aliased": aliased,
-        "verified": aliased is not None,
-    }
-    findings: list[Finding] = []
-    if aliased is not None and aliased < donated_leaves:
-        findings.append(Finding(
-            file=f"jaxpr:{spec.entry}", line=0, col=0, code="KAI202",
-            message=(
-                f"only {aliased}/{donated_leaves} donated input "
-                f"leaves aliased an output in the compiled executable "
-                f"({marked} marked at lowering) — an unaliased donated "
-                f"buffer is freed, not reused in place, so the "
-                f"'resident' state silently diverges from the mirror "
-                f"(the PR-11 corruption class, caught statically).  "
-                f"Every donated leaf must flow to a matching output "
-                f"aval"),
-            function=spec.entry))
-    return doc, findings
+        unbounded_whiles=lc.whiles, findings=findings)
 
 
 # ---------------------------------------------------------------------------
@@ -552,15 +428,13 @@ WATCHER_COVERAGE = {
     "fused_pipeline": {"fused_pipeline"},
     "analytics": {"analytics"},
     "repack": {"repack"},
-    "resident_cycle": {"resident_cycle"},
 }
 
 
 def run_cost(names: list[str] | None = None, *,
              traces: list | None = None,
              baseline: dict | None = None,
-             config: CostConfig = DEFAULT_CONFIG,
-             donation: bool = True) -> list[CostReport]:
+             config: CostConfig = DEFAULT_CONFIG) -> list[CostReport]:
     """Audit the selected (default: all) registered entries.
 
     ``traces`` accepts pre-built :class:`trace_probe.EntryTrace`
@@ -574,22 +448,9 @@ def run_cost(names: list[str] | None = None, *,
     elif names:
         sel = set(names)
         traces = [t for t in traces if t.name in sel]
-    specs = _donation_specs() if donation else {}
-    env = None
-    reports = []
-    for t in traces:
-        rep = _report_from_closed(t.name, t.closed, config=config,
-                                  base_entry=baseline.get(t.name))
-        if t.name in specs:
-            if env is None:
-                env = tp._canonical_env(now=1000.0)
-            probe_spec = {s.name: s for s in tp._registry()}[t.name]
-            args, kwargs = probe_spec.make_args(env)
-            doc, dfind = check_donation(specs[t.name], args, kwargs)
-            rep.donation = doc
-            rep.findings.extend(dfind)
-        reports.append(rep)
-    return reports
+    return [_report_from_closed(t.name, t.closed, config=config,
+                                base_entry=baseline.get(t.name))
+            for t in traces]
 
 
 # ---------------------------------------------------------------------------
@@ -598,19 +459,6 @@ def run_cost(names: list[str] | None = None, *,
 def load_cost_baseline(path: str = COST_BASELINE_PATH) -> dict:
     with open(path, encoding="utf-8") as f:
         return json.load(f)
-
-
-def unverifiable_donations(reports: list[CostReport]) -> list[str]:
-    """Donating entries whose compiled executable exposed no aliasing
-    introspection — always a failure (the KAI202 check must never pass
-    vacuously), and a blocker for ``--update-baseline`` too."""
-    return [
-        f"{r.name}: compiled executable exposes no "
-        f"input_output_alias introspection — the KAI202 "
-        f"donation check is UNVERIFIABLE on this jax; re-wire "
-        f"_compiled_aliased_params, don't skip the check"
-        for r in reports
-        if r.donation is not None and not r.donation["verified"]]
 
 
 def check_against_cost_baseline(reports: list[CostReport],
@@ -622,7 +470,7 @@ def check_against_cost_baseline(reports: list[CostReport],
     helper.  Blowup regressions surface as KAI201 findings instead
     (:func:`cost_findings`), not here."""
     entries = baseline.get("entries", {})
-    problems: list[str] = unverifiable_donations(reports)
+    problems: list[str] = []
     for r in reports:
         base = entries.get(r.name)
         if base is None:
@@ -703,7 +551,7 @@ def fit_exponent(node_counts, peaks) -> float:
     return float(np.polyfit(xs, ys, 1)[0])
 
 
-def scaling_report(names: tuple = ("fused_pipeline", "resident_cycle"),
+def scaling_report(names: tuple = ("fused_pipeline",),
                    node_counts: tuple = (32, 64, 128), *,
                    config: CostConfig = DEFAULT_CONFIG) -> dict:
     """Re-trace key entries at 2-3 padded node widths and fit each
@@ -777,15 +625,6 @@ def _fixture_blowup_good(x):
     return x * jnp.float32(2.0) + jnp.float32(1.0)
 
 
-def _fixture_donation_bad(x):
-    """Donated f32[8] reduced to a scalar — no output can alias it."""
-    return jnp.sum(x)
-
-
-def _fixture_donation_good(x):
-    return x + jnp.float32(1.0)
-
-
 def audit_fixture(code: str, kind: str = "bad") -> list[Finding]:
     """Run one KAI2xx fixture through the same audit path as
     production entries and return its findings."""
@@ -798,11 +637,4 @@ def audit_fixture(code: str, kind: str = "bad") -> list[Finding]:
                                   config=DEFAULT_CONFIG,
                                   base_entry=None)
         return rep.findings
-    if code == "KAI202":
-        fn = (_fixture_donation_bad if kind == "bad"
-              else _fixture_donation_good)
-        spec = DonationSpec(entry=f"fixture_{code}_{kind}", fn=fn,
-                            donate_argnums=(0,), static_argnames=())
-        _doc, findings = check_donation(spec, (x,), {})
-        return findings
     raise ValueError(f"unknown cost rule {code}")

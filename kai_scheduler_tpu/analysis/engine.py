@@ -85,9 +85,6 @@ def rule(code: str, title: str, *, bad: str = "", good: str = ""):
 PROGRAM_RULES = {
     "KAI201": "intermediate aval exceeds blowup_factor × the entry's "
               "largest input (broadcast blowup, jaxpr-level)",
-    "KAI202": "donated input leaf not aliased to any output in the "
-              "compiled executable (ineffective donation, "
-              "jaxpr-level)",
     "KAI301": "intermediate materializes the full node axis "
               "REPLICATED on every device above the size threshold "
               "(accidental node-axis replication, jaxpr-level)",

@@ -12,19 +12,15 @@ Code families (stable — suppressions and baselines reference them):
 * ``KAI061``        observability discipline (tracer calls in traces)
 * ``KAI071``        wire discipline (raw device transfers outside the
   ledger choke point)
-* ``KAI081``        donation discipline (host-side read of a buffer
-  previously passed through a donated argnum — use-after-donate)
 * ``KAI091``        intake discipline (direct hub-journal mark writes
   outside the journal's module and the kai-intake gate)
 * ``KAI2xx``        kai-cost program-level family (``costmodel.py``,
   catalog in ``engine.PROGRAM_RULES``): KAI201 broadcast blowup — an
   intermediate aval exceeding ``blowup_factor ×`` the entry's largest
-  input; KAI202 ineffective donation — a donated input leaf the
-  compiled executable did not alias to any output.  These judge the
-  traced *program*, not source: their fixtures are jax functions
-  (``tests/test_costmodel.py``), their findings ride the engine's
-  count-based baseline rows (``cost_baseline.json``), and inline
-  source suppressions do not apply.
+  input.  These judge the traced *program*, not source: their fixtures
+  are jax functions (``tests/test_costmodel.py``), their findings ride
+  the engine's count-based baseline rows (``cost_baseline.json``), and
+  inline source suppressions do not apply.
 * ``KAI3xx``        kai-comms program-level family (``comms.py``,
   catalog in ``engine.PROGRAM_RULES``): KAI301 accidental node-axis
   replication — an intermediate materializing the full node axis
@@ -687,8 +683,8 @@ def _tracer_in_jit(ctx: RuleCtx) -> Iterator[Finding]:
 #: the TransferLedger choke point: the only module allowed to touch
 #: the raw host↔device transfer API.  Every other call site must route
 #: through ``wire_ledger.LEDGER.device_put`` so per-leaf upload
-#: accounting (bytes, reasons, redundancy — the ROADMAP-1 evidence
-#: layer) can never silently rot as code grows.
+#: accounting (bytes, reasons, redundancy) can never silently rot as
+#: code grows.
 _WIRE_CHOKE_POINT = frozenset({
     "kai_scheduler_tpu/runtime/wire_ledger.py",
 })
@@ -723,8 +719,7 @@ def _raw_device_transfer(ctx: RuleCtx) -> Iterator[Finding]:
                 "raw jax.device_put bypasses the TransferLedger — "
                 "every host→device transfer must flow through "
                 "runtime/wire_ledger.LEDGER.device_put so per-leaf "
-                "bytes, reasons, and redundancy stay on the books "
-                "(ROADMAP-1's measurement substrate)",
+                "bytes, reasons, and redundancy stay on the books",
                 _in_function(ctx, node) or "")
         elif attr == "device_get":
             yield ctx.finding(
@@ -735,107 +730,6 @@ def _raw_device_transfer(ctx: RuleCtx) -> Iterator[Finding]:
                 "route readbacks through the packed commit bundle "
                 "instead of ad-hoc transfers the wire ledger cannot "
                 "see", _in_function(ctx, node) or "")
-
-
-# ---------------------------------------------------------------------------
-# KAI081 — donation discipline
-
-#: jit entry points that DONATE argument buffers (``donate_argnums``):
-#: the value passed at a donated position is dead the moment the call
-#: dispatches — on a real accelerator the buffer is reused in place and
-#: any later host read raises (or worse, reads scribbled memory).  The
-#: classic donation use-after-free is invisible on backends that ignore
-#: donation, so it must be caught statically.
-_DONATING_CALLEES: dict[str, tuple[int, ...]] = {
-    # kai-resident fused cycle entry (framework/scheduler.py): the
-    # device-resident ClusterState at position 0 is donated
-    "_resident_cycle": (0,),
-    "resident_cycle": (0,),
-}
-
-
-def _target_names(node: ast.AST) -> Iterator[str]:
-    """Plain names bound by an assignment target (tuples unpacked)."""
-    if isinstance(node, ast.Name):
-        yield node.id
-    elif isinstance(node, (ast.Tuple, ast.List)):
-        for elt in node.elts:
-            yield from _target_names(elt)
-    elif isinstance(node, ast.Starred):
-        yield from _target_names(node.value)
-
-
-@rule(
-    "KAI081", "host-side read of an array previously passed through a "
-    "donated argnum (use-after-donate)",
-    bad="""
-def run(state, delta):
-    packed = resident_cycle(state, delta)
-    return state, packed
-""",
-    good="""
-def run(state, delta):
-    state, packed = resident_cycle(state, delta)
-    return state, packed
-""")
-def _donated_buffer_read(ctx: RuleCtx) -> Iterator[Finding]:
-    for qual, fn in ctx.mod.functions.items():
-        donations: list[tuple[int, str, ast.Call]] = []
-        for node in ast.walk(fn):
-            if not isinstance(node, ast.Call):
-                continue
-            callee = None
-            if isinstance(node.func, ast.Name):
-                callee = node.func.id
-            elif isinstance(node.func, ast.Attribute):
-                callee = node.func.attr
-            spec = _DONATING_CALLEES.get(callee or "")
-            if not spec:
-                continue
-            for pos in spec:
-                if pos < len(node.args) and isinstance(node.args[pos],
-                                                       ast.Name):
-                    donations.append(
-                        (getattr(node, "end_lineno", node.lineno)
-                         or node.lineno, node.args[pos].id, node))
-        if not donations:
-            continue
-        bind_lines: dict[str, list[int]] = {}
-        for node in ast.walk(fn):
-            targets: list = []
-            if isinstance(node, ast.Assign):
-                targets = list(node.targets)
-            elif isinstance(node, (ast.AugAssign, ast.AnnAssign)):
-                targets = [node.target]
-            elif isinstance(node, ast.For):
-                targets = [node.target]
-            for t in targets:
-                for nm in _target_names(t):
-                    bind_lines.setdefault(nm, []).append(node.lineno)
-        for node in ast.walk(fn):
-            if not (isinstance(node, ast.Name)
-                    and isinstance(node.ctx, ast.Load)):
-                continue
-            for call_end, var, call in donations:
-                if node.id != var or node.lineno <= call_end:
-                    continue
-                # a rebind between the donating call and the read makes
-                # the name safe again (typically the call's own
-                # `state, ... = f(state, ...)` unpack)
-                if any(call.lineno <= ln <= node.lineno
-                       for ln in bind_lines.get(var, ())):
-                    continue
-                yield ctx.finding(
-                    "KAI081", node,
-                    f"`{var}` was passed through a donated argnum of "
-                    f"`{getattr(call.func, 'id', None) or getattr(call.func, 'attr', '?')}` "
-                    f"on line {call.lineno} — its device buffer is "
-                    f"consumed in place by the dispatch, so this later "
-                    f"read is a use-after-donate (deleted-array error "
-                    f"on donating backends, silent on backends that "
-                    f"ignore donation).  Rebind the name from the "
-                    f"call's outputs instead", qual)
-                break
 
 
 # ---------------------------------------------------------------------------

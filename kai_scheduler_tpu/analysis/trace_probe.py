@@ -34,15 +34,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import budgets
-from ..framework.scheduler import (_fused_pipeline, _resident_cycle,
-                                   resident_cycle, run_actions,
+from ..framework.scheduler import (_fused_pipeline, run_actions,
                                    stale_eviction_jit)
 from ..framework.session import (SessionConfig, _pack_commit,
                                  _set_fair_share_jit)
 from ..ops import analytics as pulse
 from ..ops import drf
 from ..ops import repack as repack_ops
-from ..ops import resident as resident_ops
 from ..ops.allocate import (AllocateConfig, allocate, allocate_jit,
                             init_result)
 from ..ops.stale import stale_gang_eviction
@@ -216,27 +214,6 @@ def _registry() -> list[ProbeSpec]:
             lambda env: (state_fs_args(env)[0],
                          dict(actions=actions, num_levels=nl, acfg=acfg,
                               vcfg=vcfg, grace_s=cfg.stale_grace_s))),
-        ProbeSpec(
-            # kai-resident fused cycle entry (framework/scheduler.py):
-            # delta scatter-apply + fair share + the whole action
-            # pipeline + analytics + packed commit as ONE program over
-            # donated state — probed with a structurally-valid empty
-            # delta (zero-size segments) at the canonical shapes, with
-            # analytics riding (the production steady-state cycle)
-            "resident_cycle",
-            functools.partial(
-                resident_cycle, actions=actions, num_levels=nl,
-                acfg=acfg, vcfg=vcfg, grace_s=cfg.stale_grace_s,
-                track_devices=False,
-                analytics_cfg=pulse.AnalyticsConfig()),
-            _resident_cycle,
-            lambda env: ((env[0], resident_ops.empty_delta(env[0]),
-                          jnp.zeros((env[0].gangs.g,), jnp.float32),
-                          jnp.float32(0.0)),
-                         dict(actions=actions, num_levels=nl, acfg=acfg,
-                              vcfg=vcfg, grace_s=cfg.stale_grace_s,
-                              track_devices=False,
-                              analytics_cfg=pulse.AnalyticsConfig()))),
         ProbeSpec(
             "pack_commit",
             functools.partial(getattr(_pack_commit, "__wrapped__",

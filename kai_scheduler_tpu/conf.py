@@ -206,10 +206,12 @@ def load_config(doc: dict | str | None,
         out = dataclasses.replace(out,
                                   incremental=bool(doc["incremental"]))
     if "resident" in doc:
-        # kai-resident device-resident cluster state (ops/resident.py):
-        # patched cycles ship packed journal deltas into donated device
-        # buffers and run the whole cycle as one fused dispatch
-        out = dataclasses.replace(out, resident=bool(doc["resident"]))
+        # refused, not ignored: an operator who still sets it would
+        # otherwise believe they run a path that no longer exists
+        raise ValueError(
+            "config key 'resident': the device-resident snapshot path "
+            "was removed in PR 30; delete the key (every cycle patches "
+            "the snapshot and ships its changed leaves)")
     if "verifyIncremental" in doc:
         out = dataclasses.replace(
             out, verify_incremental=bool(doc["verifyIncremental"]))
@@ -285,7 +287,6 @@ def effective_config_doc(cfg: SchedulerConfig) -> dict:
         "seed": cfg.seed,
         "twinRecord": cfg.twin_record,
         "incremental": cfg.incremental,
-        "resident": cfg.resident,
         "verifyIncremental": cfg.verify_incremental,
         "incrementalDirtyThreshold": cfg.incremental_dirty_threshold,
         "pyroscopeAddress": cfg.pyroscope_address,

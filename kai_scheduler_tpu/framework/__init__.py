@@ -1,8 +1,8 @@
 from .scheduler import (CycleResult, Scheduler, SchedulerConfig,
-                        action_names, register_action)
+                        action_names)
 from .session import Session, SessionConfig
 
 __all__ = [
     "CycleResult", "Scheduler", "SchedulerConfig", "Session",
-    "SessionConfig", "action_names", "register_action",
+    "SessionConfig", "action_names",
 ]

@@ -111,8 +111,8 @@ wire_dispatches = registry.counter(
 wire_redundant_bytes = registry.counter(
     "kai_wire_redundant_bytes_total",
     "Re-uploaded-IDENTICAL bytes: the uploaded leaf's content "
-    "fingerprint matched the last upload of the same leaf — the "
-    "invariant ROADMAP item 1 must drive to zero on the patch path",
+    "fingerprint matched the last upload of the same leaf — zero on "
+    "the patch path, which ships changed leaves only",
     label_names=("reason",))
 wire_dispatch_seconds = registry.counter(
     "kai_wire_dispatch_seconds_total",
@@ -125,25 +125,15 @@ wire_resident_bytes = registry.gauge(
 wire_resident_buffers = registry.gauge(
     "kai_wire_resident_buffers",
     "Ledger-known device-resident buffer count")
-# kai-resident (ops/resident.py): the device-resident-state payoff
-# gauge pair — per cycle, resident snapshot bytes REUSED on device
-# without touching the wire vs bytes actually uploaded (the packed
-# journal delta in steady state).  Donated delta buffers are transient
-# and never double-count into the residency watermark.
+# per cycle, snapshot bytes REUSED on device without touching the wire
+# vs bytes actually uploaded (a patched cycle's changed leaves)
 wire_resident_reused_bytes = registry.gauge(
     "kai_wire_resident_reused_bytes",
     "Device-resident bytes reused last cycle without re-upload "
     "(resident snapshot leaves not touched by the wire)")
 wire_resident_uploaded_bytes = registry.gauge(
     "kai_wire_resident_uploaded_bytes",
-    "Bytes uploaded last cycle (steady resident cycles: the packed "
-    "journal-delta size)")
-wire_downloaded_bytes = registry.counter(
-    "kai_wire_downloaded_bytes_total",
-    "Accounted device→host readback bytes through the ledger's "
-    "device_get (verify gathers, rare repack-plan readbacks) — "
-    "booked apart from uploads so patch-bytes invariants stay exact",
-    label_names=("reason",))
+    "Bytes uploaded last cycle (a patched cycle: its changed leaves)")
 wire_cycle_uploaded_bytes = registry.histogram(
     "kai_wire_cycle_uploaded_bytes",
     "Per-cycle bytes on the wire (all reasons; observed at cycle roll)",

@@ -4,19 +4,18 @@ The reference loop (``pkg/scheduler/scheduler.go:109-170``): every
 ``schedulePeriod`` open a session (snapshot + plugin init), execute the
 configured action pipeline (default ``allocate, consolidation, reclaim,
 preempt, stalegangeviction``), close the session (flush status).  The
-TPU rebuild keeps that exact shape; each action is a host function that
-invokes one compiled kernel and merges its commit set.
+TPU rebuild keeps that exact shape; the configured actions run as ONE
+compiled program over the snapshot (``_fused_pipeline``), each merging
+into the cycle's commit set.
 
-Actions register by name (ref ``actions/factory.go:31-37``
-RegisterAction) so configuration strings select and order them the same
-way ``SchedulerConfiguration.Actions`` does.
+Actions are selected and ordered by name (ref ``actions/factory.go:31-37``)
+the same way ``SchedulerConfiguration.Actions`` does.
 """
 from __future__ import annotations
 
 import dataclasses
 import time
 import weakref
-from typing import Callable, Protocol
 
 import functools
 
@@ -24,22 +23,18 @@ import jax
 import numpy as np
 
 from ..apis import types as apis
-from ..ops import drf
-from ..ops import resident as resident_ops
-from ..ops.allocate import (AllocationResult, allocate, allocate_jit,
-                            init_result)
-from ..ops.analytics import cluster_analytics, cluster_analytics_jit
+from ..ops.allocate import AllocationResult, allocate, init_result
+from ..ops.analytics import cluster_analytics_jit
 from ..ops.repack import RepackConfig, plan_repack_jit
 from ..ops.stale import stale_gang_eviction
-from ..ops.victims import (VICTIM_ACTIONS, run_victim_action,
-                           run_victim_action_jit)
+from ..ops.victims import VICTIM_ACTIONS, run_victim_action
 from ..runtime import compile_watch
 from ..runtime import wire_ledger as _wire
 from ..runtime.cluster import Cluster
 from ..runtime import events as gang_events
 from ..runtime.events import DecisionLog
 from ..runtime.tracing import CycleTracer
-from .session import FIT_REASONS, Session, SessionConfig, _pack_commit
+from .session import FIT_REASONS, Session, SessionConfig
 
 stale_eviction_jit = compile_watch.watch(
     "stale_gang_eviction",
@@ -47,8 +42,8 @@ stale_eviction_jit = compile_watch.watch(
         "grace_s", "num_levels"))(stale_gang_eviction))
 
 #: pure (unjitted) action bodies — composed into ONE jitted program per
-#: cycle when every configured action is built in.  Separate per-action
-#: jit calls cost a dispatch each and hide cross-action fusion from XLA.
+#: cycle.  Separate per-action jit calls would cost a dispatch each and
+#: hide cross-action fusion from XLA.
 _PURE_ACTIONS = {
     "allocate": lambda st, fs, res, nl, acfg, vcfg, grace: allocate(
         st, fs, num_levels=nl, config=acfg, init=res),
@@ -94,117 +89,6 @@ def _fused_pipeline(state, fair_share, *, actions, num_levels, acfg,
 # kai-wire compile watcher: per-(entry, signature) cache-miss
 # attribution (runtime/compile_watch.py)
 _fused_pipeline = compile_watch.watch("fused_pipeline", _fused_pipeline)
-
-#: ``_pack_commit``'s raw (unjitted) body — inlined into the fused
-#: resident entry below so the commit pack costs no second dispatch
-_PACK_COMMIT_FN = getattr(_pack_commit, "__wrapped__", _pack_commit)
-
-
-def resident_cycle(state, delta, ages, k_value, *, actions, num_levels,
-                   acfg, vcfg, grace_s, track_devices, analytics_cfg):
-    """kai-resident: ONE fused program for a steady-state patched cycle.
-
-    ``state`` is the device-resident snapshot (DONATED — the caller must
-    never touch the passed-in value again, KAI081); ``delta`` the packed
-    journal delta (``ops/resident.py``).  The chain that used to be up
-    to four dispatches — fair-share division, the action pipeline,
-    kai-pulse analytics, and the packed commit — runs as one XLA
-    program over the in-place-updated state, so a steady cycle is: one
-    small delta upload, one dispatch, one device sync.
-
-    Returns ``(new_state, result, packed)``: the post-delta resident
-    state for the next cycle (aliasing the donated buffers), the
-    commit-set tensors, and the i16 commit array ``gather_host`` syncs.
-    ``analytics_cfg=None`` is an analytics-skipped cadence cycle.
-    """
-    with jax.named_scope("apply_delta"):
-        state = resident_ops.apply_delta(state, delta)
-    with jax.named_scope("fair_share"):
-        fair_share = drf.set_fair_share(state, num_levels=num_levels,
-                                        k_value=k_value)
-    solved = state.replace(
-        queues=state.queues.replace(fair_share=fair_share))
-    res = run_actions(solved, fair_share, actions=actions,
-                      num_levels=num_levels, acfg=acfg, vcfg=vcfg,
-                      grace_s=grace_s)
-    bundle = None
-    if analytics_cfg is not None:
-        with jax.named_scope("analytics"):
-            bundle = cluster_analytics(solved, res, ages,
-                                       config=analytics_cfg)
-    with jax.named_scope("pack_commit"):
-        packed = _PACK_COMMIT_FN(
-            res, solved, track_devices=track_devices,
-            track_analytics=analytics_cfg is not None, analytics=bundle)
-    # the resident state returns WITHOUT the fair-share replacement:
-    # fair share is derived per cycle, and the device state must stay
-    # leaf-identical to the snapshotter's host mirror (verify compares)
-    return state, res, packed
-
-
-def _resident_donate_argnums() -> tuple[int, ...]:
-    """Donate the resident state only on accelerator backends.
-
-    Donation exists to update the snapshot in place in device memory —
-    on the CPU backend there is no transfer to save, and XLA:CPU's
-    donation path has been OBSERVED to corrupt the scattered-into state
-    under the multi-device host config the test mesh uses (the fused
-    program returns a state whose free pool drifted from the bitwise
-    mirror; identical program without donation is exact).  The CPU
-    carve-out keeps tier-1 bit-exactness unconditional; on TPU the
-    ``verify_incremental`` device gather-and-compare is the guard.
-    """
-    return () if jax.default_backend() == "cpu" else (0,)
-
-
-#: jitted fused entries keyed by donation tuple — created LAZILY at the
-#: first resident dispatch, never at import: an import-time
-#: ``jax.default_backend()`` would both force backend initialisation on
-#: every package import and freeze the CPU donation carve-out before
-#: the process has picked its platform (a stale ``(0,)`` on a
-#: later-selected CPU backend is exactly the corruption mode the
-#: carve-out exists to prevent)
-_RESIDENT_JIT_CACHE: dict = {}
-
-#: static argnames of the resident fused entry — ONE source of truth
-#: shared by the production jit build below and the kai-cost donation
-#: audit (``analysis/costmodel.py``), which re-jits the same signature
-#: with donation forced on
-RESIDENT_STATIC_ARGNAMES = ("actions", "num_levels", "acfg", "vcfg",
-                            "grace_s", "track_devices",
-                            "analytics_cfg")
-
-
-def _resident_jit():
-    donate = _resident_donate_argnums()
-    fn = _RESIDENT_JIT_CACHE.get(donate)
-    if fn is None:
-        # built ONCE per donation tuple and cached above — the KAI032
-        # hazard (a fresh jit callable per call missing the compile
-        # cache) cannot occur; the in-function build is deliberate so
-        # the backend choice is read at first use, not at import
-        fn = functools.partial(  # kai-lint: disable=KAI032
-            jax.jit, donate_argnums=donate,
-            static_argnames=RESIDENT_STATIC_ARGNAMES)(resident_cycle)
-        _RESIDENT_JIT_CACHE[donate] = fn
-        # forward the jit cache probe through the public watched
-        # wrapper so the trace probe's compile-once assertion keeps
-        # seeing the real cache
-        probe = getattr(fn, "_cache_size", None)
-        if probe is not None:
-            _resident_cycle._cache_size = probe
-        _resident_cycle.__kai_jit__ = fn
-    return fn
-
-
-@functools.wraps(resident_cycle)
-def _resident_dispatch(*args, **kwargs):
-    return _resident_jit()(*args, **kwargs)
-
-
-_resident_cycle = compile_watch.watch("resident_cycle",
-                                      _resident_dispatch)
-
 
 @dataclasses.dataclass
 class CycleResult:
@@ -284,82 +168,10 @@ def cycle_seed_for(seed: int, cycle_index: int) -> int:
     return x & 0x7FFFFFFF
 
 
-class Action(Protocol):
-    """An action mutates the cycle's commit set — ref ``framework/interface.go``."""
-
-    def __call__(self, session: Session, result: CycleResult) -> None: ...
-
-
-_ACTION_REGISTRY: dict[str, Callable[[], Action]] = {}
-
-
-def register_action(name: str):
-    """ref ``framework.RegisterAction`` (``actions/factory.go:31-37``)."""
-    def deco(builder: Callable[[], Action]):
-        _ACTION_REGISTRY[name] = builder
-        return builder
-    return deco
-
-
 def action_names() -> list[str]:
-    return list(_ACTION_REGISTRY)
-
-
-@register_action("allocate")
-def _allocate_action() -> Action:
-    def run(session: Session, result: CycleResult) -> None:
-        result.tensors = allocate_jit(
-            session.state, session.state.queues.fair_share,
-            num_levels=session.config.num_levels,
-            config=session.config.allocate,
-            init=result.tensors)
-    return run
-
-
-def _victim_action(mode: str) -> Action:
-    def run(session: Session, result: CycleResult) -> None:
-        result.tensors = run_victim_action_jit(
-            session.state, session.state.queues.fair_share, result.tensors,
-            num_levels=session.config.num_levels, mode=mode,
-            config=session.config.victims)
-    return run
-
-
-@register_action("reclaim")
-def _reclaim_action() -> Action:
-    """Cross-queue fairness enforcement — ref ``actions/reclaim``."""
-    return _victim_action("reclaim")
-
-
-@register_action("preempt")
-def _preempt_action() -> Action:
-    """Intra-queue priority preemption — ref ``actions/preempt``."""
-    return _victim_action("preempt")
-
-
-@register_action("consolidation")
-def _consolidation_action() -> Action:
-    """Evict-and-reallocate defragmentation — ref ``actions/consolidation``
-    (every victim must be re-placed; see ``victim_move``)."""
-    return _victim_action("consolidate")
-
-
-@register_action("stalegangeviction")
-def _stale_action() -> Action:
-    """Evict gangs below minMember past grace — ref
-    ``actions/stalegangeviction``."""
-    def run(session: Session, result: CycleResult) -> None:
-        result.tensors = stale_eviction_jit(
-            session.state, result.tensors,
-            grace_s=session.config.stale_grace_s,
-            num_levels=session.config.num_levels)
-    return run
-
-
-#: builders as shipped — the fused pipeline only engages when the
-#: configured actions still resolve to these (a re-registered override
-#: must run through the per-action path)
-_BUILTIN_BUILDERS = dict(_ACTION_REGISTRY)
+    """The actions a configuration may name, in the reference's default
+    order (ref ``actions/factory.go:31-37``)."""
+    return list(_PURE_ACTIONS)
 
 
 @dataclasses.dataclass
@@ -401,22 +213,8 @@ class SchedulerConfig:
     #: automatically for sharded instances (the shard filter re-shapes
     #: the object set per cycle).
     incremental: bool = True
-    #: kai-resident (ops/resident.py): keep the snapshot resident on
-    #: device across cycles — patched cycles upload only a packed
-    #: journal delta and run the WHOLE dispatch chain (delta apply →
-    #: fair share → action pipeline → analytics → packed commit) as
-    #: one fused jit entry with donated state buffers.  Requires the
-    #: incremental engine; structural changes fall back to the full
-    #: build + re-upload path automatically.  Off by default so the
-    #: classic per-leaf patch ship stays the verified reference path;
-    #: the resident bench config and production deployments opt in.
-    resident: bool = False
     #: after every patched refresh, rebuild from scratch and assert the
     #: patched ClusterState is element-wise identical (debug/CI flag).
-    #: On the resident path this additionally gathers the device-
-    #: resident state back and compares it leaf-wise against the host
-    #: mirror after every fused apply (non-verify runs never read the
-    #: donated state back).
     verify_incremental: bool = False
     #: dirty fraction above which patching falls back to a full rebuild
     incremental_dirty_threshold: float = 0.35
@@ -578,8 +376,9 @@ class Scheduler:
         self._repack_cooldown: int = 0
         self._repack_watch: dict[str, int] = {}
         self._last_repack: dict = {}
-        self._actions: list[tuple[str, Action]] = [
-            (name, _ACTION_REGISTRY[name]()) for name in self.config.actions]
+        unknown = [a for a in self.config.actions if a not in _PURE_ACTIONS]
+        if unknown:
+            raise KeyError(f"unknown action(s): {unknown}")
 
     def _shard_filter(self, nodes, queues, groups, pods, topology):
         """Restrict the snapshot to this shard's partition (ref
@@ -603,15 +402,6 @@ class Scheduler:
         keep = {g.name for g in groups}
         pods = [p for p in pods if p.group in keep]
         return nodes, queues, groups, pods, topology
-
-    def _builtin_pipeline(self) -> bool:
-        """True when every configured action still resolves to the
-        shipped builders — the precondition for running the pipeline as
-        one fused program (classic or resident)."""
-        return all(name in _PURE_ACTIONS
-                   and _ACTION_REGISTRY.get(name)
-                   is _BUILTIN_BUILDERS.get(name)
-                   for name in self.config.actions)
 
     def run_once(self, cluster: Cluster) -> CycleResult:
         """One scheduling cycle: snapshot → actions → commit set.
@@ -655,15 +445,6 @@ class Scheduler:
             # one extra cycle, never spuriously unschedulable with a
             # stale reason.
             upload_s = 0.0
-            resident_mode = False
-            staged_delta = None
-            # kai-resident engages only over the built-in fused action
-            # pipeline (an overridden action must run eagerly, outside
-            # the one fused entry) and never for sharded instances
-            use_resident = (self.config.resident
-                            and self.config.incremental
-                            and self.config.shard is None
-                            and self._builtin_pipeline())
             if self.config.incremental and self.config.shard is None:
                 # journaled incremental refresh: the snapshotter patches
                 # the previous cycle's snapshot from the cluster's
@@ -680,39 +461,16 @@ class Scheduler:
                         .incremental_dirty_threshold,
                         tracer=self.tracer)
                     self._snapshotter_cluster = weakref.ref(cluster)
-                if use_resident:
-                    # kai-resident: on patched cycles the snapshotter
-                    # stages only a packed journal delta (uploaded as
-                    # the cycle's ONE device_put) and the device state
-                    # stays put; structural changes land here as mode
-                    # "full" with a freshly built + re-uploaded state
-                    rr = self._snapshotter.refresh_resident(
-                        cluster, now=cluster.now,
-                        queue_usage=queue_usage)
-                    # snapshot.session: the Session over the refreshed
-                    # state (classic path: the fair-share dispatch)
-                    with self.tracer.span("snapshot.session"):
-                        if rr.mode == "resident":
-                            resident_mode = True
-                            staged_delta = rr.delta
-                            session = Session.resident(
-                                rr.index, config=self.config.session,
-                                host_state=rr.host)
-                        else:
-                            session = Session.from_state(
-                                rr.state, rr.index,
-                                config=self.config.session)
-                            session.host_state = rr.host
-                else:
-                    state, index = self._snapshotter.refresh(
-                        cluster, now=cluster.now,
-                        queue_usage=queue_usage)
-                    with self.tracer.span("snapshot.session"):
-                        session = Session.from_state(
-                            state, index, config=self.config.session)
+                state, index = self._snapshotter.refresh(
+                    cluster, now=cluster.now, queue_usage=queue_usage)
+                # snapshot.session: the Session over the refreshed state
+                # (the fair-share dispatch)
+                with self.tracer.span("snapshot.session"):
+                    session = Session.from_state(
+                        state, index, config=self.config.session)
                 # journal-delta stats of THIS refresh onto the span:
-                # mode (patched/full/resident), fallback reason, dirty
-                # rows, changed leaves and bytes actually uploaded
+                # mode (patched/full), fallback reason, dirty rows,
+                # changed leaves and bytes actually uploaded
                 snap_sp.attrs.update(self._snapshotter.stats.last)
                 upload_s = float(
                     self._snapshotter.stats.last.get("ship_seconds", 0.0))
@@ -737,95 +495,32 @@ class Scheduler:
         result.cycle_seed = cycle_seed_for(self.config.seed,
                                            self._cycle_index)
         result.open_seconds = open_s
-        packed = None
         with self.tracer.span("solve_dispatch"):
-            if not resident_mode:
-                # a dozen small dispatches: inside the span, as the
-                # phase's checkpoints already count them
-                result.tensors = init_result(session.state)
+            # a dozen small dispatches: inside the span, as the phase's
+            # checkpoints already count them
+            result.tensors = init_result(session.state)
             every = self.config.analytics_every
             run_analytics = every > 0 and self._cycle_index % every == 0
             self._cycle_index += 1
             bundle = None
             ages = None
-            if resident_mode:
-                # kai-resident fast path: delta apply + fair share +
-                # action pipeline + analytics + packed commit as ONE
-                # fused dispatch over the donated device-resident state
-                cfg = session.config
-                ta = time.perf_counter()
-                if run_analytics:
-                    ages = self._pending_age_vector(cluster, session)
-                    ages_arg = ages
-                else:
-                    # cadence-skipped cycle: the fused entry never
-                    # reads `ages` (analytics_cfg=None drops it at
-                    # trace time) — a zeros placeholder skips the
-                    # O(pending) host walk the classic path also
-                    # skips.  `ages` itself stays None so the repack
-                    # block below still computes REAL ages when its
-                    # trigger fires on a non-analytics cycle (an
-                    # all-zero vector would make every plan_repack
-                    # target gate fail and burn the cooldown for
-                    # nothing).
-                    src = (session.host_state
-                           if session.host_state is not None
-                           else session.state)
-                    ages_arg = np.zeros((src.gangs.g,), np.float32)
-                with self.tracer.span("action:resident_cycle"):
-                    donated = self._snapshotter.device_state
-                    new_state, tensors, packed = _resident_cycle(
-                        donated, staged_delta, ages_arg,
-                        np.float32(cfg.k_value),
-                        actions=tuple(self.config.actions),
-                        num_levels=cfg.num_levels, acfg=cfg.allocate,
-                        vcfg=cfg.victims, grace_s=cfg.stale_grace_s,
-                        track_devices=session.index.needs_device_table,
-                        analytics_cfg=(cfg.analytics if run_analytics
-                                       else None))
-                # `donated` is dead past this point (buffers consumed
-                # in place); the post-delta state takes over as both
-                # the session's state and the next cycle's resident base
-                self._snapshotter.adopt_device_state(new_state)
-                session.state = new_state
-                result.tensors = tensors
-                result.action_seconds["resident_cycle"] = \
-                    time.perf_counter() - ta
-                metrics.action_latency.observe(
-                    "resident_cycle",
-                    value=result.action_seconds["resident_cycle"])
-                if self.config.verify_incremental:
-                    self._snapshotter.verify_device_residency()
-            elif self._builtin_pipeline():
-                # fast path: the whole action pipeline as one compiled
-                # program
-                cfg = session.config
-                ta = time.perf_counter()
-                with self.tracer.span("action:pipeline"):
-                    result.tensors = _fused_pipeline(
-                        session.state, session.state.queues.fair_share,
-                        actions=tuple(self.config.actions),
-                        num_levels=cfg.num_levels, acfg=cfg.allocate,
-                        vcfg=cfg.victims, grace_s=cfg.stale_grace_s)
-                result.action_seconds["pipeline"] = \
-                    time.perf_counter() - ta
-                metrics.action_latency.observe(
-                    "pipeline", value=result.action_seconds["pipeline"])
-            else:
-                for name, action in self._actions:
-                    ta = time.perf_counter()
-                    with self.tracer.span(f"action:{name}"):
-                        action(session, result)
-                    result.action_seconds[name] = time.perf_counter() - ta
-                    metrics.action_latency.observe(
-                        name, value=result.action_seconds[name])
+            # the whole action pipeline as one compiled program
+            cfg = session.config
+            ta = time.perf_counter()
+            with self.tracer.span("action:pipeline"):
+                result.tensors = _fused_pipeline(
+                    session.state, session.state.queues.fair_share,
+                    actions=tuple(self.config.actions),
+                    num_levels=cfg.num_levels, acfg=cfg.allocate,
+                    vcfg=cfg.victims, grace_s=cfg.stale_grace_s)
+            result.action_seconds["pipeline"] = time.perf_counter() - ta
+            metrics.action_latency.observe(
+                "pipeline", value=result.action_seconds["pipeline"])
             # kai-pulse: dispatch the cluster-health kernel over the
             # final commit set (ops/analytics.py) — async like the
-            # actions above, so its device time overlaps and lands in
+            # pipeline above, so its device time overlaps and lands in
             # device_wait; the bundle rides the packed commit transfer.
-            # (On resident cycles the kernel already ran INSIDE the
-            # fused entry and the bundle is in `packed` — no dispatch.)
-            if run_analytics and not resident_mode:
+            if run_analytics:
                 ta = time.perf_counter()
                 with self.tracer.span("analytics"):
                     ages = self._pending_age_vector(cluster, session)
@@ -865,19 +560,11 @@ class Scheduler:
         # device-sync marker (dispatches above were async, so this wait
         # is link + device time, not host work).
         with self.tracer.span("device_wait", device_sync=True):
-            # ONE batched transfer: the packed commit (analytics bundle
-            # and — on fired classic cycles — the repack plan ride it;
-            # see Session.gather_host).  Resident cycles sync the
-            # packed array the fused entry already produced.
-            if resident_mode:
-                host = session.gather_host(
-                    result.tensors, packed=packed,
-                    packed_analytics=run_analytics,
-                    repack_plan=repack_plan)
-            else:
-                host = session.gather_host(
-                    result.tensors, analytics=bundle,
-                    repack_plan=repack_plan)
+            # ONE batched transfer: the packed commit (the analytics
+            # bundle and a fired cycle's repack plan ride it; see
+            # Session.gather_host)
+            host = session.gather_host(
+                result.tensors, analytics=bundle, repack_plan=repack_plan)
             plan_host = host.get("repack_plan")
         t_gather = time.perf_counter()
         repack_target = ""
@@ -1206,11 +893,7 @@ class Scheduler:
         top-K table, and ``_advance_starvation`` advances the host copy
         identically after decode)."""
         self._scope_ages(cluster)
-        # shapes come from the host mirror on resident cycles (the
-        # device state is not constructed until the fused dispatch)
-        src = (session.host_state if session.host_state is not None
-               else session.state)
-        ages = np.zeros((src.gangs.g,), np.float32)
+        ages = np.zeros((session.state.gangs.g,), np.float32)
         if self._pending_age:
             names = session.index.gang_names
             valid = session.index.host_tables["gang_valid"]

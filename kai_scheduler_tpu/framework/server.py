@@ -63,13 +63,15 @@ import pstats
 import threading
 import time
 import urllib.parse
+from concurrent.futures import ThreadPoolExecutor
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 import numpy as np
 
 from ..intake import apply as intake_apply
 from ..intake.router import IntakeConfig, IntakeRouter
-from ..runtime import compile_cache, compile_watch, wire_ledger
+from ..runtime import (compile_cache, compile_watch, malloc_tune,
+                       wire_ledger)
 from ..runtime.cluster import Cluster
 from ..runtime.snapshot import dump_cluster, load_cluster
 from ..runtime.tracing import GcWatch
@@ -223,12 +225,18 @@ def _commit_doc(result) -> dict:
     }
 
 
+#: how many requests are served at once; further ones wait their turn
+HANDLER_THREADS = 16
+
+
 class SchedulerServer:
     """Serve the debug/sidecar endpoints for one cluster + scheduler.
 
-    Concurrency model: ``ThreadingHTTPServer`` runs every request in its
-    own thread, so the stored cluster document and the (stateful)
-    Scheduler are shared mutable state.  All handler access to them is
+    Concurrency model: ``ThreadingHTTPServer`` runs every request in a
+    thread beside the others — one of ``HANDLER_THREADS`` threads that
+    live as long as the server (``_serve_on_pool``) — so the stored
+    cluster document and the (stateful) Scheduler are shared mutable
+    state.  All handler access to them is
     serialized under ``_state_lock`` — payloads are computed under the
     lock and written to the socket after releasing it, so a slow client
     never stalls the next request's state access.  ``GET /healthz``
@@ -633,6 +641,9 @@ class SchedulerServer:
                 pass
 
         self._httpd = ThreadingHTTPServer(("127.0.0.1", port), Handler)
+        self._pool = ThreadPoolExecutor(
+            max_workers=HANDLER_THREADS, thread_name_prefix="kai-http")
+        self._httpd.process_request = self._serve_on_pool
         self.port = self._httpd.server_address[1]
         self._thread: threading.Thread | None = None
 
@@ -767,8 +778,26 @@ class SchedulerServer:
         with self._state_lock:
             self.intake.coalesce(self.cluster)
 
+    def _serve_on_pool(self, request, client_address) -> None:
+        """In place of ``ThreadingHTTPServer``'s new thread a request.
+
+        glibc gives a new thread an arena of its own as long as the
+        thread before it has not quite exited, up to eight arenas a
+        core.  A patched cycle's 23 MB of host temporaries then came
+        out of a heap that had to be mapped and faulted in first, about
+        35 ms of a cycle of 150, until, a hundred threads on, new
+        threads began to share the arenas that were there: whole runs
+        of the idle cell sat on one of two levels by how that race went
+        (PERF.md, PR 30).  A thread that stays keeps its arena, and the
+        arena its heap (``runtime/malloc_tune.py``)."""
+        self._pool.submit(self._httpd.process_request_thread, request,
+                          client_address)
+
     def start(self) -> "SchedulerServer":
         compile_cache.enable()
+        # a serving process owns its allocator: cycles of one level, not
+        # of whichever the process's earlier frees left it on
+        malloc_tune.fix_thresholds()
         compile_watch.WATCHER.listen()
         self.scheduler.tracer.gc_watch = self._gc_watch.install()
         self._thread = threading.Thread(
@@ -785,5 +814,6 @@ class SchedulerServer:
         self.intake.stop()
         self._gc_watch.uninstall()
         self._httpd.shutdown()
+        self._pool.shutdown(wait=False)
         if self._thread is not None:
             self._thread.join(timeout=5)

@@ -28,7 +28,6 @@ from ..ops import analytics as pulse
 from ..ops import drf
 from ..runtime import compile_watch
 from ..runtime import events as gang_events
-from ..runtime import wire_ledger as _wire
 from ..ops.allocate import AllocateConfig, AllocationResult
 from ..ops.victims import VICTIM_ACTIONS, VictimConfig
 from ..state.cluster_state import (ClusterState, SnapshotIndex,
@@ -108,7 +107,7 @@ def _pack_commit(result: AllocationResult, state: ClusterState,
         # kai-repack: a fired cycle's migration plan rides the packed
         # commit too (pod indices can exceed i16, so i32/f32 fields
         # bitcast to i16 pairs) — the plan never costs its own
-        # device→host readback on the classic path
+        # device→host readback
         parts.append(jax.lax.bitcast_convert_type(
             repack_plan.move_pod, jnp.int16).ravel())
         parts.append(jax.lax.bitcast_convert_type(
@@ -217,10 +216,7 @@ class SessionConfig:
 def _auto_tune(config: SessionConfig, index: SnapshotIndex,
                padded_nodes: int, padded_running: int) -> SessionConfig:
     """Derive the kernel fast-path flags + wavefront widths from the
-    snapshot's index hints and padded shapes — shared verbatim by the
-    classic :meth:`Session.from_state` open and the kai-resident open
-    (which has only the host mirror's shapes in hand), so the two paths
-    always compile and run the SAME static config."""
+    snapshot's index hints and padded shapes."""
     # a hierarchy deeper than the configured recursion would
     # leave leaf levels undivided — widen to the snapshot depth
     if index.max_queue_depth + 1 > config.num_levels:
@@ -285,12 +281,6 @@ class Session:
     state: ClusterState
     index: SnapshotIndex
     config: SessionConfig
-    #: kai-resident: the snapshotter's numpy mirror of ``state``.  When
-    #: set, host-side decode paths read snapshot columns (gang→queue)
-    #: from it instead of pulling a device-resident leaf back over the
-    #: wire — and never touch a leaf a donated dispatch may have
-    #: consumed (KAI081).
-    host_state: ClusterState | None = None
 
     @classmethod
     def open(
@@ -326,38 +316,14 @@ class Session:
         state = state.replace(queues=state.queues.replace(fair_share=fair_share))
         return cls(state=state, index=index, config=config)
 
-    @classmethod
-    def resident(cls, index: SnapshotIndex,
-                 config: SessionConfig | None = None,
-                 host_state: ClusterState | None = None) -> "Session":
-        """Open a session for a kai-resident cycle: the snapshot is
-        already resident on device and the WHOLE dispatch chain —
-        fair-share division included — runs inside the one fused
-        ``resident_cycle`` entry, so this constructor dispatches
-        nothing.  Auto-tuning reads the host mirror's padded shapes
-        (identical to the device state's by construction); ``state`` is
-        assigned by the scheduler after the fused dispatch returns the
-        post-delta device state."""
-        config = config or SessionConfig()
-        if config.auto_tune and host_state is not None:
-            config = _auto_tune(config, index, host_state.nodes.n,
-                                host_state.running.m)
-        return cls(state=None, index=index, config=config,
-                   host_state=host_state)
-
     def _gangs_queue_host(self) -> "np.ndarray":
-        """The gang→queue column as host numpy — from the mirror when
-        one exists (resident cycles must not read device leaves back,
-        and must NEVER touch a donated previous-cycle state)."""
-        src = self.host_state if self.host_state is not None else self.state
-        return np.asarray(src.gangs.queue)
+        """The gang→queue column as host numpy."""
+        return np.asarray(self.state.gangs.queue)
 
     # -- commit path ------------------------------------------------------
 
     def gather_host(self, result: AllocationResult,
-                    analytics=None, *, packed=None,
-                    packed_analytics: bool = False,
-                    repack_plan=None) -> dict:
+                    analytics=None, *, repack_plan=None) -> dict:
         """ONE compact device→host transfer of the cycle's results,
         merged with the snapshot-side numpy tables the host never let go
         of (see ``_pack_commit``).  ``analytics`` (an
@@ -365,15 +331,6 @@ class Session:
         packed array — the kai-pulse bundle never costs a second
         transfer — and so does a fired cycle's kai-repack plan
         (``repack_plan``), decoded into ``host["repack_plan"]``.
-
-        kai-resident cycles pass ``packed=`` — the i16 commit array the
-        fused ``resident_cycle`` entry already produced on device
-        (``packed_analytics`` says whether the analytics bundle rode
-        it); this method then only syncs that one array.  A repack plan
-        on a resident cycle (rare: the trigger fired) is read back as
-        one accounted batched ``LEDGER.device_get`` instead — the plan
-        was solved in its own dispatch after the fused entry, so it
-        cannot ride the fused pack.
         """
         g, q, r = self.state.gangs, self.state.queues, self.state.running
         G, T, M, Q = g.g, g.t, r.m, q.q
@@ -383,16 +340,12 @@ class Session:
             # would bind pods to the wrong nodes
             raise ValueError("i16 commit packing needs < 32k nodes")
         devices = self.index.needs_device_table
-        plan_from_pack = repack_plan is not None and packed is None
-        if packed is None:
-            has_analytics = analytics is not None
-            flat = np.asarray(_pack_commit(
-                result, self.state, track_devices=devices,
-                track_analytics=has_analytics, analytics=analytics,
-                track_repack=plan_from_pack, repack_plan=repack_plan))
-        else:
-            has_analytics = packed_analytics
-            flat = np.asarray(packed)
+        has_analytics = analytics is not None
+        has_plan = repack_plan is not None
+        flat = np.asarray(_pack_commit(
+            result, self.state, track_devices=devices,
+            track_analytics=has_analytics, analytics=analytics,
+            track_repack=has_plan, repack_plan=repack_plan))
 
         def take(n):
             nonlocal off
@@ -435,7 +388,7 @@ class Session:
             ai = np.frombuffer(take(ni * 2).tobytes(), np.int32)
             out["analytics"] = pulse.host_unpack(
                 a32, ai, config=acfg, q=Q, r=R_, g=G)
-        if plan_from_pack:
+        if has_plan:
             P = repack_plan.move_pod.shape[0]
             mp = np.frombuffer(take(2 * P).tobytes(), np.int32)
             mn = np.frombuffer(take(2 * P).tobytes(), np.int32)
@@ -447,13 +400,6 @@ class Session:
                 "target_rack": ints[2], "feasible": bool(ints[3]),
                 "needed": fls[0], "rack_units_before": fls[1],
                 "rack_units_after": fls[2], "total_units": fls[3]}
-        elif repack_plan is not None:
-            # resident cycle + fired trigger: the plan is tiny and
-            # rare — one accounted batched readback through the ledger
-            out["repack_plan"] = _wire.LEDGER.device_get(
-                {f: getattr(repack_plan, f)
-                 for f in repack_plan.__dataclass_fields__},
-                reason="repack-plan")
         return out
 
     def bind_requests_from(self, result: AllocationResult,

@@ -510,15 +510,6 @@ class AllocateConfig:
     #: the snapshot — gangs without preferred levels skip the band's
     #: per-lane argmax + domain compare over the node axis entirely.
     preferred_topology: bool = True
-    #: uniform-kernel wavefront protocol: lanes emit placements only and
-    #: the chunk reconstructs capacity deltas with K-entry sparse
-    #: scatters (False restores the dense [B, N, R] delta/cumsum accept
-    #: path — debug/A-B knob, results are identical)
-    sparse_wavefront: bool = True
-    #: hoist per-TYPE feasibility/replica-count/score tables out of the
-    #: uniform kernel's lane vmap, [Y, N] once per chunk (False restores
-    #: the per-lane computation — debug/A-B knob, results are identical)
-    hoist_type_tables: bool = True
 
 
 def _attempt_gang_in_domain(
@@ -1550,7 +1541,7 @@ def allocate(
     # vmap and the accept cumsums — the dominant HBM traffic at
     # 10k nodes x 256 lanes
     sparse = (config.uniform_tasks and not config.extended
-              and not config.track_devices and config.sparse_wavefront
+              and not config.track_devices
               # measured: sparse lanes lose to the dense path when the
               # required-topology domain machinery is active (the
               # hoisted domain caps already carry the dense tensors)
@@ -1561,8 +1552,7 @@ def allocate(
     # per chunk (instead of [B, N] per lane under the vmap) leaves only
     # gathers + tie-jitter + top-k as per-lane node-axis work
     Yu = g.type_req.shape[0]
-    hoist_types = (config.uniform_tasks and Yu <= B
-                   and config.hoist_type_tables)
+    hoist_types = config.uniform_tasks and Yu <= B
 
     def build_type_tables(free_c, dev_c):
         zero_t = jnp.zeros((), free_c.dtype)

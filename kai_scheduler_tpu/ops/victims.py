@@ -81,8 +81,6 @@ class VictimConfig:
     """Knobs of the victim actions (ref reclaim/preempt action args)."""
 
     placement: AllocateConfig = AllocateConfig(dynamic_order=False)
-    #: reclaimerSaturationMultiplier (``plugins/proportion/proportion.go:67-95``)
-    saturation_multiplier: float = 1.0
     #: max preemptor gangs attempted per QUEUE (QueueDepthPerAction) for
     #: reclaim/consolidation; None = unlimited
     queue_depth: int | None = None

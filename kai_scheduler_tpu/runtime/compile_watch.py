@@ -18,14 +18,13 @@ every other cycle).
 Mechanics: the watcher models jax's cache key — the pytree structure of
 ``(args, kwargs)`` with array leaves abstracted to ``(shape, dtype)``
 and non-array leaves (static configs) to their ``repr`` — and treats
-the first call per unseen signature as the compile.  The kai-resident
-fused entry (``resident_cycle``) is the one the steady-state cycle
-lives on: its delta segments bucket to powers of two precisely so this
-watcher sees ONE signature per snapshot shape bucket — a resident
-recompile storm means the bucketing broke, and the alarm below is the
-tripwire.  The model is
-checked against jax itself where possible: wrappers forward the
-underlying ``_cache_size`` probe, which the trace probe's
+the first call per unseen signature as the compile.  The fused
+five-action entry (``fused_pipeline``) is the one the steady-state
+cycle lives on: the snapshot's padded axes are kept across rebuilds
+precisely so this watcher sees ONE signature per capacity — a storm
+there means the padding broke, and the alarm below is the tripwire.
+The model is checked against jax itself where possible: wrappers
+forward the underlying ``_cache_size`` probe, which the trace probe's
 compile-once assertion continues to consume.
 
 The wrapper is HOST-side and adds ~tens of microseconds per call

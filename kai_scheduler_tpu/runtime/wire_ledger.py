@@ -14,13 +14,6 @@ name, nbytes, dtype/shape, content fingerprint, and a *reason*:
 * ``full-build``     — ``build_snapshot``'s one-shot snapshot transfer;
 * ``journal-patch``  — the incremental snapshotter's changed-leaves
   ship (``state/incremental.py``), batched into ONE dispatch;
-* ``delta-apply``    — the kai-resident packed journal delta
-  (``ops/resident.py``): the only steady-state upload once the
-  snapshot lives on device; its buffers are **transient** (consumed by
-  the donated scatter-apply dispatch), so they are counted on the wire
-  but kept out of the device-residency gauge and the redundancy
-  compare (delta *indices* legitimately repeat cycle-to-cycle — the
-  redundancy invariant is about resident snapshot leaves);
 * ``fallback``       — the incremental engine rebuilt in full (cold
   start, structural change, feature pods, dirty-threshold, ...);
 * ``verify``         — the patched==fresh verifier's reference rebuild;
@@ -31,12 +24,10 @@ Three derived surfaces ride the ledger:
 * a **redundancy detector**: every upload is fingerprinted (full-buffer
   ``zlib.crc32`` + nbytes/dtype/shape) against the last upload of the
   same ``(site, leaf)`` key, and re-uploaded-*identical* bytes are
-  counted per reason — the exact invariant ROADMAP-1's delta-only
-  device-resident rewrite must drive to zero on the patch path;
+  counted per reason — the invariant the patch path holds at zero;
 * a **device-residency gauge**: the ledger-known resident set (last
   upload per leaf key) as live buffer count / bytes plus a per-cycle
-  peak watermark — the baseline ROADMAP-1's buffer donation will be
-  measured against;
+  peak watermark;
 * per-cycle summaries in a bounded ring (``GET /debug/wire``, the
   ``/healthz`` wire slice, ``CycleResult.wire``, Chrome-trace counter
   lanes) and cumulative ``kai_wire_*`` registry metrics.
@@ -71,13 +62,12 @@ import numpy as np
 
 __all__ = [
     "TransferLedger", "LEDGER", "REASON_FULL_BUILD",
-    "REASON_JOURNAL_PATCH", "REASON_DELTA_APPLY", "REASON_FALLBACK",
+    "REASON_JOURNAL_PATCH", "REASON_FALLBACK",
     "REASON_VERIFY", "REASON_MESH_SHARD",
 ]
 
 REASON_FULL_BUILD = "full-build"
 REASON_JOURNAL_PATCH = "journal-patch"
-REASON_DELTA_APPLY = "delta-apply"
 REASON_FALLBACK = "fallback"
 REASON_VERIFY = "verify"
 REASON_MESH_SHARD = "mesh-shard"
@@ -157,13 +147,8 @@ class TransferLedger:
         self._resident_bytes = 0
         #: resident keys (re)uploaded in the open window — at roll
         #: time, resident bytes NOT in this set were *reused* on device
-        #: without touching the wire (the kai-resident payoff gauge)
+        #: without touching the wire
         self._window_uploaded_keys: set[tuple[str, str]] = set()
-        #: cumulative accounted D2H readbacks (:meth:`device_get`) —
-        #: kept separate from the upload ``by_reason`` totals so upload
-        #: invariants (bytes == delta size) never absorb download bytes
-        self._downloads: dict[str, dict] = {}
-        self._window_downloads: dict[str, dict] = {}
         #: cumulative per-reason aggregates since process start
         self._totals: dict[str, dict] = {}
         #: ring/event bounds + fingerprint limit — immutable after init
@@ -192,8 +177,7 @@ class TransferLedger:
 
     def device_put(self, tree, sharding=None, *, reason: str,
                    site: str = "snapshot", replace_site: bool = False,
-                   leaf_names: list[str] | None = None,
-                   transient: bool = False):
+                   leaf_names: list[str] | None = None):
         """THE package choke point for ``jax.device_put`` (KAI071).
 
         Dispatches the whole ``tree`` in ONE ``jax.device_put`` call
@@ -209,15 +193,6 @@ class TransferLedger:
         redundancy tracking keys identically across full builds and
         patches.  Names must follow the tree's FLATTEN order (jax
         flattens dict keys SORTED, not in insertion order).
-
-        ``transient=True`` marks a consumable upload — a buffer a
-        donated dispatch eats in the same cycle (the kai-resident
-        packed delta).  Transient leaves count toward bytes on the
-        wire but are excluded from the device-residency gauge (they do
-        not outlive the dispatch, and counting them would double-book
-        the donated snapshot buffers they scatter into) and from the
-        redundancy compare (delta segments may legitimately repeat
-        content across cycles without any leaf being re-uploaded).
         """
         override = getattr(self._local, "reason", None)
         if override is not None:
@@ -238,41 +213,34 @@ class TransferLedger:
         for i, (path, leaf) in enumerate(leaves_p):
             name = (leaf_names[i] if leaf_names is not None
                     else jax.tree_util.keystr(path) or f"[{i}]")
-            # transient (donated-consumable) uploads skip the content
-            # fingerprint: they never enter the resident set or the
-            # redundancy compare, so hashing them is pure overhead
             staged.append((name, leaf, int(getattr(leaf, "nbytes", 0)),
-                           None if transient
-                           else _fingerprint(leaf, limit)))
+                           _fingerprint(leaf, limit)))
         agg = dict.fromkeys(_TOTAL_FIELDS, 0)
         agg["dispatches"] = 1
         with self._lock:
             # replace_site: leaves of this site NOT re-uploaded by this
             # dispatch are superseded and leave the resident set — but
             # only AFTER the per-leaf compares, so a full rebuild that
-            # re-ships identical bytes is still caught red-handed (the
-            # redundancy ROADMAP-1's device-resident rewrite deletes)
+            # re-ships identical bytes is still caught red-handed
             stale = ({k for k in self._resident if k[0] == site}
                      if replace_site else None)
             for name, leaf, nbytes, fp in staged:
                 key = (site, name)
                 if stale is not None:
                     stale.discard(key)
-                redundant = False
-                if not transient:
-                    prev = self._resident.get(key)
-                    redundant = (fp is not None and prev is not None
-                                 and prev[0] == fp)
-                    self._resident_bytes += nbytes - (
-                        prev[1] if prev is not None else 0)
-                    self._resident[key] = (fp, nbytes)
-                    self._window_uploaded_keys.add(key)
+                prev = self._resident.get(key)
+                redundant = (fp is not None and prev is not None
+                             and prev[0] == fp)
+                self._resident_bytes += nbytes - (
+                    prev[1] if prev is not None else 0)
+                self._resident[key] = (fp, nbytes)
+                self._window_uploaded_keys.add(key)
                 agg["leaves"] += 1
                 agg["bytes"] += nbytes
                 if redundant:
                     agg["redundant_leaves"] += 1
                     agg["redundant_bytes"] += nbytes
-                if fp is None and not transient:
+                if fp is None:
                     agg["unfingerprinted_bytes"] += nbytes
                 if len(self._window_events) < self.max_events_per_cycle:
                     self._window_events.append(
@@ -317,31 +285,6 @@ class TransferLedger:
         metrics.wire_resident_bytes.set(value=float(resident_bytes))
         metrics.wire_resident_buffers.set(value=float(resident_buffers))
 
-    def device_get(self, tree, *, reason: str, site: str = "snapshot"):
-        """Accounted batched device→host readback — the D2H counterpart
-        of :meth:`device_put` for the few legitimate bulk gathers
-        outside the packed commit (the kai-resident verify gather, the
-        rare repack-plan readback on resident cycles).  One
-        ``jax.device_get`` call for the whole tree; bytes are booked in
-        a separate ``downloads`` ledger so upload invariants (patched
-        bytes == delta size) never absorb readback traffic."""
-        leaves = jax.tree_util.tree_leaves(tree)
-        out = jax.device_get(tree)
-        nbytes = sum(int(getattr(leaf, "nbytes", 0)) for leaf in leaves)
-        with self._lock:
-            for dst in (self._window_downloads, self._downloads):
-                t = dst.setdefault(reason, {"leaves": 0, "bytes": 0,
-                                            "dispatches": 0})
-                t["leaves"] += len(leaves)
-                t["bytes"] += nbytes
-                t["dispatches"] += 1
-        try:
-            from ..framework import metrics  # package-relative, lazy
-        except Exception:  # noqa: BLE001 — mirror must never fail a read
-            return out
-        metrics.wire_downloaded_bytes.inc(reason, by=float(nbytes))
-        return out
-
     def roll_cycle(self, cycle_id: int) -> dict:
         """Close the open window into an immutable ring entry and
         return the cycle summary (``CycleResult.wire``).  Called by the
@@ -354,19 +297,15 @@ class TransferLedger:
             events = tuple(self._window_events)
             dropped = self._window_dropped
             peak = max(self._window_peak, self._resident_bytes)
-            # kai-resident payoff gauge: resident bytes that stayed on
-            # device this cycle without touching the wire, vs bytes
-            # actually uploaded.  A steady resident cycle reads
-            # reused ≈ snapshot size, uploaded ≈ packed delta size.
+            # resident bytes that stayed on device this cycle without
+            # touching the wire, vs bytes actually uploaded: a patched
+            # cycle reads reused ≈ snapshot size less the changed leaves
             reused = sum(
                 ent[1] for key, ent in self._resident.items()
                 if key not in self._window_uploaded_keys)
-            downloads = {r: dict(t) for r, t
-                         in sorted(self._window_downloads.items())}
             self._window_events = []
             self._window_dropped = 0
             self._window_totals = {}
-            self._window_downloads = {}
             self._window_uploaded_keys = set()
             self._window_peak = self._resident_bytes
             resident_bytes = self._resident_bytes
@@ -379,7 +318,6 @@ class TransferLedger:
                 "resident_buffers": resident_buffers,
                 "peak_resident_bytes": peak,
                 "resident_reused_bytes": reused,
-                "downloads": downloads,
             }
             for field in _TOTAL_FIELDS:
                 summary[field] = sum(t[field] for t in by_reason.values())
@@ -398,9 +336,7 @@ class TransferLedger:
             return
         metrics.wire_cycle_uploaded_bytes.observe(
             value=float(summary["bytes"]))
-        # kai-resident: reused-on-device vs uploaded bytes per cycle —
-        # the gauge pair ROADMAP-1's acceptance bar reads (reused ≈
-        # snapshot size, uploaded ≈ packed delta size in steady state)
+        # reused-on-device vs uploaded bytes per cycle
         metrics.wire_resident_reused_bytes.set(
             value=float(summary["resident_reused_bytes"]))
         metrics.wire_resident_uploaded_bytes.set(
@@ -414,9 +350,6 @@ class TransferLedger:
         with self._lock:
             return {"by_reason": {r: dict(t) for r, t
                                   in sorted(self._totals.items())},
-                    "downloads_by_reason": {
-                        r: dict(t)
-                        for r, t in sorted(self._downloads.items())},
                     "resident_bytes": self._resident_bytes,
                     "resident_buffers": len(self._resident)}
 
@@ -452,14 +385,11 @@ class TransferLedger:
                          "peak_bytes": max(self._window_peak,
                                            self._resident_bytes)}
             totals = {r: dict(t) for r, t in sorted(self._totals.items())}
-            downloads = {r: dict(t)
-                         for r, t in sorted(self._downloads.items())}
         return {
             "cycles": [dict(c, events=list(c["events"])) for c in ring],
             "window": window,
             "residency": residency,
-            "totals": {"by_reason": totals,
-                       "downloads_by_reason": downloads},
+            "totals": {"by_reason": totals},
         }
 
 

@@ -6,12 +6,10 @@ cache and only the objects that changed since the last cycle cost any
 work.  The seed port re-ran the full vectorized ``build_snapshot`` host
 pass (~0.2 s warm at 10k nodes × 50k pods) plus one monolithic
 ``device_put`` every cycle — historically several times the entire
-on-device solve, until this module (PR 1) made the host pass
-O(change) and kai-resident (PR 11, ``ops/resident.py``) removed the
-per-cycle re-upload entirely: the snapshot stays resident on device
-and patched cycles ship only a packed journal delta.  At production
+on-device solve, until this module (PR 1) made the host pass patch
+dirty rows and ship only the leaves that changed.  At production
 scale, cycle-to-cycle churn is a tiny fraction of the cluster; state
-refresh cost is proportional to *change*, not cluster size (the
+refresh cost should be proportional to *change*, not cluster size (the
 Tesserae approach, arXiv:2508.04953).
 
 Three pieces:
@@ -57,11 +55,7 @@ Three pieces:
 
 ``verify=True`` (the scheduler's ``verify_incremental`` flag) rebuilds
 from scratch after every patch and asserts the patched ``ClusterState``
-is element-wise identical — including ``SnapshotIndex`` name maps.  On
-the kai-resident path it additionally gates a device gather-and-compare
-(:meth:`IncrementalSnapshotter.verify_device_residency`) so the
-donated, in-place-updated device state is provably the mirror's twin —
-without ever reading the device state back on non-verify runs.
+is element-wise identical — including ``SnapshotIndex`` name maps.
 """
 from __future__ import annotations
 
@@ -75,7 +69,6 @@ import jax
 import numpy as np
 
 from ..apis import types as apis
-from ..ops import resident as _resident
 from ..runtime import wire_ledger as _wire
 from ..runtime.tracing import span_of
 from . import cluster_state as _cs
@@ -408,24 +401,6 @@ class SnapshotterStats:
         self.fallbacks[key] = self.fallbacks.get(key, 0) + 1
 
 
-@dataclasses.dataclass
-class ResidentRefresh:
-    """One kai-resident refresh outcome (``refresh_resident``)."""
-
-    #: "resident" — a packed delta is staged for the fused apply;
-    #: "full" — a structural/cold fallback rebuilt and re-uploaded
-    mode: str
-    index: object
-    #: freshly built device state (mode "full" only)
-    state: object | None
-    #: device-side packed journal delta (mode "resident" only)
-    delta: dict | None
-    #: the numpy mirror — host-side snapshot reads for the Session
-    #: (None while a persistent environment condition keeps the
-    #: per-entity ledger cold, e.g. DRA/volume feature stores)
-    host: object | None
-
-
 class IncrementalSnapshotter:
     """Journal-driven snapshot refresher for one ``Cluster``.
 
@@ -456,14 +431,6 @@ class IncrementalSnapshotter:
         self._capacity = SnapshotCapacity()
         #: the last rebuild's irregular vocabularies (``stats.last``)
         self._built_vocab: dict = {}
-        #: kai-resident desync guard: True between a staged delta
-        #: (refresh_resident) and its adoption (adopt_device_state)
-        self._delta_outstanding = False
-        #: kai-resident bucket hysteresis: per-group segment lengths
-        #: only grow (see ops/resident.pack_delta) so the fused entry's
-        #: abstract signature converges instead of recompiling whenever
-        #: churn wobbles across a pow2 boundary
-        self._delta_buckets: dict[str, int] = {}
 
     def _add_span(self, name: str, start: float, **attrs) -> None:
         if self._tracer is not None:
@@ -477,7 +444,8 @@ class IncrementalSnapshotter:
 
     # -- public -----------------------------------------------------------
 
-    def _bind_cluster(self, cluster) -> None:
+    def refresh(self, cluster, *, now: float | None = None,
+                queue_usage=None):
         if (self._cluster_ref is None
                 or self._cluster_ref() is not cluster):
             self._cluster_ref = weakref.ref(cluster)
@@ -485,10 +453,6 @@ class IncrementalSnapshotter:
             self._cursor = (journal.register()
                             if journal is not None else None)
             self._host = None
-
-    def refresh(self, cluster, *, now: float | None = None,
-                queue_usage=None):
-        self._bind_cluster(cluster)
         j = (self._cursor.consume() if self._cursor is not None
              else None)
         reason = self._patch_blockers(cluster, j)
@@ -529,139 +493,6 @@ class IncrementalSnapshotter:
         self.stats.last = dict(_FULL_STATS, fallback_reason=reason,
                                **self._built_vocab)
         return out
-
-    # -- kai-resident ------------------------------------------------------
-
-    def refresh_resident(self, cluster, *, now: float | None = None,
-                         queue_usage=None) -> "ResidentRefresh":
-        """The kai-resident refresh: patch the host mirror, then stage
-        a **packed journal delta** (``ops/resident.py``) for the fused
-        scatter-apply dispatch instead of shipping changed leaves.
-
-        On success (``mode == "resident"``) the device state has NOT
-        been touched yet: the scheduler runs the fused entry over
-        :attr:`device_state` (donating it) and hands the post-delta
-        state back via :meth:`adopt_device_state` — until then a desync
-        guard forces the next refresh to a full rebuild, so an aborted
-        cycle can never leave the mirror ahead of the device.  Every
-        fallback (cold start, structural change, feature pods, ...)
-        returns ``mode == "full"`` with a freshly built + uploaded
-        device state, exactly like :meth:`refresh`.
-        """
-        self._bind_cluster(cluster)
-        j = (self._cursor.consume() if self._cursor is not None
-             else None)
-        reason = None
-        if self._delta_outstanding:
-            # the previous staged delta was never applied (the cycle
-            # aborted between refresh and adopt): the mirror is ahead
-            # of the device — rebuild rather than diff against it
-            self._delta_outstanding = False
-            self._host = None
-            reason = "resident-desync"
-        if reason is None:
-            reason = self._patch_blockers(cluster, j)
-        if reason is None:
-            with self._span("snapshot.patch") as patch_sp:
-                try:
-                    host_new, index = self._patch(cluster, j, now,
-                                                  queue_usage)
-                    with self._span("patch.pack_delta"):
-                        delta, merged, dstats = _resident.pack_delta(
-                            self._host, host_new,
-                            min_buckets=self._delta_buckets)
-                except _Fallback as exc:
-                    reason = exc.reason
-                    patch_sp.name = "snapshot.patch_abandoned"
-                    patch_sp.attrs["fallback_reason"] = reason
-                except _resident.DeltaShapeError as exc:
-                    reason = f"delta-shape:{exc}"
-                    patch_sp.name = "snapshot.patch_abandoned"
-                    patch_sp.attrs["fallback_reason"] = "delta-shape"
-            if reason is None:
-                t_ship = time.perf_counter()
-                # ONE transient device_put: the delta is consumed by
-                # the donated scatter-apply dispatch and never joins
-                # the ledger's resident set (wire_ledger.py)
-                delta_dev = _wire.LEDGER.device_put(
-                    delta, reason=_wire.REASON_DELTA_APPLY,
-                    site="delta", transient=True)
-                ship_s = time.perf_counter() - t_ship
-                self._host = merged
-                self._index = index
-                self._delta_outstanding = True
-                self._delta_buckets.update(dstats["buckets"])
-                self.stats.patched += 1
-                self.stats.leaves_shipped += dstats["leaves"]
-                self.stats.bytes_shipped += dstats["bytes"]
-                self.stats.last = {
-                    "mode": "resident", "fallback_reason": "",
-                    "dirty_pods": self._last_dirty[0],
-                    "dirty_gangs": self._last_dirty[1],
-                    "pods_removed": self._last_removed[0],
-                    "gangs_removed": self._last_removed[1],
-                    "leaves_shipped": dstats["leaves"],
-                    "bytes_shipped": dstats["bytes"],
-                    "delta_elements": dstats["elements"],
-                    "ship_seconds": ship_s, "ship_dispatches": 1,
-                    **_PLAIN_VOCAB,
-                }
-                patch_sp.attrs.update(self.stats.last)
-                self._add_span("upload", t_ship,
-                               leaves=dstats["leaves"],
-                               bytes=dstats["bytes"], dispatches=1)
-                if self.verify:
-                    self._verify(cluster, now, queue_usage)
-                return ResidentRefresh(
-                    mode="resident", index=index, state=None,
-                    delta=delta_dev, host=self._host)
-        self.stats.fallback(reason)
-        with self._span("snapshot.full_build", fallback_reason=reason):
-            state, index = self._full(cluster, now, queue_usage)
-        self.stats.last = dict(_FULL_STATS, fallback_reason=reason,
-                               **self._built_vocab)
-        return ResidentRefresh(mode="full", index=index, state=state,
-                               delta=None, host=self._host)
-
-    @property
-    def device_state(self):
-        """The device-resident ``ClusterState`` (the fused entry's
-        donation target).  Reading it is safe; the VALUE passed into a
-        donated dispatch must never be touched afterwards (KAI081)."""
-        return self._dev
-
-    def adopt_device_state(self, state) -> None:
-        """Install the fused entry's post-delta output as the resident
-        state for the next cycle (clears the desync guard armed by
-        :meth:`refresh_resident`)."""
-        self._dev = state
-        self._delta_outstanding = False
-
-    def verify_device_residency(self) -> None:
-        """Gather the device-resident state and assert it is leaf-wise
-        identical to the host mirror — the kai-resident half of
-        ``verify_incremental``.  Only ever called on verify runs, so
-        the donation discipline of production cycles is untouched."""
-        if self._host is None or self._dev is None:
-            return
-        host_paths = jax.tree_util.tree_flatten_with_path(self._host)[0]
-        dev_host = _wire.LEDGER.device_get(
-            self._dev, reason=_wire.REASON_VERIFY)
-        dev_leaves = jax.tree_util.tree_leaves(dev_host)
-        for (path, mine), dev in zip(host_paths, dev_leaves):
-            name = jax.tree_util.keystr(path)
-            dev = np.asarray(dev)
-            if dev.shape != mine.shape or dev.dtype != mine.dtype:
-                raise IncrementalVerifyError(
-                    f"resident leaf {name}: shape/dtype "
-                    f"{dev.shape}/{dev.dtype} != "
-                    f"{mine.shape}/{mine.dtype}")
-            if not np.array_equal(dev, mine,
-                                  equal_nan=mine.dtype.kind == "f"):
-                bad = np.nonzero(dev != mine)
-                raise IncrementalVerifyError(
-                    f"resident leaf {name}: {len(bad[0])} elements "
-                    f"diverged from the host mirror")
 
     # -- fallback decisions ----------------------------------------------
 
@@ -759,7 +590,6 @@ class IncrementalSnapshotter:
             return state, index
         # pin realized padded dims as the next capacity (floors already
         # include the slack via `cap`; Y absorbs its own round-up slack)
-        old_capacity = self._capacity
         self._capacity = SnapshotCapacity(
             nodes=host.nodes.valid.shape[0],
             queues=host.queues.valid.shape[0],
@@ -767,15 +597,6 @@ class IncrementalSnapshotter:
             tasks=host.gangs.task_valid.shape[1],
             running=host.running.valid.shape[0],
             types=host.gangs.type_req.shape[0])
-        if self._capacity != old_capacity:
-            # kai-resident bucket hysteresis is scoped to ONE snapshot
-            # shape: a rebuild that re-padded the axes recompiles the
-            # fused entry regardless, and carrying a larger previous
-            # cluster's floors forward would pin every future delta to
-            # its historical maximum (inflated wire bytes + scatter
-            # work forever).  Same-shape rebuilds keep the floors — the
-            # settled signature stays warm across the fallback.
-            self._delta_buckets.clear()
         self._host, self._dev, self._index = host, state, index
         with self._span("snapshot.ledgers"):
             self._rebuild_ledgers(cluster, lists, host, index)

@@ -180,8 +180,6 @@ def test_known_jit_entry_points_probed():
         "cluster_analytics": {"analytics"},
         # kai-repack defragmentation solver (ops/repack.py)
         "plan_repack": {"repack"},
-        # kai-resident fused cycle entry (framework/scheduler.py)
-        "resident_cycle": {"resident_cycle"},
     }
     graph = PackageGraph(ROOT)
     entries = {q for _m, q in graph._entries()}

@@ -140,33 +140,6 @@ def test_fused_five_actions_compile_non_dense(topo, saturated_pools):
     _fits_one_chip(compiled)
 
 
-def test_resident_cycle_compiles_with_donation(topo, saturated):
-    """The fused resident entry exactly as ``_resident_jit`` builds it
-    off-CPU: state donated (it never is on the CPU backend)."""
-    from kai_scheduler_tpu.framework import scheduler as S
-    from kai_scheduler_tpu.ops import resident as resident_ops
-    one = SingleDeviceSharding(topo.devices[0])
-    state, cfg = saturated.state, saturated.config
-    tmpl = resident_ops.empty_delta(state)
-    seg = resident_ops.MIN_BUCKET
-    delta = {
-        "idx": {k: jax.ShapeDtypeStruct((seg,), jnp.int32, sharding=one)
-                for k in tmpl["idx"]},
-        "val": {k: jax.ShapeDtypeStruct((seg,), v.dtype, sharding=one)
-                for k, v in tmpl["val"].items()}}
-    fn = jax.jit(S.resident_cycle, donate_argnums=(0,),
-                 static_argnames=S.RESIDENT_STATIC_ARGNAMES)
-    compiled = fn.lower(
-        _shapes(state, one), delta,
-        jax.ShapeDtypeStruct((state.gangs.g,), jnp.float32, sharding=one),
-        jax.ShapeDtypeStruct((), jnp.float32, sharding=one),
-        track_devices=saturated.index.needs_device_table,
-        analytics_cfg=cfg.analytics, **_pipeline_kwargs(cfg)).compile()
-    _fits_one_chip(compiled)
-    # donation took: the returned state aliases the donated buffers
-    assert compiled.memory_analysis().alias_size_in_bytes > 0
-
-
 def test_headline_allocate_compiles(topo):
     """``bench.py``'s headline step — fair share + allocate on an empty
     cluster under the session's tuned config."""

@@ -63,6 +63,17 @@ def test_unknown_action_rejected():
         raise AssertionError("expected ValueError")
 
 
+def test_resident_key_is_refused_by_name():
+    """The device-resident path went in PR 30.  A document that still
+    sets the key — true or false — is refused, not ignored: an operator
+    must not believe they run a path that no longer exists."""
+    for value in ("true", "false"):
+        with pytest.raises(ValueError, match="'resident'.*PR 30"):
+            conf.load_config(f"resident: {value}\n")
+    assert "resident" not in conf.effective_config_doc(
+        conf.load_config(None))
+
+
 def test_config_drives_scheduler_pipeline():
     """Changing actions via a config document — no code edits — changes
     which actions run (VERDICT r2 item 8's 'done' bar)."""

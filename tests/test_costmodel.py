@@ -6,14 +6,13 @@ Mirrors the three-layer guarantee structure of ``test_analysis.py``:
 1. **Unit pins** — the liveness scan, the per-primitive FLOP table,
    and the worst-case-resident sub-jaxpr rule against hand-computed
    jaxprs (the model itself is under test, not just its outputs).
-2. **Rule fixtures** — KAI201/KAI202 carry must-trigger and
+2. **Rule fixtures** — KAI201 carries must-trigger and
    must-not-trigger fixtures like every AST rule; both directions run.
 3. **Package invariants** — every CompileWatcher-tracked production
    entry has a cost report and a checked-in budget (the watcher entry
    list is the coverage oracle, so a new jit entry cannot dodge the
    auditor), the production package audits clean with zero baselined
-   findings, the fused resident entry's donation verifies leaf-exact,
-   and the model's memory-traffic ranking agrees with measured
+   findings, and the model's memory-traffic ranking agrees with measured
    dispatch ordering (model vs reality, tolerance-gated).
 """
 import importlib.util
@@ -37,8 +36,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 @pytest.fixture(scope="module")
 def cost_reports():
-    """One full audit (shared walk + donation check) for the module —
-    the donating compile rides the suite's persistent XLA cache."""
+    """One full audit (the shared walk) for the module."""
     base = cm.load_cost_baseline()
     reports = cm.run_cost(baseline=base.get("entries", {}))
     return base, {r.name: r for r in reports}
@@ -175,7 +173,7 @@ def test_cost_findings_ride_engine_baseline_rows():
                        largest_input_bytes=0, flops=0, traffic_bytes=0,
                        max_blowup=0.0, top_intermediates=[],
                        unknown_prims={}, unbounded_whiles=0,
-                       donation=None, findings=findings)],
+                       findings=findings)],
         {"baselined": [{"file": findings[0].file, "code": "KAI201",
                         "count": 1}]})
     assert eaten == []
@@ -183,7 +181,7 @@ def test_cost_findings_ride_engine_baseline_rows():
         name="f", peak_live_bytes=0, input_bytes=0,
         largest_input_bytes=0, flops=0, traffic_bytes=0,
         max_blowup=0.0, top_intermediates=[], unknown_prims={},
-        unbounded_whiles=0, donation=None, findings=findings)], {})
+        unbounded_whiles=0, findings=findings)], {})
     assert [f.code for f in kept] == ["KAI201"]
 
 
@@ -205,44 +203,6 @@ def test_production_package_audits_clean(cost_reports):
         # precedent) — an unjustified row fails tier-1
         assert row.get("justification", "").strip(), (
             f"unjustified baselined cost finding: {row}")
-
-
-def test_resident_donation_verifies_leaf_exact(cost_reports):
-    """The KAI202 production check: the fused resident entry's
-    donating build must alias EVERY donated state leaf to an output in
-    the compiled executable — the static form of the PR-11 guard.
-    ``verified`` must be True (an introspection regression fails
-    loudly, never passes vacuously)."""
-    _base, reports = cost_reports
-    doc = reports["resident_cycle"].donation
-    assert doc is not None and doc["verified"] is True
-    assert doc["donated_leaves"] > 0
-    assert doc["compiled_aliased"] == doc["donated_leaves"], doc
-    assert doc["lowered_aliased"] == doc["donated_leaves"], doc
-
-
-def test_unverifiable_donation_is_always_a_problem():
-    """A donating entry whose executable exposed no aliasing
-    introspection fails the baseline check AND blocks
-    ``--update-baseline`` (the CLI's update branch calls the same
-    helper) — the KAI202 guard can never pass or be absorbed
-    vacuously."""
-    rep = cm.CostReport(
-        name="r", peak_live_bytes=1, input_bytes=1,
-        largest_input_bytes=1, flops=1, traffic_bytes=1,
-        max_blowup=1.0, top_intermediates=[], unknown_prims={},
-        unbounded_whiles=0,
-        donation={"entry": "r", "donate_argnums": [0],
-                  "donated_leaves": 3, "lowered_aliased": 3,
-                  "compiled_aliased": None, "verified": False},
-        findings=[])
-    probs = cm.unverifiable_donations([rep])
-    assert len(probs) == 1 and "UNVERIFIABLE" in probs[0]
-    checked = cm.check_against_cost_baseline(
-        [rep], {"entries": {"r": {"peak_live_bytes": 1, "flops": 1,
-                                  "traffic_bytes": 1}}},
-        full_coverage=False)
-    assert checked == probs
 
 
 def test_peak_mb_for_state_is_a_pure_retrace(cost_reports):
@@ -273,7 +233,6 @@ def test_watcher_entries_are_the_coverage_oracle(cost_reports):
         "run_victim_action_jit": "run_victim_action",
         "cluster_analytics": "analytics",
         "plan_repack": "repack",
-        "resident_cycle": "resident_cycle",
         "cumsum_ds": None,      # analysis-only probe helper
     }
     graph = PackageGraph(ROOT)
@@ -468,7 +427,7 @@ def test_list_rules_includes_cost_family(capsys):
     from kai_scheduler_tpu.analysis.__main__ import main
     assert main(["--list-rules"]) == 0
     out = capsys.readouterr().out
-    assert "KAI201" in out and "KAI202" in out
+    assert "KAI201" in out and "KAI301" in out
 
 
 def test_update_baseline_refreshes_all_in_one_invocation(

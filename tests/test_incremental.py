@@ -575,6 +575,245 @@ class TestFallbacks:
 
 
 # ---------------------------------------------------------------------------
+# Every named refusal: reached, booked, rebuilt right, and left behind
+# ---------------------------------------------------------------------------
+
+
+def _pending(cluster, n=1):
+    out = [p for p in cluster.pods.values()
+           if p.status == apis.PodStatus.PENDING][:n]
+    assert len(out) == n
+    return out
+
+
+def _node_with_mig(c):
+    c.nodes["node-0"].extended = {"nvidia.com/mig-1g.5gb": 2}
+
+
+def _node_without_mig(c):
+    c.nodes["node-0"].extended = {}
+
+
+def _start_move(c):
+    # a consolidation move in flight: the pod releases its node while a
+    # Pending bind request holds its target
+    name = next(p.name for p in c.pods.values()
+                if p.status == apis.PodStatus.RUNNING)
+    c.evict_pod(name, restart=True)
+    c.create_bind_request(apis.BindRequest(name, "node-1"))
+
+
+def _submit_subgrouped(c):
+    c.submit(apis.PodGroup("sg", queue="queue-0-0", min_member=2,
+                           sub_groups=[apis.SubGroup("a", min_member=1)]),
+             [apis.Pod(f"sg-{i}", "sg", apis.ResourceVec(1, 1, 4))
+              for i in range(2)])
+
+
+def _add_storage_class(c):
+    c.storage_classes["fast"] = apis.StorageClass("fast")
+
+
+def _cordon_by_delta(c):
+    intake_apply.apply_cluster_delta(c, {"nodes_upsert": [
+        {"name": "node-3", "unschedulable": True,
+         "allocatable": {"accel": 8.0, "cpu": 64.0, "memory": 256.0}}]})
+
+
+def _swap_topology(c):
+    c.topology = dataclasses.replace(c.topology)
+
+
+def _drop_journal(c):
+    c.journal = None
+
+
+def _replace_pod_object(c):
+    pod = _pending(c)[0]
+    c.pods[pod.name] = dataclasses.replace(pod)
+
+
+def _replace_gang_object(c):
+    name = next(iter(c.pod_groups))
+    c.pod_groups[name] = dataclasses.replace(c.pod_groups[name])
+
+
+def _rewrite_node_allocatable(c):
+    n = c.nodes["node-2"]
+    n.allocatable = dataclasses.replace(n.allocatable)
+
+
+def _gang_grows_subgroups(c):
+    g = next(iter(c.pod_groups.values()))
+    g.unschedulable = True
+    g.sub_groups = [apis.SubGroup("late", min_member=1)]
+
+
+def _gang_sheds_subgroups(c):
+    g = next(iter(c.pod_groups.values()))
+    g.unschedulable = False
+    g.sub_groups = []
+
+
+def _swap_last_queues(c):
+    items = list(c.queues.items())
+    items[-1], items[-2] = items[-2], items[-1]
+    c.queues = dict(items)
+
+
+def _many_new_groups(c):
+    for i in range(64):
+        c.submit(apis.PodGroup(f"wave-{i}", queue="queue-0-0",
+                               min_member=1), [])
+
+
+def _one_wide_gang(c):
+    c.submit(apis.PodGroup("wide", queue="queue-0-0", min_member=1),
+             [apis.Pod(f"wide-{i}", "wide", apis.ResourceVec(1, 1, 4))
+              for i in range(12)])
+
+
+def _resize_pods_apart(c):
+    for i, pod in enumerate(_pending(c, 6)):
+        pod.resources = apis.ResourceVec(1, 1 + i, 4)
+        gate.pod_touched(c.journal, pod.name)
+
+
+def _bind_most(c):
+    for i, pod in enumerate(_pending(c, 40)):
+        c.bind_pod(pod.name, f"node-{i % 8}")
+
+
+def _resubmit_deleted_pod(c):
+    pod = _pending(c)[0]
+    del c.pods[pod.name]
+    c.submit(c.pod_groups[pod.group], [pod])
+
+
+def _resubmit_deleted_group(c):
+    name = next(iter(c.pod_groups))
+    group = c.pod_groups.pop(name)
+    c.submit(group, [])
+
+
+def _evict_then_drop_pod(c):
+    pod = _pending(c)[0]
+    c.evict_pod(pod.name)
+    del c.pods[pod.name]
+
+
+def _touch_then_drop_group(c):
+    name = next(iter(c.pod_groups))
+    c.submit(c.pod_groups[name], [])
+    del c.pod_groups[name]
+
+
+def _drop_pod_unseen(c):
+    del c.pods[_pending(c)[0].name]
+
+
+def _drop_group_unseen(c):
+    del c.pod_groups[next(reversed(c.pod_groups))]
+
+
+def _drop_node_unseen(c):
+    del c.nodes["node-7"]
+
+
+#: reason -> (what the cluster is built with, what is done to it before
+#: the first refresh, the named mutation, what undoes a lasting cause).
+#: ``topology-drift`` is absent by design: ``_patch_blockers`` names the
+#: same condition ``topology-changed`` before the sweep can see it.
+_REFUSALS = {
+    "vocab-residue": ({}, _node_with_mig, None, _node_without_mig),
+    "inflight-move": ({"running_fraction": 0.5}, None, _start_move,
+                      lambda c: c.tick()),
+    "nonplain-gangs": ({}, None, _submit_subgrouped,
+                       lambda c: delete_groups(c, ["sg"])),
+    "feature-stores": ({}, None, _add_storage_class,
+                       lambda c: c.storage_classes.clear()),
+    "node-dirty": ({}, None, _cordon_by_delta, None),
+    "topology-changed": ({"topology_levels": (2, 2)}, None,
+                         _swap_topology, None),
+    "no-journal": ({}, _drop_journal, None, None),
+    "pod-object-drift": ({}, None, _replace_pod_object, None),
+    "gang-object-drift": ({}, None, _replace_gang_object, None),
+    "node-drift": ({}, None, _rewrite_node_allocatable, None),
+    "gang-grew-subgroups": ({}, None, _gang_grows_subgroups,
+                            _gang_sheds_subgroups),
+    "queue-order-changed": ({}, None, _swap_last_queues, None),
+    "overflow-gangs": ({}, None, _many_new_groups, None),
+    "overflow-tasks": ({}, None, _one_wide_gang, None),
+    "overflow-types": ({}, None, _resize_pods_apart, None),
+    "overflow-running": ({"num_gangs": 24}, None, _bind_most, None),
+    "pod-add-drift": ({}, None, _resubmit_deleted_pod, None),
+    "gang-add-drift": ({}, None, _resubmit_deleted_group, None),
+    "pod-removed-unjournaled": ({}, None, _evict_then_drop_pod, None),
+    "gang-removed-unjournaled": ({}, None, _touch_then_drop_group, None),
+    "pod-membership-drift": ({}, None, _drop_pod_unseen, None),
+    "gang-membership-drift": ({}, None, _drop_group_unseen, None),
+    "node-membership-drift": ({}, None, _drop_node_unseen, None),
+}
+
+
+def _assert_fresh(state, snap, cluster):
+    """``state`` equals a fresh ``build_snapshot`` of the cluster as it
+    stands, leaf for leaf."""
+    from kai_scheduler_tpu.state.cluster_state import build_snapshot
+    fresh, _ = build_snapshot(
+        *cluster.snapshot_lists(), now=cluster.now,
+        resource_claims=cluster.resource_claims,
+        device_classes=cluster.device_classes,
+        volume_claims=cluster.volume_claims,
+        storage_classes=cluster.storage_classes,
+        capacity=snap._capacity)
+    mine = jax.tree_util.tree_flatten_with_path(state)[0]
+    ref = jax.tree_util.tree_leaves(fresh)
+    assert len(mine) == len(ref)
+    for (path, a), b in zip(mine, ref):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.shape == b.shape and a.dtype == b.dtype, path
+        assert np.array_equal(a, b, equal_nan=a.dtype.kind == "f"), (
+            jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("reason", sorted(_REFUSALS))
+def test_refusal_is_named_rebuilt_right_and_left_behind(reason):
+    """Each reason ``refresh`` refuses to patch for: a named mutation
+    of a small cluster reaches it, ``stats.fallbacks`` books it under
+    that name, what comes back is a fresh build of the cluster as it
+    now stands, and once the cause is gone a quiet cycle patches."""
+    kw, before, mutate, undo = _REFUSALS[reason]
+    cluster = build(**{"num_gangs": 8, **kw})
+    snap = IncrementalSnapshotter(verify=True, dirty_threshold=1.0)
+    if before is not None:
+        before(cluster)
+    refresh(snap, cluster)
+    if before is None:
+        # the cluster as built patches: the refusal is the mutation's
+        refresh(snap, cluster)
+        assert snap.stats.last["mode"] == "patched"
+    booked = dict(snap.stats.fallbacks)
+    if mutate is not None:
+        mutate(cluster)
+    state, _ = refresh(snap, cluster)
+    assert snap.stats.last["mode"] == "full"
+    assert snap.stats.last["fallback_reason"] == reason
+    assert snap.stats.fallbacks.get(reason, 0) == booked.get(reason, 0) + 1
+    _assert_fresh(state, snap, cluster)
+    if reason == "no-journal":
+        # nothing to patch from, ever: the same objects under a store
+        # that keeps a journal are a new document to the snapshotter
+        cluster = Cluster.from_objects(*cluster.snapshot_lists())
+    elif undo is not None:
+        undo(cluster)
+    refresh(snap, cluster)
+    state, _ = refresh(snap, cluster)
+    assert snap.stats.last["mode"] == "patched", snap.stats.last
+    _assert_fresh(state, snap, cluster)
+
+
+# ---------------------------------------------------------------------------
 # Scheduler integration (the verify_incremental flag end-to-end)
 # ---------------------------------------------------------------------------
 

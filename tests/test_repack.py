@@ -435,6 +435,39 @@ def test_repack_unblocks_rack_required_gang():
     assert status["last"]["migrations_executed"] == 4
 
 
+def test_repack_fires_with_real_ages_on_a_cadence_skipped_cycle():
+    """The frag streak completes at the end of an analytics cycle, so
+    with ``analytics_every > 1`` the trigger fires on the NEXT cycle,
+    which the cadence skips: no analytics pass has computed the pending
+    ages (``ages is None`` at the repack dispatch).  The solve must then
+    compute REAL ages itself — an all-zero vector fails
+    ``plan_repack``'s target gate and burns the cooldown on an
+    infeasible plan."""
+    from kai_scheduler_tpu.binder import Binder
+    from kai_scheduler_tpu.framework.scheduler import Scheduler
+    cluster = _frag_cluster()
+    sched, binder = Scheduler(_repack_cfg(analytics_every=2)), Binder()
+    fired = placed = None
+    for cyc in range(1, 12):
+        res = sched.run_once(cluster)
+        if res.repack and fired is None:
+            fired = cyc
+            # the scenario's point: the firing landed on a cycle with
+            # no analytics pass (nothing else had computed the ages)
+            assert res.analytics == {} and res.analytics_seconds == 0.0
+            assert res.repack["feasible"], res.repack
+            assert res.repack["target_gang"] == "big-gang"
+            assert res.repack["migrations_executed"] > 0
+        if sum(b.pod_name.startswith("big-")
+               for b in res.bind_requests) >= 8:
+            placed = cyc
+            break
+        binder.reconcile(cluster)
+        cluster.tick()
+    assert fired is not None, "repack never fired"
+    assert placed is not None and placed >= fired
+
+
 def test_minruntime_protected_fillers_are_not_movable():
     """The consolidation-mode victim protection applies to repack too:
     fillers inside their queue's preempt-minruntime window expose no

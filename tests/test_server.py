@@ -2,6 +2,7 @@
 ``plugins/snapshot`` HTTP endpoints and the snapshot-in/placements-out
 wire boundary (SURVEY.md §7d)."""
 import json
+import time
 import urllib.request
 
 from kai_scheduler_tpu.apis import types as apis
@@ -436,3 +437,76 @@ def test_served_gang_turnover_patches_over_deleted_groups():
     assert "job-0-3" not in placed and bound_by_commit
     assert {g["name"] for g in stored["pod_groups"]} == set(placed)
     assert len(stored["pod_groups"]) == 128
+
+
+def test_fixed_thresholds_keep_a_freed_array_mapped():
+    """What ``SchedulerServer.start`` sets: a large array freed and
+    allocated again comes from the heap that is already mapped, so the
+    second one faults in (almost) no page — the patched cycle's arrays,
+    every cycle on one level."""
+    import resource
+
+    import numpy as np
+    import pytest
+
+    from kai_scheduler_tpu.runtime import malloc_tune
+    if not malloc_tune.fix_thresholds():
+        pytest.skip("no glibc mallopt in this process")
+    assert malloc_tune.fix_thresholds()  # again: the same answer
+
+    def faults_of_one_array():
+        before = resource.getrusage(resource.RUSAGE_SELF).ru_minflt
+        np.ones((24 << 20,), np.uint8)  # under the 32 MiB threshold
+        return resource.getrusage(resource.RUSAGE_SELF).ru_minflt - before
+
+    faults_of_one_array()
+    pages = (24 << 20) // resource.getpagesize()
+    # (read here: 0 with the thresholds fixed, 480 of 6 144 without)
+    assert faults_of_one_array() < pages // 64
+
+
+def test_server_start_fixes_the_thresholds(monkeypatch):
+    from kai_scheduler_tpu.runtime import malloc_tune
+    calls = []
+    monkeypatch.setattr(malloc_tune, "fix_thresholds",
+                        lambda: calls.append(1) or True)
+    server = SchedulerServer(_cluster()).start()
+    server.stop()
+    assert calls == [1]
+
+
+def test_requests_run_on_threads_that_stay():
+    """A request gets no thread of its own: however many arrive, one
+    after another or at once, ``HANDLER_THREADS`` threads that live as
+    long as the server answer them all, and go when it stops."""
+    import threading
+    from concurrent.futures import ThreadPoolExecutor
+
+    from kai_scheduler_tpu.framework import server as server_mod
+
+    def handlers():
+        return {t.ident for t in threading.enumerate()
+                if t.name.startswith("kai-http")}
+
+    before = handlers()
+    server = SchedulerServer(_cluster()).start()
+    url = f"http://127.0.0.1:{server.port}/healthz"
+    try:
+        for _ in range(3 * server_mod.HANDLER_THREADS):
+            assert urllib.request.urlopen(url).status == 200
+        serial = handlers() - before
+        assert 1 <= len(serial) <= server_mod.HANDLER_THREADS
+        with ThreadPoolExecutor(4 * server_mod.HANDLER_THREADS) as clients:
+            statuses = list(clients.map(
+                lambda _: urllib.request.urlopen(url).status,
+                range(8 * server_mod.HANDLER_THREADS)))
+        assert statuses == [200] * (8 * server_mod.HANDLER_THREADS)
+        mine = handlers() - before
+        assert serial <= mine
+        assert len(mine) <= server_mod.HANDLER_THREADS
+    finally:
+        server.stop()
+    deadline = time.monotonic() + 5
+    while handlers() - before and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert not handlers() - before

@@ -382,8 +382,6 @@ def test_compile_watcher_covers_callgraph_jit_entries():
         "cluster_analytics": "analytics",
         # kai-repack defragmentation solver (ops/repack.py)
         "plan_repack": "repack",
-        # kai-resident fused cycle entry (framework/scheduler.py)
-        "resident_cycle": "resident_cycle",
         # analysis-only probe helper, never on the production cycle
         "cumsum_ds": None,
     }
