@@ -6,6 +6,7 @@ from .cluster_state import (  # noqa: F401
     RunningState,
     SnapshotCapacity,
     SnapshotIndex,
+    SnapshotVocabulary,
     build_snapshot,
 )
 from .incremental import (  # noqa: F401

@@ -374,6 +374,47 @@ class SnapshotCapacity:
     types: int = 0
 
 
+@dataclasses.dataclass(frozen=True)
+class SnapshotVocabulary:
+    """The irregular id spaces a snapshot was numbered with.
+
+    ``build_snapshot`` numbers selector keys, label values and node-
+    filter specs by first encounter.  Handed a vocabulary, it keeps the
+    ids already given and appends what it meets for the first time; the
+    one it ends with is ``SnapshotIndex.vocabulary``.  The incremental
+    snapshotter pins it beside :class:`SnapshotCapacity` so a patch can
+    encode a pod's selector row and filter class by lookup, and so the
+    label columns and ``filter_masks`` rows (compiled shapes) never
+    shrink when the last pod that used one leaves.  Numbering is free
+    to pin: label ids are only compared for equality, a class id only
+    gathers a row, and a spec row no pod carries is inert.
+    """
+
+    #: columns of ``nodes.labels`` / ``task_selector``
+    selector_keys: tuple = ()
+    #: (key, value) -> id, over the selector keys
+    label_vocab: dict = dataclasses.field(default_factory=dict)
+    #: rows of ``filter_masks`` / ``soft_scores``; row 0 the empty spec
+    filter_specs: tuple = (node_filters.EMPTY_SPEC,)
+    #: spec -> a stand-in pod holding the tolerations and affinity
+    #: expressions ``evaluate_filter_classes`` reads (detached from the
+    #: pod that brought the spec, which may change or leave)
+    spec_pods: dict = dataclasses.field(default_factory=dict)
+
+    def node_only(self) -> "SnapshotVocabulary":
+        """The part a later build may be handed: every selector key and
+        label value, and the specs whose masks read nothing but the
+        nodes (tolerations, node affinity, DRA and volume labels).  A
+        pod-affinity, host-port or reverse-anti spec is evaluated
+        against the running pods, so its row is not inert once its pod
+        has left; it is numbered again while a pod carries it."""
+        keep = tuple(s for s in self.filter_specs
+                     if not (s[2] or s[5] or s[6]))
+        return dataclasses.replace(
+            self, filter_specs=keep,
+            spec_pods={s: self.spec_pods[s] for s in keep})
+
+
 # ---------------------------------------------------------------------------
 # Per-section builders — factored out of build_snapshot so the
 # incremental snapshotter (state/incremental.py) re-derives sections
@@ -605,6 +646,10 @@ class SnapshotIndex:
     #: feasibility spans the whole node axis: no selectors, filter
     #: classes, anti-affinity, or topology constraints in the snapshot
     dense_feasibility: bool = False
+    #: the selector keys, label ids and filter specs of this snapshot
+    #: (``selector_keys`` and ``label_vocab`` above are its first two)
+    vocabulary: SnapshotVocabulary = dataclasses.field(
+        default_factory=SnapshotVocabulary)
 
     def node_index(self, name: str) -> int:
         return self.node_names.index(name)
@@ -645,6 +690,7 @@ def build_snapshot(
     volume_claims: dict[str, apis.PersistentVolumeClaim] | None = None,
     storage_classes: dict[str, apis.StorageClass] | None = None,
     capacity: SnapshotCapacity | None = None,
+    vocabulary: SnapshotVocabulary | None = None,
     _return_host: bool = False,
     tracer=None,
 ) -> tuple[ClusterState, SnapshotIndex]:
@@ -653,6 +699,10 @@ def build_snapshot(
     This is the TPU-native analogue of the reference's snapshot step
     (``cache/cluster_info/cluster_info.go:229`` snapshotNodes,
     ``:346`` snapshotPodGroups).
+
+    ``capacity`` and ``vocabulary`` are floors the incremental
+    snapshotter pins (padded axes; selector keys, label ids and filter
+    specs): without them the build sizes and numbers from what it holds.
 
     ``tracer`` (a ``runtime.tracing.CycleTracer``; ``None`` records
     nothing) gets the two halves as spans of the open cycle:
@@ -669,7 +719,8 @@ def build_snapshot(
                 resource_claims=resource_claims,
                 device_classes=device_classes,
                 volume_claims=volume_claims,
-                storage_classes=storage_classes, capacity=capacity)
+                storage_classes=storage_classes, capacity=capacity,
+                vocabulary=vocabulary)
         finally:
             sections.close()
     # through the kai-wire TransferLedger (the package's device_put
@@ -706,19 +757,22 @@ def _encode_snapshot(
     volume_claims: dict[str, apis.PersistentVolumeClaim] | None,
     storage_classes: dict[str, apis.StorageClass] | None,
     capacity: SnapshotCapacity | None,
+    vocabulary: SnapshotVocabulary | None,
 ) -> tuple[ClusterState, SnapshotIndex]:
     """The host half of :func:`build_snapshot`: the snapshot as numpy
     leaves and its index.  ``sections(name)`` marks where each group of
     sections starts (a span each under a tracer)."""
     cap = capacity or SnapshotCapacity()
-    # --- vocabularies -----------------------------------------------------
+    # --- vocabularies: ids a pinned vocabulary gave stay, new ones
+    # append (first encounter; from nothing when none is passed) ----------
     sections("encode.vocab")
-    selector_keys: list[str] = []
+    vocab = vocabulary or SnapshotVocabulary()
+    selector_keys: list[str] = list(vocab.selector_keys)
     for pod in pods:
         for k in pod.node_selector:
             if k not in selector_keys:
                 selector_keys.append(k)
-    label_vocab: dict[tuple[str, str], int] = {}
+    label_vocab: dict[tuple[str, str], int] = dict(vocab.label_vocab)
 
     def value_id(key: str, value: str) -> int:
         return label_vocab.setdefault((key, value), len(label_vocab))
@@ -901,10 +955,11 @@ def _encode_snapshot(
     sub_slot: list[dict[str, int]] = [{} for _ in range(G)]
     sub_running = np.zeros((G, S), np.int32)
     # --- node-filter classes: dedupe pod specs ---------------------------
-    filter_specs: list[tuple] = [node_filters.EMPTY_SPEC]
-    spec_index: dict[tuple, int] = {node_filters.EMPTY_SPEC: 0}
+    filter_specs: list[tuple] = list(vocab.filter_specs)
+    spec_index: dict[tuple, int] = {
+        spec: x for x, spec in enumerate(filter_specs)}
     spec_pods: dict[tuple, apis.Pod] = {
-        node_filters.EMPTY_SPEC: apis.Pod("", "")}
+        node_filters.EMPTY_SPEC: apis.Pod("", ""), **vocab.spec_pods}
 
     #: consumers admitted this snapshot per claim name — dra_of runs
     #: once per pending pod in intake order, so the counter mirrors the
@@ -1008,7 +1063,9 @@ def _encode_snapshot(
         if key not in spec_index:
             spec_index[key] = len(filter_specs)
             filter_specs.append(key)
-            spec_pods[key] = pod
+            spec_pods[key] = apis.Pod(
+                "", "", tolerations=list(pod.tolerations),
+                node_affinity=list(pod.node_affinity))
         return spec_index[key]
 
     node_idx0 = {name: i for i, name in enumerate(node_names)}
@@ -1839,5 +1896,8 @@ def _encode_snapshot(
             and bool(np.asarray(filter_masks)[0][node_valid].all())
             and bool((gk["anti_self_level"] < 0).all())
             and bool((gk["subgroup_required_level"] < 0).all())),
+        vocabulary=SnapshotVocabulary(
+            selector_keys=tuple(selector_keys), label_vocab=label_vocab,
+            filter_specs=tuple(filter_specs), spec_pods=spec_pods),
     )
     return state, index

@@ -40,12 +40,26 @@ Three pieces:
     group with its owner, so every completion and eviction does
     this); only a name deleted and created again inside one window
     escalates, since it moved to the end of the store;
-  * vocabulary growth — selector keys, extended (MIG) keys, or filter
-    classes beyond the empty spec would renumber dense id spaces;
-  * feature pods — fractional/memory-share requests, DRA claims,
-    volumes, host ports, pod affinity, tolerations, node affinity,
-    nominated nodes, declared subgroups (the irregular intake paths
-    stay on the proven full builder);
+  * vocabulary growth (``vocab-growth``) — a node selector and
+    tolerations ride the patch: the snapshotter pins the selector
+    keys, label ids and node-only filter specs of the last rebuild
+    (:class:`~.cluster_state.SnapshotVocabulary`, beside the capacity)
+    and a pod's selector row and filter class are lookups in it.  A
+    selector key, a pending pod's label value or a filter spec it does
+    not hold would be numbered by a fresh build, so that one cycle
+    rebuilds, pins the larger vocabulary, and the next patches.  Like
+    the capacity it only grows: label columns and ``filter_masks`` rows
+    are compiled shapes and ``dense_feasibility`` a static argument of
+    the solve, so they stay when the last pod that used them leaves;
+  * vocabulary residue (``vocab-residue``) — what the patch still
+    cannot carry after its pods or nodes are gone: extended (MIG) keys,
+    or a spec numbered by the last build but not pinned;
+  * feature pods (``nonplain-pods``) — fractional/memory-share
+    requests, DRA claims, volumes, host ports, pod affinity, node
+    affinity, nominated nodes, extended resources, declared subgroups
+    (the irregular intake paths stay on the proven full builder; a
+    pod-affinity or host-port spec is evaluated against the running
+    pods, so it is never pinned);
   * dirty fraction above ``dirty_threshold`` — patching stops paying
     once most of the cluster changed;
   * ledger drift — an object mutated without a journal mark (the
@@ -54,7 +68,8 @@ Three pieces:
     snapshot).
 
 ``verify=True`` (the scheduler's ``verify_incremental`` flag) rebuilds
-from scratch after every patch and asserts the patched ``ClusterState``
+from scratch — the same builder, under the pinned capacity and
+vocabulary — after every patch and asserts the patched ``ClusterState``
 is element-wise identical — including ``SnapshotIndex`` name maps.
 """
 from __future__ import annotations
@@ -72,8 +87,10 @@ from ..apis import types as apis
 from ..runtime import wire_ledger as _wire
 from ..runtime.tracing import span_of
 from . import cluster_state as _cs
+from . import node_filters
 from .cluster_state import (
     SnapshotCapacity,
+    SnapshotVocabulary,
     _LEADER_ROLES,
     _round_up,
     build_queue_tables,
@@ -321,11 +338,13 @@ def _slack(n: int) -> int:
 
 
 def _is_plain_pod(pod: apis.Pod) -> bool:
-    """Pods the patch path can encode row-wise.  Everything else rides
-    the irregular intake paths of the full builder (filter classes,
-    vocab growth, device-share bookkeeping) and forces a fallback."""
+    """Pods the patch path can encode row-wise: a node selector and
+    tolerations are looked up in the pinned vocabulary
+    (``_encode_pod``).  Everything else rides the irregular intake
+    paths of the full builder (affinity terms, device-share
+    bookkeeping, claims) and forces a fallback."""
     return not (
-        pod.node_selector or pod.tolerations or pod.node_affinity
+        pod.node_affinity
         or pod.pod_affinity or pod.extended or pod.resource_claims
         or pod.volume_claims or pod.host_ports
         or pod.nominated_node is not None or pod.subgroup
@@ -374,13 +393,13 @@ _FULL_STATS = {
     "dirty_pods": 0, "dirty_gangs": 0,
     "pods_removed": 0, "gangs_removed": 0,
     "leaves_shipped": 0, "bytes_shipped": 0,
-    "ship_seconds": 0.0, "ship_dispatches": 0,
+    "ship_seconds": 0.0, "ship_dispatches": 0, "filtered_pods": 0,
 }
 
-#: what a patched cycle's cluster holds of the irregular vocabularies:
-#: nothing, or ``_patch_blockers`` would have rebuilt.  A rebuilt cycle
-#: reports what its build found (``_full``)
-_PLAIN_VOCAB = {"nonplain_pods": 0, "filter_classes": 1, "selector_keys": 0}
+#: in the intern tables, a label value or a filter spec the pinned
+#: vocabulary does not hold (``_assemble`` refuses with ``vocab-growth``
+#: where a fresh build would number it)
+_UNKNOWN = -2
 
 
 @dataclasses.dataclass
@@ -429,6 +448,10 @@ class IncrementalSnapshotter:
         self._dev = None         # device ClusterState (previous cycle)
         self._index = None
         self._capacity = SnapshotCapacity()
+        #: selector keys, label ids and node-only filter specs of the
+        #: last rebuild, pinned like the capacity: they only grow (a
+        #: spec that reads the running pods is never among them)
+        self._vocabulary = SnapshotVocabulary()
         #: the last rebuild's irregular vocabularies (``stats.last``)
         self._built_vocab: dict = {}
 
@@ -478,7 +501,11 @@ class IncrementalSnapshotter:
                     "gangs_removed": self._last_removed[1],
                     "leaves_shipped": ship[0], "bytes_shipped": ship[1],
                     "ship_seconds": ship[2], "ship_dispatches": ship[3],
-                    **_PLAIN_VOCAB,
+                    # what the patch carried of the pinned vocabulary
+                    "nonplain_pods": 0,
+                    "filter_classes": len(self._vocabulary.filter_specs),
+                    "selector_keys": len(self._vocabulary.selector_keys),
+                    "filtered_pods": self._last_filtered,
                 }
                 patch_sp.attrs.update(self.stats.last)
                 if self.verify:
@@ -576,7 +603,8 @@ class IncrementalSnapshotter:
                 device_classes=cluster.device_classes,
                 volume_claims=cluster.volume_claims,
                 storage_classes=cluster.storage_classes,
-                capacity=cap, _return_host=True, tracer=self._tracer)
+                capacity=cap, vocabulary=self._vocabulary,
+                _return_host=True, tracer=self._tracer)
         self._built_vocab = {
             "filter_classes": int(host.nodes.filter_masks.shape[0]),
             "selector_keys": len(index.selector_keys)}
@@ -597,6 +625,7 @@ class IncrementalSnapshotter:
             tasks=host.gangs.task_valid.shape[1],
             running=host.running.valid.shape[0],
             types=host.gangs.type_req.shape[0])
+        self._vocabulary = index.vocabulary.node_only()
         self._host, self._dev, self._index = host, state, index
         with self._span("snapshot.ledgers"):
             self._rebuild_ledgers(cluster, lists, host, index)
@@ -614,14 +643,15 @@ class IncrementalSnapshotter:
         self._node_cache = [
             (n, n.allocatable, n.labels, n.taints, n.extended,
              n.accel_memory_gib) for n in live_nodes]
-        # the patch path only reproduces builds whose dense id spaces
-        # are trivial — any residual vocabulary (from since-departed
-        # feature pods) keeps forcing full rebuilds until one comes out
-        # clean
+        # the patch path looks selectors and filter classes up in the
+        # pinned vocabulary; what it cannot carry keeps forcing full
+        # rebuilds until a build comes out without it: extended (MIG)
+        # keys, and a spec numbered in this build but not pinned (its
+        # mask reads the running pods)
+        vocab = self._vocabulary
         self._clean = (
-            not index.selector_keys and not index.label_vocab
-            and not index.extended_keys
-            and np.asarray(host.nodes.filter_masks).shape[0] == 1)
+            not index.extended_keys
+            and vocab.filter_specs == index.vocabulary.filter_specs)
         self._accel_counts = np.fromiter(
             (int(round(n.allocatable.accel)) for n in live_nodes),
             np.int64, len(live_nodes))
@@ -667,8 +697,16 @@ class IncrementalSnapshotter:
         self.p_sweep: list = [None] * U
         for name, dtype, fill, tail in _POD_COLUMNS:
             setattr(self, name, np.full((U,) + tail, fill, dtype))
+        #: distinct (request, selector items, filter class) of the
+        #: ledger's pods, as the builder's ``_tkey`` tells task types
+        #: apart, with each one's encoded rows
         self._intern: dict[tuple, int] = {}
         self._intern_req = np.zeros((0, R), np.float32)
+        self._intern_sel = np.zeros(
+            (0, max(1, len(vocab.selector_keys))), np.int32)
+        self._intern_cls = np.zeros((0,), np.int32)
+        self._intern_newkey = np.zeros((0,), bool)
+        self._class_of: dict[tuple, int] = {}
         self._nonplain = 0
         self._present_twice = 0
         # NOTE: ledger rows follow the RAW pod-dict order — the lists
@@ -695,10 +733,8 @@ class IncrementalSnapshotter:
         # constant gang-side tables reused by identity between refreshes
         g = host.gangs
         self._const = dict(
-            task_selector=np.asarray(g.task_selector),
             task_portion=np.asarray(g.task_portion),
             task_accel_mem=np.asarray(g.task_accel_mem),
-            task_filter_class=np.asarray(g.task_filter_class),
             task_nominated=np.asarray(g.task_nominated),
             anti_self_level=np.asarray(g.anti_self_level),
             anti_marks=np.asarray(g.anti_marks),
@@ -710,11 +746,6 @@ class IncrementalSnapshotter:
             task_extended=np.asarray(g.task_extended),
             task_dra=np.asarray(g.task_dra),
             ext_accel=np.asarray(g.ext_accel),
-            type_selector=np.asarray(g.type_selector),
-            type_portion=np.asarray(g.type_portion),
-            type_mem=np.asarray(g.type_mem),
-            type_class=np.asarray(g.type_class),
-            type_extended=np.asarray(g.type_extended),
         )
 
     def _seed_task_slots(self, host) -> None:
@@ -805,15 +836,46 @@ class IncrementalSnapshotter:
         self.p_eff_status[row] = -2 if twice else st
         self.p_eff_node[row] = (self._node_index.get(nd, -1)
                                 if nd is not None else -1)
-        key = tuple(float(x) for x in pod.resources.as_tuple())
+        # the class the builder's filter_class_of gives a pod the patch
+        # carries: its spec holds tolerations and nothing else
+        cls = 0
+        if plain and pod.tolerations:
+            tol = tuple(pod.tolerations)
+            cls = self._class_of.get(tol)
+            if cls is None:
+                spec = node_filters.pod_filter_spec(pod)
+                specs = self._vocabulary.filter_specs
+                cls = self._class_of[tol] = (
+                    specs.index(spec) if spec in specs else _UNKNOWN)
+        key = (tuple(float(x) for x in pod.resources.as_tuple()),
+               tuple(sorted(pod.node_selector.items()))
+               if pod.node_selector else (), cls)
         iid = self._intern.get(key)
         if iid is None:
-            iid = len(self._intern)
-            self._intern[key] = iid
-            self._intern_req = np.concatenate(
-                [self._intern_req,
-                 np.asarray([key], np.float32)], axis=0)
+            iid = self._intern_add(key)
         self.p_iid[row] = iid
+
+    def _intern_add(self, key: tuple) -> int:
+        """A new row of the intern tables: the request, the selector
+        row and the class, by lookup in the pinned vocabulary."""
+        req, sel_items, cls = key
+        iid = len(self._intern)
+        self._intern[key] = iid
+        sel = np.full((1, self._intern_sel.shape[1]), -1, np.int32)
+        vocab = self._vocabulary
+        newkey = False
+        for k, v in sel_items:
+            if k in vocab.selector_keys:
+                sel[0, vocab.selector_keys.index(k)] = (
+                    vocab.label_vocab.get((k, v), _UNKNOWN))
+            else:
+                newkey = True
+        self._intern_req = np.concatenate(
+            [self._intern_req, np.asarray([req], np.float32)])
+        self._intern_sel = np.concatenate([self._intern_sel, sel])
+        self._intern_cls = np.append(self._intern_cls, np.int32(cls))
+        self._intern_newkey = np.append(self._intern_newkey, newkey)
+        return iid
 
     def _release_pod(self, row) -> None:
         if not self.p_live[row]:
@@ -1154,6 +1216,16 @@ class IncrementalSnapshotter:
         order = self._order
         eff = self.p_eff_status[order]
         grp_all = self.p_group[order]
+        # --- the pinned vocabulary: a fresh build would number what it
+        # lacks (a selector key of any pod, a label value of a pending
+        # one, a spec of a pending or running one), so one rebuild pins
+        # the larger vocabulary ---------------------------------------
+        iid_all = self.p_iid[order]
+        if self._intern_newkey[iid_all].any():
+            raise _Fallback("vocab-growth")
+        filtered = ((self._intern_cls != 0)
+                    | (self._intern_sel != -1).any(axis=1))
+        self._last_filtered = int(filtered[iid_all].sum())
         # --- queues (always re-encoded; tiny) ----------------------------
         queues = list(cluster.queues.values())
         qt = build_queue_tables(queues, Q)
@@ -1168,6 +1240,9 @@ class IncrementalSnapshotter:
             raise _Fallback("overflow-tasks")
         # fresh first-encounter type ids from the stable intern ids
         iid_seq = self.p_iid[intake]
+        if ((self._intern_sel[iid_seq] == _UNKNOWN).any()
+                or (self._intern_cls[iid_seq] == _UNKNOWN).any()):
+            raise _Fallback("vocab-growth")
         if len(iid_seq):
             uniq, first, inv = np.unique(
                 iid_seq, return_index=True, return_inverse=True)
@@ -1228,14 +1303,25 @@ class IncrementalSnapshotter:
                 tnames[g_of, ti] = self.p_names[rows_s]
         self._task_names_obj = tnames
         # task_type renumbers globally (dense first-encounter ids)
+        # and with it the selector row and the filter class of a task
+        K = self._intern_sel.shape[1]
         task_type = np.zeros((G, T), np.int32)
+        task_selector = np.full((G, T, K), -1, np.int32)
+        task_class = np.zeros((G, T), np.int32)
         if len(intake):
-            task_type[self.p_group[intake], self.p_ti[intake]] = tid_seq
+            slot = self.p_group[intake], self.p_ti[intake]
+            task_type[slot] = tid_seq
+            task_selector[slot] = self._intern_sel[iid_seq]
+            task_class[slot] = self._intern_cls[iid_seq]
         task_type = self._swap_if_equal(task_type, task_type_old)
         # --- type table ---------------------------------------------------
         type_req = np.zeros((Y, R), np.float32)
+        type_selector = np.full((Y, K), -1, np.int32)
+        type_class = np.zeros((Y,), np.int32)
         if Yn:
             type_req[:Yn] = self._intern_req[reps]
+            type_selector[:Yn] = self._intern_sel[reps]
+            type_class[:Yn] = self._intern_cls[reps]
         type_req = self._swap_if_equal(type_req, np.asarray(og.type_req))
         # --- gang scalar tables (vectorized over the ledger) -------------
         gk_valid = np.zeros((G,), bool)
@@ -1281,6 +1367,9 @@ class IncrementalSnapshotter:
         r_grp = self.p_group[run_rows]
         r_rel = self.p_eff_status[run_rows] == _RELEASING
         r_req = self.p_req[run_rows].copy()
+        r_cls = self._intern_cls[self.p_iid[run_rows]]
+        if (r_cls == _UNKNOWN).any():
+            raise _Fallback("vocab-growth")
         rk = dict(
             req=np.zeros((M, R), np.float32),
             node=np.full((M,), -1, np.int32),
@@ -1307,6 +1396,7 @@ class IncrementalSnapshotter:
             rk["gang"][:Mu] = r_grp
             rk["valid"][:Mu] = True
             rk["releasing"][:Mu] = r_rel
+            rk["filter_class"][:Mu] = r_cls
             has_grp = r_grp >= 0
             gsafe = np.maximum(r_grp, 0)
             if NG:
@@ -1370,17 +1460,14 @@ class IncrementalSnapshotter:
             and bool((self._const["anti_self_level"] == -1).all())
             and bool((np.where(tvm, task_req, task_req[:, :1])
                       == task_req[:, :1]).all())
-            and bool((np.where(
-                tvm, self._const["task_selector"],
-                self._const["task_selector"][:, :1])
-                == self._const["task_selector"][:, :1]).all())
-            and bool((np.where(
-                task_valid, self._const["task_filter_class"],
-                self._const["task_filter_class"][:, :1])
-                == self._const["task_filter_class"][:, :1]).all()))
+            and bool((np.where(tvm, task_selector, task_selector[:, :1])
+                      == task_selector[:, :1]).all())
+            and bool((np.where(task_valid, task_class, task_class[:, :1])
+                      == task_class[:, :1]).all()))
         node_valid = np.asarray(old.nodes.valid)
+        vocab = self._vocabulary
         dense = (
-            len(self._node_names) >= 0
+            not vocab.selector_keys and len(vocab.filter_specs) == 1
             and bool(np.asarray(old.nodes.filter_masks)[0][
                 node_valid].all())
             and bool((self._const["anti_self_level"] < 0).all())
@@ -1405,8 +1492,13 @@ class IncrementalSnapshotter:
             min_needed=sw(min_needed, np.asarray(og.min_needed)),
             stale_s=sw(stale_s, np.asarray(og.stale_s)),
             task_type=sw(task_type, task_type_old),
+            task_selector=sw(task_selector, np.asarray(og.task_selector)),
+            task_filter_class=sw(task_class,
+                                 np.asarray(og.task_filter_class)),
             sig=sw(sig, np.asarray(og.sig)),
             type_req=type_req,
+            type_selector=sw(type_selector, np.asarray(og.type_selector)),
+            type_class=sw(type_class, np.asarray(og.type_class)),
             subgroup_valid=sw(sub_valid, np.asarray(og.subgroup_valid)),
             subgroup_min_member=sw(sub_minm,
                                    np.asarray(og.subgroup_min_member)),
@@ -1470,8 +1562,8 @@ class IncrementalSnapshotter:
             gang_names=list(self.g_names),
             task_names=self._task_names_obj.tolist(),
             running_pod_names=running_names,
-            selector_keys=[],
-            label_vocab={},
+            selector_keys=list(vocab.selector_keys),
+            label_vocab=vocab.label_vocab,
             topology_levels=self._topo_levels,
             needs_device_table=has_fracs,
             uniform_gangs=uniform,
@@ -1506,6 +1598,7 @@ class IncrementalSnapshotter:
                 "gang_valid": np.asarray(gangs.valid),
             },
             dense_feasibility=dense,
+            vocabulary=vocab,
         )
         # pre-seed the columnar name views (cached_property slots)
         index.task_names_arr = self._task_names_obj
@@ -1725,7 +1818,8 @@ class IncrementalSnapshotter:
                 device_classes=cluster.device_classes,
                 volume_claims=cluster.volume_claims,
                 storage_classes=cluster.storage_classes,
-                capacity=self._capacity, _return_host=True)
+                capacity=self._capacity, vocabulary=self._vocabulary,
+                _return_host=True)
         paths_new = jax.tree_util.tree_flatten_with_path(self._host)[0]
         paths_ref = jax.tree_util.tree_flatten_with_path(fresh_host)[0]
         for (path, mine), (_, ref) in zip(paths_new, paths_ref):
@@ -1751,7 +1845,7 @@ class IncrementalSnapshotter:
                       "max_queue_depth", "num_leaf_queues",
                       "num_pending_gangs",
                       "num_anti_groups", "claims_by_pod",
-                      "dense_feasibility"):
+                      "dense_feasibility", "vocabulary"):
             if getattr(mine_i, field) != getattr(ref_i, field):
                 raise IncrementalVerifyError(
                     f"index.{field}: {getattr(mine_i, field)!r} != "

@@ -575,6 +575,271 @@ class TestFallbacks:
 
 
 # ---------------------------------------------------------------------------
+# Tolerations and node selectors ride the patch (ISSUE 31)
+# ---------------------------------------------------------------------------
+
+TAINT = apis.Taint("nvidia.com/gpu", "present", "NoSchedule")
+TOLERATES = apis.Toleration("nvidia.com/gpu", "Exists", effect="NoSchedule")
+#: what a pod of each kind carries beside its request
+FILTERED = {
+    "tolerating": {"tolerations": [TOLERATES]},
+    "selecting": {"node_selector": {"gpu.type": "volta"}},
+    "both": {"tolerations": [TOLERATES],
+             "node_selector": {"gpu.type": "pascal"}},
+}
+
+
+def pool(**kw) -> Cluster:
+    """The small cluster as an accelerator pool: nodes 0-3 tainted
+    ``volta``, 4-5 tainted ``pascal``, 6-7 as built."""
+    cluster = build(num_nodes=8, num_gangs=6, tasks_per_gang=2, **kw)
+    for i, gpu in enumerate(["volta"] * 4 + ["pascal"] * 2):
+        node = cluster.nodes[f"node-{i}"]
+        node.labels = {**node.labels, "gpu.type": gpu}
+        node.taints = [TAINT]
+    return cluster
+
+
+def submit_filtered(cluster, name, kind, tasks=2) -> list[str]:
+    cluster.submit(
+        apis.PodGroup(name, queue="queue-0-0", min_member=tasks),
+        [apis.Pod(f"{name}-p{t}", name, apis.ResourceVec(1, 1, 4),
+                  **{k: type(v)(v) for k, v in FILTERED[kind].items()})
+         for t in range(tasks)])
+    return [f"{name}-p{t}" for t in range(tasks)]
+
+
+def warm_pool(**kw):
+    """A pool whose cold build has met every kind of ``FILTERED``: two
+    specs, one selector key, two label values."""
+    cluster = pool(**kw)
+    for kind in FILTERED:
+        submit_filtered(cluster, f"seed-{kind}", kind)
+    snap = IncrementalSnapshotter(verify=True, dirty_threshold=1.0)
+    refresh(snap, cluster)
+    return cluster, snap
+
+
+def patched_and_fresh(snap, cluster):
+    state, index = refresh(snap, cluster)
+    assert snap.stats.last["mode"] == "patched", snap.stats.last
+    _assert_fresh(state, snap, cluster)
+    return state, index
+
+
+class TestFilteredPodsPatch:
+    """A toleration and a node selector are per-pod constants once the
+    filter-class and label numbering survives from cycle to cycle:
+    ``verify=True`` and ``_assert_fresh`` hold every patched state to a
+    fresh build under the pinned vocabulary."""
+
+    @pytest.mark.parametrize("kind", sorted(FILTERED))
+    def test_a_filtered_gang_lives_and_dies_in_patched_cycles(self, kind):
+        cluster, snap = warm_pool(running_fraction=0.5)
+        vocab0 = snap._vocabulary
+        # arrives
+        pods = submit_filtered(cluster, "job", kind)
+        state, index = patched_and_fresh(snap, cluster)
+        gi = index.gang_names.index("job")
+        want = FILTERED[kind]
+        cls = np.asarray(state.gangs.task_filter_class)[gi, :2]
+        sel = np.asarray(state.gangs.task_selector)[gi, :2, 0]
+        assert (cls == (1 if "tolerations" in want else 0)).all()
+        gpu = want.get("node_selector", {}).get("gpu.type")
+        assert (sel == (index.label_vocab[("gpu.type", gpu)]
+                        if gpu else -1)).all()
+        assert snap.stats.last["filter_classes"] == 2
+        assert snap.stats.last["selector_keys"] == 1
+        assert snap.stats.last["nonplain_pods"] == 0
+        # the seeds and this gang, two pods each
+        assert snap.stats.last["filtered_pods"] == 8
+        # binds (a bind request presents it bound, then the pod is)
+        node = "node-5" if gpu == "pascal" else "node-0"
+        for name in pods:
+            cluster.bind_pod(name, node)
+        state, index = patched_and_fresh(snap, cluster)
+        rows = [index.running_pod_names.index(n) for n in pods]
+        assert (np.asarray(state.running.filter_class)[rows]
+                == (1 if "tolerations" in want else 0)).all()
+        # one pod is evicted and reaped, the gang is short again
+        cluster.evict_pod(pods[0])
+        patched_and_fresh(snap, cluster)
+        cluster.tick()
+        patched_and_fresh(snap, cluster)
+        # the gang finishes: the group goes with its pods
+        delete_groups(cluster, ["job"])
+        patched_and_fresh(snap, cluster)
+        assert snap.stats.last["filtered_pods"] == 6
+        assert snap.stats.fallbacks == {"cold": 1}
+        assert snap._vocabulary == vocab0
+
+    @pytest.mark.parametrize("grows,pod", [
+        ("spec", {"tolerations": [apis.Toleration(
+            "dedicated", "Equal", "ml", "NoSchedule")]}),
+        ("key", {"node_selector": {"zone": "a"}}),
+        # a value no node carries: the pod can go nowhere, and the
+        # build still numbers it
+        ("value", {"node_selector": {"gpu.type": "hopper"}}),
+    ])
+    def test_growth_rebuilds_once_then_patches(self, grows, pod):
+        cluster, snap = warm_pool()
+        before = snap._vocabulary
+        cluster.submit(
+            apis.PodGroup("new", queue="queue-0-0", min_member=1),
+            [apis.Pod("new-p", "new", apis.ResourceVec(1, 1, 4), **pod)])
+        state, _ = refresh(snap, cluster)
+        assert snap.stats.last["mode"] == "full"
+        assert snap.stats.last["fallback_reason"] == "vocab-growth"
+        _assert_fresh(state, snap, cluster)
+        after = snap._vocabulary
+        grew = {"spec": len(after.filter_specs) - len(before.filter_specs),
+                "key": len(after.selector_keys) - len(before.selector_keys),
+                "value": len(after.label_vocab) - len(before.label_vocab)}
+        assert grew[grows] == 1, grew
+        # ids already given stay
+        assert after.filter_specs[:2] == before.filter_specs
+        assert after.selector_keys[:1] == before.selector_keys
+        assert before.label_vocab.items() <= after.label_vocab.items()
+        # the cycle after patches, and so does a second pod like it
+        patched_and_fresh(snap, cluster)
+        cluster.submit(
+            apis.PodGroup("next", queue="queue-0-0", min_member=1),
+            [apis.Pod("next-p", "next", apis.ResourceVec(1, 1, 4), **pod)])
+        patched_and_fresh(snap, cluster)
+        assert snap.stats.fallbacks == {"cold": 1, "vocab-growth": 1}
+
+    def test_the_last_selecting_pod_leaving_changes_no_shape(self):
+        """``K`` and ``X`` are compiled shapes and ``dense_feasibility``
+        a static argument of the solve: the vocabulary only grows."""
+        cluster, snap = warm_pool()
+        state0, index0 = refresh(snap, cluster)
+        delete_groups(cluster, [f"seed-{kind}" for kind in FILTERED])
+        state, index = patched_and_fresh(snap, cluster)
+        assert snap.stats.last["filtered_pods"] == 0
+        assert snap.stats.last["filter_classes"] == 2
+        assert snap.stats.last["selector_keys"] == 1
+        # ... and a rebuild keeps them too
+        cluster.journal.mark_structural("test")
+        rebuilt, rebuilt_index = refresh(snap, cluster)
+        assert snap.stats.last["mode"] == "full"
+        for st, ix in ((state, index), (rebuilt, rebuilt_index)):
+            assert ix.selector_keys == index0.selector_keys == ["gpu.type"]
+            assert ix.label_vocab == index0.label_vocab
+            assert ix.dense_feasibility is index0.dense_feasibility is False
+            assert ([leaf.shape for leaf in jax.tree.leaves(st)]
+                    == [leaf.shape for leaf in jax.tree.leaves(state0)])
+        patched_and_fresh(snap, cluster)
+
+    def test_a_spec_that_reads_running_pods_is_not_pinned(self):
+        """A host port's mask changes with the running pods, so its row
+        is not inert once its pod has left: the pod stays a blocker, and
+        the vocabulary forgets its spec with it."""
+        cluster, snap = warm_pool()
+        cluster.submit(
+            apis.PodGroup("web", queue="queue-0-0", min_member=1),
+            [apis.Pod("web-p", "web", apis.ResourceVec(1, 1, 4),
+                      host_ports=[8080], tolerations=[TOLERATES])])
+        for _ in range(2):
+            refresh(snap, cluster)
+            assert snap.stats.last["fallback_reason"] == "nonplain-pods"
+            assert snap.stats.last["filter_classes"] == 3
+            assert snap.stats.last["nonplain_pods"] == 1
+            assert len(snap._vocabulary.filter_specs) == 2
+        delete_groups(cluster, ["web"])
+        refresh(snap, cluster)  # the ledger still held the pod
+        patched_and_fresh(snap, cluster)
+        assert snap.stats.last["filter_classes"] == 2
+
+    def test_a_running_pod_selecting_an_unknown_value_does_not_rebuild(
+            self):
+        """The builder numbers label values of nodes and of pending
+        pods only.  A running pod whose value no node carries (its node
+        was relabelled) has none, before and after any rebuild, so it
+        must not be refused as growth cycle after cycle."""
+        cluster = pool(running_fraction=0.5)
+        ghost = next(p for p in cluster.pods.values()
+                     if p.status == apis.PodStatus.RUNNING)
+        ghost.node_selector = {"gpu.type": "ghost"}
+        submit_filtered(cluster, "seed", "selecting")
+        snap = IncrementalSnapshotter(verify=True, dirty_threshold=1.0)
+        _, index = refresh(snap, cluster)
+        assert ("gpu.type", "ghost") not in index.label_vocab
+        patched_and_fresh(snap, cluster)
+        # restarted and pending again, its value is one a build numbers
+        cluster.evict_pod(ghost.name, restart=True)
+        patched_and_fresh(snap, cluster)
+        cluster.tick()
+        _, index = refresh(snap, cluster)
+        assert ghost.status == apis.PodStatus.PENDING
+        assert snap.stats.last["fallback_reason"] == "vocab-growth"
+        assert ("gpu.type", "ghost") in index.label_vocab
+        patched_and_fresh(snap, cluster)
+
+
+class TestVocabularyNumbering:
+    def lists(self):
+        cluster = pool()
+        for kind in ("selecting", "both", "tolerating"):
+            submit_filtered(cluster, f"job-{kind}", kind)
+        return cluster.snapshot_lists()
+
+    def test_without_a_vocabulary_numbers_by_first_encounter(self):
+        """What every caller but the snapshotter gets, as before: keys
+        in pod order, values over nodes then pending types, specs over
+        pending tasks; the empty spec is row 0."""
+        from kai_scheduler_tpu.state.cluster_state import (
+            SnapshotVocabulary, build_snapshot)
+        from kai_scheduler_tpu.state.node_filters import EMPTY_SPEC
+        state, index = build_snapshot(*self.lists())
+        assert index.selector_keys == ["gpu.type"]
+        assert index.label_vocab == {("gpu.type", "volta"): 0,
+                                     ("gpu.type", "pascal"): 1}
+        assert np.asarray(state.nodes.filter_masks).shape[0] == 2
+        assert index.vocabulary.filter_specs[0] == EMPTY_SPEC
+        assert index.vocabulary.selector_keys == ("gpu.type",)
+        assert index.vocabulary.label_vocab == index.label_vocab
+        again, index2 = build_snapshot(
+            *self.lists(), vocabulary=SnapshotVocabulary())
+        assert index2.vocabulary == index.vocabulary
+        for a, b in zip(jax.tree.leaves(state), jax.tree.leaves(again)):
+            assert np.array_equal(np.asarray(a), np.asarray(b),
+                                  equal_nan=True)
+
+    def test_a_pinned_vocabulary_keeps_its_ids_and_appends(self):
+        from kai_scheduler_tpu.state.cluster_state import (
+            SnapshotVocabulary, build_snapshot)
+        from kai_scheduler_tpu.state.node_filters import (
+            EMPTY_SPEC, pod_filter_spec)
+        other = apis.Pod("", "", tolerations=[apis.Toleration(
+            "dedicated", "Exists")])
+        pinned = SnapshotVocabulary(
+            selector_keys=("zone",),
+            label_vocab={("zone", "a"): 0, ("gpu.type", "pascal"): 1},
+            filter_specs=(EMPTY_SPEC, pod_filter_spec(other)),
+            spec_pods={pod_filter_spec(other): other})
+        state, index = build_snapshot(*self.lists(), vocabulary=pinned)
+        assert index.selector_keys == ["zone", "gpu.type"]
+        assert index.label_vocab == {
+            ("zone", "a"): 0, ("gpu.type", "pascal"): 1,
+            ("gpu.type", "volta"): 2}
+        assert index.vocabulary.filter_specs[:2] == pinned.filter_specs
+        masks = np.asarray(state.nodes.filter_masks)
+        assert masks.shape[0] == 3
+        # row 1, which no pod carries, tolerates nothing of this pool;
+        # row 2 is the pool's own toleration
+        assert not masks[1, :6].any() and masks[1, 6:8].all()
+        assert masks[2, :8].all()
+        labels = np.asarray(state.nodes.labels)
+        assert (labels[:8, 0] == -1).all()
+        assert labels[:6, 1].tolist() == [2, 2, 2, 2, 1, 1]
+        assert not index.dense_feasibility
+        gi = index.gang_names.index("job-both")
+        assert np.asarray(state.gangs.task_filter_class)[gi, 0] == 2
+        assert np.asarray(state.gangs.task_selector)[gi, 0].tolist() == [
+            -1, 1]
+
+
+# ---------------------------------------------------------------------------
 # Every named refusal: reached, booked, rebuilt right, and left behind
 # ---------------------------------------------------------------------------
 
@@ -716,6 +981,15 @@ def _drop_group_unseen(c):
     del c.pod_groups[next(reversed(c.pod_groups))]
 
 
+def _submit_tolerating(c):
+    # a toleration no pod of the cluster has carried: a filter spec the
+    # pinned vocabulary lacks
+    c.submit(apis.PodGroup("tol", queue="queue-0-0", min_member=1),
+             [apis.Pod("tol-p", "tol", apis.ResourceVec(1, 1, 4),
+                       tolerations=[apis.Toleration(
+                           "dedicated", "Exists", effect="NoSchedule")])])
+
+
 def _drop_node_unseen(c):
     del c.nodes["node-7"]
 
@@ -726,6 +1000,7 @@ def _drop_node_unseen(c):
 #: same condition ``topology-changed`` before the sweep can see it.
 _REFUSALS = {
     "vocab-residue": ({}, _node_with_mig, None, _node_without_mig),
+    "vocab-growth": ({}, None, _submit_tolerating, None),
     "inflight-move": ({"running_fraction": 0.5}, None, _start_move,
                       lambda c: c.tick()),
     "nonplain-gangs": ({}, None, _submit_subgrouped,
@@ -758,7 +1033,7 @@ _REFUSALS = {
 
 def _assert_fresh(state, snap, cluster):
     """``state`` equals a fresh ``build_snapshot`` of the cluster as it
-    stands, leaf for leaf."""
+    stands under the pinned capacity and vocabulary, leaf for leaf."""
     from kai_scheduler_tpu.state.cluster_state import build_snapshot
     fresh, _ = build_snapshot(
         *cluster.snapshot_lists(), now=cluster.now,
@@ -766,7 +1041,7 @@ def _assert_fresh(state, snap, cluster):
         device_classes=cluster.device_classes,
         volume_claims=cluster.volume_claims,
         storage_classes=cluster.storage_classes,
-        capacity=snap._capacity)
+        capacity=snap._capacity, vocabulary=snap._vocabulary)
     mine = jax.tree_util.tree_flatten_with_path(state)[0]
     ref = jax.tree_util.tree_leaves(fresh)
     assert len(mine) == len(ref)

@@ -4,9 +4,10 @@
 
 Clusters as accelerator pools are deployed: tainted nodes of two GPU
 types named by a label, a few untainted nodes; gangs that tolerate the
-taint or do not, that select a type or none.  No pod of such a cluster
-is plain to the program: intake takes the generic parser, every refresh
-rebuilds with reason ``nonplain-pods``, and the device places by
+taint or do not, that select a type or none.  Intake takes the generic
+parser for a tolerating pod, the cold build numbers the filter specs
+and the selector key, every refresh after it patches against that
+pinned vocabulary (``state/incremental.py``), and the device places by
 selector match, filter-class mask and feasible rank
 (``dense_feasibility`` false).  The default wavefront and the ``B=1``
 sequential scan are both held to the oracle, and to each other.
@@ -194,28 +195,37 @@ def test_served_cycles_bind_where_the_oracle_allows(seed, split, nodes):
 
 
 @pytest.mark.parametrize("seed,split,nodes", CASES + LARGER)
-def test_every_cycle_rebuilds_for_nonplain_pods(seed, split, nodes):
-    """One toleration or selector anywhere and the refresh cannot patch:
-    every cycle after the cold one falls back with ``nonplain-pods``,
-    counts what stands in the way, and (verification on) never serves a
-    patched state that differs from a fresh build."""
+def test_every_cycle_after_the_cold_one_patches(seed, split, nodes):
+    """A toleration and a node selector ride the patch: the cold build
+    numbers the pool's two filter specs and its selector key, every
+    cycle after it patches against that vocabulary — arrivals, binds
+    and finished gangs alike — and (verification on) never serves a
+    patched state that differs from a fresh build.  The oracle's counts
+    stay 0 on the patched states."""
     cycles, _pools = _served(seed, split, nodes)
-    assert cycles[0]["health"]["snapshot"]["fallback_reason"] == "cold"
+    cold = cycles[0]["health"]
+    assert cold["snapshot"]["mode"] == "full"
+    assert cold["snapshot"]["fallback_reason"] == "cold"
+    assert any(path.endswith("/encode.filters")
+               for path in cold["span_self_seconds"])
     for c in cycles:
         snap = c["health"]["snapshot"]
-        assert snap["mode"] == "full"
         # the empty spec and the toleration's; the selector is a key
         assert snap["filter_classes"] == 2 and snap["selector_keys"] == 1
-        assert snap["nonplain_pods"] > 0
         # 6 gangs arrive, 4 of them with a toleration: a list of
         # structs, which the fast path for new pods leaves to the
         # generic parser (a node selector is a plain mapping and rides
         # the fast path)
         assert c["health"]["intake_parsed_pods"] == 4 * TASKS
-        assert any(path.endswith("/encode.filters")
-                   for path in c["health"]["span_self_seconds"])
+        assert c["counts"] == {"misplaced": 0, "split": 0, "unbound": 0,
+                               "wrongly_bound": 0}
     for c in cycles[1:]:
-        assert c["health"]["snapshot"]["fallback_reason"] == "nonplain-pods"
+        snap = c["health"]["snapshot"]
+        assert snap["mode"] == "patched", snap
+        assert snap["nonplain_pods"] == 0 and snap["filtered_pods"] > 0
+        # no spec is evaluated against the nodes again
+        assert not any("encode." in path
+                       for path in c["health"]["span_self_seconds"])
 
 
 @pytest.mark.parametrize("seed,split,nodes", CASES)
