@@ -138,6 +138,9 @@ class CycleResult:
     #: ``AllocationResult.victim_skipped``), else 0
     victim_actions_skipped: dict[str, int] = dataclasses.field(
         default_factory=dict)
+    #: the placement kernels the session chose for this cycle and the
+    #: shapes they unroll over (``Session.kernels``)
+    kernels: dict = dataclasses.field(default_factory=dict)
     #: kai-twin determinism anchors: the cycle's logical index and the
     #: per-cycle seed derived from ``SchedulerConfig.seed`` — pure
     #: functions of (config seed, cycle index), never of wall clock or
@@ -495,6 +498,7 @@ class Scheduler:
         result.cycle_seed = cycle_seed_for(self.config.seed,
                                            self._cycle_index)
         result.open_seconds = open_s
+        result.kernels = session.kernels()
         with self.tracer.span("solve_dispatch"):
             # a dozen small dispatches: inside the span, as the phase's
             # checkpoints already count them

@@ -737,6 +737,7 @@ class SchedulerServer:
                     intake_parsed_pods=parsed_pods,
                     victim_actions_skipped=dict(
                         result.victim_actions_skipped),
+                    kernels=dict(result.kernels),
                     startup={"phase_seconds": first,
                              **compile_watch.WATCHER.stage_seconds()})
             # kai-pulse slice: the headline cluster-health gauges of

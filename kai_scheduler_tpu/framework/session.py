@@ -28,7 +28,8 @@ from ..ops import analytics as pulse
 from ..ops import drf
 from ..runtime import compile_watch
 from ..runtime import events as gang_events
-from ..ops.allocate import AllocateConfig, AllocationResult
+from ..ops.allocate import (AllocateConfig, AllocationResult,
+                            lane_width as allocate_lane_width)
 from ..ops.victims import VICTIM_ACTIONS, VictimConfig
 from ..state.cluster_state import (ClusterState, SnapshotIndex,
                                    _pow2_ceil, build_snapshot)
@@ -315,6 +316,19 @@ class Session:
             k_value=jnp.float32(config.k_value))
         state = state.replace(queues=state.queues.replace(fair_share=fair_share))
         return cls(state=state, index=index, config=config)
+
+    def kernels(self) -> dict:
+        """Which placement kernels this cycle compiles and runs, as
+        ``_auto_tune`` chose them from the snapshot, with the shapes
+        they unroll over (``/healthz`` ``last_cycle.kernels``)."""
+        acfg, g = self.config.allocate, self.state.gangs
+        return {"uniform_tasks": acfg.uniform_tasks,
+                "track_devices": acfg.track_devices,
+                "dense_feasibility": acfg.dense_feasibility,
+                "allocate_lanes": allocate_lane_width(acfg, g.g),
+                "tasks": g.t,
+                "subgroups": g.s,
+                "pending_gangs": self.index.num_pending_gangs}
 
     def _gangs_queue_host(self) -> "np.ndarray":
         """The gang→queue column as host numpy."""

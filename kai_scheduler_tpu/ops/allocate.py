@@ -1266,12 +1266,15 @@ def _attempt_gang(state: ClusterState, gang_idx: jax.Array,
     def run(banned):
         extras = ((topo_tables, sparse_out, type_tables_u)
                   if config.uniform_tasks else ())
-        return in_domain(
-            state, gang_idx, free, device_free, q_alloc, q_alloc_np,
-            num_levels, config, dmask, pref_doms, has_pref,
-            extra_releasing, extra_device_releasing, lane, chain,
-            prior_nodes, quota, ext_free, extra_extended_releasing,
-            banned, score_bias, *extras)
+        # which of the two kernels ran is in every operation's op_name
+        with jax.named_scope("whole_gang_fill" if config.uniform_tasks
+                             else "per_task_fill"):
+            return in_domain(
+                state, gang_idx, free, device_free, q_alloc, q_alloc_np,
+                num_levels, config, dmask, pref_doms, has_pref,
+                extra_releasing, extra_device_releasing, lane, chain,
+                prior_nodes, quota, ext_free, extra_extended_releasing,
+                banned, score_bias, *extras)
 
     out = run(None)
     if config.uniform_tasks and sparse_out:
@@ -1295,6 +1298,18 @@ def _attempt_gang(state: ClusterState, gang_idx: jax.Array,
     return out[:12]
 
 
+def lane_width(config: AllocateConfig, num_gangs: int) -> int:
+    """Gangs one wavefront chunk of :func:`allocate` attempts in
+    parallel (the ``vmap`` width B) over ``num_gangs`` padded rows."""
+    B = max(1, min(config.batch_size, num_gangs))
+    if config.subgroup_topology and not config.uniform_tasks:
+        # the per-task kernel's domain segment reduction multiplies lane
+        # scratch by the N*L segment count; wide wavefronts exceed TPU
+        # scratch limits (observed device faults at B=256, 5k nodes)
+        B = min(B, 64)
+    return B
+
+
 def allocate(
     state: ClusterState,
     fair_share: jax.Array,          # f32 [Q, R]  from ops.drf.set_fair_share
@@ -1313,12 +1328,7 @@ def allocate(
     g, n, q = state.gangs, state.nodes, state.queues
     G, T = g.g, g.t
     total = state.total_capacity
-    B = max(1, min(config.batch_size, G))
-    if config.subgroup_topology and not config.uniform_tasks:
-        # the per-task kernel's domain segment reduction multiplies lane
-        # scratch by the N*L segment count; wide wavefronts exceed TPU
-        # scratch limits (observed device faults at B=256, 5k nodes)
-        B = min(B, 64)
+    B = lane_width(config, G)
     if init is None:
         init = init_result(state)
 

@@ -502,7 +502,8 @@ class IncrementalSnapshotter:
                     "leaves_shipped": ship[0], "bytes_shipped": ship[1],
                     "ship_seconds": ship[2], "ship_dispatches": ship[3],
                     # what the patch carried of the pinned vocabulary
-                    "nonplain_pods": 0,
+                    "nonplain_pods": self._nonplain,
+                    "nonplain_gangs": self._nonplain_gangs,
                     "filter_classes": len(self._vocabulary.filter_specs),
                     "selector_keys": len(self._vocabulary.selector_keys),
                     "filtered_pods": self._last_filtered,
@@ -629,7 +630,8 @@ class IncrementalSnapshotter:
         self._host, self._dev, self._index = host, state, index
         with self._span("snapshot.ledgers"):
             self._rebuild_ledgers(cluster, lists, host, index)
-        self._built_vocab["nonplain_pods"] = self._nonplain
+        self._built_vocab.update(nonplain_pods=self._nonplain,
+                                 nonplain_gangs=self._nonplain_gangs)
         return state, index
 
     def _rebuild_ledgers(self, cluster, lists, host, index) -> None:

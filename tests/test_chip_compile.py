@@ -7,8 +7,10 @@ emitter, not a Python exception — on every victim action of the default
 cycle, at any size; these compiles guard that at the saturated 64-node
 shape where the abort reproduced, the fifth on the non-dense placement
 path (selectors, a filter class, feasible-rank tie-break) that a cluster
-of tainted pools takes and the CPU alone had run before PR 28.  Nothing
-runs: a compile that passes says what the compiler accepts, never what
+of tainted pools takes and the CPU alone had run before PR 28, the sixth
+on the per-task placement kernel that declared subgroups and gangs of
+unequal pods take (Kubeflow's jobs; PR 32).  Nothing runs: a compile
+that passes says what the compiler accepts, never what
 the chip does.
 
 Only one process at a time may load the TPU library, and it keeps it
@@ -85,6 +87,33 @@ def saturated_pools():
     return Session.open(nodes, queues, groups, pods, topology)
 
 
+@pytest.fixture(scope="module")
+def saturated_kubeflow():
+    """``saturated`` with its pending gangs as Kubeflow's operators make
+    them: a subgroup per replica type, the leader labelled with its
+    job-role, and in every second one an MPI launcher that asks for no
+    accelerator, so that the gang's pods differ."""
+    from kai_scheduler_tpu.apis import types as apis
+    from kai_scheduler_tpu.framework.session import Session
+    nodes, queues, groups, pods, topology = _saturated_objects()
+    by_group: dict = {}
+    for pod in pods:
+        if pod.node is None:
+            by_group.setdefault(pod.group, []).append(pod)
+    for i, group in enumerate(g for g in groups if g.name in by_group):
+        leader = "launcher" if i % 2 else "master"
+        mine = by_group[group.name]
+        group.sub_groups = [apis.SubGroup(leader, 1),
+                            apis.SubGroup("worker", len(mine) - 1)]
+        for t, pod in enumerate(mine):
+            role = "worker" if t else leader
+            pod.subgroup = role
+            pod.labels["training.kubeflow.org/job-role"] = role
+        if leader == "launcher":
+            mine[0].resources = apis.ResourceVec(0.0, 1.0, 2.0)
+    return Session.open(nodes, queues, groups, pods, topology)
+
+
 def _shapes(tree, sharding):
     """ShapeDtypeStructs of ``tree`` placed by ``sharding`` — one
     sharding for every leaf, or a matching pytree of them."""
@@ -133,6 +162,24 @@ def test_fused_five_actions_compile_non_dense(topo, saturated_pools):
     assert not ses.config.allocate.dense_feasibility
     assert not ses.config.victims.placement.dense_feasibility
     assert ses.config.allocate.uniform_tasks
+    one = SingleDeviceSharding(topo.devices[0])
+    st = _shapes(ses.state, one)
+    compiled = S._fused_pipeline.__kai_jit__.lower(
+        st, st.queues.fair_share, **_pipeline_kwargs(ses.config)).compile()
+    _fits_one_chip(compiled)
+
+
+def test_fused_five_actions_compile_per_task(topo, saturated_kubeflow):
+    """The same entry over Kubeflow jobs: declared subgroups and a
+    launcher without accelerator, so allocate and every victim
+    placement compile the per-task kernel (and preempt its dense
+    composed path), which no other compile here reaches."""
+    from kai_scheduler_tpu.framework import scheduler as S
+    ses = saturated_kubeflow
+    assert not ses.index.uniform_gangs
+    assert not ses.config.allocate.uniform_tasks
+    assert not ses.config.victims.placement.uniform_tasks
+    assert ses.state.gangs.s >= 3
     one = SingleDeviceSharding(topo.devices[0])
     st = _shapes(ses.state, one)
     compiled = S._fused_pipeline.__kai_jit__.lower(
