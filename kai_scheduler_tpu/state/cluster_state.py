@@ -372,6 +372,8 @@ class SnapshotCapacity:
     tasks: int = 0
     running: int = 0
     types: int = 0
+    #: the subgroup axis ``S`` (slot 0 and the declared subgroups)
+    subgroups: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -946,7 +948,9 @@ def _encode_snapshot(
     )
     # --- subgroup tables (slot 0 = implicit default subgroup, so the
     # slot count is max declared subgroups + 1) ----------------------------
-    S = _round_up(max([len(g.sub_groups) for g in pod_groups] + [0]) + 1, 4)
+    S = max(_round_up(
+        max([len(g.sub_groups) for g in pod_groups] + [0]) + 1, 4),
+        cap.subgroups)
     gk["task_subgroup"] = np.zeros((G, T), np.int32)
     gk["subgroup_valid"] = np.zeros((G, S), bool)
     gk["subgroup_min_member"] = np.zeros((G, S), np.int32)

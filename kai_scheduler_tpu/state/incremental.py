@@ -34,7 +34,10 @@ Three pieces:
 
   * structural change — node/queue set or order changed, topology
     swapped, padded-dim overflow (entity counts outgrew the pinned
-    :class:`~.cluster_state.SnapshotCapacity`).  The pod-group set is
+    :class:`~.cluster_state.SnapshotCapacity`: ``overflow-gangs``,
+    ``-tasks``, ``-types``, ``-running``, and ``overflow-subgroups``
+    for a gang that declares more subgroups than the pinned ``S``
+    holds).  The pod-group set is
     NOT structural: a new group appends a ledger row, a deleted one
     has its row closed up (``_remove_gangs`` — a cluster deletes a
     group with its owner, so every completion and eviction does
@@ -56,10 +59,14 @@ Three pieces:
     or a spec numbered by the last build but not pinned;
   * feature pods (``nonplain-pods``) — fractional/memory-share
     requests, DRA claims, volumes, host ports, pod affinity, node
-    affinity, nominated nodes, extended resources, declared subgroups
-    (the irregular intake paths stay on the proven full builder; a
-    pod-affinity or host-port spec is evaluated against the running
-    pods, so it is never pinned);
+    affinity, nominated nodes, extended resources (the irregular
+    intake paths stay on the proven full builder; a pod-affinity or
+    host-port spec is evaluated against the running pods, so it is
+    never pinned).  A declared subgroup is not among them: the gang
+    ledger holds each gang's name -> slot map and its ``[S]`` quorum
+    rows, the pod ledger each pod's slot, and ``S`` is pinned with the
+    capacity, so a Kubeflow job patches like a plain gang (a plain
+    gang is one whose only slot is 0);
   * dirty fraction above ``dirty_threshold`` — patching stops paying
     once most of the cluster changed;
   * ledger drift — an object mutated without a journal mark (the
@@ -347,22 +354,29 @@ def _is_plain_pod(pod: apis.Pod) -> bool:
         pod.node_affinity
         or pod.pod_affinity or pod.extended or pod.resource_claims
         or pod.volume_claims or pod.host_ports
-        or pod.nominated_node is not None or pod.subgroup
+        or pod.nominated_node is not None
         or pod.accel_portion > 0 or pod.accel_memory_gib > 0
         or pod.dra_accel_count > 0)
 
 
-#: the gang ledger's per-row arrays as (attribute, dtype, fill): built
-#: by ``_rebuild_ledgers``, grown by ``_grow_gangs``, closed up by
-#: ``_remove_gangs`` (the ``g_objs``/``g_names``/``g_tc`` lists beside
-#: them hold exactly one entry per row)
+#: the gang ledger's per-row arrays as (attribute, dtype, fill, one
+#: entry per subgroup slot): built by ``_rebuild_ledgers``, grown by
+#: ``_grow_gangs``, closed up by ``_remove_gangs`` (the lists of
+#: ``_GANG_LISTS`` beside them hold exactly one entry per row)
 _GANG_COLUMNS = (
-    ("g_queue", np.int32, 0), ("g_minm", np.int32, 0),
-    ("g_prio", np.int32, 0), ("g_preempt", bool, False),
-    ("g_unsched", bool, False), ("g_start", np.float64, -1.0),
-    ("g_stale", np.float64, np.nan), ("g_reqlvl", np.int32, -1),
-    ("g_preflvl", np.int32, -1),
+    ("g_queue", np.int32, 0, False), ("g_minm", np.int32, 0, False),
+    ("g_prio", np.int32, 0, False), ("g_preempt", bool, False, False),
+    ("g_unsched", bool, False, False), ("g_start", np.float64, -1.0, False),
+    ("g_stale", np.float64, np.nan, False),
+    ("g_reqlvl", np.int32, -1, False), ("g_preflvl", np.int32, -1, False),
+    ("g_sub_valid", bool, False, True), ("g_sub_minm", np.int32, 0, True),
+    ("g_sub_rlvl", np.int32, -1, True),
 )
+
+#: the lists: the object, its name, the identities the sweep compares
+#: (``topology_constraint``, ``sub_groups``) and the subgroup
+#: name -> slot map
+_GANG_LISTS = ("g_objs", "g_names", "g_tc", "g_subs", "g_slot")
 
 #: likewise the pod ledger's, as (attribute, dtype, fill, row shape);
 #: ``p_objs`` and ``p_sweep`` are the lists beside them
@@ -374,7 +388,7 @@ _POD_COLUMNS = (
     ("p_devmask", np.int32, 0, ()), ("p_held", np.float32, 0.0, ()),
     ("p_hasdev", bool, False, ()), ("p_eff_status", np.int8, -1, ()),
     ("p_eff_node", np.int32, -1, ()), ("p_iid", np.int32, -1, ()),
-    ("p_ti", np.int32, -1, ()),
+    ("p_ti", np.int32, -1, ()), ("p_sub", np.int32, 0, ()),
 )
 
 
@@ -394,6 +408,9 @@ _FULL_STATS = {
     "pods_removed": 0, "gangs_removed": 0,
     "leaves_shipped": 0, "bytes_shipped": 0,
     "ship_seconds": 0.0, "ship_dispatches": 0, "filtered_pods": 0,
+    "subgrouped_pods": 0, "subgrouped_gangs": 0,
+    # nothing of a gang refuses the patch: the key stays for its readers
+    "nonplain_gangs": 0,
 }
 
 #: in the intern tables, a label value or a filter spec the pinned
@@ -501,12 +518,16 @@ class IncrementalSnapshotter:
                     "gangs_removed": self._last_removed[1],
                     "leaves_shipped": ship[0], "bytes_shipped": ship[1],
                     "ship_seconds": ship[2], "ship_dispatches": ship[3],
-                    # what the patch carried of the pinned vocabulary
+                    # what the patch cannot carry ...
                     "nonplain_pods": self._nonplain,
-                    "nonplain_gangs": self._nonplain_gangs,
+                    "nonplain_gangs": 0,
+                    # ... and what it carried, of the pinned vocabulary
+                    # and of declared subgroups
                     "filter_classes": len(self._vocabulary.filter_specs),
                     "selector_keys": len(self._vocabulary.selector_keys),
                     "filtered_pods": self._last_filtered,
+                    "subgrouped_pods": self._last_subgrouped,
+                    "subgrouped_gangs": self._subgrouped_gangs,
                 }
                 patch_sp.attrs.update(self.stats.last)
                 if self.verify:
@@ -544,8 +565,6 @@ class IncrementalSnapshotter:
         # leave behind: a reason names its cause
         if self._nonplain > 0:
             return "nonplain-pods"
-        if self._nonplain_gangs > 0:
-            return "nonplain-gangs"
         if not self._clean:
             return "vocab-residue"
         if self._present_twice > 0:
@@ -591,7 +610,8 @@ class IncrementalSnapshotter:
             queues=keep(old.queues, len(queues)),
             gangs=keep(old.gangs, len(groups)),
             tasks=keep(old.tasks, max_pending),
-            running=keep(old.running, n_running), types=old.types)
+            running=keep(old.running, n_running), types=old.types,
+            subgroups=old.subgroups)
         # through the module attribute so test harnesses that wrap
         # build_snapshot (padding unification) stay in effect.  The
         # wire ledger re-labels the build's transfer "fallback": the
@@ -625,13 +645,13 @@ class IncrementalSnapshotter:
             gangs=host.gangs.valid.shape[0],
             tasks=host.gangs.task_valid.shape[1],
             running=host.running.valid.shape[0],
-            types=host.gangs.type_req.shape[0])
+            types=host.gangs.type_req.shape[0],
+            subgroups=host.gangs.subgroup_valid.shape[1])
         self._vocabulary = index.vocabulary.node_only()
         self._host, self._dev, self._index = host, state, index
         with self._span("snapshot.ledgers"):
             self._rebuild_ledgers(cluster, lists, host, index)
-        self._built_vocab.update(nonplain_pods=self._nonplain,
-                                 nonplain_gangs=self._nonplain_gangs)
+        self._built_vocab.update(nonplain_pods=self._nonplain)
         return state, index
 
     def _rebuild_ledgers(self, cluster, lists, host, index) -> None:
@@ -679,16 +699,18 @@ class IncrementalSnapshotter:
             off += len(t.levels)
         # --- gang ledger --------------------------------------------------
         NG = len(groups)
-        # rows start as None so _encode_gang's nonplain delta-tracking
-        # sees a fresh row (not the gang it is about to encode)
-        self.g_objs: list = [None] * NG
-        self.g_names: list[str] = [g.name for g in groups]
+        # rows start as None so _encode_gang's delta-tracking sees a
+        # fresh row (not the gang it is about to encode)
+        for name in _GANG_LISTS:
+            setattr(self, name, [None] * NG)
         self._gang_index = {g.name: i for i, g in enumerate(groups)}
-        for name, dtype, fill in _GANG_COLUMNS:
-            setattr(self, name, np.full((NG,), fill, dtype))
-        self.g_tc: list = [None] * NG
+        S = self._capacity.subgroups
+        for name, dtype, fill, wide in _GANG_COLUMNS:
+            setattr(self, name,
+                    np.full((NG, S) if wide else (NG,), fill, dtype))
         self._q_index = {n: i for i, n in enumerate(self._queue_names)}
-        self._nonplain_gangs = 0
+        #: live gangs that declare subgroups (``uniform_gangs``)
+        self._subgrouped_gangs = 0
         for i, g in enumerate(groups):
             self._encode_gang(i, g)
         # --- pod ledger ---------------------------------------------------
@@ -744,7 +766,6 @@ class IncrementalSnapshotter:
             attract_needs=np.asarray(g.attract_needs),
             anti_term_level=np.asarray(g.anti_term_level),
             attract_static=np.asarray(g.attract_static),
-            task_subgroup=np.asarray(g.task_subgroup),
             task_extended=np.asarray(g.task_extended),
             task_dra=np.asarray(g.task_dra),
             ext_accel=np.asarray(g.ext_accel),
@@ -769,8 +790,23 @@ class IncrementalSnapshotter:
 
     def _encode_gang(self, i, g: apis.PodGroup) -> None:
         prev = self.g_objs[i]
-        was_nonplain = bool(prev is not None and prev.sub_groups)
-        self._nonplain_gangs += int(bool(g.sub_groups)) - int(was_nonplain)
+        subs = g.sub_groups
+        S = self._capacity.subgroups
+        if len(subs) > S - 1:
+            raise _Fallback("overflow-subgroups")
+        self._subgrouped_gangs += int(bool(subs)) - int(
+            prev is not None and bool(self.g_subs[i]))
+        # declared subgroups take slots 1.., slot 0 is the default
+        # subgroup (the builder's ``sub_slot``)
+        slot = {sg.name: si for si, sg in enumerate(subs, start=1)}
+        reslot = prev is not None and slot != self.g_slot[i]
+        self.g_subs[i] = subs
+        self.g_slot[i] = slot
+        if reslot:
+            # the pods this row already has keep their names and move
+            mine = np.flatnonzero(self.p_live & (self.p_group == i))
+            for row in mine.tolist():
+                self.p_sub[row] = self._slot_of(i, self.p_objs[row])
         self.g_objs[i] = g
         self.g_names[i] = g.name
         self.g_queue[i] = self._q_index.get(g.queue, 0)
@@ -785,8 +821,21 @@ class IncrementalSnapshotter:
                            else g.stale_since)
         tc = g.topology_constraint
         self.g_tc[i] = tc
-        self.g_reqlvl[i] = self._resolve_level(tc, "required_level")
+        req = self._resolve_level(tc, "required_level")
+        self.g_reqlvl[i] = req
         self.g_preflvl[i] = self._resolve_level(tc, "preferred_level")
+        self.g_sub_valid[i] = np.arange(S) <= len(subs)
+        self.g_sub_minm[i] = 0
+        self.g_sub_minm[i, 0] = 0 if subs else g.min_member
+        # a slot without a level of its own, padding included, takes
+        # the gang's required level
+        self.g_sub_rlvl[i] = req
+        for si, sg in enumerate(subs, start=1):
+            self.g_sub_minm[i, si] = sg.min_member
+            own = self._resolve_level(sg.topology_constraint,
+                                      "required_level")
+            if own >= 0:
+                self.g_sub_rlvl[i, si] = own
 
     def _resolve_level(self, tc, attr) -> int:
         if tc is None or not self._topo_levels:
@@ -807,7 +856,9 @@ class IncrementalSnapshotter:
         self.p_req[row] = pod.resources.as_tuple()
         self.p_prio[row] = pod.priority
         self.p_crea[row] = pod.creation_timestamp
-        self.p_group[row] = self._gang_index.get(pod.group, -1)
+        gi = self._gang_index.get(pod.group, -1)
+        self.p_group[row] = gi
+        self.p_sub[row] = self._slot_of(gi, pod)
         labels = pod.labels
         self.p_leader[row] = (
             (labels.get("training.kubeflow.org/job-role")
@@ -856,6 +907,14 @@ class IncrementalSnapshotter:
         if iid is None:
             iid = self._intern_add(key)
         self.p_iid[row] = iid
+
+    def _slot_of(self, gi: int, pod: apis.Pod) -> int:
+        """The pod's subgroup slot in gang row ``gi``, as the builder
+        looks it up: a name the gang does not declare, no name and no
+        gang are the default slot."""
+        if gi < 0:
+            return 0
+        return self.g_slot[gi].get(pod.subgroup or "", 0)
 
     def _intern_add(self, key: tuple) -> int:
         """A new row of the intern tables: the request, the selector
@@ -925,9 +984,10 @@ class IncrementalSnapshotter:
     def _grow_gangs(self, extra: int) -> None:
         """Array-capacity growth; the g_* lists append exactly."""
         n = max(extra, 8)
-        for name, dtype, fill in _GANG_COLUMNS:
+        for name, dtype, fill, _wide in _GANG_COLUMNS:
+            col = getattr(self, name)
             setattr(self, name, np.concatenate(
-                [getattr(self, name), np.full((n,), fill, dtype)]))
+                [col, np.full((n,) + col.shape[1:], fill, dtype)]))
 
     def _apply_journal(self, cluster, j
                        ) -> tuple[set, set, np.ndarray | None]:
@@ -971,9 +1031,8 @@ class IncrementalSnapshotter:
                 i = len(self._gang_index)
                 if i >= len(self.g_queue):
                     self._grow_gangs(max(8, i // 4))
-                self.g_objs.append(None)
-                self.g_names.append("")
-                self.g_tc.append(None)
+                for lst in _GANG_LISTS:
+                    getattr(self, lst).append(None)
                 self._gang_index[name] = i
                 self._encode_gang(i, g)
                 dirty_gangs.add(i)
@@ -984,6 +1043,7 @@ class IncrementalSnapshotter:
                 gi = self._gang_index.get(self.p_objs[row].group, -1)
                 if gi >= 0:
                     self.p_group[row] = gi
+                    self.p_sub[row] = self._slot_of(gi, self.p_objs[row])
                     dirty_rows.add(row)
                     dirty_gangs.add(gi)
         for name in j.gangs_dirty:
@@ -1061,14 +1121,13 @@ class IncrementalSnapshotter:
         keep[gone] = False
         src = np.flatnonzero(keep)
         remap = np.where(keep, np.cumsum(keep) - 1, -1).astype(np.int32)
-        self._nonplain_gangs -= sum(
-            bool(self.g_objs[i].sub_groups) for i in gone)
+        self._subgrouped_gangs -= sum(bool(self.g_subs[i]) for i in gone)
         kept = keep.tolist()
-        self.g_objs = list(itertools.compress(self.g_objs, kept))
-        self.g_names = list(itertools.compress(self.g_names, kept))
-        self.g_tc = list(itertools.compress(self.g_tc, kept))
+        for name in _GANG_LISTS:
+            setattr(self, name,
+                    list(itertools.compress(getattr(self, name), kept)))
         self._gang_index = {n: i for i, n in enumerate(self.g_names)}
-        for name, _dtype, _fill in _GANG_COLUMNS:
+        for name, _dtype, _fill, _wide in _GANG_COLUMNS:
             col = getattr(self, name)
             col[:len(src)] = col[src]
         had = self.p_group >= 0
@@ -1151,9 +1210,8 @@ class IncrementalSnapshotter:
                             and stale_c == g.stale_since))
             if (bool(g.unschedulable) != bool(self.g_unsched[i])
                     or self.g_start[i] != start or not stale_eq
-                    or self.g_tc[i] is not g.topology_constraint):
-                if g.sub_groups:
-                    raise _Fallback("gang-grew-subgroups")
+                    or self.g_tc[i] is not g.topology_constraint
+                    or self.g_subs[i] is not g.sub_groups):
                 self._encode_gang(i, g)
                 dirty_gangs.add(i)
         # nodes: any drift at all → full rebuild (vocabularies, masks,
@@ -1183,8 +1241,6 @@ class IncrementalSnapshotter:
         self._last_dirty = (len(dirty_rows), len(dirty_gangs))
         if self._nonplain > 0:
             raise _Fallback("nonplain-pods")
-        if self._nonplain_gangs > 0:
-            raise _Fallback("nonplain-gangs")
         if self._present_twice > 0:
             raise _Fallback("inflight-move")
         live = int(self.p_live.sum())
@@ -1228,6 +1284,7 @@ class IncrementalSnapshotter:
         filtered = ((self._intern_cls != 0)
                     | (self._intern_sel != -1).any(axis=1))
         self._last_filtered = int(filtered[iid_all].sum())
+        self._last_subgrouped = int(np.count_nonzero(self.p_sub[order]))
         # --- queues (always re-encoded; tiny) ----------------------------
         queues = list(cluster.queues.values())
         qt = build_queue_tables(queues, Q)
@@ -1310,11 +1367,13 @@ class IncrementalSnapshotter:
         task_type = np.zeros((G, T), np.int32)
         task_selector = np.full((G, T, K), -1, np.int32)
         task_class = np.zeros((G, T), np.int32)
+        task_sub = np.zeros((G, T), np.int32)
         if len(intake):
             slot = self.p_group[intake], self.p_ti[intake]
             task_type[slot] = tid_seq
             task_selector[slot] = self._intern_sel[iid_seq]
             task_class[slot] = self._intern_cls[iid_seq]
+            task_sub[slot] = self.p_sub[intake]
         task_type = self._swap_if_equal(task_type, task_type_old)
         # --- type table ---------------------------------------------------
         type_req = np.zeros((Y, R), np.float32)
@@ -1344,14 +1403,13 @@ class IncrementalSnapshotter:
         req_lvl[:NG] = self.g_reqlvl[:NG]
         pref_lvl = np.full((G,), -1, np.int32)
         pref_lvl[:NG] = self.g_preflvl[:NG]
-        S = np.asarray(og.subgroup_valid).shape[1]
+        S = cap.subgroups
         sub_valid = np.zeros((G, S), bool)
-        sub_valid[:NG, 0] = True
+        sub_valid[:NG] = self.g_sub_valid[:NG]
         sub_minm = np.zeros((G, S), np.int32)
-        sub_minm[:NG, 0] = min_member[:NG]
+        sub_minm[:NG] = self.g_sub_minm[:NG]
         sub_rlvl = np.full((G, S), -1, np.int32)
-        sub_rlvl[:NG] = np.where(req_lvl[:NG, None] >= 0,
-                                 req_lvl[:NG, None], -1)
+        sub_rlvl[:NG] = self.g_sub_rlvl[:NG]
         stale_s = np.full((G,), -1.0, np.float32)
         has_stale = ~np.isnan(self.g_stale[:NG])
         stale_s[:NG] = np.where(
@@ -1415,13 +1473,11 @@ class IncrementalSnapshotter:
             active = has_grp & ~r_rel
             np.add.at(running_count, gsafe[active], 1)
             np.add.at(sub_running,
-                      (gsafe[active],
-                       np.zeros(int(active.sum()), np.int64)), 1)
+                      (gsafe[active], self.p_sub[run_rows[active]]), 1)
         self._occupancy(rk, run_rows, r_node, r_rel, N)
         min_needed = np.maximum(min_member - running_count, 0)
         sub_min_needed = np.maximum(sub_minm - sub_running, 0)
         # --- scheduling signatures (same code as the builder) ------------
-        task_sub = self._const["task_subgroup"]
         big = np.int64(Y) * (S + 1) + 1
         comp = np.where(task_valid,
                         task_type.astype(np.int64) * (S + 1) + task_sub,
@@ -1458,6 +1514,7 @@ class IncrementalSnapshotter:
         tvm = task_valid[:, :, None]
         uniform = (
             not has_fracs
+            and self._subgrouped_gangs == 0
             and bool((self._const["task_nominated"] < 0).all())
             and bool((self._const["anti_self_level"] == -1).all())
             and bool((np.where(tvm, task_req, task_req[:, :1])
@@ -1497,6 +1554,7 @@ class IncrementalSnapshotter:
             task_selector=sw(task_selector, np.asarray(og.task_selector)),
             task_filter_class=sw(task_class,
                                  np.asarray(og.task_filter_class)),
+            task_subgroup=sw(task_sub, np.asarray(og.task_subgroup)),
             sig=sw(sig, np.asarray(og.sig)),
             type_req=type_req,
             type_selector=sw(type_selector, np.asarray(og.type_selector)),

@@ -200,7 +200,7 @@ class TestPatchEquivalence:
                         topology_levels=(2, 2))
         snap = IncrementalSnapshotter(verify=True, dirty_threshold=1.0)
         refresh(snap, cluster)
-        submitted = 0
+        submitted = subgrouped = 0
         for cycle in range(12):
             for _ in range(int(rng.integers(1, 4))):
                 op = rng.choice(["bind", "evict", "submit", "tick",
@@ -225,6 +225,12 @@ class TestPatchEquivalence:
                 elif op == "submit":
                     submitted += 1
                     name = f"extra-{submitted}"
+                    shape = rng.choice(["plain", *sorted(SUBGROUPED)])
+                    if shape != "plain":
+                        # a job with declared subgroups
+                        submit_subgrouped(cluster, name, str(shape))
+                        subgrouped += 1
+                        continue
                     g = apis.PodGroup(name=name, queue="queue-0-0",
                                       min_member=1)
                     cluster.submit(g, [apis.Pod(
@@ -248,6 +254,7 @@ class TestPatchEquivalence:
             refresh(snap, cluster)
         # the stream must exercise the patch path, not just fall back
         assert snap.stats.patched >= 8, snap.stats
+        assert subgrouped >= 3
 
     def test_patch_through_binder_devices(self):
         """Binder-bound pods carry concrete accel devices — the
@@ -775,6 +782,292 @@ class TestFilteredPodsPatch:
         assert ("gpu.type", "ghost") in index.label_vocab
         patched_and_fresh(snap, cluster)
 
+# ---------------------------------------------------------------------------
+# Declared subgroups ride the patch
+# ---------------------------------------------------------------------------
+
+ACCEL = apis.ResourceVec(1, 1, 4)
+NO_ACCEL = apis.ResourceVec(0, 1, 2)
+ROLE = "training.kubeflow.org/job-role"
+#: required levels of ``build(topology_levels=(2, 2))``: 0 and 2
+BLOCK = apis.TopologyConstraint("default", required_level="topo/level0")
+HOST = apis.TopologyConstraint(
+    "default", required_level="kubernetes.io/hostname")
+
+
+def _sub(name, min_member, tc=None):
+    return apis.SubGroup(name, min_member=min_member,
+                         topology_constraint=tc)
+
+
+#: shape -> (the group's fields, [(a pod's subgroup, its request, its
+#: job-role label)], and the ``[S]`` rows a build gives the gang: each
+#: pod's slot, ``subgroup_min_member``, ``subgroup_required_level``)
+SUBGROUPED = {
+    # a master and workers, every pod with an accelerator
+    "pytorch": (
+        {"sub_groups": [_sub("master", 1), _sub("worker", 3)]},
+        [("master", ACCEL, "master")] + [("worker", ACCEL, "worker")] * 3,
+        [1, 2, 2, 2], [0, 1, 3, 0], [-1, -1, -1, -1]),
+    # a launcher that asks for no accelerator, and workers
+    "mpi": (
+        {"sub_groups": [_sub("launcher", 1), _sub("worker", 2)]},
+        [("launcher", NO_ACCEL, "launcher")]
+        + [("worker", ACCEL, "worker")] * 2,
+        [1, 2, 2], [0, 1, 2, 0], [-1, -1, -1, -1]),
+    # a subgroup with a required topology level of its own
+    "own-level": (
+        {"sub_groups": [_sub("a", 1), _sub("b", 2, HOST)]},
+        [("a", ACCEL, None)] + [("b", ACCEL, None)] * 2,
+        [1, 2, 2], [0, 1, 2, 0], [-1, -1, 2, -1]),
+    # the gang's level, inherited by every slot without one
+    "inherited-level": (
+        {"sub_groups": [_sub("a", 1), _sub("b", 1, HOST)],
+         "topology_constraint": BLOCK},
+        [("a", ACCEL, None), ("b", ACCEL, None), ("a", ACCEL, None)],
+        [1, 2, 1], [0, 1, 1, 0], [0, 0, 2, 0]),
+    # a pod that names a subgroup its gang does not declare, and one
+    # that names none: the default slot
+    "unknown-name": (
+        {"sub_groups": [_sub("a", 1)]},
+        [("a", ACCEL, None), ("ghost", ACCEL, None), (None, ACCEL, None)],
+        [1, 0, 0], [0, 1, 0, 0], [-1, -1, -1, -1]),
+}
+
+
+def submit_subgrouped(cluster, name, shape) -> list[str]:
+    fields, pods, *_ = SUBGROUPED[shape]
+    # a list of its own a gang: the sweep tells them apart by identity
+    fields = {**fields, "sub_groups": list(fields["sub_groups"])}
+    cluster.submit(
+        apis.PodGroup(name, queue="queue-0-0", min_member=len(pods),
+                      **fields),
+        [apis.Pod(f"{name}-p{t}", name, req, subgroup=sub,
+                  labels={ROLE: role} if role else {})
+         for t, (sub, req, role) in enumerate(pods)])
+    return [f"{name}-p{t}" for t in range(len(pods))]
+
+
+def task_slots(state, index, gang) -> dict:
+    """Pending pod name -> its entry of ``task_subgroup``."""
+    gi = index.gang_names.index(gang)
+    return dict(zip(index.task_names[gi],
+                    np.asarray(state.gangs.task_subgroup)[gi].tolist()))
+
+
+def warm_topo(**kw):
+    cluster = build(num_nodes=8, num_gangs=6, tasks_per_gang=2,
+                    topology_levels=(2, 2), **kw)
+    snap = IncrementalSnapshotter(verify=True, dirty_threshold=1.0)
+    refresh(snap, cluster)
+    return cluster, snap
+
+
+class TestSubgroupedGangsPatch:
+    """A declared subgroup is a slot in the gang's ``[S]`` rows and a
+    number on each of its pods: ``verify=True`` and ``_assert_fresh``
+    hold every patched state to a fresh build under the pinned ``S``."""
+
+    @pytest.mark.parametrize("shape", sorted(SUBGROUPED))
+    def test_a_subgrouped_gang_lives_and_dies_in_patched_cycles(
+            self, shape):
+        cluster, snap = warm_topo(running_fraction=0.5)
+        _state, index0 = refresh(snap, cluster)
+        assert index0.uniform_gangs is True
+        cap0 = snap._capacity
+        _fields, _pods, slots, minm, rlvl = SUBGROUPED[shape]
+        # arrives
+        pods = submit_subgrouped(cluster, "job", shape)
+        state, index = patched_and_fresh(snap, cluster)
+        gi = index.gang_names.index("job")
+        g = state.gangs
+        by_name = task_slots(state, index, "job")
+        assert [by_name[n] for n in pods] == slots
+        assert np.asarray(g.subgroup_valid)[gi].tolist() == [
+            s <= len(_fields["sub_groups"]) for s in range(4)]
+        assert np.asarray(g.subgroup_min_member)[gi].tolist() == minm
+        assert np.asarray(g.subgroup_min_needed)[gi].tolist() == minm
+        assert np.asarray(g.subgroup_required_level)[gi].tolist() == rlvl
+        assert index.uniform_gangs is False
+        assert index.has_subgroup_topology is any(x >= 0 for x in rlvl)
+        last = snap.stats.last
+        assert last["nonplain_pods"] == last["nonplain_gangs"] == 0
+        assert last["subgrouped_gangs"] == 1
+        assert last["subgrouped_pods"] == sum(s > 0 for s in slots)
+        # the first two pods bind: their subgroups need that many fewer
+        for name in pods[:2]:
+            cluster.bind_pod(name, "node-0")
+        state, index = patched_and_fresh(snap, cluster)
+        gi = index.gang_names.index("job")
+        need = list(minm)
+        for s in slots[:2]:
+            need[s] = max(need[s] - 1, 0)
+        assert np.asarray(
+            state.gangs.subgroup_min_needed)[gi].tolist() == need
+        assert snap.stats.last["subgrouped_pods"] == sum(
+            s > 0 for s in slots)
+        # one of them is evicted and reaped: its subgroup is short again
+        cluster.evict_pod(pods[1])
+        patched_and_fresh(snap, cluster)
+        cluster.tick()
+        state, index = patched_and_fresh(snap, cluster)
+        need[slots[1]] = minm[slots[1]]
+        assert np.asarray(state.gangs.subgroup_min_needed)[
+            index.gang_names.index("job")].tolist() == need
+        # the job finishes: the group goes with its pods
+        delete_groups(cluster, ["job"])
+        _state, index = patched_and_fresh(snap, cluster)
+        assert index.uniform_gangs is True
+        assert snap.stats.last["subgrouped_gangs"] == 0
+        assert snap.stats.last["subgrouped_pods"] == 0
+        assert only_cold(snap), snap.stats
+        assert snap._capacity == cap0
+
+    @pytest.mark.parametrize("by", ["sweep", "journal"])
+    @pytest.mark.parametrize("status", ["pending", "running"])
+    def test_growing_and_shedding_subgroups_reslots_the_gangs_pods(
+            self, status, by):
+        """The pods name a subgroup all along; the slot it means is the
+        gang's to give and to take, written with or without a mark."""
+        cluster = build(num_nodes=8, num_gangs=6, tasks_per_gang=2,
+                        running_fraction=0.5)
+        want = (apis.PodStatus.PENDING if status == "pending"
+                else apis.PodStatus.RUNNING)
+        first = next(p for p in cluster.pods.values()
+                     if p.status == want)
+        group = cluster.pod_groups[first.group]
+        mine = [p for p in cluster.pods.values() if p.group == group.name]
+        for p in mine:
+            p.subgroup = "late"
+        snap = IncrementalSnapshotter(verify=True, dirty_threshold=1.0)
+        refresh(snap, cluster)
+
+        def write(sub_groups):
+            group.sub_groups = sub_groups
+            if by == "journal":
+                gate.gang_touched(cluster.journal, group.name)
+
+        def slots(state, index):
+            gi = index.gang_names.index(group.name)
+            if status == "pending":
+                return np.asarray(
+                    state.gangs.task_subgroup)[gi, :2].tolist()
+            # where the running pods count: needed = min_member - running
+            return (np.asarray(state.gangs.subgroup_min_member)[gi]
+                    - np.asarray(state.gangs.subgroup_min_needed)[gi]
+                    ).tolist()
+
+        state, index = patched_and_fresh(snap, cluster)
+        assert snap.stats.last["subgrouped_pods"] == 0
+        assert index.uniform_gangs is True
+        write([_sub("early", 2), _sub("late", 2)])
+        state, index = patched_and_fresh(snap, cluster)
+        assert slots(state, index) == ([2, 2] if status == "pending"
+                                       else [0, 0, 2, 0])
+        assert snap.stats.last["subgrouped_pods"] == 2
+        assert snap.stats.last["subgrouped_gangs"] == 1
+        assert index.uniform_gangs is False
+        write([_sub("late", 2)])
+        state, index = patched_and_fresh(snap, cluster)
+        assert slots(state, index) == ([1, 1] if status == "pending"
+                                       else [0, 2, 0, 0])
+        write([])
+        state, index = patched_and_fresh(snap, cluster)
+        assert slots(state, index) == ([0, 0] if status == "pending"
+                                       else [2, 0, 0, 0])
+        assert snap.stats.last["subgrouped_pods"] == 0
+        assert snap.stats.last["subgrouped_gangs"] == 0
+        assert index.uniform_gangs is True
+        assert only_cold(snap), snap.stats
+
+    def test_a_pod_ahead_of_its_group_takes_its_slot_when_it_arrives(
+            self):
+        cluster, snap = warm_topo()
+        _fields, pods, slots, *_ = SUBGROUPED["mpi"]
+        intake_apply.apply_cluster_delta(cluster, {"pods_upsert": [
+            {"name": f"early-p{t}", "group": "early", "subgroup": sub,
+             "resources": {"accel": req.accel, "cpu": 1.0, "memory": 2.0}}
+            for t, (sub, req, _role) in enumerate(pods)]})
+        patched_and_fresh(snap, cluster)
+        assert snap.stats.last["subgrouped_pods"] == 0
+        intake_apply.apply_cluster_delta(cluster, {"pod_groups_upsert": [
+            {"name": "early", "queue": "queue-0-0", "min_member": 3,
+             "sub_groups": [{"name": "launcher", "min_member": 1},
+                            {"name": "worker", "min_member": 2}]}]})
+        state, index = patched_and_fresh(snap, cluster)
+        by_name = task_slots(state, index, "early")
+        assert [by_name[f"early-p{t}"] for t in range(3)] == slots
+        assert snap.stats.last["subgrouped_pods"] == 3
+        # and loses it when the group goes ahead of its pods
+        delete_groups(cluster, ["early"], pods=False)
+        patched_and_fresh(snap, cluster)
+        assert snap.stats.last["subgrouped_pods"] == 0
+        assert only_cold(snap), snap.stats
+
+    def test_more_subgroups_than_slots_rebuild_once_and_S_stays(self):
+        """``S`` is a compiled shape, pinned with the capacity: it grows
+        in one rebuild and never shrinks."""
+        cluster, snap = warm_topo()
+        assert snap._capacity.subgroups == 4
+        submit_subgrouped(cluster, "fits", "pytorch")
+        patched_and_fresh(snap, cluster)
+
+        def wide(name):
+            cluster.submit(
+                apis.PodGroup(name, queue="queue-0-0", min_member=4,
+                              sub_groups=[_sub(f"r{i}", 1)
+                                          for i in range(4)]),
+                [apis.Pod(f"{name}-p{i}", name, ACCEL, subgroup=f"r{i}")
+                 for i in range(4)])
+
+        wide("wide")
+        state, _ = refresh(snap, cluster)
+        assert snap.stats.last["fallback_reason"] == "overflow-subgroups"
+        assert snap._capacity.subgroups == 8
+        assert np.asarray(state.gangs.subgroup_valid).shape[1] == 8
+        wide("wider")   # a second one like it patches
+        state, index = patched_and_fresh(snap, cluster)
+        by_name = task_slots(state, index, "wider")
+        assert [by_name[f"wider-p{i}"] for i in range(4)] == [1, 2, 3, 4]
+        # the last subgrouped gang leaves, and a rebuild after it: the
+        # axis stays, the hint is the fresh build's
+        delete_groups(cluster, ["fits", "wide", "wider"])
+        state, index = patched_and_fresh(snap, cluster)
+        assert index.uniform_gangs is True
+        cluster.journal.mark_structural("test")
+        rebuilt, rebuilt_index = refresh(snap, cluster)
+        assert snap.stats.last["mode"] == "full"
+        assert rebuilt_index.uniform_gangs is True
+        assert ([leaf.shape for leaf in jax.tree.leaves(rebuilt)]
+                == [leaf.shape for leaf in jax.tree.leaves(state)])
+        assert snap._capacity.subgroups == 8
+        assert snap.stats.fallbacks == {
+            "cold": 1, "overflow-subgroups": 1, "structural": 1}
+
+    def test_the_last_subgrouped_gang_leaving_changes_no_shape(self):
+        """``uniform_gangs`` is a static argument of the solve and the
+        builder's expression: it flips when the first subgrouped gang
+        arrives and back when the last leaves, exactly as a fresh build
+        flips it, and no leaf changes shape either way."""
+        cluster, snap = warm_topo(running_fraction=0.5)
+        state0, index0 = refresh(snap, cluster)
+        shapes = [leaf.shape for leaf in jax.tree.leaves(state0)]
+        assert index0.uniform_gangs is True
+        for name, shape in (("a", "pytorch"), ("b", "mpi")):
+            submit_subgrouped(cluster, name, shape)
+        state, index = patched_and_fresh(snap, cluster)
+        assert index.uniform_gangs is False
+        assert [leaf.shape for leaf in jax.tree.leaves(state)] == shapes
+        delete_groups(cluster, ["a"])
+        state, index = patched_and_fresh(snap, cluster)
+        assert index.uniform_gangs is False
+        assert snap.stats.last["subgrouped_gangs"] == 1
+        delete_groups(cluster, ["b"])
+        state, index = patched_and_fresh(snap, cluster)
+        assert index.uniform_gangs is True
+        assert [leaf.shape for leaf in jax.tree.leaves(state)] == shapes
+        assert only_cold(snap), snap.stats
+
 
 class TestVocabularyNumbering:
     def lists(self):
@@ -868,11 +1161,13 @@ def _start_move(c):
     c.create_bind_request(apis.BindRequest(name, "node-1"))
 
 
-def _submit_subgrouped(c):
+def _submit_five_subgroups(c):
+    # one more than the cold build's four slots hold beside slot 0
     c.submit(apis.PodGroup("sg", queue="queue-0-0", min_member=2,
-                           sub_groups=[apis.SubGroup("a", min_member=1)]),
-             [apis.Pod(f"sg-{i}", "sg", apis.ResourceVec(1, 1, 4))
-              for i in range(2)])
+                           sub_groups=[apis.SubGroup(f"r{i}", min_member=0)
+                                       for i in range(5)]),
+             [apis.Pod(f"sg-{i}", "sg", apis.ResourceVec(1, 1, 4),
+                       subgroup=f"r{i}") for i in range(2)])
 
 
 def _add_storage_class(c):
@@ -906,18 +1201,6 @@ def _replace_gang_object(c):
 def _rewrite_node_allocatable(c):
     n = c.nodes["node-2"]
     n.allocatable = dataclasses.replace(n.allocatable)
-
-
-def _gang_grows_subgroups(c):
-    g = next(iter(c.pod_groups.values()))
-    g.unschedulable = True
-    g.sub_groups = [apis.SubGroup("late", min_member=1)]
-
-
-def _gang_sheds_subgroups(c):
-    g = next(iter(c.pod_groups.values()))
-    g.unschedulable = False
-    g.sub_groups = []
 
 
 def _swap_last_queues(c):
@@ -1003,8 +1286,6 @@ _REFUSALS = {
     "vocab-growth": ({}, None, _submit_tolerating, None),
     "inflight-move": ({"running_fraction": 0.5}, None, _start_move,
                       lambda c: c.tick()),
-    "nonplain-gangs": ({}, None, _submit_subgrouped,
-                       lambda c: delete_groups(c, ["sg"])),
     "feature-stores": ({}, None, _add_storage_class,
                        lambda c: c.storage_classes.clear()),
     "node-dirty": ({}, None, _cordon_by_delta, None),
@@ -1014,10 +1295,9 @@ _REFUSALS = {
     "pod-object-drift": ({}, None, _replace_pod_object, None),
     "gang-object-drift": ({}, None, _replace_gang_object, None),
     "node-drift": ({}, None, _rewrite_node_allocatable, None),
-    "gang-grew-subgroups": ({}, None, _gang_grows_subgroups,
-                            _gang_sheds_subgroups),
     "queue-order-changed": ({}, None, _swap_last_queues, None),
     "overflow-gangs": ({}, None, _many_new_groups, None),
+    "overflow-subgroups": ({}, None, _submit_five_subgroups, None),
     "overflow-tasks": ({}, None, _one_wide_gang, None),
     "overflow-types": ({}, None, _resize_pods_apart, None),
     "overflow-running": ({"num_gangs": 24}, None, _bind_most, None),

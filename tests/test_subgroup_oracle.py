@@ -6,10 +6,12 @@ podgrouper groups them: PyTorchJobs (a master and workers, every pod
 with an accelerator) and MPIJobs (a launcher that asks for no
 accelerator, and workers), each one pod group with a subgroup per
 replica type.  One such job and the session's ``uniform_tasks`` is
-false: allocate and every victim placement run the per-task kernel, and
-every refresh rebuilds (``nonplain-pods``).  The default wavefront and
-the ``B=1`` sequential scan are both held to the oracle, and to each
-other.
+false: allocate and every victim placement run the per-task kernel.
+The snapshot's patch carries a declared subgroup (each pod's slot, each
+gang's ``[S]`` quorum rows), so after the cold build every refresh is a
+patch, and ``verify_incremental`` holds each one to a fresh build.  The
+default wavefront and the ``B=1`` sequential scan are both held to the
+oracle, and to each other.
 """
 import functools
 import json
@@ -140,7 +142,8 @@ def _served(seed, nodes=64, shape="half", scan=False,
     ``last_cycle``."""
     doc = _cluster(seed, nodes, shape)
     model = oracle.Oracle(doc)
-    config = SchedulerConfig(**({"session": SCAN} if scan else {}))
+    config = SchedulerConfig(verify_incremental=True,
+                             **({"session": SCAN} if scan else {}))
     server = SchedulerServer(load_cluster(doc), Scheduler(config),
                              port=0).start()
     base = f"http://127.0.0.1:{server.port}"
@@ -200,26 +203,32 @@ def test_every_job_that_fits_is_bound_whole(seed, nodes, scan):
 
 
 @pytest.mark.parametrize("seed,nodes", CASES)
-def test_served_cycles_run_the_per_task_kernel_and_rebuild(seed, nodes):
+def test_served_cycles_run_the_per_task_kernel_and_patch_after_the_cold_cycle(
+        seed, nodes):
     """What the session chose is served: the per-task kernel at the
     auto-tuned lane width, over padded task and subgroup axes that hold
-    the MPIJob's five pods and two subgroups; every refresh is a
-    rebuild, and the reason names the pods."""
+    the MPIJob's five pods and two subgroups; after the cold build
+    every refresh is a patch that equals a fresh build
+    (``verify_incremental``), and the oracle finds nothing."""
     cycles = _served(seed, nodes)
     for n, c in enumerate(cycles):
+        assert c["counts"] == oracle.ZERO, (n, c["verdict"])
         k = c["health"]["kernels"]
         assert k["uniform_tasks"] is False and k["track_devices"] is False
         assert k["dense_feasibility"] is True
         assert k["pending_gangs"] == 4 and k["allocate_lanes"] > 1
         assert k["tasks"] >= 5 and k["subgroups"] >= 3
         snap = c["health"]["snapshot"]
-        assert snap["mode"] == "full"
-        assert snap["fallback_reason"] == ("cold" if n == 0
-                                           else "nonplain-pods")
+        assert snap["nonplain_pods"] == snap["nonplain_gangs"] == 0
+        if n == 0:
+            assert snap["mode"] == "full"
+            assert snap["fallback_reason"] == "cold"
+            continue
+        assert snap["mode"] == "patched" and snap["fallback_reason"] == ""
         # every job but the one that finished, the binds of the cycles
-        # before and this cycle's arrivals
-        assert snap["nonplain_gangs"] == nodes // 2 + 3 * (n + 1)
-        assert snap["nonplain_pods"] == 4 * (nodes // 2) + 13 * (n + 1)
+        # before and this cycle's arrivals: every pod names a subgroup
+        assert snap["subgrouped_gangs"] == nodes // 2 + 3 * (n + 1)
+        assert snap["subgrouped_pods"] == 4 * (nodes // 2) + 13 * (n + 1)
 
 
 @pytest.mark.parametrize("seed,nodes", CASES[:3])
