@@ -23,7 +23,8 @@ import jax
 import numpy as np
 
 from ..apis import types as apis
-from ..ops.allocate import AllocationResult, allocate, init_result
+from ..ops.allocate import (TOPOLOGY_STATS, AllocationResult, allocate,
+                            init_result)
 from ..ops.analytics import cluster_analytics_jit
 from ..ops.repack import RepackConfig, plan_repack_jit
 from ..ops.stale import stale_gang_eviction
@@ -141,6 +142,11 @@ class CycleResult:
     #: the placement kernels the session chose for this cycle and the
     #: shapes they unroll over (``Session.kernels``)
     kernels: dict = dataclasses.field(default_factory=dict)
+    #: what allocate did under the topology tree, by the names of
+    #: ``ops.allocate.TOPOLOGY_STATS`` (the device counter
+    #: ``AllocationResult.topology_stats``); all 0 in a cycle whose
+    #: program was compiled without a required or preferred level
+    topology: dict[str, int] = dataclasses.field(default_factory=dict)
     #: kai-twin determinism anchors: the cycle's logical index and the
     #: per-cycle seed derived from ``SchedulerConfig.seed`` — pure
     #: functions of (config seed, cycle index), never of wall clock or
@@ -720,6 +726,11 @@ class Scheduler:
                 action: int(n) for action, n in zip(VICTIM_ACTIONS, skipped)}
             for action, n in result.victim_actions_skipped.items():
                 metrics.victim_action_skipped.set(action, value=float(n))
+        # ... and the four counts of allocate's work under the tree
+        topo = host.get("topology_stats")
+        if topo is not None:
+            result.topology = {
+                name: int(n) for name, n in zip(TOPOLOGY_STATS, topo)}
         # arrays come from the cycle's single batched transfer; change
         # detection is VECTORIZED against the previous cycle's tables so
         # the Python loop touches only cells that moved — O(changed)

@@ -738,6 +738,7 @@ class SchedulerServer:
                     victim_actions_skipped=dict(
                         result.victim_actions_skipped),
                     kernels=dict(result.kernels),
+                    topology=dict(result.topology),
                     startup={"phase_seconds": first,
                              **compile_watch.WATCHER.stage_seconds()})
             # kai-pulse slice: the headline cluster-health gauges of

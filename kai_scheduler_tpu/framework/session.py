@@ -28,7 +28,8 @@ from ..ops import analytics as pulse
 from ..ops import drf
 from ..runtime import compile_watch
 from ..runtime import events as gang_events
-from ..ops.allocate import (AllocateConfig, AllocationResult,
+from ..ops.allocate import (TOPOLOGY_STATS, AllocateConfig,
+                            AllocationResult,
                             lane_width as allocate_lane_width)
 from ..ops.victims import VICTIM_ACTIONS, VictimConfig
 from ..state.cluster_state import (ClusterState, SnapshotIndex,
@@ -91,6 +92,8 @@ def _pack_commit(result: AllocationResult, state: ClusterState,
             result.wavefront_stats, jnp.int16).ravel(),
         jax.lax.bitcast_convert_type(
             result.victim_skipped, jnp.int16).ravel(),
+        jax.lax.bitcast_convert_type(
+            result.topology_stats, jnp.int16).ravel(),
     ]
     if track_devices:
         parts.append(
@@ -325,6 +328,10 @@ class Session:
         return {"uniform_tasks": acfg.uniform_tasks,
                 "track_devices": acfg.track_devices,
                 "dense_feasibility": acfg.dense_feasibility,
+                "subgroup_topology": acfg.subgroup_topology,
+                "preferred_topology": acfg.preferred_topology,
+                "topology_levels": len(self.index.topology_levels),
+                "topology_domains": self.index.topology_domains,
                 "allocate_lanes": allocate_lane_width(acfg, g.g),
                 "tasks": g.t,
                 "subgroups": g.s,
@@ -389,6 +396,8 @@ class Session:
             take(2 * 5 * 2).tobytes(), np.int32).reshape(2, 5)
         out["victim_skipped"] = np.frombuffer(
             take(len(VICTIM_ACTIONS) * 2).tobytes(), np.int32)
+        out["topology_stats"] = np.frombuffer(
+            take(len(TOPOLOGY_STATS) * 2).tobytes(), np.int32)
         if devices:
             out["placement_device"] = (take(G * T).astype(np.int32) - 1
                                        ).reshape(G, T)

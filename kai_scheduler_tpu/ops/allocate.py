@@ -123,6 +123,20 @@ class AllocationResult(struct.PyTreeNode):
     #: (``ops/victims.py`` ``VICTIM_ACTIONS``).  Rides the packed commit
     #: beside ``wavefront_stats``; feeds ``kai_victim_action_skipped``.
     victim_skipped: jax.Array
+    #: what allocate's topology machinery did this cycle — i32 [4]
+    #: (``TOPOLOGY_STATS``): pending gangs attempted under a required
+    #: level, of those bound, attempts whose domain gate found no
+    #: fitting domain, gangs with a preferred level bound inside one
+    #: domain of it.  Zeros, and no operation, in a program compiled
+    #: without ``subgroup_topology`` / ``preferred_topology``.  Rides
+    #: the packed commit beside ``victim_skipped``; feeds
+    #: ``CycleResult.topology``.
+    topology_stats: jax.Array
+
+
+#: the slots of ``AllocationResult.topology_stats``, in order
+TOPOLOGY_STATS = ("required_attempted", "required_bound", "domain_misses",
+                  "preferred_together")
 
 
 def init_result(state: ClusterState) -> AllocationResult:
@@ -135,6 +149,7 @@ def init_result(state: ClusterState) -> AllocationResult:
         anti_used=jnp.zeros((TA + 1, AD + 1), bool),
         wavefront_stats=jnp.zeros((2, 5), jnp.int32),
         victim_skipped=jnp.zeros((3,), jnp.int32),
+        topology_stats=jnp.zeros((len(TOPOLOGY_STATS),), jnp.int32),
         placements=jnp.full((G, T), -1, jnp.int32),
         extended_free=n.extended_free,
         placement_device=jnp.full((G, T), -1, jnp.int32),
@@ -732,30 +747,33 @@ def _attempt_gang_in_domain(
         locked = sub_dom[s_t]
         dom_band = jnp.zeros((N,), jnp.float32)
         if config.subgroup_topology:
-            allowed = allowed & (
-                ~has_srl | (locked < 0) | (dom_col == locked))
-            # a constrained subgroup's FIRST placement must pick a domain
-            # whose aggregate capacity still fits the subgroup's
-            # remaining chunk, or the lock would doom the attempt
-            needs_pick = has_srl & (locked < 0)
-            node_agg = agg[jnp.maximum(dom_col, 0)]                    # [N, R]
-            dom_ok = jnp.all(
-                node_agg + EPS >= sub_rem[s_t][None, :],
-                axis=-1) & (dom_col >= 0)
-            if banned_doms is not None:
-                # in-cycle retry after a fragmented-domain failure: the
-                # previously locked domain is off the table this attempt
-                dom_ok = dom_ok & (dom_col != banned_doms[s_t])
-            allowed = allowed & (~needs_pick | dom_ok)
-            # binpack the domain choice: fullest fitting domain first
-            # (ref topology/node_scoring.go domain ordering) — scaled
-            # into the topology band so node-level bands stay subordinate
-            agg_accel = node_agg[:, 0]
-            mx = jnp.max(jnp.where(dom_ok, agg_accel, 0.0))
-            dom_band = jnp.where(
-                needs_pick & dom_ok,
-                W_TOPOLOGY * (1.0 - agg_accel / jnp.maximum(mx, EPS)),
-                0.0)
+            with jax.named_scope("domain_pick"):
+                allowed = allowed & (
+                    ~has_srl | (locked < 0) | (dom_col == locked))
+                # a constrained subgroup's FIRST placement must pick a
+                # domain whose aggregate capacity still fits the subgroup's
+                # remaining chunk, or the lock would doom the attempt
+                needs_pick = has_srl & (locked < 0)
+                node_agg = agg[jnp.maximum(dom_col, 0)]            # [N, R]
+                dom_ok = jnp.all(
+                    node_agg + EPS >= sub_rem[s_t][None, :],
+                    axis=-1) & (dom_col >= 0)
+                if banned_doms is not None:
+                    # in-cycle retry after a fragmented-domain failure:
+                    # the previously locked domain is off the table this
+                    # attempt
+                    dom_ok = dom_ok & (dom_col != banned_doms[s_t])
+                allowed = allowed & (~needs_pick | dom_ok)
+                # binpack the domain choice: fullest fitting domain first
+                # (ref topology/node_scoring.go domain ordering) — scaled
+                # into the topology band so node-level bands stay
+                # subordinate
+                agg_accel = node_agg[:, 0]
+                mx = jnp.max(jnp.where(dom_ok, agg_accel, 0.0))
+                dom_band = jnp.where(
+                    needs_pick & dom_ok,
+                    W_TOPOLOGY * (1.0 - agg_accel / jnp.maximum(mx, EPS)),
+                    0.0)
         fit_idle = fit_idle & allowed
         fit_pipe = fit_pipe & allowed                                  # [N]
         # preferred-level locality band (topology plugin node scoring):
@@ -1043,50 +1061,53 @@ def _attempt_gang_in_domain_uniform(
         dom_col = jnp.take(n.topology, jnp.clip(srl0, 0, L - 1), axis=1)
         NDu = N * L
         want0 = jnp.minimum(goal if not legacy else tcount, m_gate)
-        if topo_tables is not None:
-            # chunk-hoisted tables (see allocate()): per-lane work is
-            # gathers + one cumsum — the vmapped per-lane argsort +
-            # segment-sums over the domain axis dominated the wavefront
-            # at 5k nodes
-            dom_caps_y, level_of_dom, order_by_agg = topo_tables
-            dom_caps = dom_caps_y[g.task_type[gang_idx, 0]]   # [ND]
-            fits_dom = ((dom_caps >= jnp.maximum(want0, 1))
-                        & (level_of_dom == srl0))
-            if banned_doms is not None:
-                fits_dom = fits_dom & (
-                    jnp.arange(NDu) != jnp.maximum(banned_doms[0], -1))
-            fs = fits_dom[order_by_agg]
-            n_fit = jnp.sum(fs.astype(jnp.int32))
-            sel = jnp.mod(lane, jnp.maximum(n_fit, 1)) + 1
-            pos = jnp.argmax(fs & (jnp.cumsum(fs.astype(jnp.int32))
-                                   == sel))
-            target = jnp.where(n_fit > 0, order_by_agg[pos], -1)
-        else:
-            ids = jnp.where(n.valid & (dom_col >= 0), dom_col, NDu)
-            dom_caps = jax.ops.segment_sum(
-                c_pipe, ids, num_segments=NDu + 1)[:NDu]  # [ND] replicas
-            avail_accel = (free[:, 0] + n.releasing[:, 0]
-                           + extra_releasing[:, 0])
-            agg_accel = jax.ops.segment_sum(
-                jnp.where(n.valid, avail_accel, 0.0), ids,
-                num_segments=NDu + 1)[:NDu]
-            fits_dom = dom_caps >= jnp.maximum(want0, 1)
-            if banned_doms is not None:
-                fits_dom = fits_dom & (
-                    jnp.arange(NDu) != jnp.maximum(banned_doms[0], -1))
-            # spread wavefront lanes across the fitting domains, fullest
-            # first: lane 0 takes the binpack choice, lane k the k-th-
-            # fullest — otherwise every lane of a chunk fills the same
-            # domain and the accept prefix caps at one domain's capacity
-            order_dom = jnp.argsort(
-                jnp.where(fits_dom, agg_accel, jnp.inf))
-            n_fit = jnp.sum(fits_dom.astype(jnp.int32))
-            target = order_dom[jnp.mod(lane, jnp.maximum(n_fit, 1))]
-            target = jnp.where(jnp.any(fits_dom), target, -1)
-        prior_dom = jnp.where(
-            jnp.any(already),
-            dom_col[jnp.maximum(prior_nodes[jnp.argmax(already)], 0)], -1)
-        target = jnp.where(prior_dom >= 0, prior_dom, target)
+        with jax.named_scope("domain_pick"):
+            if topo_tables is not None:
+                # chunk-hoisted tables (see allocate()): per-lane work is
+                # gathers + one cumsum — the vmapped per-lane argsort +
+                # segment-sums over the domain axis dominated the
+                # wavefront at 5k nodes
+                dom_caps_y, level_of_dom, order_by_agg = topo_tables
+                dom_caps = dom_caps_y[g.task_type[gang_idx, 0]]   # [ND]
+                fits_dom = ((dom_caps >= jnp.maximum(want0, 1))
+                            & (level_of_dom == srl0))
+                if banned_doms is not None:
+                    fits_dom = fits_dom & (
+                        jnp.arange(NDu) != jnp.maximum(banned_doms[0], -1))
+                fs = fits_dom[order_by_agg]
+                n_fit = jnp.sum(fs.astype(jnp.int32))
+                sel = jnp.mod(lane, jnp.maximum(n_fit, 1)) + 1
+                pos = jnp.argmax(fs & (jnp.cumsum(fs.astype(jnp.int32))
+                                       == sel))
+                target = jnp.where(n_fit > 0, order_by_agg[pos], -1)
+            else:
+                ids = jnp.where(n.valid & (dom_col >= 0), dom_col, NDu)
+                dom_caps = jax.ops.segment_sum(
+                    c_pipe, ids, num_segments=NDu + 1)[:NDu]  # [ND]
+                avail_accel = (free[:, 0] + n.releasing[:, 0]
+                               + extra_releasing[:, 0])
+                agg_accel = jax.ops.segment_sum(
+                    jnp.where(n.valid, avail_accel, 0.0), ids,
+                    num_segments=NDu + 1)[:NDu]
+                fits_dom = dom_caps >= jnp.maximum(want0, 1)
+                if banned_doms is not None:
+                    fits_dom = fits_dom & (
+                        jnp.arange(NDu) != jnp.maximum(banned_doms[0], -1))
+                # spread wavefront lanes across the fitting domains,
+                # fullest first: lane 0 takes the binpack choice, lane k
+                # the k-th-fullest — otherwise every lane of a chunk fills
+                # the same domain and the accept prefix caps at one
+                # domain's capacity
+                order_dom = jnp.argsort(
+                    jnp.where(fits_dom, agg_accel, jnp.inf))
+                n_fit = jnp.sum(fits_dom.astype(jnp.int32))
+                target = order_dom[jnp.mod(lane, jnp.maximum(n_fit, 1))]
+                target = jnp.where(jnp.any(fits_dom), target, -1)
+            prior_dom = jnp.where(
+                jnp.any(already),
+                dom_col[jnp.maximum(prior_nodes[jnp.argmax(already)], 0)],
+                -1)
+            target = jnp.where(prior_dom >= 0, prior_dom, target)
         # target == -1 (no domain fits) must FAIL the gang, not fall
         # through to nodes that lack the level's label (their dom_col is
         # also -1)
@@ -1226,8 +1247,11 @@ def _attempt_gang(state: ClusterState, gang_idx: jax.Array,
                   domain_mask: jax.Array | None = None,
                   score_bias: jax.Array | None = None,
                   sparse_out: bool = False,
-                  type_tables_u=None):
-    """Try to place one gang; returns tentative post-gang state + success.
+                  type_tables_u=None,
+                  with_domains: bool = False):
+    """Try to place one gang; returns tentative post-gang state + success
+    (and, ``with_domains``, the domain each subgroup slot locked: i32 [S],
+    -1 where the domain gate chose none).
 
     Topology handling (ref ``plugins/topology`` SubsetNodesFn +
     ``topology/job_filtering.go:34``): a *required* level — gang-level
@@ -1295,7 +1319,38 @@ def _attempt_gang(state: ClusterState, gang_idx: jax.Array,
         retry_ok = ~success1 & jnp.any(sub_dom1 >= 0)
         out = lax.cond(retry_ok, lambda _: run(sub_dom1),
                        lambda _: out, None)
-    return out[:12]
+    return out[:13] if with_domains else out[:12]
+
+
+def _under_required_level(state: ClusterState) -> jax.Array:
+    """bool [G] — gangs placed under a required topology level, their
+    own (inherited into every subgroup slot at snapshot build) or a
+    subgroup's."""
+    return jnp.any(state.gangs.subgroup_required_level >= 0, axis=-1)
+
+
+def _topology_counts(state: ClusterState, before: AllocationResult,
+                     after: AllocationResult) -> jax.Array:
+    """What one action's placements did under the tree — i32 [4], the
+    slots of ``TOPOLOGY_STATS`` (slot 2, the domain gate's misses, is 0
+    here: it is counted chunk by chunk where the lanes run): gangs this
+    action attempted and bound under a required level, and the gangs it
+    bound whose placed tasks all lie in one domain of their preferred
+    level."""
+    g, n = state.gangs, state.nodes
+    has_req = _under_required_level(state)
+    tried = after.attempted & ~before.attempted
+    bound = after.allocated & ~before.allocated
+    placed = after.placements >= 0                                  # [G, T]
+    pref_dom = n.topology[jnp.maximum(after.placements, 0),
+                          jnp.maximum(g.preferred_level, 0)[:, None]]
+    first = jnp.take_along_axis(
+        pref_dom, jnp.argmax(placed, axis=-1)[:, None], axis=-1)    # [G, 1]
+    together = jnp.all(~placed | ((pref_dom == first) & (pref_dom >= 0)),
+                       axis=-1)
+    counts = (tried & has_req, bound & has_req, jnp.zeros_like(bound),
+              bound & (g.preferred_level >= 0) & together)
+    return jnp.stack([jnp.sum(c.astype(jnp.int32)) for c in counts])
 
 
 def lane_width(config: AllocateConfig, num_gangs: int) -> int:
@@ -1432,15 +1487,19 @@ def allocate(
     L = n.topology.shape[1]
     ND = n.n * L
     hoist_topo = config.uniform_tasks and config.subgroup_topology
+    # every operation of the chunk-hoisted domain tables carries this
+    # scope in its op_name (docs/TRACING.md)
+    topo_scope = functools.partial(jax.named_scope, "topology_tables")
     if hoist_topo:
         # domain-id → topology level (the global dense id space spans
         # all levels; each id belongs to exactly one)
-        level_of_dom = jnp.full((ND + 1,), -1, jnp.int32)
-        for lvl in range(L):
-            ids_l = jnp.where(n.valid & (n.topology[:, lvl] >= 0),
-                              n.topology[:, lvl], ND)
-            level_of_dom = level_of_dom.at[ids_l].set(lvl)
-        level_of_dom = level_of_dom[:ND]
+        with topo_scope():
+            level_of_dom = jnp.full((ND + 1,), -1, jnp.int32)
+            for lvl in range(L):
+                ids_l = jnp.where(n.valid & (n.topology[:, lvl] >= 0),
+                                  n.topology[:, lvl], ND)
+                level_of_dom = level_of_dom.at[ids_l].set(lvl)
+            level_of_dom = level_of_dom[:ND]
 
     if hoist_topo:
         Y = g.type_req.shape[0]
@@ -1592,7 +1651,8 @@ def allocate(
                              extended_releasing_extra,
                              topo_tables=topo_tables,
                              domain_mask=dmask, sparse_out=sparse,
-                             type_tables_u=utables)
+                             type_tables_u=utables,
+                             with_domains=config.subgroup_topology)
 
     def cond(carry):
         return jnp.any(carry[1]) & (carry[4] > 0)
@@ -1672,8 +1732,9 @@ def allocate(
         if hoist_topo:
             # live caps (incrementally maintained), live fullest-first
             # order (one single-key argsort per chunk)
-            order_by_agg = jnp.argsort(
-                jnp.where(level_of_dom >= 0, dom_agg, jnp.inf))
+            with topo_scope():
+                order_by_agg = jnp.argsort(
+                    jnp.where(level_of_dom >= 0, dom_agg, jnp.inf))
             tables = (dom_caps_y, level_of_dom, order_by_agg)
         else:
             tables = None
@@ -1710,12 +1771,22 @@ def allocate(
             devt_b = jnp.full((B, T), -1, jnp.int32)
         else:
             (free2_b, dev2_b, qa2_b, qan2_b, nodes_b, devt_b, pipe_b,
-             succ_b, bind_b, devbind_b, ext2_b, extbind_b) = \
+             succ_b, bind_b, devbind_b, ext2_b, extbind_b, *subdom_b) = \
                 jax.vmap(attempt_one,
                          in_axes=(0, 0, 0, 0, dmask_ax, None, None, None,
                                   None, None, None, None))(
                     cand, lanes, prior_b, quota_b, dmask_b, free, dev, qa,
                     qan, ext, tables, utables)
+        if config.subgroup_topology:
+            # the domain gate's miss: a live lane under a required level
+            # whose attempt failed with no domain locked in any slot
+            # (the sparse lanes never run with a required level)
+            has_req_b = _under_required_level(state)[
+                jnp.minimum(cand, G - 1)]
+            miss_b = (cand_valid & has_req_b & ~succ_b
+                      & jnp.all(subdom_b[0] < 0, axis=-1))
+            res = res.replace(topology_stats=res.topology_stats.at[2].add(
+                jnp.sum(miss_b.astype(jnp.int32))))
         # a same-group duplicate lane is CONFLICT-rejected (retries next
         # chunk), never counted as a genuine fit failure
         succ_all = succ_b & cand_valid
@@ -1874,9 +1945,10 @@ def allocate(
                 state, res.anti_used, dom_static, cand, nodes_b, take))
         out = (res, remaining, q_attempts, failed_sig, fuel - 1)
         if hoist_topo:
-            dom_caps_y, dom_agg, c_y_store = topo_tables_update(
-                dom_caps_y, dom_agg, c_y_store, res.free,
-                take, cand, nodes_b)
+            with topo_scope():
+                dom_caps_y, dom_agg, c_y_store = topo_tables_update(
+                    dom_caps_y, dom_agg, c_y_store, res.free,
+                    take, cand, nodes_b)
             out = out + (dom_caps_y, dom_agg, c_y_store)
         return out
 
@@ -1888,10 +1960,15 @@ def allocate(
     carry0 = (init, remaining0, jnp.zeros((q.q,), jnp.int32),
               jnp.zeros((G,), bool), jnp.asarray(G * (T + 1), jnp.int32))
     if hoist_topo:
-        carry0 = carry0 + topo_tables_build(init.free)
+        with topo_scope():
+            carry0 = carry0 + topo_tables_build(init.free)
     with jax.named_scope("placement_loop"):
         out = lax.while_loop(cond, chunk, carry0)
-    return out[0]
+    res = out[0]
+    if config.subgroup_topology or config.preferred_topology:
+        res = res.replace(topology_stats=res.topology_stats
+                          + _topology_counts(state, init, res))
+    return res
 
 
 @functools.partial(jax.jit, static_argnames=("num_levels", "config"))

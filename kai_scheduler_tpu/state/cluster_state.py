@@ -602,6 +602,9 @@ class SnapshotIndex:
     selector_keys: list[str]
     label_vocab: dict[tuple[str, str], int]
     topology_levels: list[str]
+    #: dense topology domain ids in use over all levels (the nodes'
+    #: distinct label paths); ``Session.kernels`` reports it
+    topology_domains: int = 0
     #: snapshot-derived kernel-config hints (see AllocateConfig): whether
     #: any fractional/memory-based accel request exists (device table
     #: needed), whether every gang's pending tasks are identical replicas
@@ -1861,6 +1864,7 @@ def _encode_snapshot(
         selector_keys=selector_keys,
         label_vocab=label_vocab,
         topology_levels=topo_levels,
+        topology_domains=len(domain_vocab),
         needs_device_table=has_fracs,
         uniform_gangs=uniform,
         has_required_topology=bool((gk["required_level"] >= 0).any()),

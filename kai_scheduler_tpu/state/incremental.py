@@ -1227,8 +1227,6 @@ class IncrementalSnapshotter:
                     or cached[4] is not n.extended
                     or cached[5] != n.accel_memory_gib):
                 raise _Fallback("node-drift")
-        if cluster.topology is not self._topology:
-            raise _Fallback("topology-drift")
 
     def _patch(self, cluster, j, now, queue_usage):
         with self._span("patch.journal"):
@@ -1625,6 +1623,8 @@ class IncrementalSnapshotter:
             selector_keys=list(vocab.selector_keys),
             label_vocab=vocab.label_vocab,
             topology_levels=self._topo_levels,
+            # the node section is the rebuild's: so are its domains
+            topology_domains=self._index.topology_domains,
             needs_device_table=has_fracs,
             uniform_gangs=uniform,
             has_required_topology=bool((req_lvl >= 0).any()),
@@ -1896,7 +1896,7 @@ class IncrementalSnapshotter:
         mine_i, ref_i = self._index, fresh_index
         for field in ("node_names", "queue_names", "gang_names",
                       "task_names", "running_pod_names", "selector_keys",
-                      "label_vocab", "topology_levels",
+                      "label_vocab", "topology_levels", "topology_domains",
                       "needs_device_table", "uniform_gangs",
                       "has_required_topology", "has_preferred_topology",
                       "has_subgroup_topology", "has_extended_resources",

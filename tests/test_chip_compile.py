@@ -9,9 +9,10 @@ shape where the abort reproduced, the fifth on the non-dense placement
 path (selectors, a filter class, feasible-rank tie-break) that a cluster
 of tainted pools takes and the CPU alone had run before PR 28, the sixth
 on the per-task placement kernel that declared subgroups and gangs of
-unequal pods take (Kubeflow's jobs; PR 32).  Nothing runs: a compile
-that passes says what the compiler accepts, never what
-the chip does.
+unequal pods take (Kubeflow's jobs; PR 32), the seventh on the
+whole-gang kernel under a topology tree (required and preferred levels,
+the hoisted domain tables; PR 34).  Nothing runs: a compile that passes
+says what the compiler accepts, never what the chip does.
 
 Only one process at a time may load the TPU library, and it keeps it
 until it exits: the topology is described inside a module-scoped
@@ -114,6 +115,34 @@ def saturated_kubeflow():
     return Session.open(nodes, queues, groups, pods, topology)
 
 
+@pytest.fixture(scope="module")
+def saturated_tree():
+    """``saturated`` under a block / rack / host tree (2 blocks x 4
+    racks x 8 nodes): the running gangs ask for the rack; the pending
+    ones have 8 pods and ask for the rack, or lose half their pods and
+    ask for the block and prefer the rack.  Gangs of unequal size, each
+    of equal pods, none with subgroups: the whole-gang kernel with the
+    domain lock and the preferred band."""
+    from kai_scheduler_tpu.apis import types as apis
+    from kai_scheduler_tpu.framework.session import Session
+    from kai_scheduler_tpu.state import make_cluster
+    nodes, queues, groups, pods, topology = make_cluster(
+        num_nodes=64, node_accel=4.0, num_gangs=40, tasks_per_gang=8,
+        running_fraction=0.8, queue_accel_quota=6.4,
+        partition_queues_by_running=True, topology_levels=(2, 4),
+        required_level="topo/level1", seed=0)
+    pending = [g for g in groups if g.last_start_timestamp is None]
+    for group in pending[::2]:
+        group.min_member = 4
+        group.topology_constraint = apis.TopologyConstraint(
+            topology="default", required_level="topo/level0",
+            preferred_level="topo/level1")
+    small = {g.name for g in pending[::2]}
+    pods = [p for p in pods if not (
+        p.group in small and int(p.name.rsplit("-", 1)[1]) >= 4)]
+    return Session.open(nodes, queues, groups, pods, topology)
+
+
 def _shapes(tree, sharding):
     """ShapeDtypeStructs of ``tree`` placed by ``sharding`` — one
     sharding for every leaf, or a matching pytree of them."""
@@ -180,6 +209,30 @@ def test_fused_five_actions_compile_per_task(topo, saturated_kubeflow):
     assert not ses.config.allocate.uniform_tasks
     assert not ses.config.victims.placement.uniform_tasks
     assert ses.state.gangs.s >= 3
+    one = SingleDeviceSharding(topo.devices[0])
+    st = _shapes(ses.state, one)
+    compiled = S._fused_pipeline.__kai_jit__.lower(
+        st, st.queues.fair_share, **_pipeline_kwargs(ses.config)).compile()
+    _fits_one_chip(compiled)
+
+
+def test_fused_five_actions_compile_topology(topo, saturated_tree):
+    """The same entry over a three-level tree: rack-required gangs of 8
+    and block-required, rack-preferred gangs of 4, so allocate and every
+    victim placement compile the whole-gang kernel with the hoisted
+    domain tables (``topology_tables``), the lane-spread domain pick
+    (``domain_pick``) and the preferred band — the CPU alone had run
+    them before PR 34."""
+    from kai_scheduler_tpu.framework import scheduler as S
+    ses = saturated_tree
+    assert ses.index.uniform_gangs and ses.state.nodes.topology.shape[1] == 3
+    for cfg in (ses.config.allocate, ses.config.victims.placement):
+        assert cfg.uniform_tasks and not cfg.dense_feasibility
+        assert cfg.subgroup_topology and cfg.preferred_topology
+    sizes = set(ses.state.gangs.min_needed[
+        ses.state.gangs.valid].tolist())
+    assert sizes == {4, 8}
+    assert ses.kernels()["topology_domains"] == 2 + 8 + 64
     one = SingleDeviceSharding(topo.devices[0])
     st = _shapes(ses.state, one)
     compiled = S._fused_pipeline.__kai_jit__.lower(

@@ -1279,8 +1279,9 @@ def _drop_node_unseen(c):
 
 #: reason -> (what the cluster is built with, what is done to it before
 #: the first refresh, the named mutation, what undoes a lasting cause).
-#: ``topology-drift`` is absent by design: ``_patch_blockers`` names the
-#: same condition ``topology-changed`` before the sweep can see it.
+#: A replaced ``cluster.topology`` has one name, ``topology-changed``:
+#: ``_patch_blockers`` tests it before the sweep runs, and the sweep
+#: does not test it again.
 _REFUSALS = {
     "vocab-residue": ({}, _node_with_mig, None, _node_without_mig),
     "vocab-growth": ({}, None, _submit_tolerating, None),
