@@ -505,7 +505,7 @@ class Session:
             moves_all = (None if victim_move is None
                          else np.asarray(victim_move))
             gang_all = np.asarray(self.state.running.gang)
-        mask[len(self.index.running_pod_names):] = False
+        mask[len(self.index.running_pod_names_arr):] = False
         mi = np.nonzero(mask)[0]
         names = self.index.running_pod_names_arr[mi]
         keep = names != ""

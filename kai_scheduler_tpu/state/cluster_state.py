@@ -518,52 +518,80 @@ def build_queue_tables(queues: list[apis.Queue], Q: int) -> dict:
 
 def derive_rollups(*, node_alloc, claim_used, rk, gk, g_of_ext, r_mig,
                    queue_usage, q_index, q_parent, q_depth,
-                   num_queues) -> dict:
+                   num_queues, kept=None, touched_nodes=None,
+                   touched_queues=None) -> dict:
     """Derived node free/releasing + queue allocated/request/usage
     rollups — the host mirror of the queuecontroller status (vectorized
     scatter-adds over the running/pending tables).  Shared verbatim by
     the full build and the incremental patch path so both derive
     bit-identical ledgers from the same section tables.
+
+    Every table here is keyed by a node or a leaf queue, and a key's
+    entry is the sum of its members in row order.  The build derives
+    every key.  The patch hands back last cycle's ``kept`` tables (the
+    ``kept`` of the result: per node and, before the parents are added,
+    per queue) with the ``[N]`` / ``[Q]`` masks of the keys a dirty row
+    feeds, and only those entries are summed again, over their members
+    alone — a subset in row order adds the same numbers in the same
+    order, which ``old - before + after`` would not.  A table no
+    touched key feeds comes back as the object it was.
     """
     N = node_alloc.shape[0]
     Q = q_parent.shape[0]
-    node_used = np.zeros((N, R), np.float32)
-    node_rel = np.zeros((N, R), np.float32)
-    on_node = rk["valid"] & (rk["node"] >= 0)
-    rel_m = on_node & rk["releasing"]
-    used_m = on_node & ~rk["releasing"]
-    # unknown nodes count for queues, not for node capacity
-    np.add.at(node_rel, rk["node"][rel_m], rk["req"][rel_m])
-    np.add.at(node_used, rk["node"][used_m], rk["req"][used_m])
-    node_free = np.maximum(
-        node_alloc - node_used - node_rel - claim_used, 0.0)
-
-    q_alloc = np.zeros((Q, R), np.float32)
-    q_alloc_np = np.zeros((Q, R), np.float32)
-    q_request = np.zeros((Q, R), np.float32)
-    vmask = rk["valid"]
-    np.add.at(q_alloc, rk["queue"][vmask], rk["req"][vmask])
-    np_mask = vmask & ~rk["preemptible"]
-    np.add.at(q_alloc_np, rk["queue"][np_mask], rk["req"][np_mask])
-    # The MIG g-equivalents enter the rollups — REQUESTED amounts, not
-    # the capacity-clamped held table (rk["extended"]): like the
-    # core-resource path, a running MIG pod on an unknown/overcommitted
-    # node still counts toward its queue's ledger.
-    if g_of_ext.any():
-        np.add.at(q_alloc[:, 0], rk["queue"][vmask], r_mig[vmask])
-        np.add.at(q_alloc_np[:, 0], rk["queue"][np_mask],
-                  r_mig[np_mask])
-    q_request += q_alloc
-    pending_req = (gk["task_req"]
-                   * gk["task_valid"][:, :, None]).sum(axis=1)  # [G, R]
-    np.add.at(q_request, gk["queue"][gk["valid"]],
-              pending_req[gk["valid"]])
-    if g_of_ext.any():
-        g_mig = ((gk["task_extended"]
-                  * gk["task_valid"][:, :, None]).sum(axis=1)
-                 @ g_of_ext)                                    # [G]
-        np.add.at(q_request[:, 0], gk["queue"][gk["valid"]],
-                  g_mig[gk["valid"]])
+    if kept is None:
+        kept = dict(
+            {k: np.zeros((N, R), np.float32)
+             for k in ("node_used", "node_rel", "node_free")},
+            **{k: np.zeros((Q, R), np.float32)
+               for k in ("q_alloc", "q_alloc_np", "q_request")})
+        touched_nodes = np.ones((N,), bool)
+        touched_queues = np.ones((Q,), bool)
+    out = dict(kept)
+    tn = np.flatnonzero(touched_nodes)
+    if len(tn):
+        node_used, node_rel = (_zeroed(kept[k], tn)
+                               for k in ("node_used", "node_rel"))
+        # unknown nodes count for queues, not for node capacity
+        on = np.flatnonzero(rk["valid"] & (rk["node"] >= 0)
+                            & touched_nodes[rk["node"]])
+        rel_m = on[rk["releasing"][on]]
+        used_m = on[~rk["releasing"][on]]
+        np.add.at(node_rel, rk["node"][rel_m], rk["req"][rel_m])
+        np.add.at(node_used, rk["node"][used_m], rk["req"][used_m])
+        node_free = kept["node_free"].copy()
+        node_free[tn] = np.maximum(
+            node_alloc[tn] - node_used[tn] - node_rel[tn]
+            - claim_used[tn], 0.0)
+        out.update(node_used=node_used, node_rel=node_rel,
+                   node_free=node_free)
+    tq = np.flatnonzero(touched_queues)
+    if len(tq):
+        q_alloc, q_alloc_np, q_request = (
+            _zeroed(kept[k], tq)
+            for k in ("q_alloc", "q_alloc_np", "q_request"))
+        vmask = np.flatnonzero(rk["valid"] & touched_queues[rk["queue"]])
+        np.add.at(q_alloc, rk["queue"][vmask], rk["req"][vmask])
+        np_mask = vmask[~rk["preemptible"][vmask]]
+        np.add.at(q_alloc_np, rk["queue"][np_mask], rk["req"][np_mask])
+        # The MIG g-equivalents enter the rollups — REQUESTED amounts,
+        # not the capacity-clamped held table (rk["extended"]): like the
+        # core-resource path, a running MIG pod on an unknown/
+        # overcommitted node still counts toward its queue's ledger.
+        if g_of_ext.any():
+            np.add.at(q_alloc[:, 0], rk["queue"][vmask], r_mig[vmask])
+            np.add.at(q_alloc_np[:, 0], rk["queue"][np_mask],
+                      r_mig[np_mask])
+        q_request[tq] += q_alloc[tq]
+        gm = np.flatnonzero(gk["valid"] & touched_queues[gk["queue"]])
+        task_valid = gk["task_valid"][gm][:, :, None]
+        pending_req = (gk["task_req"][gm] * task_valid).sum(axis=1)
+        np.add.at(q_request, gk["queue"][gm], pending_req)
+        if g_of_ext.any():
+            g_mig = ((gk["task_extended"][gm] * task_valid).sum(axis=1)
+                     @ g_of_ext)                                # [gm]
+            np.add.at(q_request[:, 0], gk["queue"][gm], g_mig)
+        out.update(q_alloc=q_alloc, q_alloc_np=q_alloc_np,
+                   q_request=q_request)
     # historical usage (usagedb feed), normalized usage/clusterCapacity —
     # the k_value term of the DRF waterfill (ref usagedb.go:20-60)
     q_usage = np.zeros((Q, R), np.float32)
@@ -572,20 +600,35 @@ def derive_rollups(*, node_alloc, claim_used, rk, gk, g_of_ext, r_mig,
             qi2 = q_index.get(qname)
             if qi2 is not None:
                 q_usage[qi2] = np.asarray(vec, np.float32)
-    # propagate to parents (requests/allocations roll up the hierarchy)
-    for arr in (q_alloc, q_alloc_np, q_request, q_usage):
-        for i in sorted(range(num_queues), key=lambda i: -q_depth[i]):
-            p = q_parent[i]
-            if p >= 0:
-                arr[p] += arr[i]
-    return dict(node_rel=node_rel, node_free=node_free, q_alloc=q_alloc,
-                q_alloc_np=q_alloc_np, q_request=q_request,
-                q_usage=q_usage)
+    # propagate to parents (requests/allocations roll up the hierarchy):
+    # the deepest level first, a level's queues in index order
+    rolled = {"q_usage": q_usage,
+              **{k: out[k].copy()
+                 for k in ("q_alloc", "q_alloc_np", "q_request")}}
+    depth = q_depth[:num_queues]
+    for d in range(int(depth.max(initial=0)), 0, -1):
+        level = np.flatnonzero(depth == d)
+        for arr in rolled.values():
+            np.add.at(arr, q_parent[level], arr[level])
+    return dict(node_rel=out["node_rel"], node_free=out["node_free"],
+                kept=out, **rolled)
+
+
+def _zeroed(table: np.ndarray, rows: np.ndarray) -> np.ndarray:
+    """A copy of ``table`` with ``rows`` zero, to be summed again."""
+    table = table.copy()
+    table[rows] = 0.0
+    return table
 
 
 # ---------------------------------------------------------------------------
 # Snapshot builder (host): api objects -> ClusterState
 # ---------------------------------------------------------------------------
+
+#: ``SnapshotIndex``'s long name tables and the view each can be made of
+_NAMES_FROM_ARR = {"task_names": "task_names_arr",
+                   "running_pod_names": "running_pod_names_arr"}
+
 
 @dataclasses.dataclass
 class SnapshotIndex:
@@ -596,9 +639,12 @@ class SnapshotIndex:
     node_names: list[str]
     queue_names: list[str]
     gang_names: list[str]
-    #: task pod names per gang slot, [G][T]
-    task_names: list[list[str | None]]
-    running_pod_names: list[str]
+    #: task pod names per gang slot, [G][T]; this and
+    #: ``running_pod_names`` may be handed over as ``None`` with the
+    #: ``*_arr`` view seeded in its place (the patch keeps the names as
+    #: object arrays): the list is then made when something reads it
+    task_names: list[list[str | None]] | None
+    running_pod_names: list[str] | None
     selector_keys: list[str]
     label_vocab: dict[tuple[str, str], int]
     topology_levels: list[str]
@@ -655,6 +701,19 @@ class SnapshotIndex:
     #: (``selector_keys`` and ``label_vocab`` above are its first two)
     vocabulary: SnapshotVocabulary = dataclasses.field(
         default_factory=SnapshotVocabulary)
+
+    def __post_init__(self):
+        for name in _NAMES_FROM_ARR:
+            if self.__dict__[name] is None:
+                del self.__dict__[name]
+
+    def __getattr__(self, name: str):
+        # only reached for a name table that was handed over as None
+        arr = _NAMES_FROM_ARR.get(name)
+        if arr is None or arr not in self.__dict__:
+            raise AttributeError(name)
+        names = self.__dict__[name] = self.__dict__[arr].tolist()
+        return names
 
     def node_index(self, name: str) -> int:
         return self.node_names.index(name)

@@ -25,9 +25,12 @@ Three pieces:
   per-section builders factored out of ``build_snapshot``
   (``build_queue_tables``/``derive_rollups`` are shared verbatim; the
   pending-task and running-pod sections are re-assembled from cached
-  per-entity encodes with vectorized numpy).  Only changed leaves ship
-  to the device; unchanged leaves reuse the previous cycle's device
-  buffers.
+  per-entity encodes with vectorized numpy).  The tables keyed by a
+  gang, a node or a leaf queue (running counts, device cells, the
+  rollups) are kept from patch to patch, and only the entries of the
+  keys a dirty row feeds are summed again, from their members in row
+  order (``_rederive``).  Only changed leaves ship to the device;
+  unchanged leaves reuse the previous cycle's device buffers.
 
 - Automatic **fallback to the full rebuild** whenever a patch cannot be
   proven bit-identical to a fresh ``build_snapshot``:
@@ -92,7 +95,7 @@ import numpy as np
 
 from ..apis import types as apis
 from ..runtime import wire_ledger as _wire
-from ..runtime.tracing import span_of
+from ..runtime.tracing import SpanSections, span_of
 from . import cluster_state as _cs
 from . import node_filters
 from .cluster_state import (
@@ -389,6 +392,10 @@ _POD_COLUMNS = (
     ("p_hasdev", bool, False, ()), ("p_eff_status", np.int8, -1, ()),
     ("p_eff_node", np.int32, -1, ()), ("p_iid", np.int32, -1, ()),
     ("p_ti", np.int32, -1, ()), ("p_sub", np.int32, 0, ()),
+    # what ``_occupancy`` gave a running pod (``devices_mask`` and
+    # ``accel_held``): kept by pod because the running section is
+    # positional, and derived again when the pod's node is touched
+    ("p_occ_mask", np.int32, 0, ()), ("p_occ_held", np.float32, 0.0, ()),
 )
 
 
@@ -471,6 +478,13 @@ class IncrementalSnapshotter:
         self._vocabulary = SnapshotVocabulary()
         #: the last rebuild's irregular vocabularies (``stats.last``)
         self._built_vocab: dict = {}
+        #: the patch's derived tables as the last patch left them, by
+        #: gang (``running_count``, ``sub_running``), by node
+        #: (``dev_free``, ``dev_rel`` and ``derive_rollups``' own) and
+        #: by queue before the parents are added: ``_rederive`` derives
+        #: the touched keys' entries again and takes the rest from here
+        self._kept: dict | None = None
+        self._forget_touched()
 
     def _add_span(self, name: str, start: float, **attrs) -> None:
         if self._tracer is not None:
@@ -528,6 +542,9 @@ class IncrementalSnapshotter:
                     "filtered_pods": self._last_filtered,
                     "subgrouped_pods": self._last_subgrouped,
                     "subgrouped_gangs": self._subgrouped_gangs,
+                    # the keys whose entries were derived again, and
+                    # the running rows that fed one
+                    **self._last_rederived,
                 }
                 patch_sp.attrs.update(self.stats.last)
                 if self.verify:
@@ -657,8 +674,10 @@ class IncrementalSnapshotter:
     def _rebuild_ledgers(self, cluster, lists, host, index) -> None:
         nodes, queues, groups, pods, topology = lists
         self._topology = cluster.topology
+        self._kept = None
         # --- node-section caches (valid until any node is dirty) ---------
         self._node_names = index.node_names
+        self._node_names_arr = np.array(index.node_names, dtype=object)
         self._node_index = {n: i for i, n in enumerate(index.node_names)}
         live_nodes = [n for n in nodes if not n.unschedulable]
         self._node_objs = live_nodes
@@ -770,6 +789,12 @@ class IncrementalSnapshotter:
             task_dra=np.asarray(g.task_dra),
             ext_accel=np.asarray(g.ext_accel),
         )
+        self._forget_touched()
+        # where a patch can follow (``_patch_blockers``), seed what it
+        # keeps; elsewhere its derivation has nothing to say (the
+        # irregular intake paths are the builder's alone)
+        if self._clean and not (self._nonplain or self._present_twice):
+            self._seed_kept(host)
 
     def _seed_task_slots(self, host) -> None:
         """Recover per-pod (gang, slot) assignments from the built task
@@ -807,9 +832,13 @@ class IncrementalSnapshotter:
             mine = np.flatnonzero(self.p_live & (self.p_group == i))
             for row in mine.tolist():
                 self.p_sub[row] = self._slot_of(i, self.p_objs[row])
+        if prev is not None:
+            self._touched_queues.add(int(self.g_queue[i]))
         self.g_objs[i] = g
         self.g_names[i] = g.name
         self.g_queue[i] = self._q_index.get(g.queue, 0)
+        self._touched_queues.add(int(self.g_queue[i]))
+        self._touched_gangs.add(i)
         self.g_minm[i] = g.min_member
         self.g_prio[i] = g.priority
         self.g_preempt[i] = (
@@ -849,6 +878,10 @@ class IncrementalSnapshotter:
         was_plain = bool(self.p_plain[row]) if self.p_live[row] else True
         was_twice = bool(self.p_live[row]
                          and self.p_eff_status[row] == -2)
+        if self.p_live[row]:
+            self._touch_row(row)
+        self.p_occ_mask[row] = 0
+        self.p_occ_held[row] = 0.0
         self.p_objs[row] = pod
         self.p_names[row] = pod.name
         self.p_live[row] = True
@@ -907,6 +940,30 @@ class IncrementalSnapshotter:
         if iid is None:
             iid = self._intern_add(key)
         self.p_iid[row] = iid
+        self._touch_row(row)
+
+    def _touch_row(self, row) -> None:
+        """The keys that live row ``row`` feeds as it stands — its
+        node, its gang, the queue its request is booked to — are
+        derived again this cycle.  Called on what a row held before it
+        is encoded anew or released, and on what it holds after: a
+        key's entry depends on its members alone, so these are all the
+        entries a change of the row can move."""
+        node, gi = int(self.p_eff_node[row]), int(self.p_group[row])
+        if node >= 0:
+            self._touched_nodes.add(node)
+        if gi >= 0:
+            self._touched_gangs.add(gi)
+        # a running pod without a gang is booked to queue 0
+        self._touched_queues.add(int(self.g_queue[gi]) if gi >= 0 else 0)
+
+    def _forget_touched(self) -> None:
+        #: node rows, gang rows and queue rows to derive again, or
+        #: every key: what ``_touched_masks`` hands over
+        self._touched_nodes: set[int] = set()
+        self._touched_gangs: set[int] = set()
+        self._touched_queues: set[int] = set()
+        self._touch_all = False
 
     def _slot_of(self, gi: int, pod: apis.Pod) -> int:
         """The pod's subgroup slot in gang row ``gi``, as the builder
@@ -941,6 +998,7 @@ class IncrementalSnapshotter:
     def _release_pod(self, row) -> None:
         if not self.p_live[row]:
             return
+        self._touch_row(row)
         self.p_live[row] = False
         self._nonplain -= int(not self.p_plain[row])
         self._present_twice -= int(self.p_eff_status[row] == -2)
@@ -968,6 +1026,7 @@ class IncrementalSnapshotter:
         keep = self.p_live[:len(self.p_objs)]
         src = np.flatnonzero(keep)
         n = len(src)
+        self._touch_all = True
         remap = np.cumsum(keep) - 1
         kept = keep.tolist()
         self.p_objs = list(itertools.compress(self.p_objs, kept))
@@ -1042,8 +1101,10 @@ class IncrementalSnapshotter:
             for row in unresolved.tolist():
                 gi = self._gang_index.get(self.p_objs[row].group, -1)
                 if gi >= 0:
+                    self._touch_row(row)
                     self.p_group[row] = gi
                     self.p_sub[row] = self._slot_of(gi, self.p_objs[row])
+                    self._touch_row(row)
                     dirty_rows.add(row)
                     dirty_gangs.add(gi)
         for name in j.gangs_dirty:
@@ -1122,6 +1183,8 @@ class IncrementalSnapshotter:
         src = np.flatnonzero(keep)
         remap = np.where(keep, np.cumsum(keep) - 1, -1).astype(np.int32)
         self._subgrouped_gangs -= sum(bool(self.g_subs[i]) for i in gone)
+        # a gang that goes takes its pending request out of its queue
+        self._touched_queues.update(self.g_queue[gone].tolist())
         kept = keep.tolist()
         for name in _GANG_LISTS:
             setattr(self, name,
@@ -1130,15 +1193,16 @@ class IncrementalSnapshotter:
         for name, _dtype, _fill, _wide in _GANG_COLUMNS:
             col = getattr(self, name)
             col[:len(src)] = col[src]
+        for rows in (dirty_gangs, self._touched_gangs):
+            moved = [int(remap[i]) for i in rows if keep[i]]
+            rows.clear()
+            rows.update(moved)
         had = self.p_group >= 0
         self.p_group[had] = remap[self.p_group[had]]
         orphans = np.flatnonzero(self.p_live & had & (self.p_group < 0))
         for row in orphans.tolist():
             self._encode_pod(row, self.p_objs[row], cluster)
             dirty_rows.add(row)
-        moved = [int(remap[i]) for i in dirty_gangs if keep[i]]
-        dirty_gangs.clear()
-        dirty_gangs.update(moved)
         return src, len(gone)
 
     def _sweep(self, cluster, dirty_rows: set, dirty_gangs: set) -> None:
@@ -1256,19 +1320,30 @@ class IncrementalSnapshotter:
         if now is None:
             order = self._order
             now = float(self.p_crea[order].max()) if len(order) else 0.0
+        sections = SpanSections(self._tracer)
         with self._span("patch.assemble"):
-            return self._assemble(
-                cluster, dirty_gangs, gang_src, now, queue_usage,
-                host_old)
+            try:
+                return self._assemble(
+                    cluster, dirty_gangs, gang_src, now, queue_usage,
+                    host_old, sections)
+            finally:
+                sections.close()
 
     # -- assembly ----------------------------------------------------------
 
     def _assemble(self, cluster, dirty_gangs, gang_src, now, queue_usage,
-                  old):
+                  old, sections):
+        """The host ``ClusterState`` and index of a patched cycle.
+        ``sections(name)`` marks where each group of sections starts:
+        ``assemble.gather`` (the ledger's columns through ``order`` and
+        ``run_rows``), ``assemble.tables`` (what is built whole each
+        cycle), ``assemble.rederive`` (what is kept and derived again by
+        key), ``assemble.index``."""
         cap = self._capacity
         G, T = cap.gangs, cap.tasks
         N, Q, M = cap.nodes, cap.queues, cap.running
         NG = len(self.g_objs)
+        sections("assemble.gather")
         order = self._order
         eff = self.p_eff_status[order]
         grp_all = self.p_group[order]
@@ -1284,6 +1359,7 @@ class IncrementalSnapshotter:
         self._last_filtered = int(filtered[iid_all].sum())
         self._last_subgrouped = int(np.count_nonzero(self.p_sub[order]))
         # --- queues (always re-encoded; tiny) ----------------------------
+        sections("assemble.tables")
         queues = list(cluster.queues.values())
         qt = build_queue_tables(queues, Q)
         if qt["queue_names"] != self._queue_names:
@@ -1416,6 +1492,7 @@ class IncrementalSnapshotter:
                                            0.0)),
             -1.0).astype(np.float32)
         # --- running section ---------------------------------------------
+        sections("assemble.gather")
         run_sel = (eff >= _BOUND) & (eff <= _RELEASING)
         run_rows = order[run_sel]
         Mu = len(run_rows)
@@ -1446,8 +1523,6 @@ class IncrementalSnapshotter:
             extended=np.zeros((M, np.asarray(old.running.extended
                                              ).shape[1]), np.float32),
         )
-        running_count = np.zeros((G,), np.int32)
-        sub_running = np.zeros((G, S), np.int32)
         if Mu:
             rk["req"][:Mu] = r_req
             rk["node"][:Mu] = r_node
@@ -1468,11 +1543,21 @@ class IncrementalSnapshotter:
                 rk["runtime_s"][:Mu] = np.where(
                     has_grp & (started >= 0),
                     np.maximum(0.0, now - started), -1.0)
-            active = has_grp & ~r_rel
-            np.add.at(running_count, gsafe[active], 1)
-            np.add.at(sub_running,
-                      (gsafe[active], self.p_sub[run_rows[active]]), 1)
-        self._occupancy(rk, run_rows, r_node, r_rel, N)
+        # --- what is kept, and derived again by key ------------------------
+        sections("assemble.rederive")
+        gk_roll = dict(task_req=task_req, task_valid=task_valid,
+                       queue=queue_col, valid=gk_valid,
+                       task_extended=self._const["task_extended"])
+        roll = self._rederive(
+            gang_src, rk, gk_roll, run_rows, r_node, r_grp, r_rel,
+            node_alloc=np.asarray(old.nodes.allocatable),
+            queue_usage=queue_usage, qt=qt)
+        kept = self._kept
+        running_count = kept["running_count"]
+        sub_running = kept["sub_running"]
+        rk["devices_mask"][:Mu] = self.p_occ_mask[run_rows]
+        rk["accel_held"][:Mu] = self.p_occ_held[run_rows]
+        sections("assemble.tables")
         min_needed = np.maximum(min_member - running_count, 0)
         sub_min_needed = np.maximum(sub_minm - sub_running, 0)
         # --- scheduling signatures (same code as the builder) ------------
@@ -1492,19 +1577,6 @@ class IncrementalSnapshotter:
             (~gk_valid[:, None]).astype(np.int64),
         ], axis=1, dtype=np.int64)
         sig = dense_row_ids(sig_mat).astype(np.int32)
-        # --- rollups (shared section builder) ----------------------------
-        gk_roll = dict(task_req=task_req, task_valid=task_valid,
-                       queue=queue_col, valid=gk_valid,
-                       task_extended=self._const["task_extended"])
-        roll = derive_rollups(
-            node_alloc=np.asarray(old.nodes.allocatable),
-            claim_used=np.zeros((N, R), np.float32),
-            rk=rk, gk=gk_roll,
-            g_of_ext=self._const["ext_accel"],
-            r_mig=np.zeros((M,), np.float32),
-            queue_usage=queue_usage, q_index=qt["q_index"],
-            q_parent=qt["q_parent"], q_depth=qt["q_depth"],
-            num_queues=len(queues))
         # --- hints (same expressions as the builder) ---------------------
         has_fracs = bool(self._const["task_portion"].any()
                          or self._const["task_accel_mem"].any()
@@ -1602,24 +1674,27 @@ class IncrementalSnapshotter:
             free=sw(roll["node_free"], np.asarray(old.nodes.free)),
             releasing=sw(roll["node_rel"],
                          np.asarray(old.nodes.releasing)),
-            device_free=sw(self._occ_dev_free,
+            device_free=sw(kept["dev_free"],
                            np.asarray(old.nodes.device_free)),
-            device_releasing=sw(self._occ_dev_rel,
+            device_releasing=sw(kept["dev_rel"],
                                 np.asarray(old.nodes.device_releasing)),
         )
         host_new = _cs.ClusterState(
             nodes=nodes_st, queues=queues_st, gangs=gangs,
             running=running)
         # --- index --------------------------------------------------------
-        running_names = [""] * M
-        if Mu:
-            running_names[:Mu] = self.p_names[run_rows].tolist()
+        sections("assemble.index")
+        running_names = np.full((M,), "", object)
+        running_names[:Mu] = self.p_names[run_rows]
+        # the two long name tables go as the columnar views the commit
+        # path gathers from (seeded below); their list forms are made
+        # when something reads them
         index = _cs.SnapshotIndex(
             node_names=self._node_names,
             queue_names=qt["queue_names"],
             gang_names=list(self.g_names),
-            task_names=self._task_names_obj.tolist(),
-            running_pod_names=running_names,
+            task_names=None,
+            running_pod_names=None,
             selector_keys=list(vocab.selector_keys),
             label_vocab=vocab.label_vocab,
             topology_levels=self._topo_levels,
@@ -1662,11 +1737,124 @@ class IncrementalSnapshotter:
         )
         # pre-seed the columnar name views (cached_property slots)
         index.task_names_arr = self._task_names_obj
-        index.node_names_arr = np.array(self._node_names, dtype=object)
-        index.gang_names_arr = np.array(index.gang_names, dtype=object)
-        index.running_pod_names_arr = np.array(running_names,
-                                               dtype=object)
+        index.node_names_arr = self._node_names_arr
+        index.running_pod_names_arr = running_names
         return host_new, index
+
+    # -- the kept tables, derived again by key ------------------------------
+
+    def _touched_masks(self) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """``[N]``, ``[G]`` and ``[Q]`` masks of the keys to derive again
+        — those ``_touch_row``, ``_encode_gang`` and ``_remove_gangs``
+        named since the last patch — and every key where there is
+        nothing kept, or pod or gang rows were moved in bulk."""
+        cap = self._capacity
+        every = self._kept is None or self._touch_all
+        masks = []
+        for keys, size in ((self._touched_nodes, cap.nodes),
+                           (self._touched_gangs, cap.gangs),
+                           (self._touched_queues, cap.queues)):
+            mask = np.full((size,), every, bool)
+            mask[list(keys)] = True
+            masks.append(mask)
+        self._forget_touched()
+        return tuple(masks)
+
+    def _seed_kept(self, host) -> None:
+        """What the patch keeps, from the rebuild's own tables and the
+        ledgers just rebuilt: the derivation by key with every key
+        touched."""
+        order = self._order
+        eff = self.p_eff_status[order]
+        run_rows = order[(eff >= _BOUND) & (eff <= _RELEASING)]
+        hq = host.queues
+        self._rederive(
+            None,
+            {k: np.asarray(getattr(host.running, k)) for k in (
+                "valid", "node", "queue", "req", "releasing",
+                "preemptible")},
+            {k: np.asarray(getattr(host.gangs, k)) for k in (
+                "task_req", "task_valid", "queue", "valid",
+                "task_extended")},
+            run_rows, self.p_eff_node[run_rows], self.p_group[run_rows],
+            self.p_eff_status[run_rows] == _RELEASING,
+            node_alloc=np.asarray(host.nodes.allocatable),
+            queue_usage=None,
+            qt=dict(q_index={}, q_parent=np.asarray(hq.parent),
+                    q_depth=np.asarray(hq.depth),
+                    queue_names=self._queue_names))
+
+    def _rederive(self, gang_src, rk, gk, run_rows, r_node, r_grp, r_rel,
+                  *, node_alloc, queue_usage, qt):
+        """The tables keyed by a gang, a node or a leaf queue, out of
+        last patch's (``self._kept``): an entry depends only on the
+        running rows (and pending gangs) that belong to its key, so the
+        touched keys' entries are computed again from their members,
+        selected by a membership mask in row order, and every other
+        entry is last cycle's.  With every key touched, and nothing
+        kept, this is the whole derivation.  → ``derive_rollups``'
+        result; the rest is left in ``self._kept``, and the device
+        cells of each running pod in ``p_occ_mask`` / ``p_occ_held``."""
+        t_nodes, t_gangs, t_queues = self._touched_masks()
+        cap = self._capacity
+        kept = self._kept
+        if kept is None:
+            kept = dict(
+                running_count=np.zeros((cap.gangs,), np.int32),
+                sub_running=np.zeros((cap.gangs, cap.subgroups), np.int32),
+                dev_free=self._dev_template,
+                dev_rel=np.zeros_like(self._dev_template),
+                rollups=None)
+        elif gang_src is not None:
+            # gang rows were closed up: the kept rows move with them
+            kept = dict(
+                kept,
+                running_count=_gather_rows(kept["running_count"],
+                                           gang_src, 0),
+                sub_running=_gather_rows(kept["sub_running"], gang_src, 0))
+        Mu = len(run_rows)
+        on_node = r_node >= 0
+        has_grp = r_grp >= 0
+        fed_node = on_node & t_nodes[r_node]
+        fed_gang = has_grp & t_gangs[r_grp]
+        fed_queue = t_queues[rk["queue"][:Mu]]
+        # --- per gang -----------------------------------------------------
+        running_count, sub_running = (kept["running_count"],
+                                      kept["sub_running"])
+        tg = np.flatnonzero(t_gangs)
+        if len(tg):
+            running_count, sub_running = (running_count.copy(),
+                                          sub_running.copy())
+            running_count[tg] = 0
+            sub_running[tg] = 0
+            active = np.flatnonzero(fed_gang & ~r_rel)
+            np.add.at(running_count, r_grp[active], 1)
+            np.add.at(sub_running,
+                      (r_grp[active], self.p_sub[run_rows[active]]), 1)
+        # --- per node: device cells ---------------------------------------
+        dev_free, dev_rel = self._occupancy(
+            kept["dev_free"], kept["dev_rel"], np.flatnonzero(t_nodes),
+            run_rows[fed_node], r_node[fed_node], r_rel[fed_node])
+        # --- per node and per queue: rollups (shared section builder) ----
+        roll = derive_rollups(
+            node_alloc=node_alloc,
+            claim_used=np.zeros((cap.nodes, R), np.float32),
+            rk=rk, gk=gk, g_of_ext=self._const["ext_accel"],
+            r_mig=np.zeros((cap.running,), np.float32),
+            queue_usage=queue_usage, q_index=qt["q_index"],
+            q_parent=qt["q_parent"], q_depth=qt["q_depth"],
+            num_queues=len(qt["queue_names"]), kept=kept["rollups"],
+            touched_nodes=t_nodes, touched_queues=t_queues)
+        self._kept = dict(
+            running_count=running_count, sub_running=sub_running,
+            dev_free=dev_free, dev_rel=dev_rel, rollups=roll["kept"])
+        self._last_rederived = {
+            "touched_nodes": int(t_nodes.sum()),
+            "touched_gangs": len(tg),
+            "touched_queues": int(t_queues.sum()),
+            "rederived_rows": int(
+                (fed_node | fed_gang | fed_queue).sum())}
+        return roll
 
     @staticmethod
     def _swap_if_equal(new: np.ndarray, old: np.ndarray) -> np.ndarray:
@@ -1681,22 +1869,34 @@ class IncrementalSnapshotter:
 
     # -- device occupancy (gated subset of the builder's section) ---------
 
-    def _occupancy(self, rk, run_rows, r_node, r_rel, N) -> None:
-        D = self._dev_template.shape[1]
-        dev_free = self._dev_template.copy()
-        dev_rel = np.zeros((N, D), np.float32)
-        whole_k = np.rint(self.p_req[run_rows, 0]).astype(np.int64)
-        has_dev = self.p_hasdev[run_rows]
-        on = r_node >= 0
-        touches = on & (whole_k > 0)
+    def _occupancy(self, dev_free, dev_rel, nodes, rows, node, rel):
+        """Device cells of the node rows ``nodes`` → (``dev_free``,
+        ``dev_rel``), the other nodes' as handed in, and the cells each
+        pod holds into ``p_occ_mask`` / ``p_occ_held``.  ``rows``,
+        ``node`` and ``rel`` are the running pods on those nodes in
+        row order: their ledger rows, node rows and whether releasing.
+        Occupancy is local to a node — first fit in row order over the
+        pods it holds — so these pods give these nodes' cells and their
+        own masks exactly."""
+        if not len(nodes):
+            return dev_free, dev_rel
+        N, D = self._dev_template.shape
+        dev_free, dev_rel = dev_free.copy(), dev_rel.copy()
+        dev_free[nodes] = self._dev_template[nodes]
+        dev_rel[nodes] = 0.0
+        self.p_occ_mask[rows] = 0
+        self.p_occ_held[rows] = 0.0
+        whole_k = np.rint(self.p_req[rows, 0]).astype(np.int64)
+        has_dev = self.p_hasdev[rows]
+        touches = whole_k > 0
         special = touches & has_dev
         node_special = np.zeros((N,), bool)
-        node_special[r_node[special]] = True
-        vec = touches & ~special & ~node_special[np.maximum(r_node, 0)]
+        node_special[node[special]] = True
+        vec = touches & ~special & ~node_special[node]
         vj = np.nonzero(vec)[0]
         if len(vj):
             accel_counts_a = self._accel_counts
-            vn = r_node[vj]
+            vn = node[vj]
             ordv = np.argsort(vn, kind="stable")
             vj, vn = vj[ordv], vn[ordv]
             vk = whole_k[vj]
@@ -1707,10 +1907,10 @@ class IncrementalSnapshotter:
             off = cum - cum[np.nonzero(first)[0]][grp]
             k_eff = np.clip(accel_counts_a[vn] - off, 0, vk)
             end = off + k_eff
-            rk["devices_mask"][vj] = (
+            self.p_occ_mask[rows[vj]] = (
                 (np.int64(1) << end) - (np.int64(1) << off)
             ).astype(np.int32)
-            rk["accel_held"][vj] = k_eff.astype(np.float32)
+            self.p_occ_held[rows[vj]] = k_eff.astype(np.float32)
             tot = int(k_eff.sum())
             if tot:
                 rep = np.repeat(np.arange(len(vj)), k_eff)
@@ -1719,7 +1919,7 @@ class IncrementalSnapshotter:
                         + np.repeat(off, k_eff))
                 nrep = vn[rep]
                 dev_free[nrep, dpos] = 0.0
-                relm = r_rel[vj][rep]
+                relm = rel[vj][rep]
                 dev_rel[nrep[relm], dpos[relm]] += 1.0
         rest = np.nonzero(touches & ~vec)[0]
         if len(rest):
@@ -1729,15 +1929,15 @@ class IncrementalSnapshotter:
             # double-booked device cell — only those nodes' pods replay
             # the builder's sequential loop
             seq_nodes = np.zeros((N,), bool)
-            seq_nodes[r_node[rest[~has_dev[rest]]]] = True
-            vecr = rest[~seq_nodes[r_node[rest]]]
-            masks = self.p_devmask[run_rows[vecr]]
+            seq_nodes[node[rest[~has_dev[rest]]]] = True
+            vecr = rest[~seq_nodes[node[rest]]]
+            masks = self.p_devmask[rows[vecr]]
 
             def held_cells(sub, sub_masks):
                 """(node*D + dev) flat indices of every held device."""
                 pj, dj = np.nonzero(
                     (sub_masks[:, None] >> np.arange(D)) & 1)
-                return r_node[sub][pj] * D + dj
+                return node[sub][pj] * D + dj
 
             cells = held_cells(vecr, masks)
             cnt = np.bincount(cells, minlength=N * D)
@@ -1745,36 +1945,34 @@ class IncrementalSnapshotter:
                 (cnt.reshape(N, D) > 1).any(axis=1))[0]
             if len(booked_nodes):
                 seq_nodes[booked_nodes] = True
-                keep = ~seq_nodes[r_node[vecr]]
+                keep = ~seq_nodes[node[vecr]]
                 vecr, masks = vecr[keep], masks[keep]
                 cells = held_cells(vecr, masks)
                 cnt = np.bincount(cells, minlength=N * D)
             if len(vecr):
                 tmpl = self._dev_template
                 dev_free -= tmpl * (cnt.reshape(N, D) > 0)
-                rk["devices_mask"][vecr] = masks
-                rk["accel_held"][vecr] = self.p_held[run_rows[vecr]]
-                relj = vecr[r_rel[vecr]]
+                self.p_occ_mask[rows[vecr]] = masks
+                self.p_occ_held[rows[vecr]] = self.p_held[rows[vecr]]
+                relj = vecr[rel[vecr]]
                 if len(relj):
                     rel_cells = held_cells(relj,
-                                           self.p_devmask[run_rows[relj]])
+                                           self.p_devmask[rows[relj]])
                     dev_rel += (tmpl.reshape(-1) * np.bincount(
                         rel_cells, minlength=N * D)).reshape(N, D)
-            seq = rest[seq_nodes[r_node[rest]]]
+            seq = rest[seq_nodes[node[rest]]]
             if len(seq):
                 self._occupancy_sequential(
-                    rk, run_rows, r_node, r_rel, seq, whole_k,
-                    dev_free, dev_rel)
-        self._occ_dev_free = dev_free
-        self._occ_dev_rel = dev_rel
+                    rows, node, rel, seq, whole_k, dev_free, dev_rel)
+        return dev_free, dev_rel
 
-    def _occupancy_sequential(self, rk, run_rows, r_node, r_rel, rest,
-                              whole_k, dev_free, dev_rel) -> None:
+    def _occupancy_sequential(self, rows, node, rel, rest, whole_k,
+                              dev_free, dev_rel) -> None:
         """Builder-identical per-pod loop for order-dependent cases
         (first-fit pods on device-recorded nodes, double-booked cells)."""
         for jj in rest.tolist():
-            pod = self.p_objs[run_rows[jj]]
-            ni = int(r_node[jj])
+            pod = self.p_objs[rows[jj]]
+            ni = int(node[jj])
             k = int(whole_k[jj])
             if pod.accel_devices:
                 devs = list(pod.accel_devices)[:k]
@@ -1785,11 +1983,11 @@ class IncrementalSnapshotter:
             for d0 in devs:
                 taken = min(1.0, dev_free[ni, d0])
                 dev_free[ni, d0] -= taken
-                if r_rel[jj]:
+                if rel[jj]:
                     dev_rel[ni, d0] += taken
                 mask |= 1 << int(d0)
-            rk["devices_mask"][jj] = mask
-            rk["accel_held"][jj] = float(len(devs))
+            self.p_occ_mask[rows[jj]] = mask
+            self.p_occ_held[rows[jj]] = float(len(devs))
 
     # -- shipping ----------------------------------------------------------
 

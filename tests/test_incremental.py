@@ -1370,6 +1370,377 @@ def test_refusal_is_named_rebuilt_right_and_left_behind(reason):
 
 
 # ---------------------------------------------------------------------------
+# The kept tables: a patch derives again what a dirty row feeds
+# ---------------------------------------------------------------------------
+
+
+def kept_tables(snap) -> dict:
+    """What the snapshotter keeps across patches, flat: the tables by
+    gang, node and leaf queue, and each live pod's device cells."""
+    kept = snap._kept
+    live = snap.p_live
+    return dict(
+        {k: v for k, v in kept.items() if k != "rollups"},
+        **kept["rollups"],
+        p_occ_mask=np.where(live, snap.p_occ_mask, 0),
+        p_occ_held=np.where(live, snap.p_occ_held, 0))
+
+
+def assert_kept_is_whole_derivation(snap) -> None:
+    """The kept tables equal those of the derivation by key with every
+    key touched (``_seed_kept``: nothing kept, so every entry is summed
+    again from the ledgers and the current host tables).  ``verify``
+    cannot see all of them: ``free`` and the quorums are clamped at 0
+    over ``node_used`` and ``sub_running``, and the queue tables ship
+    with their parents added."""
+    by_key = kept_tables(snap)
+    snap._kept = None
+    snap._seed_kept(snap._host)
+    whole = kept_tables(snap)
+    assert by_key.keys() == whole.keys()
+    for name, table in whole.items():
+        np.testing.assert_array_equal(by_key[name], table, err_msg=name)
+
+
+def rederived(snap) -> tuple:
+    last = snap.stats.last
+    return (last["touched_nodes"], last["touched_gangs"],
+            last["touched_queues"], last["rederived_rows"])
+
+
+def running(cluster) -> list:
+    return [p for p in cluster.pods.values()
+            if p.status == apis.PodStatus.RUNNING]
+
+
+class TestRederivedByKey:
+    """One case per way a key is touched.  ``verify=True`` holds every
+    leaf and name to a fresh rebuild; each case also holds the kept
+    tables to the whole derivation and checks that the patch derived
+    the few keys it should, not the cluster."""
+
+    def warm(self, **kw):
+        # requests that float32 adds inexactly: order shows
+        kw = dict(dict(num_nodes=8, num_gangs=12, tasks_per_gang=2,
+                       running_fraction=0.75, task_cpu=0.3,
+                       task_mem=1.37), **kw)
+        cluster = build(**kw)
+        snap = IncrementalSnapshotter(verify=True, dirty_threshold=1.0)
+        refresh(snap, cluster)
+        return cluster, snap
+
+    def patched(self, snap, cluster, n=1):
+        out = refresh(snap, cluster)
+        assert snap.stats.patched == n and only_cold(snap), snap.stats
+        assert_kept_is_whole_derivation(snap)
+        return out
+
+    def test_empty_delta_derives_nothing(self):
+        cluster, snap = self.warm()
+        cluster.tick()
+        host_before = snap._host
+        self.patched(snap, cluster)
+        assert rederived(snap) == (0, 0, 0, 0)
+        # nothing was touched, so last cycle's tables came back as the
+        # objects they were and nothing of them was compared or shipped
+        for leaf in ("free", "releasing", "device_free"):
+            assert getattr(snap._host.nodes, leaf) is getattr(
+                host_before.nodes, leaf)
+        assert snap._host.gangs.running_count \
+            is host_before.gangs.running_count
+
+    def test_bound_pod_turns_releasing(self):
+        cluster, snap = self.warm()
+        pod = running(cluster)[5]
+        cluster.evict_pod(pod.name)
+        state, index = self.patched(snap, cluster)
+        # its node, its gang, its queue; the rows that fed them
+        nodes, gangs, queues, rows = rederived(snap)
+        assert (nodes, gangs, queues) == (1, 1, 1)
+        assert 0 < rows < len(running(cluster)) + 1
+        ni = index.node_names.index(pod.node)
+        assert np.asarray(state.nodes.releasing)[ni, 0] == 1.0
+
+    def test_pod_deleted_from_the_middle_shifts_every_later_row(self):
+        cluster, snap = self.warm()
+        run = running(cluster)
+        gone, last = run[3], run[-1]
+        before = snap._index.running_pod_names.index(last.name)
+        intake_apply.apply_cluster_delta(
+            cluster, {"pods_delete": [gone.name]})
+        state, index = self.patched(snap, cluster)
+        assert index.running_pod_names.index(last.name) == before - 1
+        assert rederived(snap)[:3] == (1, 1, 1)
+        # the rows that moved kept their cells
+        assert np.asarray(state.running.devices_mask)[before - 1] != 0
+
+    def test_pod_moves_node(self):
+        cluster, snap = self.warm()
+        pod = running(cluster)[0]
+        old = pod.node
+        new = next(n for n in cluster.nodes if n != old)
+        pod.node = new  # direct write: the sweep finds it
+        state, index = self.patched(snap, cluster)
+        assert rederived(snap)[:3] == (2, 1, 1)  # both nodes
+        free = np.asarray(state.nodes.free)
+        used = {n: sum(p.resources.accel for p in running(cluster)
+                       if p.node == n) for n in (old, new)}
+        for n in (old, new):
+            assert free[index.node_names.index(n), 0] == 8.0 - used[n]
+
+    def test_gang_deleted_and_rows_close_up(self):
+        cluster, snap = self.warm()
+        names = list(cluster.pod_groups)
+        delete_groups(cluster, [names[1], names[4]])
+        state, index = self.patched(snap, cluster)
+        assert snap.stats.last["gangs_removed"] == 2
+        nodes, gangs, queues, rows = rederived(snap)
+        # the gangs that went have no row left to derive: the rows
+        # that closed up brought their counts with them
+        assert gangs == 0 and nodes == 4 and queues == 2
+        counts = np.asarray(state.gangs.running_count)
+        for gi, name in enumerate(cluster.pod_groups):
+            assert counts[gi] == cluster.group_running_count(name)
+
+    def test_bind_of_a_pending_gang_in_a_queue_that_held_nothing(self):
+        cluster, snap = self.warm(num_gangs=6, running_fraction=0.5,
+                                  queues_per_department=4)
+        empty = "queue-1-3"
+        assert not any(g.queue == empty
+                       for g in cluster.pod_groups.values())
+        intake_apply.apply_cluster_delta(cluster, {
+            "pod_groups_upsert": [
+                {"name": "first", "queue": empty, "min_member": 2}],
+            "pods_upsert": [
+                {"name": f"first-p{t}", "group": "first",
+                 "resources": {"accel": 1.0, "cpu": 0.3, "memory": 1.37}}
+                for t in range(2)]})
+        state, index = self.patched(snap, cluster)
+        qi = index.queue_names.index(empty)
+        assert np.asarray(state.queues.request)[qi, 0] == 2.0
+        assert np.asarray(state.queues.allocated)[qi, 0] == 0.0
+        for t in range(2):
+            cluster.create_bind_request(apis.BindRequest(
+                pod_name=f"first-p{t}", selected_node=f"node-{t}"))
+        state, index = self.patched(snap, cluster, n=2)
+        assert rederived(snap)[:3] == (2, 1, 1)
+        assert np.asarray(state.queues.allocated)[qi, 0] == 2.0
+
+    def test_eviction_moves_the_queues_parent(self):
+        cluster, snap = self.warm()
+        pod = running(cluster)[2]
+        leaf = cluster.pod_groups[pod.group].queue
+        parent = cluster.queues[leaf].parent
+        pi = snap._index.queue_names.index(parent)
+        before = np.asarray(snap._host.queues.allocated)[pi].copy()
+        intake_apply.apply_cluster_delta(
+            cluster, {"pods_delete": [pod.name]})
+        state, _ = self.patched(snap, cluster)
+        # one leaf derived again; the parents are summed every cycle
+        assert rederived(snap)[2] == 1
+        after = np.asarray(state.queues.allocated)[pi]
+        assert after[0] == before[0] - 1.0 and (after < before).all()
+
+    def test_first_fit_pod_beside_recorded_devices_gains_and_loses(self):
+        """A node whose pods hold recorded devices, with one pod beside
+        them that records none: that node's cells are order-dependent
+        and replay the builder's loop (``_occupancy_sequential``), and
+        only that node's."""
+        cluster, snap = self.warm(num_gangs=4, running_fraction=1.0)
+        on_node = [p for p in running(cluster) if p.node == "node-0"]
+        assert len(on_node) == 1
+        on_node[0].accel_devices = [3]
+        for p in running(cluster):
+            if p.node == "node-1":
+                p.accel_devices = [0]
+        cluster.journal.mark_pod(on_node[0].name)
+        self.patched(snap, cluster)
+        # gains: a pod bound by a bind request records no device
+        submit_groups(cluster, ["late"], tasks=2)
+        for t in range(2):
+            cluster.create_bind_request(apis.BindRequest(
+                pod_name=f"late-p{t}", selected_node="node-0"))
+        state, index = self.patched(snap, cluster, n=2)
+        assert rederived(snap)[0] == 1
+        ni = index.node_names.index("node-0")
+        # first fit goes round the recorded cell
+        assert np.asarray(state.nodes.device_free)[ni].tolist() == [
+            0.0, 0.0, 1.0, 0.0, 1.0, 1.0, 1.0, 1.0]
+        # loses: the recorded-device pod goes, the first-fit pods stay
+        intake_apply.apply_cluster_delta(
+            cluster, {"pods_delete": [on_node[0].name]})
+        state, index = self.patched(snap, cluster, n=3)
+        assert rederived(snap)[0] == 1
+        assert np.asarray(state.nodes.device_free)[ni].tolist() == [
+            0.0, 0.0, 1.0, 1.0, 1.0, 1.0, 1.0, 1.0]
+
+    def test_compaction_cycle_derives_every_key(self):
+        cluster, snap = self.warm(num_gangs=40, num_nodes=16)
+        names = list(cluster.pod_groups)
+        # churn until the ledger holds over twice its live rows
+        for i in range(0, 36, 4):
+            delete_groups(cluster, names[i:i + 4])
+            submit_groups(cluster, [f"new-{i + k}" for k in range(4)])
+            refresh(snap, cluster)
+        rows = len(snap.p_objs)
+        for i in range(8):
+            delete_groups(cluster, [f"new-{i}"])
+            refresh(snap, cluster)
+            if len(snap.p_objs) < rows:
+                break
+            rows = len(snap.p_objs)
+        else:
+            pytest.fail("the pod ledger was never compacted")
+        assert only_cold(snap), snap.stats
+        assert_kept_is_whole_derivation(snap)
+        cap = snap._capacity
+        assert rederived(snap)[:3] == (cap.nodes, cap.gangs, cap.queues)
+        # and the cycle after is by key again
+        cluster.tick()
+        refresh(snap, cluster)
+        assert rederived(snap) == (0, 0, 0, 0)
+
+
+@pytest.mark.parametrize("shape", ["flat", "racks", "wide_gangs"])
+def test_twenty_cycles_of_churn_keep_the_tables_whole(shape):
+    """``churn``-like deltas at 1 000 nodes: gangs finish (pods, bind
+    requests and groups deleted), gangs arrive, last cycle's arrivals
+    are bound, a few pods are evicted and reported deleted.  After every
+    patch the kept tables equal the derivation with every key
+    touched."""
+    kw = {"flat": {}, "racks": dict(topology_levels=(4, 10)),
+          "wide_gangs": dict(tasks_per_gang=8, num_gangs=375)}[shape]
+    kw = dict(dict(num_nodes=1000, num_gangs=1500, tasks_per_gang=2,
+                   running_fraction=1.0, queues_per_department=50,
+                   task_cpu=0.3, task_mem=1.37), **kw)
+    cluster = build(**kw)
+    tasks = kw["tasks_per_gang"]
+    snap = IncrementalSnapshotter(verify=True)
+    refresh(snap, cluster)
+    rng = np.random.default_rng(35)
+    leaves = [q.name for q in cluster.queues.values() if q.parent]
+    pending: list[str] = []
+    for cycle in range(20):
+        placed = [g for g in cluster.pod_groups if any(
+            p.node for p in cluster.pods_of_group(g))]
+        delete_groups(cluster, [placed[i] for i in rng.choice(
+            len(placed), size=6, replace=False)])
+        for name in pending:
+            if name in cluster.pods:
+                cluster.create_bind_request(apis.BindRequest(
+                    pod_name=name,
+                    selected_node=f"node-{rng.integers(1000)}"))
+        evicted = [p.name for p in cluster.pods.values()
+                   if p.status == apis.PodStatus.RELEASING]
+        for p in rng.choice(running(cluster), size=3, replace=False):
+            cluster.evict_pod(p.name)
+        new = [f"job-{cycle}-{i}" for i in range(6)]
+        intake_apply.apply_cluster_delta(cluster, {
+            "now": float(cycle + 1), "pods_delete": evicted,
+            "pod_groups_upsert": [
+                {"name": n, "queue": leaves[int(rng.integers(len(leaves)))],
+                 "min_member": tasks} for n in new],
+            "pods_upsert": [
+                {"name": f"{n}-p{t}", "group": n,
+                 "resources": {"accel": 1.0, "cpu": 0.1 * (1 + t % 7),
+                               "memory": 1.37}}
+                for n in new for t in range(tasks)]})
+        pending = [f"{n}-p{t}" for n in new for t in range(tasks)]
+        refresh(snap, cluster)
+        if snap.stats.last["mode"] == "patched":
+            nodes, gangs, queues, rows = rederived(snap)
+            assert 0 < rows < len(running(cluster)) // 2
+            assert nodes < 100 and queues < 30
+        assert_kept_is_whole_derivation(snap)
+    # the first arrivals outgrow the pinned task axis, once
+    assert snap.stats.patched >= 18, snap.stats
+
+
+def _random_sections(rng, N=60, Q=40, G=120, T=5, M=3000):
+    """Running and pending tables with requests float32 adds inexactly,
+    over a three-level queue tree (4 roots, 12 middles, 24 leaves)."""
+    R = apis.NUM_RESOURCES
+    parent = np.full((Q,), -1, np.int32)
+    parent[4:16] = np.arange(12) % 4
+    parent[16:] = 4 + np.arange(24) % 12
+    depth = np.where(parent < 0, 0, np.where(parent < 4, 1, 2)).astype(
+        np.int32)
+    rk = dict(
+        valid=rng.random(M) < 0.9,
+        node=rng.integers(-1, N, M).astype(np.int32),
+        queue=rng.integers(4, Q, M).astype(np.int32),
+        req=rng.random((M, R)).astype(np.float32),
+        releasing=rng.random(M) < 0.2,
+        preemptible=rng.random(M) < 0.5)
+    gk = dict(
+        task_req=rng.random((G, T, R)).astype(np.float32),
+        task_valid=rng.random((G, T)) < 0.6,
+        queue=rng.integers(16, Q, G).astype(np.int32),
+        valid=rng.random(G) < 0.8,
+        task_extended=np.zeros((G, T, 0), np.float32))
+    fixed = dict(
+        node_alloc=np.full((N, R), 64.0, np.float32),
+        claim_used=np.zeros((N, R), np.float32),
+        g_of_ext=np.zeros((0,), np.float32),
+        r_mig=np.zeros((M,), np.float32), queue_usage=None, q_index={},
+        q_parent=parent, q_depth=depth, num_queues=Q)
+    return rk, gk, fixed
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_rollups_by_key_equal_the_whole_and_the_loop(seed):
+    """``derive_rollups`` sums a touched key again from its members in
+    row order, and the parents by depth level: both must give, to the
+    bit, what summing every key gives and what the loop over every
+    queue that stood there gave."""
+    from kai_scheduler_tpu.state.cluster_state import derive_rollups
+    rng = np.random.default_rng(seed)
+    rk, gk, fixed = _random_sections(rng)
+    whole = derive_rollups(rk=rk, gk=gk, **fixed)
+    # the loop the level sums replaced, kept here as the reference
+    parent, depth = fixed["q_parent"], fixed["q_depth"]
+    for name in ("q_alloc", "q_alloc_np", "q_request"):
+        ref = whole["kept"][name].copy()
+        for i in sorted(range(len(parent)), key=lambda i: -depth[i]):
+            if parent[i] >= 0:
+                ref[parent[i]] += ref[i]
+        np.testing.assert_array_equal(whole[name], ref, err_msg=name)
+    # move some running rows and some pending gangs; derive their keys
+    rows = rng.choice(len(rk["node"]), 40, replace=False)
+    gangs = rng.choice(len(gk["queue"]), 6, replace=False)
+    touched_nodes = np.zeros(fixed["node_alloc"].shape[0], bool)
+    touched_queues = np.zeros(len(parent), bool)
+    touched_nodes[rk["node"][rows][rk["node"][rows] >= 0]] = True
+    touched_queues[rk["queue"][rows]] = True
+    touched_queues[gk["queue"][gangs]] = True
+    rk["node"][rows] = rng.integers(-1, len(touched_nodes), len(rows))
+    rk["queue"][rows] = rng.integers(4, len(parent), len(rows))
+    rk["req"][rows] = rng.random((len(rows), 3)).astype(np.float32)
+    rk["releasing"][rows] = ~rk["releasing"][rows]
+    gk["task_valid"][gangs] = ~gk["task_valid"][gangs]
+    touched_nodes[rk["node"][rows][rk["node"][rows] >= 0]] = True
+    touched_queues[rk["queue"][rows]] = True
+    assert not touched_nodes.all() and not touched_queues.all()
+    by_key = derive_rollups(rk=rk, gk=gk, **fixed, kept=whole["kept"],
+                            touched_nodes=touched_nodes,
+                            touched_queues=touched_queues)
+    again = derive_rollups(rk=rk, gk=gk, **fixed)
+    for name in ("node_free", "node_rel", "q_alloc", "q_alloc_np",
+                 "q_request", "q_usage"):
+        np.testing.assert_array_equal(by_key[name], again[name],
+                                      err_msg=name)
+    for name, table in again["kept"].items():
+        np.testing.assert_array_equal(by_key["kept"][name], table,
+                                      err_msg=name)
+    # no key touched: last cycle's tables come back as they were
+    same = derive_rollups(
+        rk=rk, gk=gk, **fixed, kept=again["kept"],
+        touched_nodes=np.zeros_like(touched_nodes),
+        touched_queues=np.zeros_like(touched_queues))
+    assert all(same["kept"][k] is v for k, v in again["kept"].items())
+
+
+# ---------------------------------------------------------------------------
 # Scheduler integration (the verify_incremental flag end-to-end)
 # ---------------------------------------------------------------------------
 

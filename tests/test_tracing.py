@@ -479,11 +479,81 @@ def test_patched_cycle_spans_nested_in_order():
     assert patch is not None and patch.attrs["mode"] == "patched"
     assert [c.name for c in patch.children] == [
         "patch.journal", "patch.sweep", "patch.assemble"]
+    # the ledger's columns are gathered twice (through ``order``, then
+    # through ``run_rows``) and the tables built whole come before and
+    # after what is derived by key: repeats of one path add up
+    assert [c.name for c in _find(patch, "patch.assemble").children] == [
+        "assemble.gather", "assemble.tables", "assemble.gather",
+        "assemble.rederive", "assemble.tables", "assemble.index"]
     snap = next(c for c in patched.root.children if c.name == "snapshot")
     # the upload stays the snapshot phase's own child, after the patch
     assert [c.name for c in snap.children][:2] == ["snapshot.patch",
                                                    "upload"]
     assert _find(patched.root, "snapshot.full_build") is None
+
+
+def test_empty_delta_touches_no_key():
+    """The counters of what a patch derived again: a cycle whose only
+    change is the clock feeds no key, and the patch span and
+    ``stats.last`` (``/healthz`` ``last_cycle.snapshot``) say so (a key
+    that is touched: ``tests/test_incremental.py``)."""
+    cluster = _small_cluster()
+    cluster.bind_pod("p", "n0")
+    sched = Scheduler()
+    sched.run_once(cluster)
+    counters = ("touched_nodes", "touched_gangs", "touched_queues",
+                "rederived_rows")
+    for _ in range(4):   # until a cycle whose only change is the clock
+        cluster.tick()
+        sched.run_once(cluster)
+        last = sched._snapshotter.stats.last
+        if last["mode"] == "patched" and not last["dirty_pods"]:
+            break
+    assert last["mode"] == "patched"
+    assert [last[k] for k in counters] == [0, 0, 0, 0]
+    patch = _find(sched.tracer.last(1)[0].root, "snapshot.patch")
+    assert [patch.attrs[k] for k in counters] == [0, 0, 0, 0]
+
+
+@pytest.mark.parametrize("metric, floor", [
+    ("patch_assemble_ms", 0.0), ("patch_sweep_ms", 0.0),
+    ("assemble_rederived_rows", -1)])
+def test_benchmark_reads_the_patch_from_healthz(metric, floor):
+    """``benchmark/layer_metrics/<metric>.py`` over ``last_cycle`` as
+    ``/healthz`` serves it (``span_self_seconds``, ``snapshot``): a
+    patched cycle gives each a number; a program from before the spans'
+    children and the counter were added gives nothing, not an error."""
+    import importlib.util
+    import os
+    import sys
+    import types
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path.insert(0, bench)
+    try:
+        spec = importlib.util.spec_from_file_location(
+            metric, os.path.join(bench, "layer_metrics", f"{metric}.py"))
+        reader = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(reader)
+    finally:
+        sys.path.remove(bench)
+    _, patched = _full_then_patched()
+    patch = _find(patched.root, "snapshot.patch")
+    health = {"span_self_seconds": patched.self_seconds(),
+              "snapshot": dict(patch.attrs)}
+    run = types.SimpleNamespace(cycles=[{"health": health}] * 2)
+    value = reader.read(run)
+    assert value is not None and value > floor
+    if metric == "patch_assemble_ms":
+        # the span with its children, and nothing of its siblings
+        under = 1e3 * sum(
+            secs for path, secs in health["span_self_seconds"].items()
+            if "patch.assemble" in path.split("/"))
+        assert value == pytest.approx(under)
+        assert value < 1e3 * patch.seconds
+    older = types.SimpleNamespace(cycles=[{"health": {
+        "phase_seconds": {}, "snapshot": {"mode": "patched"}}}])
+    assert reader.read(older) is None
 
 
 def test_build_snapshot_without_a_tracer_records_nothing():
