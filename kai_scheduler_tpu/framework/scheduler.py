@@ -508,7 +508,8 @@ class Scheduler:
         with self.tracer.span("solve_dispatch"):
             # a dozen small dispatches: inside the span, as the phase's
             # checkpoints already count them
-            result.tensors = init_result(session.state)
+            with self.tracer.span("dispatch.init_result"):
+                result.tensors = init_result(session.state)
             every = self.config.analytics_every
             run_analytics = every > 0 and self._cycle_index % every == 0
             self._cycle_index += 1

@@ -228,6 +228,12 @@ def _commit_doc(result) -> dict:
 #: how many requests are served at once; further ones wait their turn
 HANDLER_THREADS = 16
 
+#: the POST routes, each a key of ``last_cycle.requests`` once it has
+#: served a request; any other path is booked as ``other``
+POST_ROUTES = frozenset((
+    "/cycle", "/cluster", "/cluster/delta", "/intake", "/cycle/stored",
+    "/twin/record", "/twin/replay"))
+
 
 class SchedulerServer:
     """Serve the debug/sidecar endpoints for one cluster + scheduler.
@@ -241,7 +247,10 @@ class SchedulerServer:
     lock and written to the socket after releasing it, so a slow client
     never stalls the next request's state access.  ``GET /healthz``
     serves ``_cycle_stats``, an immutable per-cycle stats document
-    swapped (never mutated) after each cycle run through the server.
+    swapped (never mutated) for each cycle run through the server, once
+    the cycle's reply is encoded and before it is written.  Every
+    ``POST`` is a trace of its own on the scheduler's tracer (root span
+    ``request``; ``docs/TRACING.md``), with the cycle's root under it.
     The cluster/scheduler pair handed to a running server is owned by
     it: driving ``run_once`` on the same objects from another thread
     bypasses this lock.
@@ -260,6 +269,12 @@ class SchedulerServer:
         self._state_lock = threading.Lock()
         self.cluster = cluster  # kai-race: guarded-by=_state_lock
         self.scheduler = scheduler or Scheduler()
+        #: the scheduler's tracer: handler threads open their request
+        #: traces on it (read-only binding after init)
+        self._tracer = self.scheduler.tracer
+        #: per handler thread: when ``_serve_on_pool`` handed it the
+        #: connection it is serving (``perf_counter`` seconds)
+        self._handed = threading.local()
         # kai-intake multi-lane front end: lanes/capacity/policy come
         # from the scheduler config (conf `intake.*` document keys).
         # The sync_flush valve lets policy="sync" degrade an overflowing
@@ -271,11 +286,18 @@ class SchedulerServer:
                          lane_capacity=icfg.intake_lane_capacity,
                          policy=icfg.intake_policy,
                          batch=icfg.intake_batch),
-            sync_flush=self._intake_flush)
+            sync_flush=self._intake_flush, tracer=self._tracer)
         #: immutable per-cycle stats document (GET /healthz); handler
         #: threads swap in a fresh dict under _state_lock, readers take
         #: the current binding without it
         self._cycle_stats: dict | None = None  # kai-race: guarded-by=atomic-swap
+        #: serializes the publication's compare-and-swap: a document is
+        #: finished outside ``_state_lock`` (after the reply's encode),
+        #: and an older cycle's must not replace a newer one's
+        self._publish_lock = threading.Lock()
+        #: cycles run through the server, and the first one's phases
+        self._cycles = 0  # kai-race: guarded-by=_state_lock
+        self._first_phases = None  # kai-race: guarded-by=_state_lock
         #: times the process's garbage collections while the server
         #: runs (start() installs the hook, stop() removes it)
         self._gc_watch = GcWatch()
@@ -481,16 +503,38 @@ class SchedulerServer:
                 else:
                     self.send_error(404)
 
-            def _send_pb(self, msg, code=200):
-                body = msg.SerializeToString()
-                self.send_response(code)
-                self.send_header("Content-Type", "application/x-protobuf")
-                self.send_header("Content-Length", str(len(body)))
-                self.end_headers()
-                self.wfile.write(body)
+            def _reply(self, request, encode, ctype: str, code: int = 200,
+                       stats: dict | None = None) -> None:
+                """A POST's answer, in the request's spans:
+                ``reply.encode`` (``encode()`` makes the body), then,
+                where the request ran a cycle, the publication of its
+                ``stats`` — the document has to be whole before the
+                reply leaves, or a client that reads ``/healthz`` the
+                moment it has the reply reads the cycle before —
+                and ``reply.write``."""
+                tracer = outer._tracer
+                with tracer.span("reply.encode"):
+                    body = encode()
+                request.root.attrs.update(status=code, bytes_out=len(body))
+                if stats is not None:
+                    with tracer.span("record"):
+                        outer._publish(stats, request)
+                with tracer.span("reply.write"):
+                    self._send_text(body, ctype, code)
+
+            def _reply_json(self, request, make, code=200, stats=None):
+                self._reply(request, lambda: json.dumps(make()).encode(),
+                            "application/json", code, stats)
+
+            def _reply_pb(self, request, make, stats=None):
+                self._reply(request, lambda: make().SerializeToString(),
+                            "application/x-protobuf", stats=stats)
+
+            def _refuse(self, request, code: int, message=None) -> None:
+                request.root.attrs["status"] = code
+                self.send_error(code, message)
 
             def do_POST(self):  # noqa: N802
-                length = int(self.headers.get("Content-Length", 0))
                 # the sidecar protocol speaks two framings over the same
                 # endpoints: the stable JSON documents, and the typed
                 # protobuf schema (wire/sidecar.proto — SURVEY §7d's
@@ -498,144 +542,172 @@ class SchedulerServer:
                 # prefix).  Content-Type selects.
                 proto = self.headers.get(
                     "Content-Type", "").startswith("application/x-protobuf")
-                try:
-                    # socket read happens before taking the state lock;
-                    # the reply goes out after releasing it
-                    body = self.rfile.read(length)
-                    if proto:
-                        from ..wire import codec, sidecar_pb2 as pb
-                        if self.path == "/cycle":
-                            doc = pb.ClusterDoc()
-                            doc.ParseFromString(body)
-                            # deserialize outside the lock (a tens-of-MB
-                            # snapshot must not stall other endpoints)
-                            cycle_cluster = codec.cluster_from_msg(doc)
-                            with outer._state_lock:
-                                result = outer.scheduler.run_once(
-                                    cycle_cluster)
-                                outer._record_cycle(result)
-                            self._send_pb(codec.commit_to_msg(result))
-                        elif self.path == "/cluster":
-                            doc = pb.ClusterDoc()
-                            doc.ParseFromString(body)
-                            fresh = codec.cluster_from_msg(doc)  # no lock
-                            with outer._state_lock:
-                                outer.cluster = fresh
-                                outer._twin_attach(fresh)
-                            self._send_pb(pb.CommitSet())
-                        elif self.path == "/cluster/delta":
-                            delta = pb.ClusterDelta()
-                            delta.ParseFromString(body)
-                            with outer._state_lock:
-                                codec.apply_delta_msg(outer.cluster, delta)
-                            self._send_pb(pb.CommitSet())
-                        elif self.path == "/cycle/stored":
-                            result = outer._run_stored_cycle()
-                            self._send_pb(codec.commit_to_msg(result))
-                        else:
-                            self.send_error(404)
-                        return
-                    if self.path == "/cycle":
-                        doc = json.loads(body.decode())
-                        cycle_cluster = load_cluster(doc)
-                        with outer._state_lock:
-                            result = outer.scheduler.run_once(
-                                cycle_cluster)
-                            outer._record_cycle(result)
-                        self._send(_commit_doc(result))
-                    elif self.path == "/cluster":
-                        # replace the stored cluster (upload once ...)
-                        doc = json.loads(body.decode())
-                        fresh = load_cluster(doc)
-                        with outer._state_lock:
-                            outer.cluster = fresh
-                            outer._twin_attach(fresh)
-                        self._send({"ok": True})
-                    elif self.path == "/cluster/delta":
-                        # ... then PATCH deltas instead of re-shipping
-                        # the full document every cycle
-                        doc = json.loads(body.decode())
-                        with outer._state_lock:
-                            apply_cluster_delta(outer.cluster, doc)
-                        self._send({"ok": True})
-                    elif self.path == "/intake":
-                        # kai-intake: queue the delta through the async
-                        # multi-lane front end instead of applying it
-                        # under the commit lock.  Parse + lane offers
-                        # touch NO server state lock; the staged events
-                        # coalesce into the hub at the next cycle
-                        # boundary.  A backpressured (shed) request
-                        # reports 429 with the per-request counts —
-                        # atomically refused per lane group, nothing
-                        # journaled.
-                        doc = json.loads(body.decode())
-                        # all-or-nothing at the HTTP boundary: a 429
-                        # means NOTHING was queued, so a client's
-                        # blind full retry can never double-apply a
-                        # partially accepted delta.  Counts only on
-                        # the wire — the shed ops echo is for
-                        # in-process retriers.
-                        out = outer.intake.submit_delta(
-                            doc, all_or_nothing=True)
-                        self._send({"accepted": out["accepted"],
-                                    "shed": out["shed"],
-                                    "total": out["total"]},
-                                   code=429 if out["shed"] else 200)
-                    elif self.path == "/cycle/stored":
-                        # run a cycle against the stored cluster: the
-                        # incremental sidecar protocol's execute step.
-                        # Cycle boundary = the kai-intake coalesce
-                        # point: staged lane events merge into the hub
-                        # journal (global seq order) before the cycle
-                        # snapshots it.
-                        result = outer._run_stored_cycle()
-                        self._send(_commit_doc(result))
-                    elif self.path == "/twin/record":
-                        # kai-twin recorder control: start re-anchors
-                        # the stream at the CURRENT stored cluster,
-                        # stop freezes it (the stream stays readable
-                        # through /debug/twin?stream=1)
-                        doc = json.loads(body.decode()) if body else {}
-                        action = doc.get("action", "start")
-                        if outer.recorder is None:
-                            self.send_error(
-                                400, "twin recording disabled "
-                                     "(twinRecord: false)")
-                            return
-                        with outer._state_lock:
-                            if action in ("start", "reset"):
-                                outer._twin_attach(outer.cluster)
-                            elif action == "stop":
-                                outer.recorder.detach()
-                                outer.cluster.twin_recorder = None
+                # a request is a trace (docs/TRACING.md): its root opens
+                # where _serve_on_pool handed the connection to this
+                # thread, and everything below is a span under it
+                with outer._tracer.request(
+                        self.path if self.path in POST_ROUTES else "other",
+                        start=outer._handed.__dict__.pop("at", None),
+                        framing="protobuf" if proto else "json") as request:
+                    # (the routes stand here and in no method of their
+                    # own, and this frame holds the locals it held: a
+                    # cycle runs as deep in its thread's stack as it did,
+                    # ``_serve``)
+                    try:
+                        # socket read happens before taking the state lock;
+                        # the reply goes out after releasing it
+                        with outer._tracer.span("http.read"):
+                            body = self.rfile.read(int(
+                                self.headers.get("Content-Length", 0)))
+                        request.root.attrs["bytes_in"] = len(body)
+                        if proto:
+                            from ..wire import codec, sidecar_pb2 as pb
+                            if self.path == "/cycle":
+                                with outer._tracer.span("body.parse"):
+                                    doc = pb.ClusterDoc()
+                                    doc.ParseFromString(body)
+                                    # deserialize outside the lock (a
+                                    # tens-of-MB snapshot must not stall
+                                    # other endpoints)
+                                    doc = codec.cluster_from_msg(doc)
+                                result = outer._run_cycle(doc, request)
+                                self._reply_pb(
+                                    request,
+                                    lambda: codec.commit_to_msg(result[0]),
+                                    result[1])
+                            elif self.path == "/cluster":
+                                with outer._tracer.span("body.parse"):
+                                    doc = pb.ClusterDoc()
+                                    doc.ParseFromString(body)
+                                    # (no lock)
+                                    doc = codec.cluster_from_msg(doc)
+                                outer._replace_cluster(doc)
+                                self._reply_pb(request, pb.CommitSet)
+                            elif self.path == "/cluster/delta":
+                                with outer._tracer.span("body.parse"):
+                                    doc = pb.ClusterDelta()
+                                    doc.ParseFromString(body)
+                                outer._apply_delta(
+                                    codec.apply_delta_msg, doc, dict(
+                                        (f.name, len(v))
+                                        for f, v in doc.ListFields()
+                                        if hasattr(v, "__len__")))
+                                self._reply_pb(request, pb.CommitSet)
+                            elif self.path == "/cycle/stored":
+                                result = outer._run_stored_cycle(request)
+                                self._reply_pb(
+                                    request,
+                                    lambda: codec.commit_to_msg(result[0]),
+                                    result[1])
                             else:
-                                self.send_error(
-                                    400, f"unknown action {action!r}")
-                                return
-                        self._send({"ok": True, "action": action,
-                                    "recorder":
-                                        outer.recorder.stats()})
-                    elif self.path == "/twin/replay":
-                        # differential-oracle replay of the recorded
-                        # stream: snapshot the stream under the
-                        # recorder's own lock, replay it twice OUTSIDE
-                        # _state_lock (a long replay must never stall
-                        # the live scheduler), then atomic-swap the
-                        # verdict for /debug/twin and healthz.
-                        if (outer.recorder is None
-                                or not outer.recorder.attached):
-                            self.send_error(
-                                400, "no twin stream recorded")
+                                self._refuse(request, 404)
                             return
-                        stream = outer.recorder.stream()
-                        from ..twin import replay as twin_replay
-                        verdict = twin_replay.oracle(stream)
-                        outer._twin_doc = {"last_replay": verdict}
-                        self._send(verdict)
-                    else:
-                        self.send_error(404)
-                except Exception as exc:  # noqa: BLE001
-                    self.send_error(400, str(exc))
+                        if self.path == "/cycle":
+                            with outer._tracer.span("body.parse"):
+                                doc = load_cluster(json.loads(body.decode()))
+                            result = outer._run_cycle(doc, request)
+                            self._reply_json(
+                                request, lambda: _commit_doc(result[0]),
+                                stats=result[1])
+                        elif self.path == "/cluster":
+                            # replace the stored cluster (upload once ...)
+                            with outer._tracer.span("body.parse"):
+                                doc = load_cluster(json.loads(body.decode()))
+                            outer._replace_cluster(doc)
+                            self._reply_json(request, lambda: {"ok": True})
+                        elif self.path == "/cluster/delta":
+                            # ... then PATCH deltas instead of re-shipping
+                            # the full document every cycle
+                            with outer._tracer.span("body.parse"):
+                                doc = json.loads(body.decode())
+                            outer._apply_delta(
+                                apply_cluster_delta, doc, dict(
+                                    (k, len(v)) for k, v in doc.items()
+                                    if isinstance(v, list)))
+                            self._reply_json(request, lambda: {"ok": True})
+                        elif self.path == "/intake":
+                            # kai-intake: queue the delta through the async
+                            # multi-lane front end instead of applying it
+                            # under the commit lock.  Parse + lane offers
+                            # touch NO server state lock; the staged events
+                            # coalesce into the hub at the next cycle
+                            # boundary.  A backpressured (shed) request
+                            # reports 429 with the per-request counts —
+                            # atomically refused per lane group, nothing
+                            # journaled.
+                            with outer._tracer.span("body.parse"):
+                                doc = json.loads(body.decode())
+                            # all-or-nothing at the HTTP boundary: a 429
+                            # means NOTHING was queued, so a client's
+                            # blind full retry can never double-apply a
+                            # partially accepted delta.  Counts only on
+                            # the wire — the shed ops echo is for
+                            # in-process retriers.
+                            out = outer.intake.submit_delta(
+                                doc, all_or_nothing=True)
+                            self._reply_json(
+                                request, lambda: {"accepted": out["accepted"],
+                                                  "shed": out["shed"],
+                                                  "total": out["total"]},
+                                code=429 if out["shed"] else 200)
+                        elif self.path == "/cycle/stored":
+                            # run a cycle against the stored cluster: the
+                            # incremental sidecar protocol's execute step.
+                            # Cycle boundary = the kai-intake coalesce
+                            # point: staged lane events merge into the hub
+                            # journal (global seq order) before the cycle
+                            # snapshots it.
+                            result = outer._run_stored_cycle(request)
+                            self._reply_json(
+                                request, lambda: _commit_doc(result[0]),
+                                stats=result[1])
+                        elif self.path == "/twin/record":
+                            # kai-twin recorder control: start re-anchors
+                            # the stream at the CURRENT stored cluster,
+                            # stop freezes it (the stream stays readable
+                            # through /debug/twin?stream=1)
+                            doc = json.loads(body.decode()) if body else {}
+                            action = doc.get("action", "start")
+                            if outer.recorder is None:
+                                self._refuse(
+                                    request, 400, "twin recording disabled "
+                                                  "(twinRecord: false)")
+                                return
+                            with outer._state_lock:
+                                if action in ("start", "reset"):
+                                    outer._twin_attach(outer.cluster)
+                                elif action == "stop":
+                                    outer.recorder.detach()
+                                    outer.cluster.twin_recorder = None
+                                else:
+                                    self._refuse(
+                                        request, 400,
+                                        f"unknown action {action!r}")
+                                    return
+                            self._reply_json(request, lambda: {
+                                "ok": True, "action": action,
+                                "recorder": outer.recorder.stats()})
+                        elif self.path == "/twin/replay":
+                            # differential-oracle replay of the recorded
+                            # stream: snapshot the stream under the
+                            # recorder's own lock, replay it twice OUTSIDE
+                            # _state_lock (a long replay must never stall
+                            # the live scheduler), then atomic-swap the
+                            # verdict for /debug/twin and healthz.
+                            if (outer.recorder is None
+                                    or not outer.recorder.attached):
+                                self._refuse(
+                                    request, 400, "no twin stream recorded")
+                                return
+                            from ..twin import replay as twin_replay
+                            verdict = twin_replay.oracle(
+                                outer.recorder.stream())
+                            outer._twin_doc = {"last_replay": verdict}
+                            self._reply_json(request, lambda: verdict)
+                        else:
+                            self._refuse(request, 404)
+                    except Exception as exc:  # noqa: BLE001
+                        self._refuse(request, 400, str(exc))
 
             def log_message(self, *args):
                 pass
@@ -674,39 +746,67 @@ class SchedulerServer:
                 twin["last_replay"]["divergences"])
         return out
 
-    def _run_stored_cycle(self):
+    def _apply_delta(self, apply, delta, counts: dict) -> None:
+        """``POST /cluster/delta``, either framing: ``apply(cluster,
+        delta)`` under the state lock, as the span ``delta.apply`` with
+        the length of each of the delta's lists."""
+        t0 = time.perf_counter()
+        with self._state_lock:
+            self._tracer.add_span("lock_wait", t0, time.perf_counter())
+            with self._tracer.span("delta.apply", **counts):
+                apply(self.cluster, delta)
+
+    def _replace_cluster(self, fresh: Cluster) -> None:
+        """``POST /cluster``, either framing."""
+        t0 = time.perf_counter()
+        with self._state_lock:
+            self._tracer.add_span("lock_wait", t0, time.perf_counter())
+            self.cluster = fresh
+            self._twin_attach(fresh)
+
+    def _run_cycle(self, cycle_cluster: Cluster, request) -> tuple:
+        """``POST /cycle``, either framing: one cycle over the posted
+        document → (result, its stats document, to be published once
+        the reply is encoded)."""
+        t0 = time.perf_counter()
+        with self._state_lock:
+            self._tracer.add_span("lock_wait", t0, time.perf_counter())
+            result = self.scheduler.run_once(cycle_cluster)
+            with self._tracer.span("record"):
+                stats = self._record_cycle(result, request)
+        return result, stats
+
+    def _run_stored_cycle(self, request) -> tuple:
         """``POST /cycle/stored``, either framing: take the commit lock,
         merge what the intake lanes staged into the hub journal (global
         seq order: the cycle boundary is the kai-intake coalesce
-        point), run the cycle, publish its stats.  The wait for the
-        lock and the coalesce come before the cycle's root span opens,
-        so they are timed here and served as ``entry_seconds``."""
+        point), run the cycle → (result, its stats document, to be
+        published once the reply is encoded).  The wait for the lock
+        and the coalesce are spans of the request, beside the cycle's
+        root; ``entry_seconds`` serves their seconds."""
+        tracer = self._tracer
         t0 = time.perf_counter()
         with self._state_lock:
-            t1 = time.perf_counter()
-            merged = self.intake.coalesce(self.cluster)
-            t2 = time.perf_counter()
+            tracer.add_span("lock_wait", t0, time.perf_counter())
+            with tracer.span("coalesce"):
+                merged = self.intake.coalesce(self.cluster)
             result = self.scheduler.run_once(self.cluster)
-            self._record_cycle(result, lock_wait_s=t1 - t0,
-                               coalesce_s=t2 - t1,
-                               parsed_pods=merged["parsed_pods"])
+            with tracer.span("record"):
+                stats = self._record_cycle(result, request, merged)
             if self.recorder is not None:
                 self.recorder.record_cycle()
-        return result
+        return result, stats
 
-    def _record_cycle(self, result, lock_wait_s: float = 0.0,
-                      coalesce_s: float = 0.0,
-                      parsed_pods: int = 0) -> None:
-        """Swap in a fresh immutable per-cycle stats document (served
-        by ``GET /healthz``).  Called under ``_state_lock``; readers
-        take the current binding without it (atomic-swap discipline —
-        the dict is never mutated after publication)."""
-        prev = self._cycle_stats
-        stats = {"cycles": (prev["cycles"] + 1) if prev else 1}
+    def _record_cycle(self, result, request,
+                      merged: dict | None = None) -> dict:
+        """A fresh per-cycle stats document, but for what only the end
+        of the request knows (``_publish``).  Called under
+        ``_state_lock``, inside ``request``, the open trace of the POST
+        that ran the cycle; ``merged`` is what its coalesce returned."""
+        self._cycles += 1
+        stats = {"cycles": self._cycles}
         if result is not None:
             stats.update(
-                open_seconds=result.open_seconds,
-                commit_seconds=result.commit_seconds,
                 total_seconds=result.session_seconds,
                 phase_seconds=dict(result.phase_seconds),
                 decisions=self.scheduler.decisions.summary(),
@@ -723,24 +823,31 @@ class SchedulerServer:
                 # cycle opened.  Start-up is the first cycle's phases,
                 # frozen, and the compile stages, which count on: a
                 # recompile in steady state shows
-                first = (prev["startup"]["phase_seconds"]
-                         if prev and "startup" in prev
-                         else dict(result.phase_seconds))
+                if self._first_phases is None:
+                    self._first_phases = dict(result.phase_seconds)
+                # the request's spans beside the cycle's root
+                entry = {name: sum(sp.seconds
+                                   for sp in request.root.children
+                                   if sp.name == name)
+                         for name in ("lock_wait", "coalesce")}
                 stats.update(
                     span_self_seconds=trace.self_seconds(),
                     snapshot=next(
                         (dict(sp.attrs) for sp in trace.root.children
                          if sp.name == "snapshot"), {}),
                     gc=trace.gc,
-                    entry_seconds={"lock_wait": lock_wait_s,
-                                   "coalesce": coalesce_s},
-                    intake_parsed_pods=parsed_pods,
+                    entry_seconds=entry,
+                    intake_parsed_pods=(merged["parsed_pods"]
+                                        if merged else 0),
                     victim_actions_skipped=dict(
                         result.victim_actions_skipped),
                     kernels=dict(result.kernels),
                     topology=dict(result.topology),
-                    startup={"phase_seconds": first,
+                    startup={"phase_seconds": self._first_phases,
                              **compile_watch.WATCHER.stage_seconds()})
+                if merged:
+                    # who admitted the coalesce's events, and their waits
+                    stats["lanes"] = merged["lanes"]
             # kai-pulse slice: the headline cluster-health gauges of
             # the latest analytics cycle (this one, or — on cycles the
             # cadence skipped — the last one that ran)
@@ -770,7 +877,20 @@ class SchedulerServer:
                     "migrations_executed":
                         result.repack["migrations_executed"],
                 }
-        self._cycle_stats = stats
+        return stats
+
+    def _publish(self, stats: dict, request) -> None:
+        """Finish a cycle's stats document with what its iteration's
+        requests did (``requests``, ``gc_iteration``; the tracer's
+        ``close_iteration``) and swap it in whole (``GET /healthz``;
+        readers take the current binding with no lock, the dict is never
+        mutated after publication).  Called by the handler of
+        ``request`` once the reply is encoded, before it is written."""
+        stats.update(self._tracer.close_iteration(request, stats.get("gc")))
+        with self._publish_lock:
+            prev = self._cycle_stats
+            if prev is None or prev["cycles"] < stats["cycles"]:
+                self._cycle_stats = stats
 
     def _intake_flush(self) -> None:
         """Degrade-to-sync valve (``intake_policy="sync"``): coalesce
@@ -778,7 +898,8 @@ class SchedulerServer:
         so an overflowing lane empties.  Called by the router from the
         submitting handler thread, which holds NO lane locks here."""
         with self._state_lock:
-            self.intake.coalesce(self.cluster)
+            with self._tracer.span("coalesce"):
+                self.intake.coalesce(self.cluster)
 
     def _serve_on_pool(self, request, client_address) -> None:
         """In place of ``ThreadingHTTPServer``'s new thread a request.
@@ -792,8 +913,30 @@ class SchedulerServer:
         of the idle cell sat on one of two levels by how that race went
         (PERF.md, PR 30).  A thread that stays keeps its arena, and the
         arena its heap (``runtime/malloc_tune.py``)."""
-        self._pool.submit(self._httpd.process_request_thread, request,
+        self._pool.submit(self._serve, (time.perf_counter(), request),
                           client_address)
+
+    def _serve(self, handed, client_address) -> None:
+        """On a pool thread: note when the connection was handed over
+        (``handed`` = (``perf_counter`` seconds, the connection); the
+        request's root span starts there: what it waited for a free
+        thread is its ``accept_wait``), then serve it.
+
+        The body is ``ThreadingMixIn.process_request_thread``'s, here
+        and not called, with as many locals: the handler runs exactly
+        as deep in its thread's stack as it did.  CPython 3.12 keeps a
+        thread's frames in 16 KiB chunks and maps and unmaps a chunk
+        each time a call crosses into a new one and returns, so a frame
+        more, or a few words more in one, at a thread's base moves
+        which calls of a cycle (JAX's dispatch path, 60–80 frames down)
+        pay that (PERF.md §6, PR 36)."""
+        self._handed.at = handed[0]
+        try:
+            self._httpd.finish_request(handed[1], client_address)
+        except Exception:  # noqa: BLE001 — as socketserver reports it
+            self._httpd.handle_error(handed[1], client_address)
+        finally:
+            self._httpd.shutdown_request(handed[1])
 
     def start(self) -> "SchedulerServer":
         compile_cache.enable()

@@ -152,14 +152,22 @@ class IntakeEvent:
     entity's identity — same entity, same lane, so per-entity ordering
     survives sharding)."""
 
-    __slots__ = ("seq", "op", "coll", "key", "payload")
+    __slots__ = ("seq", "op", "coll", "key", "payload", "offered",
+                 "in_coalesce")
 
-    def __init__(self, seq: int, op: str, coll: str, key: str, payload):
+    def __init__(self, seq: int, op: str, coll: str, key: str, payload,
+                 offered: float = 0.0):
         self.seq = seq
         self.op = op          # "upsert" | "delete" | "now"
         self.coll = coll      # collection attr; "" for "now"
         self.key = key        # routing key; "" for "now"
         self.payload = payload  # upsert doc | delete name | now float
+        #: ``perf_counter`` seconds of the submit that offered it (one
+        #: read a submit); the coalesce that takes it books the wait
+        self.offered = offered
+        #: admitted by the coalesce's own pre-drain, on the cycle's
+        #: thread, and not by a lane worker before it
+        self.in_coalesce = False
 
     def __repr__(self):  # pragma: no cover - debugging aid
         return (f"IntakeEvent(seq={self.seq}, op={self.op!r}, "

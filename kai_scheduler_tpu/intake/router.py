@@ -51,6 +51,7 @@ import zlib
 from operator import attrgetter
 
 from ..framework import metrics
+from ..runtime.tracing import annotation, span_of
 from . import apply as _apply
 from .apply import IntakeEvent
 
@@ -215,11 +216,17 @@ class IntakeRouter:
     that runs ``coalesce`` against the owning cluster under its commit
     lock.  The server wires it; a router without one sheds even under
     ``policy="sync"`` (counted, never silent).
+
+    ``tracer`` (optional) is the owning server's ``CycleTracer``: a
+    submit and a coalesce record their spans under whatever request is
+    open on the calling thread (``docs/TRACING.md``); without one, or
+    outside a request, they record nothing.
     """
 
     def __init__(self, config: IntakeConfig | None = None,
-                 sync_flush=None):
+                 sync_flush=None, tracer=None):
         self.config = config or IntakeConfig()
+        self._tracer = tracer
         self._lanes = tuple(
             _Lane(i, self.config.lane_capacity)
             for i in range(self.config.lanes))
@@ -231,6 +238,13 @@ class IntakeRouter:
         self._coalesced_events = 0  # kai-race: guarded-by=_lock
         self._sync_degrades = 0    # kai-race: guarded-by=_lock
         self._apply_errors = 0     # kai-race: guarded-by=_lock
+        #: of the events coalesces took: admitted before, off the
+        #: cycle's thread / by the coalesce's own pre-drain; and their
+        #: waits in a lane, from offer to take
+        self._by_workers = 0       # kai-race: guarded-by=_lock
+        self._in_coalesce = 0      # kai-race: guarded-by=_lock
+        self._wait_sum = 0.0       # kai-race: guarded-by=_lock
+        self._wait_max = 0.0       # kai-race: guarded-by=_lock
         #: drain workers; started/stopped from the owning thread only,
         #: handler-thread reads are liveness probes on the list binding
         self._threads: list = []   # kai-race: guarded-by=single-writer
@@ -288,7 +302,7 @@ class IntakeRouter:
         return lane.would_fit(n)
 
     def _submit_atomic(self, ops, all_or_nothing: bool = False
-                       ) -> tuple[int, list]:
+                       ) -> tuple[int, list, int]:
         """Assign the sequence block AND offer every lane group while
         holding the router lock, so offer order == seq order globally.
         Without that atomicity two racing submitters could offer out of
@@ -301,8 +315,9 @@ class IntakeRouter:
         the appends themselves."""
         order: list = []
         groups: dict[int, list] = {}
+        offered = time.perf_counter()
         for op, coll, key, payload in ops:
-            ev = IntakeEvent(0, op, coll, key, payload)
+            ev = IntakeEvent(0, op, coll, key, payload, offered)
             order.append(ev)
             groups.setdefault(self._lane_index(key), []).append(ev)
         with self._lock:
@@ -320,7 +335,8 @@ class IntakeRouter:
                                                   len(events))]
                 if causing:
                     return 0, [(idx, events, idx in causing)
-                               for idx, events in sorted(groups.items())]
+                               for idx, events in sorted(groups.items())
+                               ], len(groups)
             base = self._seq
             self._seq = base + len(order)
             for off, ev in enumerate(order):
@@ -333,7 +349,7 @@ class IntakeRouter:
                 else:
                     # a per-lane refusal is always its own lane's doing
                     shed_groups.append((idx, events, True))
-        return accepted, shed_groups
+        return accepted, shed_groups, len(groups)
 
     def submit_ops(self, ops, all_or_nothing: bool = False) -> dict:
         """Queue decomposed ``(op, coll, key, payload)`` operations.
@@ -347,7 +363,8 @@ class IntakeRouter:
         safe.  In-process callers keep per-lane partial accept and
         retry the ``shed_ops`` echo exactly."""
         n = len(ops)
-        accepted, shed_groups = self._submit_atomic(ops, all_or_nothing)
+        accepted, shed_groups, lanes = self._submit_atomic(
+            ops, all_or_nothing)
         if shed_groups and self.config.policy == "sync" \
                 and self._sync_flush is not None:
             # degrade to sync: become the old single-writer intake for
@@ -365,8 +382,8 @@ class IntakeRouter:
             retry_ops = [(e.op, e.coll, e.key, e.payload)
                          for _idx, events, _causing in shed_groups
                          for e in events]
-            more, shed_groups = self._submit_atomic(retry_ops,
-                                                    all_or_nothing)
+            more, shed_groups, _ = self._submit_atomic(retry_ops,
+                                                       all_or_nothing)
             accepted += more
         # shed accounting happens HERE, on the final outcome only — a
         # refusal the degrade path then delivered is not a drop.  The
@@ -387,6 +404,7 @@ class IntakeRouter:
         # per lane group, so a mixed-lane submit can be PARTIALLY
         # accepted — callers that retry must retry these, not guess)
         return {"accepted": accepted, "shed": shed, "total": n,
+                "lanes": lanes,
                 "shed_ops": [(e.op, e.coll, e.key, e.payload)
                              for _idx, events, _causing in shed_groups
                              for e in events]}
@@ -395,8 +413,12 @@ class IntakeRouter:
                      all_or_nothing: bool = False) -> dict:
         """Queue one delta document (the ``POST /intake`` body — the
         same schema ``POST /cluster/delta`` applies synchronously)."""
-        return self.submit_ops(_apply.decompose_delta(delta),
-                               all_or_nothing)
+        with span_of(self._tracer, "intake.submit") as sp:
+            out = self.submit_ops(_apply.decompose_delta(delta),
+                                  all_or_nothing)
+            sp.attrs.update(events=out["total"], lanes=out["lanes"],
+                            shed=out["shed"])
+        return out
 
     # -- drain (worker side) --------------------------------------------------
 
@@ -404,19 +426,24 @@ class IntakeRouter:
         """One lane's drain loop (daemon thread, one per lane)."""
         while not self._stop.is_set():
             lane.wake.clear()
-            if self._drain_lane(lane) == 0:
+            if self._drain_lane(lane)[0] == 0:
                 lane.wake.wait(0.05)
 
-    def _drain_lane(self, lane: _Lane) -> int:
+    def _drain_lane(self, lane: _Lane, in_coalesce: bool = False
+                    ) -> tuple[int, int]:
         """Pop one batch, admission-check it (vectorized), stage the
         admitted events — one whole round under the lane's drain lock
-        (see ``_Lane.drain_lock``).  Returns the events popped."""
+        (see ``_Lane.drain_lock``).  Returns the events popped and, of
+        them, admitted.  ``in_coalesce`` says the round runs on the
+        cycle's own thread (``coalesce``'s pre-drain) and not on a lane
+        worker's: the admitted events carry it to the take."""
         with lane.drain_lock:
             batch = lane.take_queued(self.config.batch)
             if not batch:
-                return 0
+                return 0, 0
             try:
-                ok, reasons = _apply.admit_batch(batch)
+                with annotation("lane.admit"):
+                    ok, reasons = _apply.admit_batch(batch)
             except Exception as exc:  # noqa: BLE001 — a poisoned batch
                 # must never kill the lane's worker (the lane would
                 # stop draining forever) or leak the inflight count:
@@ -426,11 +453,14 @@ class IntakeRouter:
             admitted = [ev for ev, good in zip(batch, ok) if good]
             errors = [(ev.seq, reasons[i])
                       for i, ev in enumerate(batch) if not ok[i]]
+            if in_coalesce:
+                for ev in admitted:
+                    ev.in_coalesce = True
             lane.stage(admitted, errors, len(batch))
         if errors:
             metrics.intake_rejected.inc(str(lane.idx),
                                         by=float(len(errors)))
-        return len(batch)
+        return len(batch), len(admitted)
 
     def drain_inline(self, timeout: float = 30.0) -> bool:
         """Quiesce the queues from the calling thread: help-drain every
@@ -441,7 +471,7 @@ class IntakeRouter:
         while True:
             moved = 0
             for lane in self._lanes:
-                moved += self._drain_lane(lane)
+                moved += self._drain_lane(lane)[0]
             if moved == 0 and all(lane.quiet() for lane in self._lanes):
                 return True
             if time.monotonic() > deadline:
@@ -478,14 +508,18 @@ class IntakeRouter:
         # the next window, so a sustained storm cannot livelock the
         # cycle.  Draining waits on a mid-round worker (drain_lock),
         # so nothing submitted-before-boundary is left in flight.
-        for lane in self._lanes:
-            target = self._lane_backlog(lane)
-            moved = 0
-            while moved < target:
-                n = self._drain_lane(lane)
-                if n == 0:
-                    break
-                moved += n
+        with span_of(self._tracer, "coalesce.drain") as sp:
+            admitted_here = 0
+            for lane in self._lanes:
+                target = self._lane_backlog(lane)
+                moved = 0
+                while moved < target:
+                    n, admitted = self._drain_lane(lane, in_coalesce=True)
+                    if n == 0:
+                        break
+                    moved += n
+                    admitted_here += admitted
+            sp.attrs["events"] = admitted_here
         # the take→cut→restage window runs under the ROUTER lock: the
         # all-or-nothing probe's soundness premise is that between its
         # capacity check and the offer, lane load can only shrink —
@@ -493,25 +527,45 @@ class IntakeRouter:
         # (both sit under the same lock; lane-lock nesting stays
         # router→lane, the one direction used everywhere)
         staged: list = []
-        with self._lock:
-            for lane in self._lanes:
-                taken = self._take_staged(lane)
-                cut = len(taken)
-                while cut > 0 and taken[cut - 1].seq >= watermark:
-                    cut -= 1
-                if cut < len(taken):
-                    self._restage(lane, taken[cut:])
-                staged.extend(taken[:cut])
-        staged.sort(key=attrgetter("seq"))
+        with span_of(self._tracer, "coalesce.take") as sp:
+            with self._lock:
+                for lane in self._lanes:
+                    taken = self._take_staged(lane)
+                    cut = len(taken)
+                    while cut > 0 and taken[cut - 1].seq >= watermark:
+                        cut -= 1
+                    if cut < len(taken):
+                        self._restage(lane, taken[cut:])
+                    staged.extend(taken[:cut])
+            staged.sort(key=attrgetter("seq"))
+            # each taken event's wait in its lane, from offer to here
+            now = time.perf_counter()
+            waits = [now - ev.offered for ev in staged]
+            wait_sum, wait_max = sum(waits), max(waits, default=0.0)
+            in_coalesce = sum(ev.in_coalesce for ev in staged)
+            lanes = {"admitted_by_workers": len(staged) - in_coalesce,
+                     "admitted_in_coalesce": in_coalesce,
+                     "lane_wait_seconds": {
+                         "mean": wait_sum / len(waits) if waits else 0.0,
+                         "max": wait_max}}
+            sp.attrs["events"] = len(staged)
         apply_errors: list = []
         parsed0 = _apply.PARSED_PODS[0]
-        n = _apply.apply_events(cluster, staged, errors=apply_errors)
-        applied = n - len(apply_errors)
+        with span_of(self._tracer, "coalesce.apply") as sp:
+            n = _apply.apply_events(cluster, staged, errors=apply_errors)
+            applied = n - len(apply_errors)
+            parsed = _apply.PARSED_PODS[0] - parsed0
+            sp.attrs.update(events=applied, parsed_pods=parsed,
+                            errors=len(apply_errors))
         dt = time.perf_counter() - t0
         with self._lock:
             self._coalesces += 1
             self._coalesced_events += applied
             self._apply_errors += len(apply_errors)
+            self._by_workers += lanes["admitted_by_workers"]
+            self._in_coalesce += in_coalesce
+            self._wait_sum += wait_sum
+            self._wait_max = max(self._wait_max, wait_max)
         if applied:
             metrics.intake_coalesced.inc(by=float(applied))
         if apply_errors:
@@ -525,9 +579,8 @@ class IntakeRouter:
             metrics.intake_lane_depth.set(
                 str(snap["lane"]),
                 value=float(snap["queued"] + snap["staged"]))
-        return {"events": applied, "seconds": dt,
-                "parsed_pods": _apply.PARSED_PODS[0] - parsed0,
-                "apply_errors": apply_errors[:8]}
+        return {"events": applied, "seconds": dt, "parsed_pods": parsed,
+                "lanes": lanes, "apply_errors": apply_errors[:8]}
 
     # -- observability ----------------------------------------------------------
 
@@ -538,6 +591,9 @@ class IntakeRouter:
             merged = self._coalesced_events
             degrades = self._sync_degrades
             apply_errors = self._apply_errors
+            by_workers, in_coalesce = self._by_workers, self._in_coalesce
+            wait_sum, wait_max = self._wait_sum, self._wait_max
+        taken = by_workers + in_coalesce
         return {
             "lanes": len(lanes),
             "queued": sum(s["queued"] for s in lanes),
@@ -549,6 +605,13 @@ class IntakeRouter:
             "coalesced_events": merged,
             "apply_errors": apply_errors,
             "sync_degrades": degrades,
+            # of the events coalesces took: who admitted them, and how
+            # long they waited in a lane from offer to take
+            "admitted_by_workers": by_workers,
+            "admitted_in_coalesce": in_coalesce,
+            "lane_wait_seconds": {
+                "mean": wait_sum / taken if taken else 0.0,
+                "max": wait_max},
         }
 
     def health(self) -> dict:

@@ -1048,12 +1048,16 @@ class IncrementalSnapshotter:
             setattr(self, name, np.concatenate(
                 [col, np.full((n,) + col.shape[1:], fill, dtype)]))
 
-    def _apply_journal(self, cluster, j
+    def _apply_journal(self, cluster, j, sections
                        ) -> tuple[set, set, np.ndarray | None]:
         """Membership + dirty-field updates → (dirty pod rows, dirty
         gang rows, old row of each gang row).  The last is None unless
         gang rows went (``_remove_gangs``).  Raises _Fallback on
-        anything unpatchable."""
+        anything unpatchable.  ``sections(name)`` marks its steps:
+        ``journal.removed`` (pods that went, and the closing-up of
+        removed gang rows), ``journal.gangs`` (added and dirty gangs),
+        ``journal.pods`` (added and dirty pods, ``_encode_pod``)."""
+        sections("journal.removed")
         dirty_gangs: set[int] = set()
         dirty_rows: set[int] = set()
         membership = False
@@ -1080,6 +1084,7 @@ class IncrementalSnapshotter:
                 cluster, j.gangs_removed, dirty_rows, dirty_gangs)
         self._last_removed = (pods_gone, gangs_gone)
         # gang appends before pod appends so new pods resolve their row
+        sections("journal.gangs")
         if j.gangs_added:
             for name in j.gangs_added:
                 g = cluster.pod_groups.get(name)
@@ -1116,6 +1121,7 @@ class IncrementalSnapshotter:
                 raise _Fallback("gang-removed-unjournaled")
             self._encode_gang(i, g)
             dirty_gangs.add(i)
+        sections("journal.pods")
         added_rows: list[int] = []
         for name in j.pods_added:
             pod = cluster.pods.get(name)
@@ -1205,11 +1211,15 @@ class IncrementalSnapshotter:
             dirty_rows.add(row)
         return src, len(gone)
 
-    def _sweep(self, cluster, dirty_rows: set, dirty_gangs: set) -> None:
+    def _sweep(self, cluster, dirty_rows: set, dirty_gangs: set,
+               sections) -> None:
         """Detect un-journaled drift: object replacement, status/node
         writes, gang status writes, node mutations.  Cheap identity and
         field compares; anything the ledger cannot attribute raises
-        _Fallback (full rebuild) rather than serving stale state."""
+        _Fallback (full rebuild) rather than serving stale state.
+        ``sections(name)`` marks its four loops: ``sweep.bind_requests``,
+        ``sweep.pods``, ``sweep.gangs``, ``sweep.nodes``."""
+        sections("sweep.bind_requests")
         if len(cluster.pods) != len(self._order_list):
             raise _Fallback("pod-membership-drift")
         # BindRequest drift (created/replaced/phase-flipped/cleared —
@@ -1243,6 +1253,7 @@ class IncrementalSnapshotter:
                 gi = int(self.p_group[row])
                 if gi >= 0:
                     dirty_gangs.add(gi)
+        sections("sweep.pods")
         cache = self.p_sweep
         changed: list[int] = []
         for row, pod in zip(self._order_list, cluster.pods.values()):
@@ -1261,6 +1272,7 @@ class IncrementalSnapshotter:
                 gi = int(self.p_group[row])
                 if gi >= 0:
                     dirty_gangs.add(gi)
+        sections("sweep.gangs")
         if len(cluster.pod_groups) != len(self.g_objs):
             raise _Fallback("gang-membership-drift")
         for i, g in enumerate(cluster.pod_groups.values()):
@@ -1280,6 +1292,7 @@ class IncrementalSnapshotter:
                 dirty_gangs.add(i)
         # nodes: any drift at all → full rebuild (vocabularies, masks,
         # device tables and capacity all hang off the node section)
+        sections("sweep.nodes")
         node_vals = [n for n in cluster.nodes.values()
                      if not n.unschedulable]
         if len(node_vals) != len(self._node_objs):
@@ -1293,13 +1306,22 @@ class IncrementalSnapshotter:
                 raise _Fallback("node-drift")
 
     def _patch(self, cluster, j, now, queue_usage):
+        # each block's sections close inside its span, whatever raises
+        sections = SpanSections(self._tracer)
         with self._span("patch.journal"):
-            if len(self.p_objs) > 2 * max(int(self.p_live.sum()), 64):
-                self._compact_pods()
-            dirty_rows, dirty_gangs, gang_src = self._apply_journal(
-                cluster, j)
+            try:
+                sections("journal.compact")
+                if len(self.p_objs) > 2 * max(int(self.p_live.sum()), 64):
+                    self._compact_pods()
+                dirty_rows, dirty_gangs, gang_src = self._apply_journal(
+                    cluster, j, sections)
+            finally:
+                sections.close()
         with self._span("patch.sweep"):
-            self._sweep(cluster, dirty_rows, dirty_gangs)
+            try:
+                self._sweep(cluster, dirty_rows, dirty_gangs, sections)
+            finally:
+                sections.close()
         self._last_dirty = (len(dirty_rows), len(dirty_gangs))
         if self._nonplain > 0:
             raise _Fallback("nonplain-pods")
@@ -1320,7 +1342,6 @@ class IncrementalSnapshotter:
         if now is None:
             order = self._order
             now = float(self.p_crea[order].max()) if len(order) else 0.0
-        sections = SpanSections(self._tracer)
         with self._span("patch.assemble"):
             try:
                 return self._assemble(

@@ -809,3 +809,64 @@ def test_conf_intake_keys_round_trip():
         IntakeConfig(policy="yolo")
     with pytest.raises(ValueError):
         IntakeConfig(lanes=0)
+
+
+# ---------------------------------------------------------------------------
+# the lanes, counted: who admitted an event, how long it waited (ISSUE 36)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("drained_first", [False, True])
+def test_coalesce_says_who_admitted_and_how_long_events_waited(
+        drained_first):
+    """With the workers stopped every event of a submit is admitted by
+    the coalesce's own pre-drain, on the cycle's thread: the cycle's
+    ``lanes``, the ``coalesce.drain`` span and the router's totals all
+    count it.  With the lanes run to quiescence first none is.  Either
+    way an event's wait runs from the submit that offered it to the
+    coalesce that took it."""
+    import time
+
+    from kai_scheduler_tpu.runtime.tracing import CycleTracer
+    tracer = CycleTracer()
+    cluster = Cluster()
+    router = IntakeRouter(IntakeConfig(lanes=4), tracer=tracer)  # no workers
+    with tracer.request("/intake") as posted:
+        out = router.submit_delta({
+            "pod_groups_upsert": [{"name": "pg", "queue": "q"}],
+            "pods_upsert": [{"name": f"pg-{i}", "group": "pg"}
+                            for i in range(8)]})
+    assert out["accepted"] == 9
+    submit = posted.root.children[0]
+    assert submit.name == "intake.submit"
+    assert submit.attrs["events"] == 9 and submit.attrs["shed"] == 0
+    assert 1 <= submit.attrs["lanes"] <= 4
+    if drained_first:
+        assert router.drain_inline()
+    time.sleep(0.05)
+    with tracer.request("/cycle/stored") as req:
+        with tracer.span("coalesce"):
+            summary = router.coalesce(cluster)
+    here, before = (0, 9) if drained_first else (9, 0)
+    lanes = summary["lanes"]
+    assert lanes["admitted_in_coalesce"] == here
+    assert lanes["admitted_by_workers"] == before
+    assert lanes["lane_wait_seconds"]["max"] >= 0.05
+    assert 0.05 <= lanes["lane_wait_seconds"]["mean"] \
+        <= lanes["lane_wait_seconds"]["max"]
+    drain, take, apply_span = req.root.children[0].children
+    assert [drain.name, take.name, apply_span.name] == [
+        "coalesce.drain", "coalesce.take", "coalesce.apply"]
+    assert drain.attrs["events"] == here
+    assert take.attrs["events"] == 9
+    assert apply_span.attrs == {"events": 9, "parsed_pods": 0, "errors": 0}
+    # the totals on /debug/intake count on
+    doc = router.debug_doc()
+    assert doc["admitted_in_coalesce"] == here
+    assert doc["admitted_by_workers"] == before
+    assert doc["lane_wait_seconds"] == lanes["lane_wait_seconds"]
+    # an empty coalesce takes nothing and waits for nothing
+    empty = router.coalesce(cluster)["lanes"]
+    assert empty == {"admitted_by_workers": 0, "admitted_in_coalesce": 0,
+                     "lane_wait_seconds": {"mean": 0.0, "max": 0.0}}
+    assert router.debug_doc()["admitted_in_coalesce"] == here

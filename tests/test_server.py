@@ -5,6 +5,8 @@ import json
 import time
 import urllib.request
 
+import pytest
+
 from kai_scheduler_tpu.apis import types as apis
 from kai_scheduler_tpu.framework.server import SchedulerServer, run_cycle_doc
 from kai_scheduler_tpu.runtime.cluster import Cluster
@@ -510,3 +512,145 @@ def test_requests_run_on_threads_that_stay():
     while handlers() - before and time.monotonic() < deadline:
         time.sleep(0.01)
     assert not handlers() - before
+
+
+# ---------------------------------------------------------------------------
+# an iteration is three requests (ISSUE 36; docs/TRACING.md)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("framing", ["json", "protobuf"])
+def test_round_leaves_three_requests_on_healthz(framing):
+    """One ``/cluster/delta`` + ``/intake`` + ``/cycle/stored`` round:
+    the document a client reads directly after the cycle's reply is that
+    cycle's, whole, with the three requests of its iteration."""
+    from served_round import (PATHS, closed_requests, post_round,
+                              start_server)
+    server, base = start_server()
+    try:
+        docs = [post_round(base, cyc, framing) for cyc in range(2)]
+        ring = closed_requests(server, 6)
+    finally:
+        server.stop()
+    for n, doc in enumerate(docs, start=1):
+        assert doc["cycles"] == n     # this cycle's, not the one before
+        assert set(doc["requests"]) == set(PATHS)
+        for path, req in doc["requests"].items():
+            assert req["count"] == 1, path
+            # (an empty protobuf CommitSet is no byte at all)
+            assert req["bytes_in"] >= 0 and req["bytes_out"] >= 0
+            assert abs(sum(req["span_self_seconds"].values())
+                       - req["total_seconds"]) < 1e-6, path
+        mine = doc["requests"]["/cycle/stored"]
+        # published before the reply left: its write is the next one's
+        assert "request/reply.write" not in mine["span_self_seconds"]
+        assert ("previous_reply_write_seconds" in mine) == (n == 2)
+        assert mine["total_seconds"] >= doc["total_seconds"]
+        assert mine["bytes_out"] > 0 or framing == "protobuf"
+        # entry_seconds reads the request's spans
+        under = sum(v for p, v in mine["span_self_seconds"].items()
+                    if "coalesce" in p.split("/"))
+        assert doc["entry_seconds"]["coalesce"] == pytest.approx(under)
+        assert doc["entry_seconds"]["lock_wait"] == pytest.approx(
+            mine["span_self_seconds"]["request/lock_wait"])
+        assert doc["lanes"]["admitted_by_workers"] \
+            + doc["lanes"]["admitted_in_coalesce"] == 18
+        assert doc["lanes"]["lane_wait_seconds"]["max"] > 0.0
+        # what point 6 took off the document
+        assert "commit_seconds" not in doc and "open_seconds" not in doc
+    assert [r.path for r in ring] == list(PATHS) * 2
+    framings = {r.root.attrs["framing"] for r in ring
+                if r.path != "/intake"}
+    assert framings == {framing}
+    assert all(r.root.attrs["status"] == 200 for r in ring)
+    stored = ring[-1]
+    assert [c.name for c in stored.root.children
+            if c.name != "accept_wait"] == [
+        "http.read", "lock_wait", "coalesce", "cycle", "record",
+        "reply.encode", "record", "reply.write"]
+    coalesce = next(c for c in stored.root.children
+                    if c.name == "coalesce")
+    assert [c.name for c in coalesce.children] == [
+        "coalesce.drain", "coalesce.take", "coalesce.apply"]
+    assert coalesce.children[2].attrs["events"] == 18
+    assert coalesce.seconds == pytest.approx(
+        docs[-1]["entry_seconds"]["coalesce"])
+    delta = ring[-3]
+    apply_span = next(c for c in delta.root.children
+                      if c.name == "delta.apply")
+    assert apply_span.attrs["pods_delete"] == 8
+    assert apply_span.attrs["pod_groups_delete"] == 1
+
+
+@pytest.mark.parametrize("where, part", [
+    ("coalesce.apply", "in_requests"), ("cycle", "in_cycle"),
+    ("client", "between_requests")])
+def test_forced_collection_lands_in_its_part_of_the_iteration(
+        monkeypatch, where, part):
+    """A full collection forced inside the coalesce's apply, inside the
+    cycle, or by the client between two requests: ``gc_iteration`` books
+    it in that part alone, the three parts add up to what the watch
+    counted between two publications, and the one in the coalesce is a
+    ``gc.pause`` under ``coalesce.apply``, not in ``last_cycle.gc``."""
+    import gc
+
+    from kai_scheduler_tpu.framework.scheduler import Scheduler
+    from kai_scheduler_tpu.intake import apply as intake_apply
+    from served_round import closed_requests, post_round, start_server
+    server, base = start_server()
+    gc.disable()                      # only the forced collection
+    try:
+        post_round(base, 0)           # compiles; lanes and pool are up
+        assert len(closed_requests(server, 3)) == 3
+        apply_events = intake_apply.apply_events
+        record_metrics = Scheduler._record_metrics
+
+        def collecting(fn):
+            def wrapped(*args, **kwargs):
+                # the coalesce hands its applier an error list; the
+                # classic path of ``/cluster/delta`` does not
+                if fn is record_metrics or kwargs.get("errors") is not None:
+                    gc.collect()
+                return fn(*args, **kwargs)
+            return wrapped
+
+        if where == "coalesce.apply":
+            monkeypatch.setattr(intake_apply, "apply_events",
+                                collecting(apply_events))
+        elif where == "cycle":
+            monkeypatch.setattr(Scheduler, "_record_metrics",
+                                collecting(record_metrics))
+        # nothing collects on its own, so the watch's totals stand
+        # still from the first round's publication to here
+        before = server._gc_watch.read()
+        first = json.load(urllib.request.urlopen(
+            f"{base}/healthz"))["last_cycle"]
+        if where == "client":
+            gc.collect()
+        doc = post_round(base, 1)
+        after = server._gc_watch.read()
+    finally:
+        gc.enable()
+        server.stop()
+    assert first["cycles"] == 1 and doc["cycles"] == 2
+    parts = doc["gc_iteration"]
+    assert set(parts) == {"in_cycle", "in_requests", "between_requests"}
+    for name, booked in parts.items():
+        assert booked["collections"] == (
+            [0, 0, 1] if name == part else [0, 0, 0]), name
+    assert parts["in_cycle"] == doc["gc"]
+    for gen in range(3):
+        assert sum(p["collections"][gen] for p in parts.values()) \
+            == after[0][gen] - before[0][gen]
+        assert sum(p["pause_seconds"][gen] for p in parts.values()) \
+            == pytest.approx(after[1][gen] - before[1][gen])
+    selfs = doc["requests"]["/cycle/stored"]["span_self_seconds"]
+    pause = "request/coalesce/coalesce.apply/gc.pause"
+    if where == "coalesce.apply":
+        assert selfs[pause] == pytest.approx(
+            parts["in_requests"]["pause_seconds"][2])
+        assert doc["gc"]["collections"] == [0, 0, 0]
+        assert not [p for p in doc["span_self_seconds"]
+                    if p.endswith("gc.pause")]
+    else:
+        assert pause not in selfs

@@ -14,6 +14,7 @@ Covers the acceptance properties directly:
   which lints the new modules with the rest of the package).
 """
 import json
+import time
 import urllib.request
 
 import pytest
@@ -24,6 +25,9 @@ from kai_scheduler_tpu.framework.server import SchedulerServer
 from kai_scheduler_tpu.runtime.cluster import Cluster
 from kai_scheduler_tpu.runtime.events import DecisionLog, GangDecision
 from kai_scheduler_tpu.runtime.tracing import CycleTracer
+from served_round import churn_cluster as _churn_cluster
+from served_round import post_round as _round
+from served_round import start_server as _start_server
 
 PHASES = {"snapshot", "upload", "solve_dispatch", "device_wait",
           "host_decode", "commit"}
@@ -591,11 +595,64 @@ def test_each_action_has_a_device_scope():
                    for ln in locs), scope
 
 
-def test_cycle_and_spans_enter_profiler_annotations(monkeypatch):
+CYCLE_PHASES = ["kai:cycle", "kai:snapshot", "kai:solve_dispatch",
+                "kai:device_wait", "kai:host_decode", "kai:commit"]
+
+
+def _run_once_twice(entered, start_recording):
+    cluster = _small_cluster()
+    sched = Scheduler()
+    sched.run_once(cluster)           # compile outside the recording
+    start_recording()
+    cluster.tick()
+    sched.run_once(cluster)
+    trace = sched.tracer.last(1)[0]
+    # a cycle with no request around it enters no request's annotation
+    assert not [n for n in entered if n.startswith("kai:request")]
+    assert abs(trace.wall_start_ns / 1e9 - time.time()) < 600.0
+    return CYCLE_PHASES
+
+
+def _served_rounds(entered, start_recording):
+    """Two rounds of what the harness posts; the second patches."""
+    server, base = _start_server(_churn_cluster())
+    try:
+        _round(base, 0)               # compile outside the recording
+        start_recording()
+        _round(base, 1)
+    finally:
+        server.stop()
+    req = [n for n in entered if n.startswith("kai:request:")]
+    assert req == ["kai:request:/cluster/delta", "kai:request:/intake",
+                   "kai:request:/cycle/stored"]
+    # every span of the requests, of the patch's blocks and of the
+    # dispatch is in the capture, and the lanes' admission beside them
+    for name in ("kai:http.read", "kai:body.parse", "kai:delta.apply",
+                 "kai:intake.submit", "kai:coalesce", "kai:coalesce.drain",
+                 "kai:coalesce.take", "kai:coalesce.apply", "kai:record",
+                 "kai:reply.encode", "kai:reply.write", "kai:lane.admit",
+                 "kai:journal.compact", "kai:journal.removed",
+                 "kai:journal.gangs", "kai:journal.pods",
+                 "kai:sweep.bind_requests", "kai:sweep.pods",
+                 "kai:sweep.gangs", "kai:sweep.nodes",
+                 "kai:dispatch.init_result"):
+        assert name in entered, name
+    # the request of the cycle encloses it: coalesce before the cycle's
+    # root, the document and the reply after its last phase
+    at = entered[entered.index("kai:request:/cycle/stored"):].index
+    assert (at("kai:coalesce") < at("kai:cycle") < at("kai:commit")
+            < at("kai:record") < at("kai:reply.encode")
+            < at("kai:reply.write"))
+    return CYCLE_PHASES
+
+
+@pytest.mark.parametrize("drive", [_run_once_twice, _served_rounds])
+def test_cycle_and_spans_enter_profiler_annotations(monkeypatch, drive):
     """One clock with the device: with ``TraceAnnotation`` replaced by a
     recorder (no profiler session: one a process, seconds to start), a
-    cycle enters ``kai:cycle`` and its phases in order, and nothing
-    under a name the benchmark harness filters by."""
+    cycle enters ``kai:cycle`` and its phases in order, a served round
+    its three requests around them, and nothing under a name the
+    benchmark harness filters by."""
     import jax.profiler
 
     entered = []
@@ -611,21 +668,11 @@ def test_cycle_and_spans_enter_profiler_annotations(monkeypatch):
         def __exit__(self, *exc):
             return False
 
-    cluster = _small_cluster()
-    sched = Scheduler()
-    sched.run_once(cluster)           # compile outside the recording
-    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
-    cluster.tick()
-    sched.run_once(cluster)
+    want = drive(entered, lambda: monkeypatch.setattr(
+        jax.profiler, "TraceAnnotation", Recorder))
     assert all(n.startswith("kai:") for n in entered)
-    phases = [n for n in entered if n in (
-        "kai:cycle", "kai:snapshot", "kai:solve_dispatch",
-        "kai:device_wait", "kai:host_decode", "kai:commit")]
-    assert phases == ["kai:cycle", "kai:snapshot", "kai:solve_dispatch",
-                      "kai:device_wait", "kai:host_decode", "kai:commit"]
+    assert [n for n in entered if n in want] == want
     assert not {"churn_post", "cycle_post"} & set(entered)
-    trace = sched.tracer.last(1)[0]
-    assert abs(trace.wall_start_ns / 1e9 - trace.wall_start) < 1e-3
 
 
 def test_forced_full_collection_is_one_gc_pause_span():
@@ -709,3 +756,246 @@ def test_jit_miss_inside_a_cycle_is_a_compile_span():
     assert after["trace_s"] > before["trace_s"]
     assert all(after[k] >= before[k] for k in before)
     toy(jnp.arange(9))                # a miss outside any cycle: no-op
+
+
+# ---------------------------------------------------------------------------
+# a request is a trace; the collector wherever it runs (ISSUE 36)
+# ---------------------------------------------------------------------------
+
+
+def _bare_request(tr):
+    with tr.request("/intake", framing="json") as req:
+        with tr.span("http.read"):
+            pass
+        with tr.span("intake.submit"):
+            with tr.span("coalesce"):
+                pass
+    return req
+
+
+def _handed_over_request(tr):
+    with tr.request("/cluster/delta",
+                    start=time.perf_counter() - 0.005) as req:
+        with tr.span("delta.apply"):
+            pass
+    assert req.root.children[0].name == "accept_wait"
+    assert req.root.children[0].seconds >= 0.005
+    return req
+
+
+def _request_around_a_cycle(tr):
+    with tr.request("/cycle/stored") as req:
+        with tr.span("coalesce"):
+            pass
+        with tr.cycle():
+            with tr.span("snapshot"):
+                with tr.span("snapshot.patch"):
+                    pass
+        with tr.span("reply.write"):
+            pass
+    # the nested cycle counts whole: its own trace has its inside
+    assert "request/cycle" in req.self_seconds()
+    assert not [p for p in req.self_seconds() if "snapshot" in p]
+    return req
+
+
+def _published_request(tr):
+    with tr.request("/cycle") as req:
+        with tr.cycle() as trace:
+            pass
+        with tr.span("record"):
+            doc = tr.close_iteration(req, trace.gc)
+            mine = doc["requests"]["/cycle"]
+            # as it stood: its spans partition what it had spent
+            assert mine["count"] == 1
+            assert abs(sum(mine["span_self_seconds"].values())
+                       - mine["total_seconds"]) < 1e-9
+        with tr.span("reply.write"):
+            time.sleep(0.002)
+    # what followed is the next iteration's, under its own key
+    with tr.request("/cycle") as nxt:
+        with tr.span("record"):
+            after = tr.close_iteration(nxt, trace.gc)["requests"]["/cycle"]
+    assert after["count"] == 1
+    assert after["previous_reply_write_seconds"] >= 0.002
+    return req
+
+
+@pytest.mark.parametrize("make", [
+    _bare_request, _handed_over_request, _request_around_a_cycle,
+    _published_request])
+def test_request_self_seconds_partition_its_root(make):
+    tr = CycleTracer()
+    req = make(tr)
+    selfs = req.self_seconds()
+    assert abs(sum(selfs.values()) - req.root.seconds) < 1e-9
+    assert all(v >= 0.0 for v in selfs.values())
+    assert all(p.split("/")[0] == "request" for p in selfs)
+    assert req.root.attrs["path"] == req.path
+    assert tr.last_requests(8)[0] is req
+    _assert_strictly_nested(tr.export_chrome())
+
+
+def _phases_of(tr, inside_request: bool):
+    import contextlib
+    around = (tr.request("/cycle/stored") if inside_request
+              else contextlib.nullcontext())
+    with around as req:
+        with tr.cycle(tag="t") as trace:
+            with tr.span("snapshot"):
+                with tr.span("snapshot.patch"):
+                    pass
+                tr.add_span("upload", time.perf_counter(),
+                            time.perf_counter())
+            with tr.span("device_wait", device_sync=True):
+                pass
+    return req, trace
+
+
+@pytest.mark.parametrize("inside_request", [False, True])
+def test_cycle_is_the_same_trace_inside_a_request_and_without(
+        inside_request):
+    """A cycle nested in a request keeps its ``cycle/...`` paths and its
+    phases to the digit, and closes into the cycle ring as the trace it
+    is; with no request around it nothing is different."""
+    tr = CycleTracer()
+    req, trace = _phases_of(tr, inside_request)
+    assert tr.last(1) == [trace] and trace.root.name == "cycle"
+    assert set(trace.self_seconds()) == {
+        "cycle", "cycle/snapshot", "cycle/snapshot/snapshot.patch",
+        "cycle/snapshot/upload", "cycle/device_wait"}
+    assert set(trace.phase_seconds()) == {"snapshot", "upload",
+                                          "device_wait"}
+    assert abs(sum(trace.self_seconds().values())
+               - trace.root.seconds) < 1e-9
+    if inside_request:
+        # the same spans, read through the request's tree
+        hung = [c for c in req.root.children if c.name == "cycle"]
+        assert hung == [trace.root]
+        from kai_scheduler_tpu.runtime.tracing import CycleTrace
+        assert (CycleTrace(0, hung[0]).phase_seconds()
+                == trace.phase_seconds())
+        assert tr.last_requests(1) == [req]
+        lanes = {e["args"]["name"] for e in tr.export_chrome()["traceEvents"]
+                 if e.get("name") == "thread_name"}
+        assert lanes == {"cycle-0", "request-0 /cycle/stored"}
+    else:
+        assert req is None and tr.last_requests(1) == []
+
+
+def test_forced_collection_outside_the_cycle_is_the_requests():
+    """A full collection inside a request but outside its cycle's root
+    is a ``gc.pause`` under the span it fell in and ``in_requests`` of
+    the iteration; the cycle's own ``gc`` does not hold it, and the
+    three parts add up to what the watch counted."""
+    import gc
+
+    from kai_scheduler_tpu.runtime.tracing import GcWatch
+    watch = GcWatch().install()
+    tr = CycleTracer(gc_watch=watch)
+    gc.disable()                      # only the forced collections
+    try:
+        gc.collect()                  # before any request: between
+        with tr.request("/cycle/stored") as req:
+            with tr.span("coalesce"):
+                with tr.span("coalesce.apply"):
+                    gc.collect()
+            with tr.cycle() as trace:
+                with tr.span("snapshot"):
+                    gc.collect()
+            with tr.span("record"):
+                doc = tr.close_iteration(req, trace.gc)
+        total = watch.read()
+    finally:
+        gc.enable()
+        watch.uninstall()
+    parts = doc["gc_iteration"]
+    assert [parts[k]["collections"] for k in (
+        "in_cycle", "in_requests", "between_requests")] == [
+            [0, 0, 1], [0, 0, 1], [0, 0, 1]]
+    assert trace.gc["collections"] == [0, 0, 1]
+    for gen in range(3):
+        assert sum(parts[k]["collections"][gen] for k in parts) \
+            == total[0][gen]
+        assert abs(sum(parts[k]["pause_seconds"][gen] for k in parts)
+                   - total[1][gen]) < 1e-9
+    selfs = doc["requests"]["/cycle/stored"]["span_self_seconds"]
+    pause = selfs["request/coalesce/coalesce.apply/gc.pause"]
+    assert abs(pause - parts["in_requests"]["pause_seconds"][2]) < 1e-9
+    # the cycle's pause is the cycle's: one span, in its own tree
+    assert [p for p in selfs if p.endswith("gc.pause")] == [
+        "request/coalesce/coalesce.apply/gc.pause"]
+    assert "cycle/snapshot/gc.pause" in trace.self_seconds()
+    assert abs(sum(req.self_seconds().values()) - req.root.seconds) < 1e-9
+
+
+# ---------------------------------------------------------------------------
+# the benchmark's readers of the request traces (ISSUE 36)
+# ---------------------------------------------------------------------------
+
+#: the per-layer metrics ISSUE 36 added, with the cells their entry lists
+#: (None: every cell)
+_CHURN_CELLS = ["reclaim-10k.steady", "alloc-10k.churn", "pools-10k.churn",
+                "kubeflow-10k.churn", "topology-10k.churn"]
+REQUEST_METRICS = {
+    "entry_request_gap_ms": None, "reply_encode_ms": None,
+    "coalesce_drain_ms": _CHURN_CELLS, "coalesce_apply_ms": _CHURN_CELLS,
+    "churn_parse_ms": _CHURN_CELLS, "delta_apply_ms": _CHURN_CELLS,
+    "intake_submit_ms": _CHURN_CELLS, "lane_wait_ms": _CHURN_CELLS,
+    "lane_admitted_in_coalesce": _CHURN_CELLS,
+    "gc_outside_cycle_ms": None, "gc_full_outside_cycle": None,
+    "patch_journal_ms": None}
+
+
+@pytest.fixture(scope="module")
+def served_healths():
+    """``last_cycle`` of two served rounds at 64 nodes (the second
+    patches), each read directly after its cycle's reply."""
+    server, base = _start_server(_churn_cluster())
+    try:
+        return [_round(base, cyc) for cyc in range(2)]
+    finally:
+        server.stop()
+
+
+@pytest.mark.parametrize("metric", sorted(REQUEST_METRICS))
+def test_benchmark_reads_the_requests_from_healthz(metric, served_healths):
+    """Every per-layer entry ISSUE 36 added to ``BENCHMARK.json`` has a
+    reader file; over ``last_cycle`` of a served round it gives a
+    number, over a document without ``requests`` (a program from before
+    them) nothing, and not an error."""
+    import importlib.util
+    import os
+    import sys
+    import types
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as fh:
+        entry = next(m for m in json.load(fh)["per_layer"]
+                     if m["name"] == metric)
+    assert entry["moves"] == "cycle_ms" and entry["better"] == "lower"
+    assert entry.get("workloads") == REQUEST_METRICS[metric]
+    bench = os.path.join(root, "benchmark")
+    sys.path.insert(0, bench)
+    try:
+        spec = importlib.util.spec_from_file_location(
+            metric, os.path.join(bench, "layer_metrics", f"{metric}.py"))
+        reader = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(reader)
+    finally:
+        sys.path.remove(bench)
+    run = types.SimpleNamespace(cycles=[
+        {"health": h, "cycle_post_s": h["requests"]["/cycle/stored"][
+            "total_seconds"] + 0.001} for h in served_healths])
+    value = reader.read(run)
+    assert value is not None and value >= 0.0
+    if metric == "entry_request_gap_ms":
+        assert value == pytest.approx(1.0)
+    if metric in ("reply_encode_ms", "churn_parse_ms", "delta_apply_ms",
+                  "intake_submit_ms", "coalesce_apply_ms", "lane_wait_ms",
+                  "patch_journal_ms"):
+        assert value > 0.0
+    older = types.SimpleNamespace(cycles=[{
+        "cycle_post_s": 0.1, "health": {
+            k: v for k, v in served_healths[-1].items()
+            if k not in ("requests", "gc_iteration", "lanes")}}])
+    assert reader.read(older) is None
