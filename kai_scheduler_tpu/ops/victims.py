@@ -63,7 +63,7 @@ from ..apis.types import UNLIMITED
 from ..runtime import compile_watch
 from ..utils.numerics import cumsum_ds, einsum_exact
 from ..state.cluster_state import ClusterState
-from . import ordering
+from . import ordering, unit_segments
 from .allocate import (AllocateConfig, AllocationResult, _ancestor_gate,
                        _attempt_gang, _chain_membership, anti_defer_lanes,
                        anti_domain_tables, anti_forbid_nodes,
@@ -126,10 +126,11 @@ class VictimConfig:
     #: composed path.  True still requires the structural conditions.
     optimistic_preempt: bool | None = None
     #: width of the compact per-queue eviction-unit tables the sparse
-    #: preempt path probes (top-K units per queue, the sparse analogue
-    #: of the dense [U, Q, R] cumulative tables).  An action whose
+    #: preempt path probes (top-K units per queue, a [Q, K] grid cut
+    #: from the per-leaf unit segments).  An action whose
     #: frozen unit order gives any queue more candidate units than this
-    #: falls back to the dense composed path at run time (counted by
+    #: falls back to the composed path over the full segments at run
+    #: time (counted by
     #: the ``kai_victim_wavefront_sparse_fallbacks`` gauge).  None =
     #: auto: the Session derives it from running-pod density per leaf
     #: queue (non-Session callers get 256); an explicit value is
@@ -856,10 +857,13 @@ def _run_victim_action_chunked(
       queue's units and re-ranking yields the identical suffix), so the
       per-chunk consumed state is just a per-queue pointer ``c [Q]``
       over the frozen global rank space.
-    - all per-unit tables (requests, per-leaf-queue cumulative freed,
-      the strategy-bound subtree cumulative ``S_cols``, leaf
-      positions/counts) are built once; chunks probe them with
-      searchsorted/gathers only.
+    - all per-unit tables are built once: the units' requests, and
+      every queue's units as one rank-ordered SEGMENT of a sorted unit
+      axis with its running freed sums — a segment per leaf (``[U]``
+      rows) and, for reclaim's strategy bounds, one per subtree
+      (``[U * num_levels]`` rows; ``ops/unit_segments.py``).  Chunks
+      probe them with binary searches inside a segment and gathers;
+      nothing has a column per queue.
     - the preemptor order is frozen once (``job_order_perm`` at action
       start) — the fairness interleaving across queues is baked into
       the order; within a queue the job keys are static anyway.
@@ -889,10 +893,10 @@ def _run_victim_action_chunked(
     nothing but node free capacity, and the problem is queue-disjoint
     by construction.  The sparse path exploits that structure:
 
-    - the dense [U, Q, R] cumulative-freed tables (and their [B, U, R]
-      per-chunk gathers) shrink to compact per-queue top-K unit tables
-      ``Cq [Q, KU, R]`` / ``pos_c [Q, KU+1]`` / ``prio_c [Q, KU]``
-      probed with tiny searchsorteds;
+    - the per-leaf segments (and the composed path's [B, R, U]
+      per-chunk lane columns) shrink to compact per-queue top-K unit
+      tables ``Cq [Q, KU, R]`` / ``pos_c [Q, KU+1]`` / ``prio_c
+      [Q, KU]`` probed with tiny searchsorteds;
     - every lane solves OPTIMISTICALLY against its OWN queue's freed
       capacity only (``_freed_by_lane(compose=False)``) — no [B, N, *]
       lane-prefix cumsum is ever materialized;
@@ -923,12 +927,15 @@ def _run_victim_action_chunked(
       (``kai_victim_wavefront_leftover_demotions``).
 
     An action whose frozen unit order gives any queue more candidate
-    units than ``VictimConfig.sparse_unit_k`` falls back to the dense
-    composed path at run time (one ``lax.cond``, counted in
-    ``wavefront_stats`` — the incremental engine's auto-fallback
+    units than ``VictimConfig.sparse_unit_k`` falls back to the composed
+    path over the full segments at run time (one ``lax.cond``, counted
+    in ``wavefront_stats`` — the incremental engine's auto-fallback
     pattern); snapshots whose shape rejects the sparse placement
     protocol (devices / extended / subgroup topology / non-uniform
-    gangs) take the dense path statically.
+    gangs) take the composed path statically.  Reclaim always runs on
+    the segments: it prices a lane against EVERY other queue's units in
+    the one global rank order and bounds it by a subtree sum, so there
+    is no per-queue ``K`` it could cut at.
 
     Remaining deviations from the reference's one-preemptor-at-a-time
     walk, all chunk-granular: the preemptor and victim-job orders are
@@ -986,10 +993,10 @@ def _run_victim_action_chunked(
     leaf_safe = jnp.maximum(unit_leaf, 0)
     has_leaf = unit_leaf >= 0
     if reclaim:
-        C_all = cumsum_ds(unit_req, axis=0)                      # inclusive
+        C_all_t = cumsum_ds(unit_req.T, axis=1)        # [R, U] inclusive
         unit_prio = None
     else:
-        C_all = None
+        C_all_t = None
         unit_prio = jax.ops.segment_max(
             jnp.where(cand0, gang_prio_pod, -BIG), urank_safe,
             num_segments=M + 1)[:M].astype(jnp.float32)          # [U]
@@ -1015,13 +1022,11 @@ def _run_victim_action_chunked(
         branch never materializes the other flavor's tensors."""
 
         if sparse:
-            # compact per-queue unit tables — the sparse analogue of the
-            # dense [U, Q, *] cumulatives.  Each unit's ordinal within
+            # compact per-queue unit tables — the first KU rows of every
+            # leaf segment, as a [Q, KU] grid.  Each unit's ordinal within
             # its queue comes from one stable [M] argsort (rank order is
             # preserved within a queue), then tiny [Q, KU] scatters.
-            leaf_key = jnp.where(has_leaf, leaf_safe, Q)
-            perm_u = jnp.argsort(leaf_key.astype(jnp.int32), stable=True)
-            lk_p = leaf_key[perm_u]
+            perm_u, lk_p = unit_segments.leaf_order(unit_leaf, Q)
             first_u = jnp.concatenate(
                 [jnp.ones((1,), bool), lk_p[1:] != lk_p[:-1]])
             seg_start = jax.lax.associative_scan(
@@ -1048,29 +1053,18 @@ def _run_victim_action_chunked(
             prio_c = jnp.where(valid_pos, unit_prio[pos_safe],
                                jnp.float32(1e30))                # [Q, KU]
         else:
-            onehot_leaf = ((unit_leaf[:, None] == jnp.arange(Q)[None, :])
-                           & has_leaf[:, None])                  # [U, Q]
-            C_leaf = cumsum_ds(
-                onehot_leaf[:, :, None] * unit_req[:, None, :],
-                axis=0)                                          # [U, Q, R]
-            cnt_leaf = jnp.cumsum(onehot_leaf.astype(jnp.int32), axis=0)
-            cl = jnp.concatenate(
-                [jnp.zeros((1, Q), jnp.int32), cnt_leaf])        # [U+1, Q]
-            r_in_q = cl[jnp.arange(M), leaf_safe]                # [U]
-            pos_q = jnp.full((Q + 1, M), M, jnp.int32).at[
-                jnp.where(has_leaf, leaf_safe, Q), r_in_q].set(
-                    jnp.arange(M, dtype=jnp.int32))[:Q]          # [Q, U]
+            # every queue's units as one rank-ordered segment of a
+            # sorted [U] axis, with their running sums (unit_segments):
+            # U rows, never a column per queue
+            leaf = unit_segments.leaf_segments(unit_leaf, unit_req, Q)
+            own_cum_t = unit_segments.rank_order_cum(leaf)       # [R, U]
             if reclaim:
-                # EXCLUSIVE-before-u subtree-cumulative freed (strategy
-                # bounds)
-                inc_sub = ((chain[leaf_safe] & has_leaf[:, None])[:, :, None]
-                           * unit_req[:, None, :])               # [U, Q, R]
-                S_cols = (cumsum_ds(inc_sub, axis=0)
-                          - inc_sub).reshape(M, Q * R_)
+                # subtree segments: the strategy bounds' per-ancestor
+                # cumulative freed, U * num_levels rows
+                sub = unit_segments.subtree_segments(
+                    unit_leaf, unit_req, q.parent, num_levels)
             else:
-                prio_by_q = jnp.full((Q + 1, M), jnp.float32(1e30)).at[
-                    jnp.where(has_leaf, leaf_safe, Q), r_in_q].set(
-                        unit_prio)[:Q]                           # [Q, U]
+                prio_seg = unit_prio[leaf.pos]                   # [U]
 
         def chunk(carry):
             res, remaining, c, q_att, fuel = carry
@@ -1150,18 +1144,18 @@ def _run_victim_action_chunked(
                                         jnp.minimum(j_rb, KU), axis=1),
                     0)
             else:
-                csafe = jnp.clip(c, 0, M - 1)
-                Cv_at_c = jnp.where((c >= 0)[:, None],
-                                    C_leaf[csafe, qidx], 0.0)    # [Q, R]
+                Cv_at_c = unit_segments.sum_through(leaf, c)     # [Q, R]
+                # the lanes' own-queue columns, built per chunk from
+                # the segment sums
+                mine_b = unit_leaf[None, :] == q_b[:, None]      # [B, U]
+                arr_b = unit_segments.lane_columns(own_cum_t, mine_b)
                 if reclaim:
-                    arr_b = C_all[None] - C_leaf[:, q_b].transpose(1, 0, 2)
+                    arr_b = C_all_t[None] - arr_b                # [B, R, U]
                     base_b = (jnp.sum(Cv_at_c, axis=0)[None, :]
                               - Cv_at_c[q_b])                    # [B, R]
                 else:
-                    arr_b = C_leaf[:, q_b].transpose(1, 0, 2)    # [B, U, R]
                     base_b = Cv_at_c[q_b]
-                k_rb = jax.vmap(jax.vmap(jnp.searchsorted,
-                                         in_axes=(1, 0)))(
+                k_rb = jax.vmap(jax.vmap(jnp.searchsorted))(
                     arr_b, targets + base_b)                     # [B, R]
             K_cap = jnp.where(need_b, jnp.max(k_rb, axis=1), -1
                               ).astype(jnp.int32)                # [B]
@@ -1190,14 +1184,10 @@ def _run_victim_action_chunked(
                 avail_u = (has_leaf & (jnp.arange(M) < num_units)
                            & (jnp.arange(M)
                               > c[jnp.clip(unit_leaf, 0, Q - 1)]))
-                cum_av_leaf = jnp.cumsum(
-                    (avail_u[:, None] & onehot_leaf).astype(jnp.int32),
-                    axis=0)
-                cum_av = jnp.cumsum(avail_u.astype(jnp.int32))   # [U]
-                if reclaim:
-                    cum_av_b = cum_av[None, :] - cum_av_leaf[:, q_b].T
-                else:
-                    cum_av_b = cum_av_leaf[:, q_b].T             # [B, U]
+                # available units a lane may take: every queue's but
+                # its own (reclaim) / its own queue's only (preempt)
+                cum_av_b = unit_segments.lane_available(
+                    avail_u, ~mine_b if reclaim else mine_b)     # [B, U]
                 K_min = jax.vmap(jnp.searchsorted)(
                     cum_av_b, vrank + 1).astype(jnp.int32)       # [B]
             K_raw = jnp.where(cand_valid, jnp.maximum(K_cap, K_min), -1)
@@ -1214,16 +1204,12 @@ def _run_victim_action_chunked(
                 # its own quota.
                 S_cons = einsum_exact("va,vr->ar", chain_f,
                                       Cv_at_c)               # [Q, R]
-                thr_fs = (qa - fair_share - EPS + S_cons).reshape(-1)
-                bnd_fs = jnp.max(jax.vmap(
-                    jnp.searchsorted, in_axes=(1, 0))(
-                    S_cols, thr_fs).reshape(Q, R_), axis=1)      # [Q]
+                thr_fs = qa - fair_share - EPS + S_cons          # [Q, R]
                 thr_qt = (jnp.where(jnp.isinf(quota_eff_q), -jnp.inf,
                                     qa - quota_eff_q - EPS)
-                          + S_cons).reshape(-1)
-                bnd_qt = jnp.max(jax.vmap(
-                    jnp.searchsorted, in_axes=(1, 0))(
-                    S_cols, thr_qt).reshape(Q, R_), axis=1)      # [Q]
+                          + S_cons)
+                bnd_fs, bnd_qt = jnp.max(unit_segments.subtree_bound(
+                    sub, jnp.stack([thr_fs, thr_qt]), M), axis=-1)  # [Q]
                 under_b = jax.vmap(
                     lambda qi, tr: _ancestor_gate(
                         q.parent, qi, num_levels, qa, q.quota, tr))(
@@ -1235,9 +1221,8 @@ def _run_victim_action_chunked(
                 lq_vb = lq_tab[:, q_b]                           # [Q, B]
                 x_vb = jnp.clip(jnp.take_along_axis(
                     bnd_eff, jnp.clip(lq_vb, 0, Q - 1), axis=0), 0, M)
-                cnt_before = cl[x_vb, qidx[:, None]]             # [Q, B]
-                first_bad_vb = pos_q[qidx[:, None],
-                                     jnp.clip(cnt_before, 0, M - 1)]
+                first_bad_vb = unit_segments.first_at_or_after(
+                    leaf, x_vb, M)                               # [Q, B]
                 first_bad_vb = jnp.where(lq_vb >= 0, first_bad_vb, M)
                 hi_b = jnp.minimum(jnp.min(first_bad_vb, axis=0),
                                    num_units) - 1                # [B]
@@ -1256,11 +1241,10 @@ def _run_victim_action_chunked(
                 # victim units are priority-ascending within the queue; a
                 # lane may only consume own-queue units strictly below its
                 # priority
-                allowed = jax.vmap(jnp.searchsorted)(
-                    prio_by_q[q_b],
-                    g.priority[gsafe_b].astype(jnp.float32))     # [B]
-                hi_b = pos_q[q_b, jnp.clip(allowed, 0, M - 1)] - 1
-                hi_b = jnp.where(allowed > 0, hi_b, -1)
+                allowed, first_not = unit_segments.first_not_below(
+                    leaf, prio_seg, q_b,
+                    g.priority[gsafe_b].astype(jnp.float32), M)  # [B]
+                hi_b = jnp.where(allowed > 0, first_not - 1, -1)
 
             # ---- lane gates ---------------------------------------------
             nonpre_b = ~g.preemptible[gsafe_b]
@@ -1682,9 +1666,9 @@ def _run_victim_action_chunked(
 
     def tabled_run(sparse: bool, fell_back: bool):
         # make_run's own work is the table build (the loop runs when
-        # the closure is called): a scope of its own, since the dense
-        # [U, Q, R] cumulatives are where a many-tenant cluster's
-        # device time goes
+        # the closure is called): a scope of its own — the sorts and
+        # segmented scans of the per-queue unit segments (or preempt's
+        # compact [Q, KU] grid)
         with jax.named_scope("unit_tables"):
             return make_run(sparse, fell_back)
 

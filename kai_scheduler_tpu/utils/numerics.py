@@ -56,3 +56,26 @@ def cumsum_ds(x: jax.Array, axis: int = 0) -> jax.Array:
     s, e = jax.lax.associative_scan(
         combine, (x, jnp.zeros_like(x)), axis=axis)
     return s + e
+
+
+def segmented_cumsum_ds(x: jax.Array, first: jax.Array,
+                        axis: int = 0) -> jax.Array:
+    """:func:`cumsum_ds` restarted wherever ``first`` is set.
+
+    ``first`` (bool, broadcastable to ``x``) marks the first element of
+    every segment along ``axis``; the flag rides the scan's carry and
+    resets sum AND residue there, so a segment's running sums are built
+    from that segment's values only, in order — what a dense one-hot
+    column's ``cumsum_ds`` sums between its zeros."""
+
+    def combine(ca, cb):
+        s_a, e_a, f_a = ca
+        s_b, e_b, f_b = cb
+        s, e = _two_sum(s_a, s_b)
+        return (jnp.where(f_b, s_b, s),
+                jnp.where(f_b, e_b, e + e_a + e_b), f_a | f_b)
+
+    s, e, _ = jax.lax.associative_scan(
+        combine, (x, jnp.zeros_like(x), jnp.broadcast_to(first, x.shape)),
+        axis=axis)
+    return s + e

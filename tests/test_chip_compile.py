@@ -180,6 +180,49 @@ def test_fused_five_actions_compile(topo, saturated):
     _fits_one_chip(compiled)
 
 
+def test_reclaim_temporaries_do_not_grow_with_queues_times_units(topo):
+    """Chunked reclaim's per-queue unit tables are segments of the unit
+    axis (``ops/unit_segments.py``), not a column per queue: the
+    described-v5e executable's temporaries at 64 and at 256 queues over
+    the same 8 192 running pods differ by what ``[B, Q, R]`` lanes and
+    ``[Q, Q]`` leveled-queue tables add (+2.7 MB when written; a
+    different width of chunk moved it by 4 MB either way, which is the
+    buffer assignment's own) — nowhere near one ``[U, dQ, R]`` f32
+    table (18.9 MB), of which the dense form held five (+92.4 MB on the
+    parent of PR 37).  A tenth of one table is what the issue asked
+    for; at the 32 768 pods where that bound clears the noise (+2.5 MB
+    of 75.5) one compile takes four minutes, so this one holds half."""
+    import dataclasses
+    import functools
+    from kai_scheduler_tpu.framework.session import Session
+    from kai_scheduler_tpu.ops.allocate import init_result
+    from kai_scheduler_tpu.ops.victims import run_victim_action
+    from kai_scheduler_tpu.state import make_cluster
+    one = SingleDeviceSharding(topo.devices[0])
+    temps, shape = [], []
+    for per_department in (30, 126):
+        ses = Session.open(*make_cluster(
+            num_nodes=1024, node_accel=8.0, num_gangs=2048 + 32,
+            tasks_per_gang=4, running_fraction=2048 / 2080,
+            num_departments=2, queues_per_department=per_department,
+            queue_accel_quota=1.0, partition_queues_by_running=True,
+            seed=0))
+        cfg = dataclasses.replace(ses.config.victims, chunk_reclaim=True)
+        compiled = jax.jit(functools.partial(
+            run_victim_action, num_levels=ses.config.num_levels,
+            mode="reclaim", config=cfg)).lower(
+            _shapes(ses.state, one), _shapes(ses.state.queues.fair_share,
+                                             one),
+            _shapes(init_result(ses.state), one)).compile()
+        _fits_one_chip(compiled)
+        temps.append(compiled.memory_analysis().temp_size_in_bytes)
+        shape.append((ses.state.running.m, ses.state.queues.q,
+                      ses.state.nodes.free.shape[1]))
+    (u, q0, r), (u1, q1, _) = shape
+    assert u == u1 >= 8192 and q1 - q0 >= 192, shape
+    assert temps[1] - temps[0] < u * (q1 - q0) * r * 4 / 2, (temps, shape)
+
+
 def test_fused_five_actions_compile_non_dense(topo, saturated_pools):
     """The same entry over tainted pools: two selector values, the
     toleration's filter class, ``dense_feasibility`` false in allocate

@@ -552,7 +552,7 @@ class TestActionGate:
             ses.config.victims, chunk_reclaim=chunked,
             batch_size=ses.config.victims.batch_size if chunked else 1,
             batch_size_preempt=None,
-            # the dense tables, so that the chunked branch holds [U, Q, R]
+            # the composed path over the full unit segments
             optimistic_preempt=False)
         M, Q = ses.state.running.m, ses.state.queues.q
         jaxpr = jax.make_jaxpr(functools.partial(
