@@ -753,7 +753,7 @@ _JOURNAL_CHOKE_PREFIX = "kai_scheduler_tpu/intake/"
 _JOURNAL_MARK_METHODS = frozenset({
     "mark_pod", "mark_pod_added", "mark_pod_removed", "mark_gang",
     "mark_gang_added", "mark_gang_removed", "mark_node",
-    "mark_structural", "mark_time", "merge",
+    "mark_structural", "mark_time", "mark_stored", "merge",
 })
 
 

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import threading
 from typing import Any
 
 # ---------------------------------------------------------------------------
@@ -561,3 +562,84 @@ class Config:
     stale_gang_grace_s: float = 60.0
     default_scheduler_name: str = "kai-scheduler-tpu"
     shards: list[SchedulingShard] = dataclasses.field(default_factory=list)
+
+
+# ---------------------------------------------------------------------------
+# Watched fields: the writes a store that holds the object hears of
+# ---------------------------------------------------------------------------
+
+#: per class, the fields written in place after an object is stored (by
+#: the binder, the status pool, the controllers) that the incremental
+#: snapshot encodes, each as (name, compared by identity).  A write that
+#: changes one tells every store holding the object (``runtime/store.py``),
+#: which marks its journal; a write of what is there already tells
+#: nobody: an object-valued field is compared by ``is``, a value by
+#: ``==``.  The rest of an object is its spec, changed by storing another
+#: object under its key (or with an explicit ``intake.gate`` mark).
+WATCHED_FIELDS: dict[type, tuple[tuple[str, bool], ...]] = {
+    Pod: (("status", True), ("node", False)),
+    PodGroup: (("unschedulable", False), ("last_start_timestamp", False),
+               ("stale_since", False), ("topology_constraint", True),
+               ("sub_groups", True)),
+    Node: (("allocatable", True), ("labels", True), ("taints", True),
+           ("extended", True), ("accel_memory_gib", False),
+           ("unschedulable", False)),
+    BindRequest: (("phase", False), ("selected_node", False)),
+}
+
+#: the key of an object's ``__dict__`` under which the stores that hold
+#: it keep themselves: a plain tuple ``(serial, key, serial, key, ...)``
+#: of ints and strings, which the collector stops tracking, replaced and
+#: never changed in place.  A copy of the object carries it along, but
+#: no store holds the copy under that key, so the copy marks nothing
+HELD_BY = "_held_by"
+
+#: the stores that hold objects, by serial: weak references that
+#: ``runtime/store.py`` registers as a store is made and that drop out
+#: as it goes
+STORES: dict[int, Any] = {}
+
+#: one lock for every watched write to a stored object: the compare with
+#: the old value and the write are one step, so two threads writing one
+#: field cannot both see their own value as the old one and mark nothing
+_WRITE_LOCK = threading.Lock()
+
+
+class _Watched:
+    """A watched field.  It has no ``__get__``: a read finds the value
+    in the instance ``__dict__`` as for any other field.  A write to an
+    object that no store holds (a pod under construction, a
+    ``dataclasses.replace`` presentation copy) stores the value and
+    nothing else; to a stored object it stores the value first, then
+    tells each store whose entry at its key is this very object."""
+
+    __slots__ = ("name", "by_identity")
+
+    def __init__(self, name: str, by_identity: bool):
+        self.name = name
+        self.by_identity = by_identity
+
+    def __set__(self, obj, value) -> None:
+        d = obj.__dict__
+        held = d.get(HELD_BY)
+        if not held:
+            d[self.name] = value
+            return
+        with _WRITE_LOCK:
+            old = d.get(self.name, _UNSET)
+            d[self.name] = value
+        if old is value or (not self.by_identity and old == value):
+            return
+        for i in range(0, len(held), 2):
+            ref = STORES.get(held[i])
+            store = ref() if ref is not None else None
+            if store is not None and dict.get(store, held[i + 1]) is obj:
+                store.written(held[i + 1])
+
+
+_UNSET = object()
+
+for _cls, _fields in WATCHED_FIELDS.items():
+    for _name, _by_identity in _fields:
+        setattr(_cls, _name, _Watched(_name, _by_identity))
+del _cls, _fields, _name, _by_identity

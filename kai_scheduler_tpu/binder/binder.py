@@ -18,7 +18,6 @@ import dataclasses
 from typing import Protocol
 
 from ..apis import types as apis
-from ..intake import gate as _gate
 from ..runtime.cluster import Cluster
 
 
@@ -244,7 +243,9 @@ class Binder:
             GpuSharingPlugin()]
 
     def reconcile(self, cluster: Cluster) -> BindResult:
-        """Process all pending BindRequests once (one controller sweep)."""
+        """Process all pending BindRequests once (one controller sweep).
+        A phase written here reaches the journal through the store
+        (``BindRequest.phase`` is a watched field)."""
         result = BindResult()
         for br in list(cluster.bind_requests.values()):
             if br.phase != "Pending":
@@ -253,7 +254,6 @@ class Binder:
             if pod is None or pod.status in (apis.PodStatus.SUCCEEDED,
                                              apis.PodStatus.FAILED):
                 br.phase = "Failed"
-                _gate.pod_touched(cluster.journal, br.pod_name)
                 result.failed.append(br.pod_name)
                 continue
             if pod.status == apis.PodStatus.RELEASING:
@@ -263,7 +263,6 @@ class Binder:
                 continue
             if pod.status != apis.PodStatus.PENDING:
                 br.phase = "Failed"
-                _gate.pod_touched(cluster.journal, br.pod_name)
                 result.failed.append(br.pod_name)
                 continue
             done: list[BinderPlugin] = []
@@ -279,7 +278,6 @@ class Binder:
                 br.failures += 1
                 if br.failures > br.backoff_limit:
                     br.phase = "Failed"
-                    _gate.pod_touched(cluster.journal, br.pod_name)
                     result.failed.append(br.pod_name)
                 else:
                     result.retrying.append(br.pod_name)
@@ -287,6 +285,5 @@ class Binder:
             for plugin in self.plugins:
                 plugin.post_bind(cluster, pod, br)
             br.phase = "Succeeded"
-            _gate.pod_touched(cluster.journal, br.pod_name)
             result.bound.append(br.pod_name)
         return result

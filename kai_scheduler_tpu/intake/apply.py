@@ -286,9 +286,8 @@ def apply_events(cluster, events, errors: list | None = None) -> int:
             if len(marks) >= _MARK_CHUNK:
                 # swap-before-merge: if the merge raises mid-chunk the
                 # chunk is NOT retried (at-most-once — duplicate list
-                # marks would corrupt cursors, while a lost mark is
-                # caught by the snapshotter's drift sweep and falls
-                # back to a full rebuild)
+                # marks would corrupt cursors; the store marked its own
+                # collections' writes as they landed)
                 chunk, marks = marks, []
                 gate.merge_marks(journal, chunk)
     finally:

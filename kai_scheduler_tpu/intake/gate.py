@@ -2,10 +2,11 @@
 
 Every write into a cluster's :class:`~..state.incremental.MutationJournal`
 outside the journal's own module flows through THIS module (lint rule
-KAI091 enforces it, mirroring KAI071's wire discipline): the hub's own
-mutators (``runtime/cluster.py``), the binder's commit write-backs, the
-wire codec's delta appliers, and the intake router's coalesce step all
-mark through these helpers.  One choke point buys two things:
+KAI091 enforces it, mirroring KAI071's wire discipline): the store's
+collections as each write lands (``runtime/store.py``), the hub's own
+mutators (``runtime/cluster.py``), the wire codec's delta appliers, and
+the intake router's coalesce step all mark through these helpers.  One
+choke point buys two things:
 
 - **ordering discipline** — the kai-intake differential bar (a storm
   coalesced through the lanes must be bit-identical to the sequential
@@ -19,10 +20,11 @@ The helpers are deliberately thin pass-throughs: the journal's locking
 and cursor fan-out live with the journal (``state/incremental.py``);
 the gate owns only the *semantic mapping* from object mutations to mark
 kinds: pods, pod groups and bind requests map to per-key marks the
-snapshotter patches (added, touched, removed); a new or deleted node or
-queue, and any write to the DRA/volume stores, is ``structural`` and
-rebuilds.  Dependency-free by design so ``runtime/cluster.py`` (which
-everything imports) can route through it without cycles.
+snapshotter patches (added, touched, removed), which the store makes
+itself (``STORE_MARKS``); a new or deleted node or queue, and any write
+to the DRA/volume stores, is ``structural`` and rebuilds.
+Dependency-free by design so ``runtime/cluster.py`` (which everything
+imports) can route through it without cycles.
 """
 from __future__ import annotations
 
@@ -78,6 +80,43 @@ def time_advanced(journal: "MutationJournal") -> None:
     journal.mark_time()
 
 
+# -- the store's own marks (runtime/store.py) ----------------------------
+
+#: what a write to a store collection marks, by collection: (an object
+#: stored under a new key, an object stored over another, an object
+#: removed, a watched field of a stored object written) -> (kind, name;
+#: None for the key), and the name its marks are counted under
+#: (``JournalBatch.store_marks``).  A bind request is its pod's
+#: presentation.  A node row anchors vocabularies, masks and device
+#: tables: a node stored or removed, or written in place, rebuilds, under
+#: the name the write has (``drift``); a node stored over another is the
+#: delta's ``node`` mark.
+STORE_MARKS = {
+    "pods": ((("pod_added", None), ("pod", None), ("pod_removed", None),
+              ("pod", None)), "pods"),
+    "pod_groups": ((("gang_added", None), ("gang", None),
+                    ("gang_removed", None), ("gang", None)), "gangs"),
+    "bind_requests": ((("pod", None),) * 4, "bind_requests"),
+    "nodes": ((("drift", "node-membership-drift"), ("node", None),
+               ("drift", "node-membership-drift"), ("drift", "node-drift")),
+              "nodes"),
+}
+
+
+#: the collections whose every write the store marks as it lands: a
+#: delta document adds no mark of its own for them
+_SELF_MARKED = ("pods", "pod_groups", "bind_requests")
+
+
+def store_mark(journal: "MutationJournal", coll: str, event: int,
+               key: str) -> None:
+    """What ``event`` (an index into the collection's ``STORE_MARKS``)
+    at ``key`` of ``coll`` marks, as the write lands."""
+    marks, counted = STORE_MARKS[coll]
+    kind, name = marks[event]
+    journal.mark_stored(counted, kind, key if name is None else name)
+
+
 def merge_marks(journal: "MutationJournal", marks) -> None:
     """Bulk-replay an ordered ``(kind, name)`` mark batch — the
     coalesce step's single-lock-acquisition merge (see
@@ -91,15 +130,12 @@ def upsert_marks(coll: str, key: str, obj, existed: bool,
                  out: list) -> None:
     """Append the ``(kind, name)`` mark ops an upsert of ``key`` into
     ``coll`` records, to ``out`` — the single source of the wire-delta
-    journal mapping (formerly ``wire/codec._journal_upsert``)."""
-    if coll == "pods":
-        out.append(("pod", key) if existed else ("pod_added", key))
-    elif coll == "pod_groups":
-        out.append(("gang", key) if existed else ("gang_added", key))
-    elif coll == "bind_requests":
-        # a Pending BindRequest changes its pod's snapshot presentation
-        out.append(("pod", obj.pod_name))
-    elif coll == "nodes":
+    journal mapping (formerly ``wire/codec._journal_upsert``).  Pods,
+    pod groups and bind requests are marked by the store itself as the
+    write lands (``STORE_MARKS``)."""
+    if coll in _SELF_MARKED:
+        return
+    if coll == "nodes":
         # node rows anchor vocabularies/masks/device tables — dirty
         # nodes force a full snapshot rebuild either way
         out.append(("node", key) if existed
@@ -114,20 +150,8 @@ def upsert_marks(coll: str, key: str, obj, existed: bool,
 
 def delete_marks(coll: str, name: str, existed: bool, out: list) -> None:
     """Append the mark ops a delete records (formerly
-    ``wire/codec._journal_delete``)."""
-    if not existed:
-        return
-    if coll == "pods":
-        out.append(("pod_removed", name))
-    elif coll == "pod_groups":
-        # a cluster deletes a group with its owner, so this is every
-        # completion and every eviction: the snapshotter closes up the
-        # gang's ledger row instead of rebuilding.  The delta applies
-        # this collection before ``pods`` (COLLECTIONS), so the group's
-        # pods may outlive the mark; the consumer orders the batch
-        out.append(("gang_removed", name))
-    elif coll == "bind_requests":
-        out.append(("pod", name))
-    else:
+    ``wire/codec._journal_delete``); as ``upsert_marks``, none for the
+    collections the store marks itself."""
+    if existed and coll not in _SELF_MARKED:
         # node/queue rows anchor vocabularies and dense id spaces
         out.append(("structural", f"{coll}-delete"))

@@ -17,6 +17,11 @@ import dataclasses
 
 from ..apis import types as apis
 from ..intake import gate as _gate
+from .store import Collection
+
+#: the collections of the store that mark the journal as they are
+#: written (``runtime/store.py``)
+STORE_COLLECTIONS = ("nodes", "pod_groups", "pods", "bind_requests")
 
 
 @dataclasses.dataclass
@@ -45,7 +50,9 @@ class Cluster:
     #: mutation journal — every state change records dirty keys so the
     #: incremental snapshotter (state/incremental.py) refreshes
     #: proportional to churn instead of rebuilding per cycle (the
-    #: API-watch role of the reference's cache layer, SURVEY §2.6)
+    #: API-watch role of the reference's cache layer, SURVEY §2.6).
+    #: The four ``STORE_COLLECTIONS`` mark it themselves as they are
+    #: written, and so do the watched fields of what they hold
     journal: "object" = None
 
     def __post_init__(self):
@@ -55,6 +62,31 @@ class Cluster:
         if self.journal is None:
             from ..state.incremental import MutationJournal
             self.journal = MutationJournal()
+
+    def __setattr__(self, name, value):
+        if name in STORE_COLLECTIONS:
+            old = self.__dict__.get(name)
+            if value is old:  # ``cluster.pods |= ...`` sets it back
+                return
+            journal = self.__dict__.get("journal")
+            # filled unmarked: a new collection rebuilds as a whole
+            new = Collection(name, None, value)
+            new.journal = journal
+            object.__setattr__(self, name, new)
+            if old is not None:
+                # a collection replaced wholesale: nothing of the old one
+                # is worth patching from
+                old.detach()
+                if journal is not None:
+                    _gate.structural(journal, f"{name}-replaced")
+            return
+        object.__setattr__(self, name, value)
+        if name == "journal":
+            for coll in STORE_COLLECTIONS:
+                store = self.__dict__.get(coll)
+                if store is not None:
+                    store.journal = value
+
     #: monotonic clock advanced by the simulation driver
     now: float = 0.0
     #: evicted pods whose workload controller will recreate them (the
@@ -86,17 +118,15 @@ class Cluster:
     def submit(self, group: apis.PodGroup, pods: list[apis.Pod]) -> None:
         """Add a workload (PodGroup + its pods) — podgrouper output."""
         group.creation_timestamp = group.creation_timestamp or self.now
+        # storing marks a new object, or another one under the name; the
+        # one already there may have been changed before it came back
         if group.name in self.pod_groups:
             _gate.gang_touched(self.journal, group.name)
-        else:
-            _gate.gang_added(self.journal, group.name)
         self.pod_groups[group.name] = group
         for p in pods:
             p.creation_timestamp = p.creation_timestamp or self.now
             if p.name in self.pods:
                 _gate.pod_touched(self.journal, p.name)
-            else:
-                _gate.pod_added(self.journal, p.name)
             self.pods[p.name] = p
 
     # -- views ------------------------------------------------------------
@@ -147,9 +177,9 @@ class Cluster:
     # -- commit side (binder / evictor write-backs) -----------------------
 
     def create_bind_request(self, br: apis.BindRequest) -> None:
+        # a Pending BindRequest changes the pod's snapshot presentation:
+        # the store marks the pod
         self.bind_requests[br.pod_name] = br
-        # a Pending BindRequest changes the pod's snapshot presentation
-        _gate.pod_touched(self.journal, br.pod_name)
 
     def node_device_free(self, node_name: str) -> list[float]:
         """Free share per accel device on a node, from pods' recorded
@@ -216,11 +246,11 @@ class Cluster:
                 pod.accel_devices = fully[:k]
         pod.node = node_name
         pod.status = apis.PodStatus.BOUND
+        # the devices are not a watched field
         _gate.pod_touched(self.journal, pod_name)
         group = self.pod_groups.get(pod.group)
         if group is not None and group.last_start_timestamp is None:
             group.last_start_timestamp = self.now
-            _gate.gang_touched(self.journal, group.name)
 
     def evict_pod(self, pod_name: str, restart: bool = False) -> None:
         """Eviction = delete pod; its resources become releasing until the
@@ -233,7 +263,6 @@ class Cluster:
         pod = self.pods.get(pod_name)
         if pod is not None:
             pod.status = apis.PodStatus.RELEASING
-            _gate.pod_touched(self.journal, pod_name)
             if restart:
                 self.restarting.add(pod_name)
 
@@ -263,7 +292,5 @@ class Cluster:
                     _gate.pod_touched(self.journal, name)
                 else:
                     del self.pods[name]
-                    _gate.pod_removed(self.journal, name)
             elif pod.status == apis.PodStatus.BOUND:
                 pod.status = apis.PodStatus.RUNNING
-                _gate.pod_touched(self.journal, name)

@@ -15,10 +15,11 @@ Tesserae approach, arXiv:2508.04953).
 Three pieces:
 
 - :class:`MutationJournal` — the cluster hub's change feed.  Every
-  mutation (``submit``/``bind_pod``/``evict_pod``/``tick``, binder
-  commits, wire-delta upserts/deletes) records dirty, added and removed
-  node/gang/pod keys under a generation counter.  Multiple consumers
-  each get their own :class:`JournalCursor`.
+  mutation (a write to the store's collections or to a watched field of
+  what they hold, ``submit``/``bind_pod``/``tick``, wire-delta upserts
+  and deletes of the other collections) records dirty, added and
+  removed node/gang/pod keys under a generation counter.  Multiple
+  consumers each get their own :class:`JournalCursor`.
 
 - :class:`IncrementalSnapshotter` — retains the previous cycle's host
   arrays + ``SnapshotIndex`` and re-derives only dirty rows through the
@@ -72,10 +73,13 @@ Three pieces:
     gang is one whose only slot is 0);
   * dirty fraction above ``dirty_threshold`` — patching stops paying
     once most of the cluster changed;
-  * ledger drift — an object mutated without a journal mark (the
-    object model is uninstrumented; a cheap identity/field sweep
-    detects direct writes and falls back rather than serving a stale
-    snapshot).
+  * a node written in place (``node-drift``) or added or removed by a
+    write that is not a delta (``node-membership-drift``): the store
+    marks every write as it lands (``runtime/store.py``, the watched
+    fields of ``apis.types.WATCHED_FIELDS``), so nothing compares the
+    cluster with the ledger; a write that went round the store's
+    methods is caught only as a count of pods, gangs or nodes that
+    differs from the ledger's (``*-membership-drift``).
 
 ``verify=True`` (the scheduler's ``verify_incremental`` flag) rebuilds
 from scratch — the same builder, under the pinned capacity and
@@ -135,7 +139,12 @@ class _Fallback(Exception):
 
 _CURSOR_FIELDS = ("pods_dirty", "pods_added", "pods_removed",
                   "gangs_dirty", "gangs_added", "gangs_removed",
-                  "nodes_dirty", "structural", "time_dirty")
+                  "nodes_dirty", "structural", "drift", "time_dirty",
+                  "store_marks")
+
+
+def _no_store_marks() -> dict:
+    return {"pods": 0, "gangs": 0, "nodes": 0, "bind_requests": 0}
 
 
 class JournalBatch:
@@ -153,7 +162,12 @@ class JournalBatch:
         self.gangs_removed: set[str] = set()
         self.nodes_dirty: set[str] = set()
         self.structural: list[str] = []
+        #: writes the store saw that refuse the patch, each by the name
+        #: ``refresh`` books it under (``node-drift``)
+        self.drift: list[str] = []
         self.time_dirty = False
+        #: the marks the store's own write hooks made, by collection
+        self.store_marks: dict = _no_store_marks()
 
 
 class JournalCursor:
@@ -181,7 +195,9 @@ class JournalCursor:
         self.gangs_removed: set[str] = set()
         self.nodes_dirty: set[str] = set()
         self.structural: list[str] = []
+        self.drift: list[str] = []
         self.time_dirty = False
+        self.store_marks: dict = _no_store_marks()
 
     def consume(self) -> "JournalBatch":
         """Move the accumulated sets into a private batch and reset —
@@ -223,31 +239,28 @@ class MutationJournal:
     def register(self) -> JournalCursor:
         cur = JournalCursor(self._lock)
         with self._lock:
+            # the cursors of consumers that are gone go here; until then
+            # a mark passes their dead reference by
+            self._cursors = [r for r in self._cursors if r() is not None]
             self._cursors.append(weakref.ref(cur))
         return cur
 
-    def _each(self):
-        if not self._cursors:
-            return
-        dead = False
-        for ref in self._cursors:
-            cur = ref()
-            if cur is None:
-                dead = True
-            else:
-                yield cur
-        if dead:
-            self._cursors = [r for r in self._cursors if r() is not None]
-
     # -- marks ------------------------------------------------------------
 
-    def _apply_mark(self, kind: str, name: str) -> None:
+    def _apply_mark(self, kind: str, name: str,
+                    counted: str | None = None) -> None:
         """One mark's cursor fan-out — caller holds ``self._lock``.
         The single implementation behind both the per-mark methods and
         the kai-intake bulk :meth:`merge`, so a coalesced lane batch can
-        never drift from the sequential mark semantics."""
+        never drift from the sequential mark semantics.  ``counted``
+        names the store collection whose own hook made the mark."""
         self.generation += 1
-        for c in self._each():
+        for ref in self._cursors:
+            c = ref()
+            if c is None:
+                continue
+            if counted is not None:
+                c.store_marks[counted] += 1
             if kind == "pod":
                 c.pods_dirty.add(name)
             elif kind == "pod_added":
@@ -256,7 +269,7 @@ class MutationJournal:
                 else:
                     # removed-then-readded (or dirtied) inside one window:
                     # position in the dict may have moved — too subtle to
-                    # patch, let the sweep/full rebuild sort it out
+                    # patch, let the full rebuild sort it out
                     c.structural.append("pod-readded")
             elif kind == "pod_removed":
                 c.pods_removed.add(name)
@@ -276,6 +289,8 @@ class MutationJournal:
                 c.nodes_dirty.add(name)
             elif kind == "structural":
                 c.structural.append(name)
+            elif kind == "drift":
+                c.drift.append(name)
             elif kind == "time":
                 c.time_dirty = True
             else:
@@ -316,6 +331,12 @@ class MutationJournal:
     def mark_time(self) -> None:
         with self._lock:
             self._apply_mark("time", "")
+
+    def mark_stored(self, counted: str, kind: str, name: str) -> None:
+        """A mark the store made as a write landed (``runtime/store.py``),
+        counted under ``counted`` (``JournalBatch.store_marks``)."""
+        with self._lock:
+            self._apply_mark(kind, name, counted)
 
     def merge(self, marks) -> None:
         """Replay an ordered batch of ``(kind, name)`` mark operations
@@ -376,13 +397,13 @@ _GANG_COLUMNS = (
     ("g_sub_rlvl", np.int32, -1, True),
 )
 
-#: the lists: the object, its name, the identities the sweep compares
-#: (``topology_constraint``, ``sub_groups``) and the subgroup
+#: the lists: the object, its name, its ``sub_groups`` (whether it
+#: declares any is counted in ``_subgrouped_gangs``) and the subgroup
 #: name -> slot map
-_GANG_LISTS = ("g_objs", "g_names", "g_tc", "g_subs", "g_slot")
+_GANG_LISTS = ("g_objs", "g_names", "g_subs", "g_slot")
 
 #: likewise the pod ledger's, as (attribute, dtype, fill, row shape);
-#: ``p_objs`` and ``p_sweep`` are the lists beside them
+#: ``p_objs`` is the list beside them
 _POD_COLUMNS = (
     ("p_names", object, None, ()), ("p_live", bool, False, ()),
     ("p_req", np.float32, 0.0, (R,)), ("p_prio", np.int64, 0, ()),
@@ -509,6 +530,8 @@ class IncrementalSnapshotter:
             self._host = None
         j = (self._cursor.consume() if self._cursor is not None
              else None)
+        store_marks = (j.store_marks if j is not None
+                       else _no_store_marks())
         reason = self._patch_blockers(cluster, j)
         if reason is None:
             with self._span("snapshot.patch") as patch_sp:
@@ -545,6 +568,7 @@ class IncrementalSnapshotter:
                     # the keys whose entries were derived again, and
                     # the running rows that fed one
                     **self._last_rederived,
+                    "store_marks": store_marks,
                 }
                 patch_sp.attrs.update(self.stats.last)
                 if self.verify:
@@ -557,6 +581,7 @@ class IncrementalSnapshotter:
         with self._span("snapshot.full_build", fallback_reason=reason):
             out = self._full(cluster, now, queue_usage)
         self.stats.last = dict(_FULL_STATS, fallback_reason=reason,
+                               store_marks=store_marks,
                                **self._built_vocab)
         return out
 
@@ -574,6 +599,8 @@ class IncrementalSnapshotter:
             return "cold"
         if j.structural:
             return f"structural:{j.structural[0]}"
+        if j.drift:
+            return j.drift[0]
         if j.nodes_dirty:
             return "node-dirty"
         if cluster.topology is not self._topology:
@@ -680,10 +707,8 @@ class IncrementalSnapshotter:
         self._node_names_arr = np.array(index.node_names, dtype=object)
         self._node_index = {n: i for i, n in enumerate(index.node_names)}
         live_nodes = [n for n in nodes if not n.unschedulable]
-        self._node_objs = live_nodes
-        self._node_cache = [
-            (n, n.allocatable, n.labels, n.taints, n.extended,
-             n.accel_memory_gib) for n in live_nodes]
+        #: the store's nodes, for ``_check_membership``
+        self._nodes_stored = len(nodes)
         # the patch path looks selectors and filter classes up in the
         # pinned vocabulary; what it cannot carry keeps forcing full
         # rebuilds until a build comes out without it: extended (MIG)
@@ -735,9 +760,6 @@ class IncrementalSnapshotter:
         # --- pod ledger ---------------------------------------------------
         U = len(pods)
         self.p_objs: list = [None] * U
-        #: per-row (obj, raw status, raw node) — ONE list index per pod
-        #: in the sweep's hot loop
-        self.p_sweep: list = [None] * U
         for name, dtype, fill, tail in _POD_COLUMNS:
             setattr(self, name, np.full((U,) + tail, fill, dtype))
         #: distinct (request, selector items, filter class) of the
@@ -761,12 +783,6 @@ class IncrementalSnapshotter:
             self._encode_pod(row, pod, cluster)
         self._order = np.arange(U, dtype=np.int64)
         self._order_list = list(range(U))
-        #: BindRequest presentation cache — a Pending BR re-presents its
-        #: pod as bound (snapshot_lists), so BR creation/phase/target
-        #: drift must dirty the pod even when the pod object is untouched
-        self._br_cache = {
-            name: (br, br.phase, br.selected_node)
-            for name, br in cluster.bind_requests.items()}
         # cached per-pod task slots come from the freshly built tables
         self._task_names_obj = np.array(index.task_names, dtype=object) \
             if index.task_names else np.full(
@@ -849,7 +865,6 @@ class IncrementalSnapshotter:
         self.g_stale[i] = (np.nan if g.stale_since is None
                            else g.stale_since)
         tc = g.topology_constraint
-        self.g_tc[i] = tc
         req = self._resolve_level(tc, "required_level")
         self.g_reqlvl[i] = req
         self.g_preflvl[i] = self._resolve_level(tc, "preferred_level")
@@ -885,7 +900,6 @@ class IncrementalSnapshotter:
         self.p_objs[row] = pod
         self.p_names[row] = pod.name
         self.p_live[row] = True
-        self.p_sweep[row] = (pod, pod.status, pod.node)
         self.p_req[row] = pod.resources.as_tuple()
         self.p_prio[row] = pod.priority
         self.p_crea[row] = pod.creation_timestamp
@@ -1003,7 +1017,6 @@ class IncrementalSnapshotter:
         self._nonplain -= int(not self.p_plain[row])
         self._present_twice -= int(self.p_eff_status[row] == -2)
         self.p_objs[row] = None
-        self.p_sweep[row] = None
 
     # ------------------------------------------------------------------
     # Patch path
@@ -1030,7 +1043,6 @@ class IncrementalSnapshotter:
         remap = np.cumsum(keep) - 1
         kept = keep.tolist()
         self.p_objs = list(itertools.compress(self.p_objs, kept))
-        self.p_sweep = list(itertools.compress(self.p_sweep, kept))
         for name, _dtype, fill, _tail in _POD_COLUMNS:
             col = getattr(self, name)
             col[:n] = col[src]
@@ -1133,7 +1145,6 @@ class IncrementalSnapshotter:
             if row >= len(self.p_live):
                 self._grow_pods(64)
             self.p_objs.append(None)
-            self.p_sweep.append(None)
             self._pod_row[name] = row
             self._encode_pod(row, pod, cluster)
             dirty_rows.add(row)
@@ -1211,99 +1222,19 @@ class IncrementalSnapshotter:
             dirty_rows.add(row)
         return src, len(gone)
 
-    def _sweep(self, cluster, dirty_rows: set, dirty_gangs: set,
-               sections) -> None:
-        """Detect un-journaled drift: object replacement, status/node
-        writes, gang status writes, node mutations.  Cheap identity and
-        field compares; anything the ledger cannot attribute raises
-        _Fallback (full rebuild) rather than serving stale state.
-        ``sections(name)`` marks its four loops: ``sweep.bind_requests``,
-        ``sweep.pods``, ``sweep.gangs``, ``sweep.nodes``."""
-        sections("sweep.bind_requests")
+    def _check_membership(self, cluster) -> None:
+        """What is left of the guard against a stale answer.  Every
+        write to the store marks the journal as it lands
+        (``runtime/store.py``), so the ledger holds as many pods, gangs
+        and nodes as the store; a write that went round the store's
+        methods (``dict.__setitem__(cluster.pods, ...)``) shows here as
+        a count that differs, and rebuilds."""
         if len(cluster.pods) != len(self._order_list):
             raise _Fallback("pod-membership-drift")
-        # BindRequest drift (created/replaced/phase-flipped/cleared —
-        # bench and test harnesses touch the store directly): re-encode
-        # the affected pods' presentation
-        brs = cluster.bind_requests
-        br_cache = self._br_cache
-        br_dirty: list[str] = []
-        if brs or br_cache:
-            for name, br in brs.items():
-                c = br_cache.get(name)
-                if (c is None or c[0] is not br or c[1] != br.phase
-                        or c[2] != br.selected_node):
-                    br_dirty.append(name)
-            if len(br_cache) != len(brs) or br_dirty:
-                # sorted: the set difference iterates in hash order,
-                # which would make the dirty-row encode order (and any
-                # tie-broken downstream buffer) run-dependent (KAI041)
-                for name in sorted(br_cache.keys() - brs.keys()):
-                    br_dirty.append(name)
-                self._br_cache = {
-                    name: (br, br.phase, br.selected_node)
-                    for name, br in brs.items()}
-        for name in br_dirty:
-            row = self._pod_row.get(name)
-            if row is None or not self.p_live[row]:
-                continue
-            if row not in dirty_rows:
-                self._encode_pod(row, self.p_objs[row], cluster)
-                dirty_rows.add(row)
-                gi = int(self.p_group[row])
-                if gi >= 0:
-                    dirty_gangs.add(gi)
-        sections("sweep.pods")
-        cache = self.p_sweep
-        changed: list[int] = []
-        for row, pod in zip(self._order_list, cluster.pods.values()):
-            c = cache[row]
-            if c[1] is not pod.status or c[0] is not pod \
-                    or c[2] != pod.node:
-                changed.append(row)
-        for row in changed:
-            pod = self.p_objs[row]
-            if pod is not cache[row][0] or pod is not cluster.pods.get(
-                    pod.name if pod is not None else ""):
-                raise _Fallback("pod-object-drift")
-            if row not in dirty_rows:
-                self._encode_pod(row, pod, cluster)
-                dirty_rows.add(row)
-                gi = int(self.p_group[row])
-                if gi >= 0:
-                    dirty_gangs.add(gi)
-        sections("sweep.gangs")
         if len(cluster.pod_groups) != len(self.g_objs):
             raise _Fallback("gang-membership-drift")
-        for i, g in enumerate(cluster.pod_groups.values()):
-            if self.g_objs[i] is not g:
-                raise _Fallback("gang-object-drift")
-            start = (-1.0 if g.last_start_timestamp is None
-                     else g.last_start_timestamp)
-            stale_c = self.g_stale[i]
-            stale_eq = ((g.stale_since is None and np.isnan(stale_c))
-                        or (g.stale_since is not None
-                            and stale_c == g.stale_since))
-            if (bool(g.unschedulable) != bool(self.g_unsched[i])
-                    or self.g_start[i] != start or not stale_eq
-                    or self.g_tc[i] is not g.topology_constraint
-                    or self.g_subs[i] is not g.sub_groups):
-                self._encode_gang(i, g)
-                dirty_gangs.add(i)
-        # nodes: any drift at all → full rebuild (vocabularies, masks,
-        # device tables and capacity all hang off the node section)
-        sections("sweep.nodes")
-        node_vals = [n for n in cluster.nodes.values()
-                     if not n.unschedulable]
-        if len(node_vals) != len(self._node_objs):
+        if len(cluster.nodes) != self._nodes_stored:
             raise _Fallback("node-membership-drift")
-        for cached, n in zip(self._node_cache, node_vals):
-            if (cached[0] is not n or cached[1] is not n.allocatable
-                    or cached[2] is not n.labels
-                    or cached[3] is not n.taints
-                    or cached[4] is not n.extended
-                    or cached[5] != n.accel_memory_gib):
-                raise _Fallback("node-drift")
 
     def _patch(self, cluster, j, now, queue_usage):
         # each block's sections close inside its span, whatever raises
@@ -1318,10 +1249,7 @@ class IncrementalSnapshotter:
             finally:
                 sections.close()
         with self._span("patch.sweep"):
-            try:
-                self._sweep(cluster, dirty_rows, dirty_gangs, sections)
-            finally:
-                sections.close()
+            self._check_membership(cluster)
         self._last_dirty = (len(dirty_rows), len(dirty_gangs))
         if self._nonplain > 0:
             raise _Fallback("nonplain-pods")

@@ -9,6 +9,7 @@ drive churn through it and then check the patch path actually engaged
 (a fallback-to-full would pass verification vacuously).
 """
 import dataclasses
+import types
 
 import jax
 import numpy as np
@@ -20,6 +21,7 @@ from kai_scheduler_tpu.framework.scheduler import Scheduler, SchedulerConfig
 from kai_scheduler_tpu.intake import apply as intake_apply
 from kai_scheduler_tpu.intake import gate
 from kai_scheduler_tpu.runtime.cluster import Cluster
+from kai_scheduler_tpu.runtime.store import Collection
 from kai_scheduler_tpu.state import make_cluster
 from kai_scheduler_tpu.state.incremental import (
     IncrementalSnapshotter,
@@ -34,6 +36,20 @@ def build(num_nodes=8, num_gangs=6, tasks_per_gang=2, **kw) -> Cluster:
         num_nodes=num_nodes, num_gangs=num_gangs,
         tasks_per_gang=tasks_per_gang, **kw)
     return Cluster.from_objects(nodes, queues, groups, pods, topo)
+
+
+def marks_of(batch) -> list:
+    """A drained batch as the ``(kind, name)`` marks that make it."""
+    return ([("pod", n) for n in sorted(batch.pods_dirty)]
+            + [("pod_added", n) for n in batch.pods_added]
+            + [("pod_removed", n) for n in sorted(batch.pods_removed)]
+            + [("gang", n) for n in sorted(batch.gangs_dirty)]
+            + [("gang_added", n) for n in batch.gangs_added]
+            + [("gang_removed", n) for n in sorted(batch.gangs_removed)]
+            + [("node", n) for n in sorted(batch.nodes_dirty)]
+            + [("structural", r) for r in batch.structural]
+            + [("drift", r) for r in batch.drift]
+            + [("time", "")] * batch.time_dirty)
 
 
 def refresh(snap, cluster):
@@ -144,8 +160,19 @@ class TestJournal:
         ("resource_claims", ("structural", "resource_claims-delete")),
     ])
     def test_delete_marks_by_collection(self, coll, mark):
+        """What a delete of ``x`` marks: the store marks its own
+        collections' as the delete lands, a delta document the rest."""
         out: list = []
         gate.delete_marks(coll, "x", True, out)
+        if coll in ("pods", "pod_groups", "bind_requests"):
+            assert out == []
+            j = MutationJournal()
+            cur = j.register()
+            store = Collection(coll, j, {"x": types.SimpleNamespace()})
+            cur.consume()
+            del store["x"]
+            assert marks_of(cur.consume()) == [mark]
+            out = [mark]
         assert out == [mark]
         gate.delete_marks(coll, "x", False, out)  # nothing was stored
         assert out == [mark]
@@ -179,8 +206,9 @@ class TestPatchEquivalence:
         assert snap.stats.full_builds == 1  # the cold build only
 
     def test_direct_status_mutation_is_swept_and_patched(self):
-        """Un-journaled in-place writes (tests/controllers do this) are
-        detected by the drift sweep and patched correctly."""
+        """In-place writes of a watched field (tests/controllers do
+        this) mark the journal through the store and are patched
+        correctly."""
         cluster = build(num_gangs=4, running_fraction=0.5)
         snap = IncrementalSnapshotter(verify=True, dirty_threshold=1.0)
         refresh(snap, cluster)
@@ -850,7 +878,7 @@ UNIFORM = {"pytorch": (True, True), "mpi": (False, True),
 
 def submit_subgrouped(cluster, name, shape) -> list[str]:
     fields, pods, *_ = SUBGROUPED[shape]
-    # a list of its own a gang: the sweep tells them apart by identity
+    # a list of its own a gang: the store tells writes apart by identity
     fields = {**fields, "sub_groups": list(fields["sub_groups"])}
     cluster.submit(
         apis.PodGroup(name, queue="queue-0-0", min_member=len(pods),
@@ -958,6 +986,8 @@ class TestSubgroupedGangsPatch:
             assert index.num_nonuniform_gangs == int(not expected)
             assert index.uniform_gangs is False
 
+    # "sweep": the write alone, which the store marks (the id is older
+    # than the store's marks); "journal": the write and an explicit mark
     @pytest.mark.parametrize("by", ["sweep", "journal"])
     @pytest.mark.parametrize("status", ["pending", "running"])
     def test_growing_and_shedding_subgroups_reslots_the_gangs_pods(
@@ -1315,8 +1345,7 @@ def _drop_node_unseen(c):
 #: reason -> (what the cluster is built with, what is done to it before
 #: the first refresh, the named mutation, what undoes a lasting cause).
 #: A replaced ``cluster.topology`` has one name, ``topology-changed``:
-#: ``_patch_blockers`` tests it before the sweep runs, and the sweep
-#: does not test it again.
+#: ``_patch_blockers`` tests it before anything of the patch runs.
 _REFUSALS = {
     "vocab-residue": ({}, _node_with_mig, None, _node_without_mig),
     "vocab-growth": ({}, None, _submit_tolerating, None),
@@ -1346,6 +1375,23 @@ _REFUSALS = {
     "node-membership-drift": ({}, None, _drop_node_unseen, None),
 }
 
+#: the mutations above that the store now marks as they land
+#: (``runtime/store.py``), and what became of their refusal: None, the
+#: patch carries the write; else the name the journal refuses it by (a
+#: name deleted and stored again inside one window moved to the end of
+#: its collection).  The names themselves still refuse a write that goes
+#: round the store (``tests/test_store_marks.py``).
+_NOW_MARKED = {
+    "pod-object-drift": None,
+    "gang-object-drift": None,
+    "pod-membership-drift": None,
+    "gang-membership-drift": None,
+    "pod-removed-unjournaled": None,
+    "gang-removed-unjournaled": None,
+    "pod-add-drift": "structural:pod-readded",
+    "gang-add-drift": "structural:gang-readded",
+}
+
 
 def _assert_fresh(state, snap, cluster):
     """``state`` equals a fresh ``build_snapshot`` of the cluster as it
@@ -1373,7 +1419,9 @@ def test_refusal_is_named_rebuilt_right_and_left_behind(reason):
     """Each reason ``refresh`` refuses to patch for: a named mutation
     of a small cluster reaches it, ``stats.fallbacks`` books it under
     that name, what comes back is a fresh build of the cluster as it
-    now stands, and once the cause is gone a quiet cycle patches."""
+    now stands, and once the cause is gone a quiet cycle patches.  A
+    mutation the store now marks (``_NOW_MARKED``) is patched fresh, or
+    refused by the journal's name for it."""
     kw, before, mutate, undo = _REFUSALS[reason]
     cluster = build(**{"num_gangs": 8, **kw})
     snap = IncrementalSnapshotter(verify=True, dirty_threshold=1.0)
@@ -1388,9 +1436,15 @@ def test_refusal_is_named_rebuilt_right_and_left_behind(reason):
     if mutate is not None:
         mutate(cluster)
     state, _ = refresh(snap, cluster)
-    assert snap.stats.last["mode"] == "full"
-    assert snap.stats.last["fallback_reason"] == reason
-    assert snap.stats.fallbacks.get(reason, 0) == booked.get(reason, 0) + 1
+    expect = _NOW_MARKED.get(reason, reason)
+    if expect is None:
+        assert snap.stats.last["mode"] == "patched", snap.stats.last
+        assert snap.stats.fallbacks == booked
+    else:
+        assert snap.stats.last["mode"] == "full"
+        assert snap.stats.last["fallback_reason"] == expect
+        key = expect.split(":")[0]
+        assert snap.stats.fallbacks.get(key, 0) == booked.get(key, 0) + 1
     _assert_fresh(state, snap, cluster)
     if reason == "no-journal":
         # nothing to patch from, ever: the same objects under a store
@@ -1514,7 +1568,7 @@ class TestRederivedByKey:
         pod = running(cluster)[0]
         old = pod.node
         new = next(n for n in cluster.nodes if n != old)
-        pod.node = new  # direct write: the sweep finds it
+        pod.node = new  # direct write: the store marks it
         state, index = self.patched(snap, cluster)
         assert rederived(snap)[:3] == (2, 1, 1)  # both nodes
         free = np.asarray(state.nodes.free)
@@ -1829,8 +1883,8 @@ class TestSchedulerIntegration:
 class TestBindRequestPresentation:
     def test_direct_bind_request_clear_is_swept(self):
         """A Pending BindRequest presents its pod as bound; clearing the
-        store directly (no journal) must still flip the presentation
-        back — the sweep covers the BR table too."""
+        store directly (no explicit mark) must still flip the
+        presentation back — the store marks the BR table too."""
         cluster = build(num_nodes=4, num_gangs=4, tasks_per_gang=2)
         snap = IncrementalSnapshotter(verify=True, dirty_threshold=1.0)
         refresh(snap, cluster)
@@ -1840,7 +1894,7 @@ class TestBindRequestPresentation:
             pod_name=pod.name, selected_node="node-0"))
         state, _ = refresh(snap, cluster)
         assert int(np.asarray(state.running.valid).sum()) == 1
-        cluster.bind_requests.clear()  # direct, unjournaled
+        cluster.bind_requests.clear()  # direct, marked by the store
         state, _ = refresh(snap, cluster)
         assert int(np.asarray(state.running.valid).sum()) == 0
         assert snap.stats.patched == 2
@@ -1855,9 +1909,9 @@ class TestJournalConcurrency:
     """The journal is marked from binder / status-updater / HTTP
     handler threads while the scheduler thread drains cursors.  Before
     the journal lock, ``consume()``'s field swap could drop a mark that
-    raced it — and a dropped mark for an in-place field mutation the
-    drift sweep does not compare (e.g. pod priority) silently serves a
-    stale snapshot."""
+    raced it — and a dropped mark for an in-place write of a field the
+    store does not watch (e.g. pod priority) silently serves a stale
+    snapshot."""
 
     def test_marks_hammered_from_thread_patched_equals_fresh(self):
         import threading
@@ -1876,7 +1930,7 @@ class TestJournalConcurrency:
 
         def hammer():
             # in-place priority bumps + marks: the exact write the
-            # sweep cannot attribute without the journal entry
+            # store cannot attribute without the journal entry
             i = 0
             while not stop.is_set():
                 pod = pending[i % len(pending)]

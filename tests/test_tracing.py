@@ -521,7 +521,7 @@ def test_empty_delta_touches_no_key():
 
 @pytest.mark.parametrize("metric, floor", [
     ("patch_assemble_ms", 0.0), ("patch_sweep_ms", 0.0),
-    ("assemble_rederived_rows", -1)])
+    ("assemble_rederived_rows", -1), ("store_marks", -1)])
 def test_benchmark_reads_the_patch_from_healthz(metric, floor):
     """``benchmark/layer_metrics/<metric>.py`` over ``last_cycle`` as
     ``/healthz`` serves it (``span_self_seconds``, ``snapshot``): a
@@ -633,9 +633,7 @@ def _served_rounds(entered, start_recording):
                  "kai:reply.encode", "kai:reply.write", "kai:lane.admit",
                  "kai:journal.compact", "kai:journal.removed",
                  "kai:journal.gangs", "kai:journal.pods",
-                 "kai:sweep.bind_requests", "kai:sweep.pods",
-                 "kai:sweep.gangs", "kai:sweep.nodes",
-                 "kai:dispatch.init_result"):
+                 "kai:patch.sweep", "kai:dispatch.init_result"):
         assert name in entered, name
     # the request of the cycle encloses it: coalesce before the cycle's
     # root, the document and the reply after its last phase
