@@ -23,15 +23,15 @@ no leaf queue starts above its quota.  The seed chooses which rack gets
 which fill, which jobs and which leaf, and the jobs' creation order;
 never a count or a shape.
 
-**The reference.**  The harness judges every run with
-``lib.host_model.HostModel`` and lets no configuration name another
-(``lib/loop.py``: ``Run.judge``; this PR may not edit it), and that
-reference knows no tree.  So this file, which the harness loads for
-this configuration alone, puts ``lib/topology_model.py``'s
-``TreeHostModel`` in its place: the same reference, with the two counts
-of ``topology_required`` beside its own, each with limit 0.  A stopgap,
-to go when ``Run.judge`` takes the reference's name from the
-configuration (``PERF.md`` §7 row 24).
+**The reference.**  The configuration names ``lib/topology_model.py``
+among its ``references``, and ``Run.judge`` builds it beside
+``lib/host_model.py`` from that list.  This file still puts that
+module's ``TreeHostModel`` in ``HostModel``'s place when the harness
+loads it, because a tier-1 test outside the benchmark reads the
+reference from there (``tests/test_benchmark_generators.py``); given
+the references by name, as ``Run.judge`` gives them, ``TreeHostModel``
+is ``HostModel``, so the assignment decides nothing.  It goes with that
+test (``PERF.md`` §7 row 24).
 """
 from __future__ import annotations
 
@@ -52,7 +52,7 @@ arriving_leaves = _uniform.arriving_leaves
 
 # inside the harness ``lib.loop`` has imported the reference before it
 # loads a generator; loaded by its path elsewhere (the generator's own
-# tests), there is nothing that judges
+# tests), there is nothing that judges.  Inert: see "The reference" above
 if "lib.host_model" in sys.modules:
     from lib.topology_model import TreeHostModel
     sys.modules["lib.host_model"].HostModel = TreeHostModel

@@ -1,5 +1,7 @@
-"""``patch.sweep``: the compare of every pod, gang and node with the
-ledger that keeps an unjournaled write from being served stale."""
+"""``patch.sweep``: the guard against a stale answer that is left once
+the store marks every write as it lands — three compares of the store's
+pod, pod-group and node counts with the ledger's
+(``state/incremental.py``: ``_check_membership``)."""
 from lib.spans import mean_span_ms
 
 

@@ -7,8 +7,8 @@ with the options the configuration file states, port 0), spoken to over
 HTTP on 127.0.0.1 by one client that waits for each reply.  One
 iteration posts the cycle's churn (``/cluster/delta``, ``/intake``) and
 then ``POST /cycle/stored``.  Nothing is judged inside the window:
-documents and commits are kept, and the host model replays them once the
-window has closed.
+documents and commits are kept, and the host model, with the references
+the configuration names, replays them once the window has closed.
 """
 from __future__ import annotations
 
@@ -37,6 +37,9 @@ class Run:
             mix, self.spec["nodes"] / config["cluster"]["nodes"])
         self.options = config["scheduler"]["options"]
         self.actions = config["scheduler"]["actions"]
+        #: the modules under ``lib/`` that judge what the configuration
+        #: guarantees beyond ``lib/host_model.py``
+        self.references = config.get("references", [])
         self.seed, self.root = seed, root
         self.cycles: list[dict] = []     # one sample per window cycle
         #: (delta, intake, commit) of every cycle, as the bytes that went
@@ -204,8 +207,10 @@ class Run:
 
     def judge(self) -> dict:
         """Replay everything posted and committed through the plain
-        reference.  Returns each number compared beside its limit."""
-        model = host_model.HostModel(json.loads(self.cluster_json))
+        reference and the references the configuration names.  Returns
+        each number compared beside its limit."""
+        model = host_model.HostModel(json.loads(self.cluster_json),
+                                     self.references)
         tallies = []
         for delta, intake, commit in self.records:
             model.apply_doc(json.loads(delta))

@@ -12,47 +12,38 @@ bound only inside one domain of it, and a pending gang of equal pods for
 which some domain of its required level has room for all of it is bound
 in that cycle.
 
-``lib/host_model.py`` decides ``correct`` and knows no tree, and the
-harness builds it by that name (``lib/loop.py``: ``Run.judge``), so
-``TreeHostModel`` is that reference with a ``TopologyModel`` fed beside
-it: the two counts of the guarantee join the numbers compared, each
-with limit 0, and each cycle's tally carries the tree's under
-``"topology"`` for the per-layer readers.  ``generators/topology_tree.py``
-puts it in ``HostModel``'s place.  Plain Python and NumPy over the
-wire's own documents; it imports nothing of the program.
+``topology-10k``'s configuration names this module among its
+``references``: ``lib/host_model.py`` builds its ``Reference`` beside
+itself, asks it for each pending gang's domains (``domain_of``), so that
+``gangs_bound_short`` claims only a gang that one domain of its required
+level holds, and compares its two numbers with 0 like its own; each
+cycle's tally carries the tree's under ``"topology"`` for the per-layer
+readers.  Plain Python and NumPy over the wire's own documents; it
+imports nothing of the program.
 """
 from __future__ import annotations
 
 import numpy as np
 
 from . import host_model
-
-RES = ("accel", "cpu", "memory")
-EPS = 1e-3
+from .host_model import vec, replicas
 
 
-def _vec(d: dict) -> np.ndarray:
-    return np.array([d[r] for r in RES], dtype=np.float64)
-
-
-def replicas(free: np.ndarray, req: np.ndarray) -> np.ndarray:
-    """How many pods of ``req`` each node's ``free`` ([N, R]) holds."""
-    per = np.where(req > 0, (free + EPS) / np.where(req > 0, req, 1.0),
-                   np.inf)
-    return np.floor(per.min(axis=1)).clip(min=0).astype(np.int64)
-
-
-class TopologyModel:
+class Reference:
     """Follows nodes, gangs and pods through the documents posted and
     counts, commit by commit, the gangs bound across domains of their
     required level, the gangs of equal pods left pending though a domain
     of that level had room, and the gangs bound inside one domain of
     their preferred level."""
 
+    KEY = "topology"
+    LIMITS = {"gangs_split_across_domains": 0, "domain_left_pending": 0}
+
     def __init__(self, cluster: dict):
+        self.counts = {k: 0 for k in self.LIMITS}
         nodes = cluster["nodes"]
         self.node_ix = {n["name"]: i for i, n in enumerate(nodes)}
-        self.alloc = np.stack([_vec(n["allocatable"]) for n in nodes])
+        self.alloc = np.stack([vec(n["allocatable"]) for n in nodes])
         self.used = np.zeros_like(self.alloc)
         topo = cluster.get("topology") or {"name": None, "levels": []}
         self.tree, self.levels = topo["name"], list(topo["levels"])
@@ -91,7 +82,7 @@ class TopologyModel:
                 g["creation_timestamp"], set()]
         for p in doc.get("pods_upsert", []):
             node = self.node_ix[p["node"]] if p.get("node") else -1
-            req = _vec(p["resources"])
+            req = vec(p["resources"])
             self.pods[p["name"]] = [p["group"], req, node]
             self.gangs[p["group"]][3].add(p["name"])
             if node >= 0:
@@ -103,6 +94,12 @@ class TopologyModel:
                 self.used[node] -= req
         for name in doc.get("pod_groups_delete", []):
             del self.gangs[name]
+
+    def domain_of(self, gang: str) -> np.ndarray | None:
+        """Each node's domain of the gang's required level, or ``None``
+        where it names none."""
+        level = self.gangs[gang][0]
+        return None if level < 0 else self.dom[:, level]
 
     def _one_domain(self, names, level: int) -> bool:
         """All placed pods of ``names`` on nodes of one domain of
@@ -171,45 +168,28 @@ class TopologyModel:
                 take = min(left, int(fits[node]))
                 free[node] -= take * reqs[0]
                 left -= take
+        self.counts["gangs_split_across_domains"] += tally["required_split"]
+        self.counts["domain_left_pending"] += tally["domain_left_pending"]
         return tally
 
 
 class TreeHostModel(host_model.HostModel):
-    """The plain reference, holding a cluster with a tree to
-    ``topology_required`` as well: gangs bound across domains of their
-    required level and gangs left pending though a domain of it had
-    room are compared with 0 like its own numbers.  A cluster without a
-    tree is judged as ``HostModel`` judges it (both read 0)."""
+    """``HostModel`` with this reference named, unless the caller names
+    the references itself: then exactly ``HostModel``.
+    ``generators/topology_tree.py`` still puts it in ``HostModel``'s
+    place when the harness loads it, for the one tier-1 test that reads
+    the reference from there (``tests/test_benchmark_generators.py``);
+    ``Run.judge`` names the configuration's references, so it decides
+    nothing."""
 
-    LIMITS = {**host_model.HostModel.LIMITS,
-              "gangs_split_across_domains": 0, "domain_left_pending": 0}
-    #: set once ``HostModel.__init__`` has applied the cluster's own pod
-    #: groups and pods, which ``TopologyModel.__init__`` applies itself
-    tree = None
-
-    def __init__(self, cluster: dict):
-        super().__init__(cluster)
-        if cluster.get("topology"):
-            self.tree = TopologyModel(cluster)
-
-    def apply_doc(self, doc: dict) -> None:
-        super().apply_doc(doc)
-        if self.tree is not None:
-            self.tree.apply_doc(doc)
-
-    def check_commit(self, commit: dict) -> dict:
-        tally = super().check_commit(commit)
-        if self.tree is not None:
-            seen = tally["topology"] = self.tree.check_commit(commit)
-            self.counts["gangs_split_across_domains"] += \
-                seen["required_split"]
-            self.counts["domain_left_pending"] += seen["domain_left_pending"]
-        return tally
+    def __init__(self, cluster: dict, references=None):
+        super().__init__(cluster, ["topology_model"] if references is None
+                         else references)
 
 
 def window_tallies(run) -> list[dict]:
     """The tree's tally of each window cycle, as ``Run.judge`` left
     them (the benchmark judges before it reads a metric); none where
-    another reference judged."""
+    the configuration does not name this reference."""
     return [t["topology"] for t in getattr(run, "tallies", [])
             if "topology" in t]

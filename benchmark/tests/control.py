@@ -5,10 +5,12 @@ one process (set-up is long; the compiled program is shared).
     python3 benchmark/tests/control.py --workload <cell> --seeds 1,2,3 \
         [--faults partial_gang,half_left_out] [--seconds 8] [--nodes N]
 
-For each seed: a sound run and, for each fault named, a run with that
-fault planted under the timed path, each with a short window at the
-cell's own load.  Prints one JSON line per run with every number compared
-beside its limit.  The benchmark's own runs never call this.
+For each seed: a sound run and, for each fault named (``faults.FAULTS``:
+the control, the faults a served scheduler can have, and those against
+a guarantee one configuration states), a run with that fault planted
+under the timed path, each with a short window at the cell's own load.
+Prints one JSON line per run with every number compared beside its
+limit.  The benchmark's own runs never call this.
 """
 from __future__ import annotations
 
@@ -27,7 +29,7 @@ def one_run(config, mix, seed, seconds, fault, nodes, meter) -> dict:
     import faults
     from lib import loop
     run = loop.Run(config, mix, seed, ROOT, nodes=nodes)
-    with faults.planted(fault):
+    with faults.planted(fault, run):
         try:
             run.start(meter)
             run.warm_up()

@@ -1,12 +1,21 @@
-"""Faults planted under the timed path: each alters the commit document
-where the server produces it (``framework/server._commit_doc``), so the
-harness above sees a program that misbehaves.  ``partial_gang`` is the
-control: it breaks one guarantee the configuration states, gang
-all-or-nothing.  The others are the faults a served scheduler can have.
+"""Faults planted under the timed path, so the harness above sees a
+program that misbehaves.  ``partial_gang`` is the control: it breaks one
+guarantee every configuration states, gang all-or-nothing.
+``state_unchanged``, ``half_left_out`` and ``answer_altered`` are the
+faults a served scheduler can have; each alters the commit document
+where the server produces it (``framework/server._commit_doc``).  The
+rest break a guarantee one configuration states beside those, and are
+kept with it: ``pool_faults.py`` (``placement``), ``kubeflow_faults.py``
+(``subgroup_quorum``), ``topology_faults.py`` (``topology_required``).
+``FAULTS`` names them all; ``planted(name, run)`` plants one.
 """
 from __future__ import annotations
 
 import contextlib
+
+import kubeflow_faults
+import pool_faults
+import topology_faults
 
 
 def _gang(bind: dict) -> str:
@@ -50,20 +59,32 @@ def answer_altered(doc: dict) -> dict:
                                     for br in binds])
 
 
-FAULTS = {f.__name__: f for f in
-          (partial_gang, state_unchanged, half_left_out, answer_altered)}
-
-
 @contextlib.contextmanager
-def planted(name: str | None):
-    """Run the block with the named fault under ``POST /cycle/stored``;
-    ``None`` plants nothing."""
+def under_commit(fault):
+    """Run the block with ``fault`` (a function of the commit document)
+    under ``POST /cycle/stored``."""
     from kai_scheduler_tpu.framework import server
     sound = server._commit_doc
-    if name is not None:
-        fault = FAULTS[name]
-        server._commit_doc = lambda result: fault(sound(result))
+    server._commit_doc = lambda result: fault(sound(result))
     try:
         yield
     finally:
         server._commit_doc = sound
+
+
+#: name -> a function of the ``lib.loop.Run`` about to start that gives
+#: the context manager planting the fault
+FAULTS = {
+    **{f.__name__: (lambda run, f=f: under_commit(f)) for f in
+       (partial_gang, state_unchanged, half_left_out, answer_altered)},
+    "wrong_pool": lambda run: under_commit(pool_faults.wrong_pool(run)),
+    "mixed_gangs_left_pending": kubeflow_faults.mixed_gangs_left_pending,
+    "launcher_left_out": kubeflow_faults.launcher_left_out,
+    "pod_in_another_rack": topology_faults.pod_in_another_rack,
+    "rack_gang_dropped": topology_faults.rack_gang_dropped,
+}
+
+
+def planted(name: str | None, run):
+    """The named fault planted for ``run``; ``None`` plants nothing."""
+    return contextlib.nullcontext() if name is None else FAULTS[name](run)

@@ -1,19 +1,21 @@
 """Faults against the guarantee ``subgroup_quorum``
-(``configs/kubeflow-10k.json``).
+(``configs/kubeflow-10k.json``), planted by ``faults.py``.
 
 ``mixed_gangs_left_pending`` is planted where the scheduler turns its
 placements into bind requests (``Session.bind_requests_from``), so the
 server's own store agrees with what it returns: every bind of a gang
 whose pods differ in request is dropped, and the gang stays pending
 cycle after cycle though it fits.  Gangs stay whole, names live, nodes
-within their capacity and the read-back equal, and the reference
-claims nothing for a gang of unequal pods — so of the benchmark's
-numbers only ``subgroup_violations`` can see it.
+within their capacity and the read-back equal: of the numbers
+``correct`` compares only ``gangs_bound_short`` can see it, because the
+configuration names ``lib/subgroup_model.py``, which has the reference
+fit a gang of unequal pods pod by pod.
 
-``launcher_left_out`` is planted like those of ``faults.py`` under
-``POST /cycle/stored``: the bind of every pod of the subgroup
-``launcher`` is left out of the commit, so an MPIJob is bound one below
-its ``min_member`` and its launcher's subgroup below its quorum.
+``launcher_left_out`` is planted under ``POST /cycle/stored``: the bind
+of every pod of the subgroup ``launcher`` is left out of the commit, so
+an MPIJob is bound one below its ``min_member`` and its launcher's
+subgroup below its quorum (``gangs_below_min_member``,
+``subgroups_below_quorum``).
 """
 from __future__ import annotations
 
@@ -68,8 +70,3 @@ def launcher_left_out(run):
     finally:
         server._commit_doc = sound
 
-
-#: name -> a context manager of the run; ``sound`` plants nothing
-FAULTS = {"sound": lambda run: contextlib.nullcontext(),
-          "mixed_gangs_left_pending": mixed_gangs_left_pending,
-          "launcher_left_out": launcher_left_out}

@@ -1,11 +1,11 @@
 """A fault against the guarantee ``placement`` (``configs/pools-10k.json``),
-planted like those of ``faults.py`` under ``POST /cycle/stored``: one
-gang that selects a pool is bound onto nodes of another.  Gangs stay
-whole, names live and nodes within their capacity, so of the
-benchmark's numbers only ``placement_violations`` can see it."""
+planted by ``faults.py`` under ``POST /cycle/stored``: one gang that
+selects a pool is bound onto nodes of another.  Gangs stay whole and
+names live; the number of the guarantee, ``binds_outside_pool``
+(``lib/placement_model.py``), counts every such bind.  The server's
+store keeps the nodes it chose, so the read-back sees the altered
+commit as well, and over a long window the other pool's nodes fill."""
 from __future__ import annotations
-
-import contextlib
 
 
 def wrong_pool(run, pool_label: str = "gpu.type"):
@@ -35,16 +35,3 @@ def wrong_pool(run, pool_label: str = "gpu.type"):
         return dict(doc, bind_requests=out)
 
     return fault
-
-
-@contextlib.contextmanager
-def planted(fault):
-    """Run the block with ``fault`` (a function of the commit document)
-    under ``POST /cycle/stored``."""
-    from kai_scheduler_tpu.framework import server
-    sound = server._commit_doc
-    server._commit_doc = lambda result: fault(sound(result))
-    try:
-        yield
-    finally:
-        server._commit_doc = sound

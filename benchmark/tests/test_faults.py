@@ -4,7 +4,9 @@ Run by hand (not part of tier-1): ``JAX_PLATFORMS=cpu python -m pytest
 benchmark/tests -q``.  Each test drives the rest of a run — serve, warm
 up, a short window, the reference's replay — at 256 nodes on whatever
 backend JAX finds, skipping only the harness's look for a chip, with one
-fault planted under ``POST /cycle/stored``.
+fault planted under ``POST /cycle/stored``.  The faults against a
+guarantee one configuration states are planted in that configuration's
+own file, on its cell.
 """
 from __future__ import annotations
 
@@ -34,6 +36,21 @@ def meter():
 
 
 CELLS = ("reclaim-10k.steady", "alloc-10k.churn")
+GENERAL = ("answer_altered", "half_left_out", "partial_gang",
+           "state_unchanged")
+#: fault -> the file that plants it on its configuration's cell
+BY_CONFIGURATION = {"wrong_pool": "test_pools.py",
+                    "mixed_gangs_left_pending": "test_kubeflow.py",
+                    "launcher_left_out": "test_kubeflow.py",
+                    "pod_in_another_rack": "test_topology.py",
+                    "rack_gang_dropped": "test_topology.py"}
+
+
+def test_every_fault_is_planted_somewhere():
+    assert set(faults.FAULTS) == set(GENERAL) | set(BY_CONFIGURATION)
+    for fault, name in BY_CONFIGURATION.items():
+        with open(os.path.join(HERE, name)) as fh:
+            assert f'"{fault}"' in fh.read(), (fault, name)
 
 
 @pytest.mark.parametrize("cell", CELLS)
@@ -45,7 +62,7 @@ def test_sound_run_is_correct(cell, meter):
 
 
 @pytest.mark.parametrize("cell", CELLS)
-@pytest.mark.parametrize("fault", sorted(faults.FAULTS))
+@pytest.mark.parametrize("fault", GENERAL)
 def test_fault_is_not_correct(cell, fault, meter):
     config, mix = _cell(cell)
     out = control.one_run(config, mix, 22, 0.5, fault, 256, meter)

@@ -49,11 +49,24 @@ def test_every_new_metric_has_an_entry_and_a_reader():
     for name in NEW:
         assert name in entries, name
         assert callable(registry.module("layer_metrics", name).read)
-    rebuilt = ["reclaim-10k.steady", "alloc-10k.churn"]
+    churned = ["reclaim-10k.steady", "alloc-10k.churn", "pools-10k.churn",
+               "kubeflow-10k.churn", "topology-10k.churn"]
     for name in NEW[:4]:
-        assert entries[name]["workloads"] == rebuilt
-    assert entries["snapshot_patch_ms"]["workloads"] == ["reclaim-10k.idle"]
+        assert entries[name]["workloads"] == churned
+    assert entries["snapshot_patch_ms"]["workloads"] == [
+        "reclaim-10k.idle", "topology-10k.churn"]
     assert all("workloads" not in entries[n] for n in NEW[5:])
+
+
+def test_every_entry_has_a_reader_and_every_reader_an_entry():
+    """A metric retired takes its reader with it, and a reader left
+    behind would be read by no cell."""
+    bench = registry.load_json(registry.ROOT, "BENCHMARK.json")
+    for kind, directory in (("per_layer", "layer_metrics"),
+                            ("end_to_end", "end_to_end")):
+        files = {f[:-3] for f in os.listdir(os.path.join(
+            registry.BENCH, directory)) if f.endswith(".py")}
+        assert files == {m["name"] for m in bench[kind]}, kind
 
 
 def test_rebuilt_cycle_splits_four_ways():

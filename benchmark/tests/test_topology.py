@@ -4,7 +4,7 @@ of a rack-required gang bound into another rack makes the run not
 ``correct`` by ``gangs_split_across_domains`` alone, and a
 rack-required gang dropped from the commit by ``domain_left_pending``
 and by ``gangs_bound_short`` (``Run.judge``, the harness's own verdict,
-through ``lib/topology_model.py``'s ``TreeHostModel``).
+with ``lib/topology_model.py``, which the configuration names).
 
 Run by hand (not part of tier-1): ``JAX_PLATFORMS=cpu python -m pytest
 benchmark/tests -q``.  The generator's own tests are tier-1
@@ -22,7 +22,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path[:0] = [HERE]
 
 import control  # noqa: E402  (puts benchmark/ and the repo on sys.path)
-import topology_faults  # noqa: E402
+import faults  # noqa: E402
 from test_rehearsal import rehearse  # noqa: E402
 
 CELL = "topology-10k.churn"
@@ -44,7 +44,6 @@ def test_rehearsal_is_sound_patches_and_keeps_the_whole_gang_kernel():
     assert {"gangs_split_across_domains", "domain_left_pending"} \
         <= res["checks"].keys()
     m = {k: v["value"] for k, v in res["metrics"].items()}
-    assert m["topology_violations"] == 0
     assert m["snapshot_fallbacks"] == 0      # after the cold cycle
     assert m["window_compiles"] == 0
     # one job arrives a cycle at this size, every one under a required
@@ -65,12 +64,13 @@ def meter():
     return meters.CompileMeter()
 
 
-@pytest.mark.parametrize("fault", list(topology_faults.FAULTS))
+@pytest.mark.parametrize("fault", [None, "pod_in_another_rack",
+                                   "rack_gang_dropped"])
 def test_a_planted_fault_is_seen_by_the_number_that_holds_it(fault, meter):
     from lib import loop, registry
     _bench, _cell, config, mix = registry.load_cell(CELL)
     run = loop.Run(config, mix, 31, control.ROOT, nodes=200)
-    with topology_faults.FAULTS[fault](run):
+    with faults.planted(fault, run):
         try:
             run.start(meter)
             run.warm_up()
@@ -80,18 +80,17 @@ def test_a_planted_fault_is_seen_by_the_number_that_holds_it(fault, meter):
     checks = run.judge()
     wrong = {k: c["value"] for k, c in checks.items()
              if c["value"] > c["limit"]}
-    count = registry.module("layer_metrics", "topology_violations").read(run)
     assert {"gangs_split_across_domains", "domain_left_pending"} \
         <= checks.keys()
     # of every 4 jobs 3 ask for the rack: 9 of the window's 12
-    if fault == "sound":
-        assert count == 0 and not wrong, wrong
+    if fault is None:
+        assert not wrong, wrong
     elif fault == "pod_in_another_rack":
         # the commit is sound but for the tree: one limit fails, alone
         assert wrong.keys() == {"gangs_split_across_domains"}, wrong
-        assert wrong["gangs_split_across_domains"] >= count >= CYCLES // 2
+        assert wrong["gangs_split_across_domains"] >= CYCLES // 2
     else:
         assert wrong.keys() == {"domain_left_pending",
                                 "gangs_bound_short"}, wrong
-        assert wrong["domain_left_pending"] >= count >= CYCLES // 2
+        assert wrong["domain_left_pending"] >= CYCLES // 2
         assert wrong["gangs_bound_short"] >= CYCLES // 2

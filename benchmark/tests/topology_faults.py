@@ -1,5 +1,5 @@
 """Faults against the guarantee ``topology_required``
-(``configs/topology-10k.json``).
+(``configs/topology-10k.json``), planted by ``faults.py``.
 
 Both are planted where the scheduler turns its placements into bind
 requests (``Session.bind_requests_from``), so the server's own store
@@ -8,15 +8,13 @@ within their capacity.
 
 ``pod_in_another_rack``: the last pod of every bound gang that asks for
 the rack as its required level is bound to a node of another rack that
-has an accelerator free.  ``lib/host_model.py`` knows no tree and sees
-a sound commit: of the numbers ``correct`` compares only
-``gangs_split_across_domains`` (``lib/topology_model.py``:
-``TreeHostModel``) can see it, and ``topology_violations`` reads it.
+has an accelerator free.  Of the numbers ``correct`` compares only
+``gangs_split_across_domains`` (``lib/topology_model.py``) can see it.
 
 ``rack_gang_dropped``: every bind of such a gang is dropped, and the
-gang stays pending cycle after cycle though a rack holds all of it
-(``domain_left_pending``); it fits the free capacity too, so
-``gangs_bound_short`` sees it as well.
+gang stays pending cycle after cycle though a rack holds all of it:
+``domain_left_pending``, and ``gangs_bound_short``, whose free slots
+the tree's reference counts inside one rack.
 """
 from __future__ import annotations
 
@@ -91,8 +89,3 @@ def rack_gang_dropped(run):
 
     return _planted(drop)
 
-
-#: name -> a context manager of the run; ``sound`` plants nothing
-FAULTS = {"sound": lambda run: contextlib.nullcontext(),
-          "pod_in_another_rack": pod_in_another_rack,
-          "rack_gang_dropped": rack_gang_dropped}
